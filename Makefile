@@ -8,7 +8,7 @@
 .PHONY: test gate native smoke-faults smoke-examples lint-determinism \
 	bench-hybrid obs-smoke netobs-smoke flows-smoke turns-smoke \
 	fusion-smoke checkpoint-smoke chaos-smoke sweep-smoke \
-	multichip-smoke bench-report check-fixtures
+	multichip-smoke check-fixtures
 
 test: native
 	python -m pytest tests/ -q
@@ -33,23 +33,26 @@ gate: native check-fixtures lint-determinism
 	$(MAKE) sweep-smoke
 	$(MAKE) multichip-smoke
 
-# Runtime fixture dirs (hermdir/, shadow.data/, pytest caches) are
+# Runtime fixture dirs (hermdir/, shadow.data/, pytest caches, the XLA
+# compile cache .jax_cache/, chip-run output chiprun_out/) are
 # .gitignore'd; a force-add or an ignore regression would commit
 # megabytes of run artifacts — fail the gate if any tracked path lands
 # inside them.
 check-fixtures:
 	@bad=$$(git ls-files -- 'hermdir/*' 'shadow.data/*' '*.pyc' \
-	  '.pytest_cache/*' '__pycache__/*' \
+	  '.pytest_cache/*' '__pycache__/*' '.jax_cache/*' 'chiprun_out/*' \
 	  '*/hermdir/*' '*/shadow.data/*' \
 	  '*/.pytest_cache/*' '*/__pycache__/*'); \
 	if [ -n "$$bad" ]; then \
 	  echo "committed runtime fixtures detected:"; echo "$$bad"; exit 1; \
 	fi
 
-# The hybrid backend's short deterministic benchmark (one JSON line):
-# the relay-chain scenario scaled down to CI size, syscall plane on 2
-# worker processes, packet plane on the CPU-JAX lane kernel — no TPU
-# time needed.  The full-scale run is bench.py's hybrid_* keys.
+# The hybrid backend's short deterministic CI smoke (one JSON line): the
+# relay-chain scenario scaled down to CI size, syscall plane on 2 worker
+# processes, packet plane on the CPU-JAX lane kernel.  JAX_PLATFORMS=cpu
+# is this target's own pin, so bench.py accepts the platform and its line
+# says "platform": "cpu" — a code-path check, never a chip number.  The
+# full-scale run is bench.py's hybrid_* keys, on the chip.
 bench-hybrid: native
 	JAX_PLATFORMS=cpu SHADOW_TPU_BENCH_HYBRID_ONLY=1 \
 	  SHADOW_TPU_BENCH_HYBRID_LANES=100 \
@@ -143,10 +146,6 @@ sweep-smoke:
 # (docs/multichip.md).
 multichip-smoke: native
 	JAX_PLATFORMS=cpu python scripts/multichip_smoke.py
-
-# Regenerate docs/bench-trajectory.md from the BENCH_r0N.json artifacts.
-bench-report:
-	python scripts/bench_report.py --write docs/bench-trajectory.md
 
 # Examples smoke for the gate: the phold classic, run twice with a
 # run-twice determinism diff (bit-identical event orderings + counters).

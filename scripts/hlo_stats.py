@@ -1,5 +1,5 @@
 """Dump op-category counts of the compiled bench while-body (static
-analysis — reliable regardless of the shared chip's timing noise).
+analysis — counts only; it says nothing of time).
 
 Usage: python scripts/hlo_stats.py [hosts] [--text out.txt]
 """

@@ -104,6 +104,11 @@ def main(argv=None) -> int:
         "sweep": spec.name,
         "size": sweep.size,
         "backend": sweep.backend,
+        # where the batched lane kernel ran (None for the cpu oracle arm)
+        "device": (
+            sweep.engines[0].device_info() if sweep.backend == "tpu"
+            else None
+        ),
         "traces": sweep.traces,
         "wall_seconds": round(wall, 3),
         "scenarios_per_hour": round(sweep.size * 3600.0 / wall, 1),
@@ -123,4 +128,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from shadow_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -1,18 +1,19 @@
-"""Device-utilization report: UTIL_r{N}.json (VERDICT r4 #10).
+"""Device-utilization report (XLA cost-analysis estimate, not a trace).
 
 For the pure and mixed flagship meshes: wall time per while-iteration on
-the real device, XLA cost-analysis flops / bytes per iteration, and the
-achieved fraction of chip peak (compute and HBM bandwidth) — the ground
-truth the per-round optimization commits cite.
+the default device, XLA cost-analysis flops / bytes per iteration, and
+the estimated fraction of chip peak (compute and HBM bandwidth).  Not
+measured on the attached chip yet; ROADMAP.md A0 replaces the peak
+constants below with a table keyed by ``device_kind``.
 
 Usage: python scripts/util_report.py [out.json]
+       (default: chiprun_out/util_report.json — an ignored directory)
 Env: UTIL_HOSTS (10000), UTIL_SIM_S (5), UTIL_REPEATS (3)
 """
 
 import json
 import os
 import sys
-import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
@@ -61,16 +62,15 @@ def calibrated_fraction(est: float, wall_per_iter: float,
 N = int(os.environ.get("UTIL_HOSTS", "10000"))
 SIM_S = int(os.environ.get("UTIL_SIM_S", "5"))
 REPEATS = int(os.environ.get("UTIL_REPEATS", "3"))
-SALT = ((os.getpid() << 16) ^ int(time.time())) & 0x3FFFFFFF
 
 
 def probe(tag: str, cfg) -> dict:
     import jax
 
     eng = TpuEngine(cfg, log_capacity=0)
-    best = eng.run(mode="device", precompile=True, cache_salt=SALT + 1)
+    best = eng.run(mode="device", precompile=True)
     for i in range(REPEATS - 1):
-        r = eng.run(mode="device", cache_salt=SALT + 2 + i)
+        r = eng.run(mode="device")
         if r.sim_seconds_per_wall_second > best.sim_seconds_per_wall_second:
             best = r
     # cost analysis from the engine's cached executable (no second
@@ -78,15 +78,9 @@ def probe(tag: str, cfg) -> dict:
     # (trip count unknown), so the totals approximate ONE iteration plus
     # prologue/epilogue — they are reported as per-iteration ESTIMATES,
     # not divided by the executed count.
-    flops_body = bytes_body = 0.0
-    try:
-        ca = eng._compiled.cost_analysis()
-        if isinstance(ca, list):
-            ca = ca[0] if ca else {}
-        flops_body = float(ca.get("flops", 0.0))
-        bytes_body = float(ca.get("bytes accessed", 0.0))
-    except Exception:  # cost analysis unsupported on this runtime
-        pass
+    ca = eng._compiled.cost_analysis()
+    flops_body = float(ca.get("flops", 0.0))
+    bytes_body = float(ca.get("bytes accessed", 0.0))
     # resident device state: a hard lower bound on per-iteration traffic
     # (the while carry is read and written every trip)
     state_bytes = sum(
@@ -120,9 +114,13 @@ def probe(tag: str, cfg) -> dict:
 
 
 def main() -> None:
-    # r06: calibrated dict-valued fractions — do not clobber the scalar
-    # UTIL_r05.json artifact that docs/tpu-backend.md and VERDICT.md cite
-    out_path = sys.argv[1] if len(sys.argv) > 1 else "UTIL_r06.json"
+    # default output lands in the ignored chiprun_out/ directory: a
+    # report is a run artifact, never a tracked record
+    out_path = (
+        sys.argv[1] if len(sys.argv) > 1
+        else os.path.join("chiprun_out", "util_report.json")
+    )
+    os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
     pure_cfg = flagship_mesh_config(
         N, sim_seconds=SIM_S, queue_capacity=16, pops_per_round=2
     )

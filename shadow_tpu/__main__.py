@@ -214,4 +214,9 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    # process entry only: main() called as a function (tests,
+    # chip_smoke.py) leaves the cache decision to its caller
+    from shadow_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

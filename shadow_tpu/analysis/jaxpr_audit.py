@@ -117,8 +117,6 @@ def _is_float(v) -> bool:
 
 def audit_jaxpr(closed_jaxpr, label: str) -> list[Finding]:
     """Audit one (closed) jaxpr; ``label`` becomes the finding path."""
-    import jax.core  # noqa: F401  (jax import deferred to call time)
-
     findings: dict[str, Finding] = {}
     # number repeated identical signatures, mirroring the AST pass: a
     # SECOND equation with the same primitive/dtype/shape signature is a

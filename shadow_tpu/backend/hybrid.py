@@ -509,6 +509,10 @@ class HybridEngine(_HostSideHybrid):
         self._flow_pending = None
         self._flow_seq = 0
 
+    def device_info(self) -> dict:
+        """Where the lane data plane ran (TpuEngine.device_info)."""
+        return self.device.device_info()
+
     # -- dynamic runahead ---------------------------------------------------
 
     def current_runahead(self) -> int:
@@ -757,7 +761,7 @@ class HybridEngine(_HostSideHybrid):
 
     def _record_turn_rows(self, turns, t_start: int, host_in: bool) -> None:
         """Record the buffered dispatches of one completed device turn
-        with their causes (docs/observability.md taxonomy): the first
+        with their causes (docs/observability.md classification): the first
         dispatch carries the turn's primary cause — ``injection`` when it
         carried staged rows, else ``host_window`` when the completed
         window has managed participation, else ``free_run`` — and every

@@ -106,7 +106,7 @@ class ExperimentalOptions:
     obs_jax_annotations: bool = False
     obs_dir: Optional[str] = None  # None = general.data_directory
     # device-turn ledger (obs/turns.py): causal per-turn accounting
-    # (cause taxonomy + conservation law) and fusable-run-length
+    # (cause classification + conservation law) and fusable-run-length
     # measurement, exported as TURNS_<backend>-seed<N>.json.  Rows derive
     # from data the host side already holds per turn — zero new
     # host<->device transfers — and are bit-identical at any hybrid

@@ -173,12 +173,12 @@ def mixed_flagship_config(
     the probe/HLO scripts' single source of truth): 1 stream pair per 100
     hosts streaming 2 MB across the datagram mesh.
 
-    Tuning (measured on v5e, round-5 probes — UTIL_r05.json is the
-    ground truth): with the TIERED stream backend the [N] side needs
-    only the pure mesh's queue shape (capacity 16, 2 pops/iter — the
-    pre-tier 48/4 was paying ~46% extra per iteration), and the tier
-    drains at 16 events/iter (8 left ~60% more iterations per window;
-    24 made each iteration dearer than the iterations it saved)."""
+    Tuning (iteration COUNTS are platform-independent; what an
+    iteration costs on the attached chip is not measured): with the
+    TIERED stream backend the [N] side needs only the pure mesh's queue
+    shape (capacity 16, 2 pops/iter), and the tier drains at 16
+    events/iter — 623 iterations per 500 windows, against 803 at 8 and
+    573 at 24 with a wider, dearer co-pop sort (docs/tpu-backend.md)."""
     cfg = flagship_mesh_config(
         n_hosts, sim_seconds=sim_seconds, queue_capacity=16,
         pops_per_round=2, stream_pairs=max(n_hosts // 100, 1),

@@ -154,8 +154,8 @@ def managed_relay_chains_large(
     hybrid_workers: int = 0,
     seed: int = 42,
 ) -> ConfigOptions:
-    """The HYBRID flagship scenario (BENCH_r06 `hybrid_*` keys, ROADMAP
-    open item 1): 100+ managed OS processes (default 151 = 25 three-relay
+    """The HYBRID flagship scenario (bench.py `hybrid_*` keys, ROADMAP
+    A1; chip_smoke.py phase c): 100+ managed OS processes (default 151 = 25 three-relay
     chains + 75 clients + origin) whose syscall plane runs across
     ``hybrid_workers`` processes, over 1k+ lane hosts (default 1000 tgen
     peers) whose data plane — and every managed packet — rides the TPU

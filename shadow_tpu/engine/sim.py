@@ -111,6 +111,7 @@ class Simulation:
         self.restarts = 0
         self.failovers = 0  # TPU->CPU graceful degradations this run
         self.engine = None  # the backend engine of the most recent run()
+        self._announced = False  # start-up log line emitted (once per run)
         self.obs = None  # the run's obs Recorder (shadow_tpu/obs/)
         # crash-safety state (docs/robustness.md): pending resume source
         # (--resume / experimental.resume_from / run-control `resume`),
@@ -238,13 +239,7 @@ class Simulation:
                 "endpoint-bucket law (per-host FIFO; docs/SEMANTICS.md "
                 "deviation 1) — there is no interface queue to interleave"
             )
-        log.info(
-            "starting simulation: %d hosts, stop_time=%s, backend=%s, seed=%d",
-            len(cfg.hosts),
-            stime.fmt(cfg.general.stop_time),
-            backend,
-            cfg.general.seed,
-        )
+        self._announced = False
         # in-process restart loop: a RestartRequest aborts the round loop,
         # the engine is torn down, and a fresh deterministic run begins;
         # a ResumeRequest (run-control `resume <ckpt>`) aborts it too and
@@ -289,6 +284,7 @@ class Simulation:
         if self.obs is not None:
             extra = {
                 "backend": backend,
+                "device": self._device_info(),
                 "seed": cfg.general.seed,
                 "num_hosts": len(cfg.hosts),
                 "sim_time_ns": result.sim_time_ns,
@@ -312,6 +308,34 @@ class Simulation:
         if write_data:
             self._write_data(result, total)
         return result
+
+    def _device_info(self) -> Optional[dict]:
+        """``{platform, kind, count}`` of the JAX devices the current
+        engine's lane state is placed on; None for the cpu engines, which
+        hold no device state.  ``backend`` names the program, this names
+        where it ran (shadow_tpu/device.py)."""
+        info = getattr(self.engine, "device_info", None)
+        return info() if info is not None else None
+
+    def _announce(self) -> None:
+        """The start-up log line.  Emitted once per run, as soon as the
+        first engine exists, so it names the devices the lane state is
+        placed on rather than the backend switch alone."""
+        if self._announced:
+            return
+        self._announced = True
+        from ..device import format_device
+
+        cfg = self.cfg
+        log.info(
+            "starting simulation: %d hosts, stop_time=%s, backend=%s, "
+            "device=%s, seed=%d",
+            len(cfg.hosts),
+            stime.fmt(cfg.general.stop_time),
+            cfg.experimental.network_backend,
+            format_device(self._device_info()),
+            cfg.general.seed,
+        )
 
     def _write_netobs(self, extra: dict) -> None:
         """Write the NETOBS_<run_id>.json telemetry artifact through the
@@ -662,6 +686,7 @@ class Simulation:
         if self.cfg.experimental.perf_logging:
             engine.perf_log = PerfLog()
         engine.obs = self.obs
+        self._announce()
         t0 = wall_time.perf_counter()
         ckpt = self._make_ckpt_hook(
             "cpu",
@@ -741,6 +766,7 @@ class Simulation:
             if self.cfg.experimental.perf_logging:
                 engine.perf_log = PerfLog()
             engine.obs = self.obs
+            self._announce()
             t0 = wall_time.perf_counter()
             on_window = self._make_on_window(
                 engine.describe_next_window, engine.current_runahead, t0
@@ -785,6 +811,7 @@ class Simulation:
                     "experimental.mesh_devices to trace flows"
                 )
             engine.attach_mesh(parallel.make_mesh(n_mesh))
+        self._announce()
         # run-control / perf logging / checkpointing / resume force the
         # step-wise driver (one device call per round, pausable, with
         # host-visible lane state at every boundary); otherwise the
@@ -854,6 +881,7 @@ class Simulation:
             "failovers": self.failovers,
             "restart_work_saved": self.restart_work_saved,
             "backend": self.cfg.experimental.network_backend,
+            "device": self._device_info(),
             "num_hosts": len(self.cfg.hosts),
             "seed": self.cfg.general.seed,
             "counters": dict(sorted(result.counters.items())),

@@ -68,7 +68,7 @@ KIND_NAMES = {
     FT_DELIVERY: "delivery",
 }
 
-# -- FT_DROP aux: the drop-cause taxonomy (matches netobs.DROP_CAUSES) ------
+# -- FT_DROP aux: the drop-cause classification (matches netobs.DROP_CAUSES) ------
 
 CAUSE_LOSS = 0
 CAUSE_CODEL = 1

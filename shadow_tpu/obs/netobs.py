@@ -66,7 +66,7 @@ COUNTERS = (
     "retry_giveup",
 )
 
-#: the drop-cause taxonomy (docs/observability.md)
+#: the drop-cause classification (docs/observability.md)
 DROP_CAUSES = ("loss", "codel", "queue", "cross_shed", "retry_giveup")
 
 TOP_TALKERS = 10

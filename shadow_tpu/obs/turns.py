@@ -1,8 +1,9 @@
 """Device-turn ledger: causal turn accounting + fusion-headroom evidence.
 
 PR 9 measured *how long* the hybrid path's blocking device turns take
-(``device_turn`` = 92.8% of wall on ``managed_relay_chains_large``,
-BENCH_r07); this module records *why each turn exists* and *how many
+(``device_turn`` = 92.8% of wall on ``managed_relay_chains_large`` on
+CPU-JAX; the share on the attached chip is not measured); this module
+records *why each turn exists* and *how many
 consecutive windows could legally have been fused into one dispatch* —
 the instrument ROADMAP open item 1 (k-window device free-run,
 speculative pipelining) designs against, the same way PR 10's
@@ -16,7 +17,7 @@ fused driver's whole free-run — and one window round on the CPU oracle,
 where the "device" is hypothetical and the ledger answers *what a
 device run of this config could legally have fused*.
 
-The **turn-cause taxonomy** — one primary cause per row, decided in
+The **turn-cause classification** — one primary cause per row, decided in
 priority order ``fault_swap`` > ``egress_drain`` > ``injection`` >
 ``host_window`` > ``snapshot``/``free_run``:
 
@@ -93,7 +94,7 @@ from .netobs import hist_bucket as _hist_bucket
 
 SCHEMA_VERSION = 1
 
-#: the turn-cause taxonomy, in report order (docs/observability.md).
+#: the turn-cause classification, in report order (docs/observability.md).
 #: ``rollback`` (PR 13) marks a fused-prefix rebuild dispatch: a k-window
 #: fused turn whose speculation failed validation re-ran its validated
 #: prefix from the checkpoint — the dispatch is real (counted by the

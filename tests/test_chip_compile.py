@@ -1,0 +1,254 @@
+"""AOT compiles for the attached chip, kept as tests (on-chip-measurement §2).
+
+The TPU compiler is installed here and compiles for a DESCRIBED ``v5e:2x2``
+device without a chip attached.  Each case lowers one program of the main
+path at the shape ``chip_smoke.py`` / ``bench.py`` run it, with the
+accelerator branches taken: ``lanes.scan_or_unroll``, the slot body's
+``slot_dataflow`` and sharded donation (``parallel/mesh.py``) all switch on
+``jax.default_backend() != "cpu"``, which every other test pins to the CPU —
+so each case patches ``jax.default_backend`` to ``"tpu"`` around its trace.
+What the compiler refuses here it would refuse on the chip, at no chip time.
+A compile that passes is not a chip run: nothing executes, no number from
+here is a device number.
+
+The topology is described inside a module-scoped fixture that skips when it
+cannot be (only one process may load libtpu; under xdist only the worker
+that is handed this file does) — never at import or collection.  The
+persistent compile cache is off around these compiles: an executable built
+for a described device cannot be read back without the chip.
+
+Full-width compiles of the cases kept narrow here are recorded once in
+CHANGES.md (PR 23).
+"""
+
+import os
+import signal
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from shadow_tpu import parallel
+from shadow_tpu.backend import lanes
+from shadow_tpu.backend.tpu_engine import TpuEngine
+from shadow_tpu.config.presets import (
+    flagship_mesh_config,
+    mixed_flagship_config,
+)
+
+MS = 1_000_000
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler / libtpu held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # Loading libtpu installs a C-level SIGTERM handler that prints a stack
+    # trace before dying.  The tier-1 command is run under `timeout`, which
+    # TERMs the whole process group: that trace would land on pytest's
+    # progress line and the dots on it would go uncounted.  Restore the
+    # default so this worker ends as quietly as every other one.
+    signal.signal(signal.SIGTERM, signal.SIG_DFL)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The program's accelerator branches, steered from the test (never a
+    program option): ``jax.default_backend()`` answers ``"tpu"``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert lanes.jax.default_backend() == "tpu"
+
+
+def _shapes(tree, sharding):
+    """ShapeDtypeStructs on ``sharding`` — a described device holds no
+    array, so programs are lowered against shapes."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _pure_cfg(n_hosts: int, stop_ns: int):
+    """bench.py ``_pure_cfg`` / chip_smoke.py ``pure_cfg``."""
+    cfg = flagship_mesh_config(n_hosts, queue_capacity=16, pops_per_round=2)
+    cfg.experimental.tpu_cross_capacity = 8
+    cfg.general.stop_time = stop_ns
+    return cfg
+
+
+def _fits(compiled, limit_bytes: int = 16 << 30) -> int:
+    mem = compiled.memory_analysis()
+    total = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes
+    )
+    assert 0 < total < limit_bytes, mem
+    return total
+
+
+def test_udp_flagship_run_fn_full_width(one_chip, as_tpu):
+    """The 10 000-lane UDP flagship, fused free-run, exactly as
+    chip_smoke.py phase a sends it through the facade (device log on)."""
+    eng = TpuEngine(_pure_cfg(10_000, 150 * MS))
+    assert eng.params.n_lanes == 10_000
+    state = _shapes(eng.initial_state(), one_chip)
+    compiled = lanes.make_run_fn(eng.params, eng.tables).lower(state).compile()
+    _fits(compiled)
+
+
+def test_udp_round_fn_step_driver(one_chip, as_tpu):
+    """The step driver's one-round kernel (run-control / checkpointing),
+    kept at 1 000 lanes (same body; the full width is the case above)."""
+    eng = TpuEngine(_pure_cfg(1_000, 150 * MS), netobs=True)
+    state = _shapes(eng.initial_state(), one_chip)
+    compiled = lanes.make_round_fn(eng.params, eng.tables).lower(
+        state
+    ).compile()
+    _fits(compiled)
+
+
+def test_mixed_run_fn_tiered(one_chip, as_tpu):
+    """The mixed TCP/UDP mesh on the tiered stream path — the unconditional
+    masked slot body (``slot_dataflow``) only exists off-CPU.  Compile time
+    is set by the unrolled tier walk, not the lane count (~110 s at the
+    flagship's 16 tier pops per iteration at ANY width), so this case keeps
+    1 000 lanes and 4 tier pops: the same branches, a quarter of the
+    unrolled copies.  The flagship compile is recorded in CHANGES.md."""
+    cfg = mixed_flagship_config(1_000)
+    cfg.general.stop_time = 120 * MS
+    cfg.experimental.tpu_stream_events_per_round = 4
+    eng = TpuEngine(cfg)
+    assert eng.params.stream_tiered
+    state = _shapes(eng.initial_state(), one_chip)
+    compiled = lanes.make_run_fn(eng.params, eng.tables).lower(state).compile()
+    _fits(compiled)
+
+
+def _relay_chain_engine(tmp_path):
+    """The device half of ``managed_relay_chains_large`` (151 managed
+    processes over 1 000 lane hosts) without spawning anything: managed
+    hosts are EXTERNAL lanes, as backend/hybrid.py marks them."""
+    from shadow_tpu.backend.hybrid import config_has_managed
+    from shadow_tpu.config.scenarios import managed_relay_chains_large
+    from shadow_tpu.models.base import _REGISTRY
+
+    cfg = managed_relay_chains_large(tmp_path / "data", sim_seconds=4)
+    assert config_has_managed(cfg) and len(cfg.hosts) == 1151
+    external = np.array([
+        any(p.path not in _REGISTRY for p in h.processes) for h in cfg.hosts
+    ])
+    assert int(external.sum()) == 151
+    return cfg, TpuEngine(cfg, external=external)
+
+
+def test_hybrid_turn_inject_and_fused_k(one_chip, as_tpu, tmp_path):
+    """The hybrid backend's three device entry points at the relay-chain-
+    large shape: single-window turn, injection merge, k-window fused turn
+    at the configured ``hybrid_fuse_k``."""
+    cfg, eng = _relay_chain_engine(tmp_path)
+    p = eng.params
+    state = _shapes(eng.initial_state(), one_chip)
+    b = p.inject_batch
+    i32 = np.int32
+    inj = {
+        "valid": jax.ShapeDtypeStruct((b,), np.bool_, sharding=one_chip),
+        **{
+            k: jax.ShapeDtypeStruct((b,), i32, sharding=one_chip)
+            for k in ("dst", "thi", "tlo", "auxh", "auxl", "size")
+        },
+    }
+    never = int(lanes.NEVER32)
+    turn_fn, inject_fn = eng.make_hybrid_fns()
+    # the host passes the external bound as Python ints (hybrid.py)
+    _fits(turn_fn.lower(state, never, never, never, inj).compile())
+    _fits(inject_fn.lower(state, inj).compile())
+    k = int(cfg.experimental.hybrid_fuse_k)
+    slots = max(2 * k, 9)  # HybridEngine._ext_slots
+    assert k >= 2
+    fused_fn, _ = eng.make_hybrid_fns(k, slots)
+    ext = jax.ShapeDtypeStruct((slots,), i32, sharding=one_chip)
+    _fits(fused_fn.lower(state, ext, ext, never, inj, np.int32(k)).compile())
+
+
+def test_sharded_run_fn_on_described_mesh(topo, as_tpu):
+    """``parallel.make_sharded_run_fn`` on a Mesh of the four described
+    devices: GSPMD must insert collectives for the cross-lane exchange, and
+    the donated lane state must alias (donation is on off-CPU).  1 000
+    lanes here; the 10 000- and 100 000-lane compiles are in CHANGES.md."""
+    eng = TpuEngine(_pure_cfg(1_000, 150 * MS), log_capacity=0)
+    mesh = Mesh(np.array(topo.devices), (parallel.HOST_AXIS,))
+    assert mesh.devices.size == 4
+    sh = parallel.state_shardings(mesh)
+    # per FIELD, not per leaf: planes compiled out are empty tuples, and
+    # the stream field is a nested pytree under one (replicated) sharding
+    state = lanes.LaneState(**{
+        f: _shapes(getattr(eng.initial_state(), f), getattr(sh, f))
+        for f in lanes.LaneState._fields
+    })
+    run_fn = parallel.make_sharded_run_fn(eng.params, eng.tables, mesh)
+    compiled = run_fn.lower(state).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    collectives = [
+        op for op in ("all-gather", "all-reduce", "collective-permute",
+                      "all-to-all", "reduce-scatter")
+        if op in text
+    ]
+    assert collectives, "no collective in the sharded program"
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 0, "donated state did not alias"
+    # the lane axis really is split: per-device argument bytes are a
+    # fraction of the whole state's
+    whole = sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(state)
+    )
+    assert mem.argument_size_in_bytes < whole
+
+
+def test_sweep_kernel_bench_shape(one_chip, as_tpu):
+    """The fleet-sweep kernel at bench.py's sweep shape: 8 scenarios x
+    1 000 lanes, tables/stop bounds/states all traced and stacked."""
+    from shadow_tpu.sweep import SweepSpec, expand_variants
+
+    cfg = flagship_mesh_config(
+        1_000, sim_seconds=5, queue_capacity=16, pops_per_round=2
+    )
+    cfg.experimental.tpu_cross_capacity = 8
+    variants = expand_variants(cfg, SweepSpec.seed_grid(cfg.general.seed, 8))
+    eng = TpuEngine(variants[0].cfg, log_capacity=0)
+
+    def stacked(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                (8, *np.shape(x)), x.dtype, sharding=one_chip
+            ),
+            tree,
+        )
+
+    stop = jax.ShapeDtypeStruct((8,), np.int32, sharding=one_chip)
+    sweep_fn = eng.make_sweep_fn()
+    compiled = sweep_fn.lower(
+        stacked(eng.sweep_tables()), stop, stop, stacked(eng.initial_state())
+    ).compile()
+    _fits(compiled)
+    assert sweep_fn.traces == 1

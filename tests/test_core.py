@@ -57,7 +57,7 @@ def test_threefry_matches_jax_reference():
     # Our generic implementation must match JAX's own threefry2x32 bit-for-bit
     # so jax.random keys and ours share one cipher.
     import jax.numpy as jnp
-    from jax._src import prng as jprng
+    from jax.extend import random as jprng
 
     k = (np.uint32(0x13198A2E), np.uint32(0x03707344))
     counts = np.arange(16, dtype=np.uint32)

@@ -157,7 +157,7 @@ class TestDeviceOracleParity:
     def test_drop_heavy_parity_fused(self):
         sc, st = _snapshots(_drop_heavy_cfg(backend="tpu"))
         _assert_snap_equal(sc, st)
-        # the scenario actually exercises the taxonomy: loss AND codel
+        # the scenario actually exercises the classification: loss AND codel
         # drops AND bucket throttles are all nonzero
         tot = nom.totals(sc["arrays"])
         assert tot["drop_loss"] > 0

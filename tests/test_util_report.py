@@ -53,12 +53,13 @@ def test_no_data_cases():
     assert UR.calibrated_fraction(10.0, 0.0, 1000.0)["frac"] is None
 
 
-def test_default_output_does_not_clobber_r05_artifact():
-    # UTIL_r05.json holds the scalar-schema round-5 record cited by
-    # docs/tpu-backend.md and VERDICT.md; the recalibrated dict-schema
-    # output must land in a new round file by default
-    path = Path(__file__).resolve().parents[1] / "scripts" / "util_report.py"
-    assert "UTIL_r06.json" in path.read_text()
+def test_default_output_is_an_ignored_run_artifact():
+    # a utilization report is a run artifact: by default it lands in the
+    # ignored chiprun_out/ directory, never in a tracked root-level file
+    root = Path(__file__).resolve().parents[1]
+    text = (root / "scripts" / "util_report.py").read_text()
+    assert '"chiprun_out"' in text and "UTIL_r0" not in text
+    assert "chiprun_out/" in (root / ".gitignore").read_text().split()
 
 
 @pytest.mark.parametrize(
