@@ -3326,12 +3326,18 @@ static void *shim_thread_tramp(void *p) {
     if (g_sud_on) sud_arm();
     if (g_tsc_on) tsc_arm();
     shim_thread_boot boot = *(shim_thread_boot *)p;
-    free(p);
     t_shm = boot.shm;
     t_vtid = boot.vtid;
     /* parks here until the thread's start event fires in the simulation */
     int64_t args[6] = {boot.vtid, 0, 0, 0, 0, 0};
     shim_call(SHIM_OP_THREAD_START, args, NULL, 0, NULL, NULL, NULL);
+    /* only now: until its first turn this thread runs BESIDE its creator
+     * (the manager has answered THREAD_CREATED), and free() contending
+     * with the creator's next malloc is a raw futex from libc text — armed
+     * above, it was emulated on cur_shm() = the MAIN thread's channel,
+     * t_shm not being set yet: two threads on one channel, seen under CPU
+     * load as a plugin that stops answering or a garbled PRETHREAD path */
+    free(p);
     void *ret = boot.start(boot.arg);
     thread_send_exit(ret);
     return ret;

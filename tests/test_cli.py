@@ -96,5 +96,11 @@ def test_simulation_tpu_mesh_shape(tmp_path):
     cfg = ConfigOptions.from_yaml(yaml)
     cfg.experimental.network_backend = "tpu"
     cfg.experimental.tpu_mesh_shape = (2,)
-    result = Simulation(cfg).run(write_data=False)
+    # the alias reaching the engine is the point, not the pop count: at
+    # the default 8 pops a sharded run on XLA:CPU takes minutes (see
+    # tests/test_multichip.py::_phold_cfg)
+    cfg.experimental.tpu_events_per_round = 2
+    sim = Simulation(cfg)
+    result = sim.run(write_data=False)
+    assert sim.engine.mesh is not None and sim.engine.mesh.devices.size == 2
     assert len(result.event_log) == 8

@@ -140,3 +140,16 @@ def test_dryrun_multichip_requires_existing_devices():
     with pytest.raises(RuntimeError, match="needs"):
         graft.dryrun_multichip(have * 2)
     assert len(jax.devices()) == have  # the backend was not rebuilt
+
+
+def test_graft_entry_single_chip():
+    graft = _load("_graft_under_test", REPO / "__graft_entry__.py")
+    fn, args = graft.entry()
+    out, done = jax.jit(fn)(*args)
+    jax.block_until_ready(out)
+    assert not bool(done)
+
+
+@pytest.mark.slow
+def test_graft_dryrun_multichip():
+    _load("_graft_under_test", REPO / "__graft_entry__.py").dryrun_multichip(8)

@@ -3,11 +3,15 @@
 The contracts under test (conftest.py forces 8 virtual CPU devices, so
 every mesh shape here runs on any box):
 
-1. **Device-count invariance** — the full Simulation facade with
-   ``experimental.mesh_devices`` set produces a bit-identical event log
-   AND a byte-identical ``NETOBS_*.json`` artifact at every mesh shape,
-   netobs ON (the per-host counter block shards with its lanes, the
-   [24] window histogram shard-then-reduces).
+1. **Device-count invariance** — the mesh shape never changes results
+   (the multi-chip analog of the reference's src/test/determinism/).
+   The raw ``parallel.make_sharded_run_fn`` driver ends in a final
+   ``LaneState`` equal field by field to the single-device run's (log
+   as a set) and to the CPU oracle's log; the full Simulation facade
+   with ``experimental.mesh_devices`` set produces a bit-identical
+   event log AND a byte-identical ``NETOBS_*.json`` artifact at every
+   mesh shape, netobs ON (the per-host counter block shards with its
+   lanes, the [24] window histogram shard-then-reduces).
 2. **Classification exhaustiveness** — ``parallel.check_classification``
    rejects unclassified, stale, and double-classified LaneState fields,
    so a future field cannot silently pick up the wrong sharding.
@@ -26,11 +30,13 @@ import copy
 import json
 import logging
 
+import jax
 import numpy as np
 import pytest
 
 from shadow_tpu import parallel
 from shadow_tpu.backend import lanes
+from shadow_tpu.backend.cpu_engine import CpuEngine
 from shadow_tpu.backend.tpu_engine import LaneCompatError, TpuEngine
 from shadow_tpu.config.columnar import columnar_mesh_config
 from shadow_tpu.config.options import ConfigOptions
@@ -41,8 +47,12 @@ pytestmark = pytest.mark.multichip
 
 
 def _phold_cfg(data_dir, mesh_devices: int = 0) -> ConfigOptions:
-    """8 phold hosts with netobs on — cheap to compile (2 pops/round)
-    and divisible by every mesh shape up to 8."""
+    """8 phold hosts with netobs on, divisible by every mesh shape up to
+    8.  2 pops/round, not the default 8: under a mesh the slot walk is
+    a Python-unrolled body (``lanes._force_unroll``), and on XLA:CPU's
+    per-op thunk dispatch 8 unrolled pops need 20-40 s to reach EACH
+    all-gather rendezvous — a run that never ends in useful time — where
+    2 pops end in seconds.  The invariance law does not depend on K."""
     return ConfigOptions.from_yaml(f"""
 general: {{stop_time: 300ms, seed: 11, data_directory: {data_dir},
            heartbeat_interval: null}}
@@ -66,7 +76,64 @@ def _facade_run(tmp_path, d: int):
     return res.log_tuples(), arts[0].read_bytes(), sim.engine
 
 
+def _final_state(engine: TpuEngine, mesh=None) -> lanes.LaneState:
+    """Final state of the fused free-run: single-device, or through the
+    raw sharded run function under ``mesh``."""
+    state = engine.initial_state()
+    if mesh is None:
+        run = lanes.make_run_fn(engine.params, engine.tables)
+    else:
+        state = parallel.shard_state(state, mesh)
+        run = parallel.make_sharded_run_fn(engine.params, engine.tables, mesh)
+    return jax.block_until_ready(run(state))
+
+
 # -- 1. device-count invariance, netobs on --------------------------------
+
+
+# the only tier-1 test of the raw sharded run function; tier-1 keeps the
+# 2-device shape, the 8-device shapes run slow-marked and at gate scale in
+# `make multichip-smoke`
+@pytest.mark.parametrize(
+    "n_devices", [2, pytest.param(8, marks=pytest.mark.slow)]
+)
+def test_sharded_run_bit_identical(tmp_path, monkeypatch, n_devices):
+    walks = []  # (trace is under _force_unroll, site is lane-axis) per walk
+    real_walk = lanes.scan_or_unroll
+
+    def spy(step, carry, xs, length, spmd_unroll=False):
+        walks.append((lanes._SPMD_UNROLL, spmd_unroll))
+        return real_walk(step, carry, xs, length, spmd_unroll)
+
+    monkeypatch.setattr(lanes, "scan_or_unroll", spy)
+    engine = TpuEngine(_phold_cfg(tmp_path))
+    single = _final_state(engine)
+    assert walks and not any(forced for forced, _ in walks)
+    sharded = _final_state(engine, parallel.make_mesh(n_devices))
+    # under the mesh the slot walk took the forced-unroll form, the one a
+    # chip runs — the cheap config must not buy its speed by dodging it
+    assert (True, True) in walks
+    # an idle run would pass the comparison below vacuously
+    assert int(single.log_count) > 0 and int(single.rounds) > 1
+    for field in lanes.LaneState._fields:
+        a, b = np.asarray(getattr(single, field)), np.asarray(getattr(sharded, field))
+        if field == "log":
+            n = int(single.log_count)
+            a, b = a[:n], b[:n]
+            # log append order may differ across shardings; content may not
+            a = a[np.lexsort(a.T[::-1])]
+            b = b[np.lexsort(b.T[::-1])]
+        np.testing.assert_array_equal(a, b, err_msg=field)
+
+
+@pytest.mark.slow
+def test_sharded_matches_cpu_reference(tmp_path):
+    cfg = _phold_cfg(tmp_path)
+    cpu = CpuEngine(cfg).run()
+    engine = TpuEngine(cfg)
+    final = _final_state(engine, parallel.make_mesh(8))
+    tpu = engine.collect(final, wall=0.0)
+    assert cpu.log_tuples() == tpu.log_tuples()
 
 
 def test_facade_invariant_2dev(tmp_path):
@@ -263,10 +330,13 @@ def native_build():
 def test_hybrid_sync_stats_unchanged_under_mesh(tmp_path, native_build):
     from tests.test_turns import _hybrid_cfg
 
-    base = Simulation(_hybrid_cfg(tmp_path / "h0", workers=1, turns=False))
+    cfg = _hybrid_cfg(tmp_path / "h0", workers=1, turns=False)
+    cfg.experimental.tpu_events_per_round = 2  # see _phold_cfg
+    base = Simulation(cfg)
     r0 = base.run(write_data=False)
     s0 = dict(base.engine.sync_stats)
     cfg = _hybrid_cfg(tmp_path / "h2", workers=1, turns=False)
+    cfg.experimental.tpu_events_per_round = 2
     cfg.experimental.mesh_devices = 2
     meshed = Simulation(cfg)
     r2 = meshed.run(write_data=False)
