@@ -435,6 +435,12 @@ class HybridEngine(_HostSideHybrid):
             "async_dispatch_hits": 0,    # eager dispatches adopted at the barrier
             "async_dispatch_misses": 0,  # eager dispatches discarded (inputs diverged)
             "dispatch_retries": 0,  # failed fused dispatches re-dispatched
+            # how often the device's record appends engaged, read once
+            # at collect (lanes._append_rows; per iteration: divide by
+            # the lane_iters counter):
+            "append_blocks": 0,       # block writes, log and egress
+            "append_rows": 0,         # rows those blocks wrote
+            "append_tail_blocks": 0,  # of them, for queue-overflow records
         }
         # k-window free-run fusion knobs (docs/hybrid.md "k-window fusion
         # law"): fuse_k == 1 keeps the PR 7 one-dispatch-per-participating-
@@ -1488,6 +1494,7 @@ class HybridEngine(_HostSideHybrid):
         wall = wall_time.perf_counter() - t0
 
         dev_result = self.device.collect(state, wall)
+        self.sync_stats.update(self.device.append_stats)
         counters: dict[str, int] = dict(dev_result.counters)
         for h in self.hosts:
             for k, v in h.counters.items():
@@ -1815,6 +1822,7 @@ class MpHybridEngine(HybridEngine):
         wall = wall_time.perf_counter() - t0
 
         dev_result = self.device.collect(state, wall)
+        self.sync_stats.update(self.device.append_stats)
         for k, v in dev_result.counters.items():
             counters[k] = counters.get(k, 0) + v
         return SimResult(

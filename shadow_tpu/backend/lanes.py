@@ -271,6 +271,13 @@ class LaneState(NamedTuple):
     fl_buf: Any = ()  # [FL, flowtrace.FT_COLS] int32 event rows
     fl_count: Any = ()  # int32 scalar: rows appended
     fl_lost: Any = ()  # int32 scalar: events dropped on ring overflow
+    # engage counters of the record appends (_append_rows), summed over the
+    # log's and the egress buffer's appends of the run: int32 scalars, read
+    # once at collect (never in SimResult.counters — the oracle has none).
+    # () when neither buffer exists, so a log-off program carries nothing.
+    ap_blocks: Any = ()  # block writes
+    ap_rows: Any = ()  # rows those blocks wrote
+    ap_tail_blocks: Any = ()  # block writes for merge-tail overflow records
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1607,7 +1614,6 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     Returns (state, overflow log-record dict).
     """
     n, c = p.n_lanes, p.capacity
-    i64 = jnp.int64
     sp = p.stream_present
 
     # -- same-lane block [N, 2K] (3K with the stream RTO channel; K when
@@ -1894,33 +1900,37 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
         ))
 
     # overflow log records from the merge tail (pre-gather losses surface
-    # only in n_queue; both paths raise in strict mode).  Only materialized
-    # when logging is on: the int64 joins are edge work the bench never pays
-    if p.log_capacity == 0:
-        over_rec = None
-    else:
-        t_tail = t_join(mthi[:, c:], mtlo[:, c:])
-        o_kind, o_src = unpack_aux_hi(mh[:, c:])
-        rows = jnp.broadcast_to(
-            jnp.arange(n, dtype=jnp.int64)[:, None], tail_mask.shape
-        )
-        over_rec = {
-            "valid": tail_mask.reshape(-1),
-            "time": t_tail.reshape(-1),
-            "src": o_src.reshape(-1).astype(i64),
-            "dst": rows.reshape(-1),
-            "seq": ml[:, c:].reshape(-1).astype(i64),
-            "size": ms[:, c:].reshape(-1).astype(i64),
-            "outcome": jnp.full(tail_mask.size, DROP_QUEUE, dtype=i64),
-        }
+    # only in n_queue; both paths raise in strict mode).  With the log on
+    # (the hybrid turn's TIMED program, the mesh cells' check program) the
+    # whole [N, 2K + Cx] tail is offered every iteration and a sound run
+    # writes none of it, so the columns stay int32 and only written rows
+    # become int64 (_append_rows).  Log off (the mesh cells' timed program):
+    # nothing is traced
+    if p.log_capacity:
+        s = _append_log(p, s, _tail_records(
+            tail_mask, mthi[:, c:], mtlo[:, c:], mh[:, c:], ml[:, c:],
+            ms[:, c:], jnp.arange(n, dtype=jnp.int32),
+        ), tail=True)
     if split_se:
-        s, over_b = _merge_stream_rows(p, tb, s, emits)
-        if over_rec is not None and over_b is not None:
-            over_rec = {
-                k: jnp.concatenate([over_rec[k], over_b[k]])
-                for k in over_rec
-            }
-    return (s, over_rec, tier_cross) if divert else (s, over_rec)
+        s = _merge_stream_rows(p, tb, s, emits)
+    return (s, tier_cross) if divert else s
+
+
+def _tail_records(tail_mask, thi, tlo, auxh, auxl, size, lane_ids):
+    """DROP_QUEUE log records for a merge tail ``[rows, T]`` (what a row
+    sort pushed past the queue's capacity), flat in row-major order;
+    ``lane_ids`` names each row's lane."""
+    _kind, o_src = unpack_aux_hi(auxh)
+    return {
+        "valid": tail_mask.reshape(-1),
+        "time": (thi.reshape(-1), tlo.reshape(-1)),
+        "src": o_src.reshape(-1),
+        "dst": jnp.broadcast_to(lane_ids[:, None], tail_mask.shape
+                                ).reshape(-1),
+        "seq": auxl.reshape(-1),
+        "size": size.reshape(-1),
+        "outcome": DROP_QUEUE,
+    }
 
 
 def _merge_stream_rows(p: LaneParams, tb: LaneTables, s: LaneState,
@@ -1938,7 +1948,6 @@ def _merge_stream_rows(p: LaneParams, tb: LaneTables, s: LaneState,
     revived here; strict mode (the default) raises on any shed either
     way, and non-strict overflow is documented non-parity."""
     n, c = p.n_lanes, p.capacity
-    i64 = jnp.int64
     kk, s2 = emits.se_valid.shape
     s_flows = s2 // 2
     bb = emits.bo_valid.shape[1]
@@ -2037,52 +2046,107 @@ def _merge_stream_rows(p: LaneParams, tb: LaneTables, s: LaneState,
             fq_rows, ml[:, c:], ms[:, c:], ftr.CAUSE_QUEUE,
         ))
     if p.log_capacity == 0:
-        return s, None
-    t_tail = t_join(mthi[:, c:], mtlo[:, c:])
-    _k, o_src = unpack_aux_hi(mh[:, c:])
-    rows64 = jnp.broadcast_to(
-        el.astype(i64)[:, None], tail_mask.shape
-    )
-    over_rec = {
-        "valid": tail_mask.reshape(-1),
-        "time": t_tail.reshape(-1),
-        "src": o_src.reshape(-1).astype(i64),
-        "dst": rows64.reshape(-1),
-        "seq": ml[:, c:].reshape(-1).astype(i64),
-        "size": ms[:, c:].reshape(-1).astype(i64),
-        "outcome": jnp.full(tail_mask.size, DROP_QUEUE, dtype=i64),
-    }
-    return s, over_rec
-
-
-def _append_log(p: LaneParams, s: LaneState, recs) -> LaneState:
-    """Append valid records to the device event log (if enabled)."""
-    if p.log_capacity == 0 or recs is None:
         return s
-    valid = recs["valid"]
-    offs = jnp.cumsum(valid.astype(jnp.int32)) - 1
-    pos = s.log_count + offs
-    ok = valid & (pos < p.log_capacity)
-    idx = jnp.where(ok, pos, p.log_capacity)
-    row = jnp.stack(
-        [
-            recs["time"],
-            recs["src"],
-            recs["dst"],
-            recs["seq"],
-            recs["size"],
-            recs["outcome"],
-        ],
-        axis=1,
+    return _append_log(p, s, _tail_records(
+        tail_mask, mthi[:, c:], mtlo[:, c:], mh[:, c:], ml[:, c:],
+        ms[:, c:], el,
+    ), tail=True)
+
+
+# rows per block write of an append (see _append_rows).  A block costs the
+# chip ~9 us plus ~0.03 us per row (TPU v5e, PERF.md PR 27), so 256 keeps a
+# sparse append (the hybrid turn: tens of records among 9 208 candidates)
+# near the floor while a dense one (the mesh check program: ~10 000 of
+# 20 000) takes ~40 trips and still a third of the old all-candidate
+# scatter's time.
+_APPEND_BLOCK = 256
+
+
+def _append_rows(buf, count, valid, rows_at):
+    """Append the valid candidates of a flat batch to ``buf`` at ``count``,
+    in flat order: the j-th valid candidate lands on row ``count + j``, rows
+    past the buffer's end are lost, everything else in ``buf`` stays as it
+    was (the cumsum-position law the log, the egress buffer and the flow
+    ring share, bit for bit).
+
+    The cost follows the rows WRITTEN, not the ``M`` candidates offered: the
+    valid rows go out in contiguous blocks of ``R = min(_APPEND_BLOCK, M,
+    capacity)`` rows — a rank search in ``cumsum(valid)`` names each block's
+    source candidates, ``rows_at(pick)`` builds the ``[R, cols]`` block from
+    them (``pick(col)`` gathers a flat ``[M]`` column at those R sources, so
+    wide columns are built on R rows, not on M), and one
+    ``dynamic_update_slice`` writes it.  Zero trips when nothing is valid,
+    one when sparse, ``ceil(kept / R)`` when dense: one code path, and the
+    trip count is the only thing that adapts.  A block that would run past
+    the buffer's end is slid back (``dynamic_update_slice`` would clamp its
+    start anyway) and keeps the rows it overlaps.
+
+    Returns ``(buf, n_valid, n_kept, blocks)`` as int32 scalars."""
+    i32 = jnp.int32
+    cap, m = buf.shape[0], valid.shape[0]
+    r = min(_APPEND_BLOCK, m, cap)
+    csum = jnp.cumsum(valid.astype(i32))
+    n = csum[-1]
+    n_kept = jnp.clip(cap - count, 0, n)
+    blocks = (n_kept + (r - 1)) // r
+    row_i = jnp.arange(r, dtype=i32)
+
+    def write_block(b, out):
+        start = jnp.minimum(count + b * r, cap - r)
+        rank = start + row_i - count  # of the candidate each row would hold
+        mine = (rank >= b * r) & (rank < n)
+        src = jnp.minimum(
+            jnp.searchsorted(csum, rank + 1, side="left",
+                             method="compare_all").astype(i32),
+            m - 1,
+        )
+        rows = rows_at(lambda col: col[src])
+        at = (start, i32(0))
+        old = lax.dynamic_slice(out, at, (r, out.shape[1]))
+        return lax.dynamic_update_slice(
+            out, jnp.where(mine[:, None], rows, old), at
+        )
+
+    buf = lax.fori_loop(i32(0), blocks, write_block, buf)
+    return buf, n, n_kept, blocks
+
+
+def _append_log(p: LaneParams, s: LaneState, recs, tail: bool = False
+                ) -> LaneState:
+    """Append valid records to the device event log (if enabled).
+
+    ``recs`` holds flat ``[M]`` columns: ``valid``, ``time`` (int64, or an
+    ``(hi, lo)`` int32 pair joined on the written rows only), and ``src``,
+    ``dst``, ``seq``, ``size``, ``outcome`` as arrays of any integer type
+    or scalars.  ``tail`` marks the queue-overflow records of a merge
+    tail, which no sound run has, for the engage counters."""
+    if p.log_capacity == 0:
+        return s
+    i64 = jnp.int64
+
+    def rows_at(pick):
+        t = recs["time"]
+        cols = [t_join(pick(t[0]), pick(t[1])) if isinstance(t, tuple)
+                else pick(t)]
+        for key in ("src", "dst", "seq", "size", "outcome"):
+            c = recs[key]
+            cols.append(pick(c).astype(i64) if jnp.ndim(c)
+                        else jnp.full(cols[0].shape, c, dtype=i64))
+        return jnp.stack(cols, axis=1)
+
+    log, n, n_kept, blocks = _append_rows(
+        s.log, s.log_count, recs["valid"], rows_at
     )
-    log = s.log.at[idx].set(row, mode="drop")
-    n_valid = valid.sum(dtype=jnp.int32)
-    n_kept = ok.sum(dtype=jnp.int32)
-    return s._replace(
+    s = s._replace(
         log=log,
-        log_count=s.log_count + n_valid,
-        log_lost=s.log_lost + (n_valid - n_kept),
+        log_count=s.log_count + n,
+        log_lost=s.log_lost + (n - n_kept),
+        ap_blocks=s.ap_blocks + blocks,
+        ap_rows=s.ap_rows + n_kept,
     )
+    if tail:
+        s = s._replace(ap_tail_blocks=s.ap_tail_blocks + blocks)
+    return s
 
 
 def flow_hash_lane(src, dst, seed: int):
@@ -2126,36 +2190,21 @@ def _append_flow(p: LaneParams, s: LaneState, rows) -> LaneState:
     if not p.flowtrace:
         return s
     i32 = jnp.int32
-    valid = rows["valid"]
-    m = valid.shape[0]
-    offs = jnp.cumsum(valid.astype(i32)) - 1
-    pos = s.fl_count + offs
-    ok = valid & (pos < p.flow_capacity)
-    idx = jnp.where(ok, pos, p.flow_capacity)
-    we_hi = jnp.broadcast_to(s.now_we_hi, (m,)).astype(i32)
-    we_lo = jnp.broadcast_to(s.now_we_lo, (m,)).astype(i32)
-    row = jnp.stack(
-        [
-            rows["t_hi"].astype(i32),
-            rows["t_lo"].astype(i32),
-            we_hi,
-            we_lo,
-            rows["kind"].astype(i32),
-            rows["src"].astype(i32),
-            rows["dst"].astype(i32),
-            rows["seq"].astype(i32),
-            rows["size"].astype(i32),
-            rows["aux"].astype(i32),
-        ],
-        axis=1,
+
+    def rows_at(pick):
+        c = [pick(rows[k]).astype(i32) for k in
+             ("t_hi", "t_lo", "kind", "src", "dst", "seq", "size", "aux")]
+        we = [jnp.broadcast_to(w, c[0].shape).astype(i32)
+              for w in (s.now_we_hi, s.now_we_lo)]
+        return jnp.stack(c[:2] + we + c[2:], axis=1)
+
+    fl_buf, n, n_kept, _blocks = _append_rows(
+        s.fl_buf, s.fl_count, rows["valid"], rows_at
     )
-    fl_buf = s.fl_buf.at[idx].set(row, mode="drop")
-    n_valid = valid.sum(dtype=i32)
-    n_kept = ok.sum(dtype=i32)
     return s._replace(
         fl_buf=fl_buf,
-        fl_count=s.fl_count + n_valid,
-        fl_lost=s.fl_lost + (n_valid - n_kept),
+        fl_count=s.fl_count + n,
+        fl_lost=s.fl_lost + (n - n_kept),
     )
 
 
@@ -2226,25 +2275,24 @@ def _append_egress(p: LaneParams, s: LaneState, valid, delivered,
     the running min pending delivery time (the device free-run guard —
     the loop must not advance a window past an unserviced host delivery);
     DROP_CODEL rows only release the host's parked payload."""
-    offs = jnp.cumsum(valid.astype(jnp.int32)) - 1
-    pos = s.egress_count + offs
-    ok = valid & (pos < p.egress_capacity)
-    idx = jnp.where(ok, pos, p.egress_capacity)
     i64 = jnp.int64
-    row = jnp.stack(
-        [
-            t_join(td_hi, td_lo),
-            src.astype(i64),
-            dst.astype(i64),
-            seq.astype(i64),
-            size.astype(i64),
-            jnp.where(delivered, DELIVERED, DROP_CODEL).astype(i64),
-        ],
-        axis=1,
+
+    def rows_at(pick):
+        return jnp.stack(
+            [
+                t_join(pick(td_hi), pick(td_lo)),
+                pick(src).astype(i64),
+                pick(dst).astype(i64),
+                pick(seq).astype(i64),
+                pick(size).astype(i64),
+                jnp.where(pick(delivered), DELIVERED, DROP_CODEL).astype(i64),
+            ],
+            axis=1,
+        )
+
+    egress, n, n_kept, blocks = _append_rows(
+        s.egress, s.egress_count, valid, rows_at
     )
-    egress = s.egress.at[idx].set(row, mode="drop")
-    n_valid = valid.sum(dtype=jnp.int32)
-    n_kept = ok.sum(dtype=jnp.int32)
     live = valid & delivered
     mh, ml = pair_min_lanes(
         jnp.where(live, td_hi, NEVER32), jnp.where(live, td_lo, NEVER32)
@@ -2252,10 +2300,12 @@ def _append_egress(p: LaneParams, s: LaneState, valid, delivered,
     is_lt = pair_lt(mh, ml, s.egress_min_hi, s.egress_min_lo)
     return s._replace(
         egress=egress,
-        egress_count=s.egress_count + n_valid,
-        egress_lost=s.egress_lost + (n_valid - n_kept),
+        egress_count=s.egress_count + n,
+        egress_lost=s.egress_lost + (n - n_kept),
         egress_min_hi=jnp.where(is_lt, mh, s.egress_min_hi),
         egress_min_lo=jnp.where(is_lt, ml, s.egress_min_lo),
+        ap_blocks=s.ap_blocks + blocks,
+        ap_rows=s.ap_rows + n_kept,
     )
 
 
@@ -2784,7 +2834,7 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
     ])
     s = s._replace(stream=ts._replace(q=q, v=v))
 
-    # ---- log appends (edge work; the bench runs log_capacity=0) ----------
+    # ---- log appends (log on: the hybrid turn, the mesh cells' check) ----
     if log_on:
         el64 = el.astype(i64)
         pe64 = tb.flow_peers.astype(i64)
@@ -2805,7 +2855,7 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
             "src": el64_k, "dst": pe64_k,
             "seq": outs["srec_seq"].reshape(-1),
             "size": outs["srec_size"].reshape(-1),
-            "outcome": jnp.full(kk * s2, DROP_LOSS, dtype=i64),
+            "outcome": DROP_LOSS,
         })
         shape_b = outs["brec_valid"].shape  # [K, B, S]
         el64_b = jnp.broadcast_to(
@@ -2818,8 +2868,7 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
             "src": el64_b, "dst": pe64_b,
             "seq": outs["brec_seq"].reshape(-1),
             "size": outs["brec_size"].reshape(-1),
-            "outcome": jnp.full(
-                shape_b[0] * shape_b[1] * s_flows, DROP_LOSS, dtype=i64),
+            "outcome": DROP_LOSS,
         })
         if p.stream_pcap:
             s = _append_log(p, s, {
@@ -2828,7 +2877,7 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
                 "src": el64_k, "dst": pe64_k,
                 "seq": outs["spc_seq"].reshape(-1),
                 "size": outs["spc_size"].reshape(-1),
-                "outcome": jnp.full(kk * s2, PCAP_TX, dtype=i64),
+                "outcome": PCAP_TX,
             })
             s = _append_log(p, s, {
                 "valid": outs["bpc_valid"].reshape(-1),
@@ -2836,22 +2885,13 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
                 "src": el64_b, "dst": pe64_b,
                 "seq": outs["bpc_seq"].reshape(-1),
                 "size": outs["bpc_size"].reshape(-1),
-                "outcome": jnp.full(
-                    shape_b[0] * shape_b[1] * s_flows, PCAP_TX, dtype=i64),
+                "outcome": PCAP_TX,
             })
         # queue-overflow records
-        t_tail = t_join(mthi[:, c2:], mtlo[:, c2:])
-        _k2, o_src = unpack_aux_hi(mh[:, c2:])
-        rows64 = jnp.broadcast_to(el64[:, None], tail_mask.shape)
-        s = _append_log(p, s, {
-            "valid": tail_mask.reshape(-1),
-            "time": t_tail.reshape(-1),
-            "src": o_src.reshape(-1).astype(i64),
-            "dst": rows64.reshape(-1),
-            "seq": ml[:, c2:].reshape(-1).astype(i64),
-            "size": ms[:, c2:].reshape(-1).astype(i64),
-            "outcome": jnp.full(tail_mask.size, DROP_QUEUE, dtype=i64),
-        })
+        s = _append_log(p, s, _tail_records(
+            tail_mask, mthi[:, c2:], mtlo[:, c2:], mh[:, c2:],
+            ml[:, c2:], ms[:, c2:], el,
+        ), tail=True)
     return s
 
 
@@ -3072,17 +3112,13 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
         if tiered:
             # unconditional merge (the tier needs the diverted cross rows
             # every iteration), then the [2S] stream tier's own pass
-            s, over_rec, tier_cross = _merge_append(
-                p_lane, tb, s, emits, divert=True
-            )
-            s = _append_log(p, s, over_rec)
+            s, tier_cross = _merge_append(p_lane, tb, s, emits, divert=True)
             s = _stream_tier_iter(p, tb, s, we_hi, we_lo, tier_cross)
         elif pure_dataflow:
             # always merge: a merge whose insert channels are all empty
             # reduces to the row re-sort that restores the sorted
             # invariant, so one unconditional path replaces the cond
-            s, over_rec = _merge_append(p, tb, s, emits)
-            s = _append_log(p, s, over_rec)
+            s = _merge_append(p, tb, s, emits)
         else:
             # the merge (exchange + wide row sort) is the expensive step;
             # iterations that generated nothing only need the invariant
@@ -3101,8 +3137,7 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                 )
 
             def do_merge(st: LaneState) -> LaneState:
-                st, over_rec = _merge_append(p, tb, st, emits)
-                return _append_log(p, st, over_rec)
+                return _merge_append(p, tb, st, emits)
 
             def do_sort(st: LaneState) -> LaneState:
                 return _sort_queues(st, with_pay=p_lane.stream_present)
@@ -3132,8 +3167,7 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                 "dst": emits.pc_dst.reshape(-1),
                 "seq": emits.pc_seq.reshape(-1),
                 "size": emits.pc_size.reshape(-1),
-                "outcome": jnp.full((kk * p.n_lanes,), PCAP_TX,
-                                    dtype=jnp.int64),
+                "outcome": PCAP_TX,
             })
         if p_lane.stream_present and p_lane.stream_pcap and p.log_capacity:
             # stream outbound pcap captures (PCAP_TX at departure)
@@ -3148,7 +3182,7 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                 "dst": jnp.broadcast_to(pe64[None, :], (kk, s2)).reshape(-1),
                 "seq": emits.spc_seq.reshape(-1),
                 "size": emits.spc_size.reshape(-1),
-                "outcome": jnp.full((kk * s2,), PCAP_TX, dtype=jnp.int64),
+                "outcome": PCAP_TX,
             })
             kk, bb, _ss = emits.bpc_valid.shape
             shape_b = (kk, bb, s_flows)
@@ -3161,8 +3195,7 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                     pe64[:s_flows][None, None, :], shape_b).reshape(-1),
                 "seq": emits.bpc_seq.reshape(-1),
                 "size": emits.bpc_size.reshape(-1),
-                "outcome": jnp.full(
-                    (kk * bb * s_flows,), PCAP_TX, dtype=jnp.int64),
+                "outcome": PCAP_TX,
             })
         if p_lane.stream_present and p.log_capacity:
             # stream loss records (DROP_LOSS at the send instant): slot-0
@@ -3179,7 +3212,7 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                 "dst": jnp.broadcast_to(pe64[None, :], (kk, s2)).reshape(-1),
                 "seq": emits.srec_seq.reshape(-1),
                 "size": emits.srec_size.reshape(-1),
-                "outcome": jnp.full((kk * s2,), DROP_LOSS, dtype=jnp.int64),
+                "outcome": DROP_LOSS,
             })
             kk, bb, _ss = emits.brec_valid.shape
             shape_b = (kk, bb, s_flows)
@@ -3192,8 +3225,7 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                     pe64[:s_flows][None, None, :], shape_b).reshape(-1),
                 "seq": emits.brec_seq.reshape(-1),
                 "size": emits.brec_size.reshape(-1),
-                "outcome": jnp.full(
-                    (kk * bb * s_flows,), DROP_LOSS, dtype=jnp.int64),
+                "outcome": DROP_LOSS,
             })
         if p.flowtrace:
             # reduce the per-slot flowtrace observations to lifecycle
@@ -3410,6 +3442,8 @@ _NB_SCALARS = ("nb_win",)
 # flowtrace extension (present only when LaneParams.flowtrace): the ring
 # cursor/lost ride the scalar vector, the [FL, F] ring is its own leaf
 _FL_SCALARS = ("fl_count", "fl_lost")
+# append engage counters (present when the log or the egress buffer is)
+_AP_SCALARS = ("ap_blocks", "ap_rows", "ap_tail_blocks")
 
 
 def pack_state(s: LaneState):
@@ -3425,13 +3459,10 @@ def pack_state(s: LaneState):
         + [s.cd_dropping.astype(jnp.int32)]
         + [getattr(s, f) for f in nb_fields]
     )
-    has_eg = not isinstance(s.egress, tuple)
-    has_fl = not isinstance(s.fl_buf, tuple)
-    sc_fields = (
-        _SCALAR_FIELDS
-        + (_EG_SCALARS if has_eg else ())
-        + (_NB_SCALARS if has_nb else ())
-        + (_FL_SCALARS if has_fl else ())
+    sc_fields = _scalar_fields(
+        has_eg=not isinstance(s.egress, tuple), has_nb=has_nb,
+        has_fl=not isinstance(s.fl_buf, tuple),
+        has_ap=not isinstance(s.ap_blocks, tuple),
     )
     sc = jnp.stack(
         [jnp.asarray(getattr(s, f), dtype=jnp.int32) for f in sc_fields]
@@ -3439,28 +3470,34 @@ def pack_state(s: LaneState):
     return (q, c32, sc, s.log, s.stream, s.egress, s.nb_hist, s.fl_buf)
 
 
+def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
+    """The packed scalar vector's layout for the optional blocks live."""
+    return (
+        _SCALAR_FIELDS
+        + (_EG_SCALARS if has_eg else ())
+        + (_NB_SCALARS if has_nb else ())
+        + (_FL_SCALARS if has_fl else ())
+        + (_AP_SCALARS if has_ap else ())
+    )
+
+
 def unpack_state(carry) -> LaneState:
     q, c32, sc, log, stream, egress, nb_hist, fl_buf = carry
     has_pay = q.shape[0] == 7
-    # extras beyond the base scalar vector disambiguate which optional
-    # blocks are live: egress adds 4 scalars, netobs adds 1, flowtrace
-    # adds 2 — every combination lands on a distinct count in 0..7
-    extra = sc.shape[0] - len(_SCALAR_FIELDS)
-    has_eg = extra >= 4
-    has_nb = extra in (1, 3, 5, 7)
-    has_fl = extra in (2, 3, 6, 7)
+    # the optional blocks' own carry leaves say which are live; the append
+    # counters have none, so the scalar count left over tells
+    has_eg = not isinstance(egress, tuple)
+    has_nb = not isinstance(nb_hist, tuple)
+    has_fl = not isinstance(fl_buf, tuple)
+    sc_fields = _scalar_fields(has_eg, has_nb, has_fl, has_ap=False)
+    if sc.shape[0] != len(sc_fields):
+        sc_fields += _AP_SCALARS
     kw = {f: c32[i] for i, f in enumerate(_I32_N_FIELDS)}
     n_base = len(_I32_N_FIELDS) + 1  # + cd_dropping
     if has_nb:
         kw.update({
             f: c32[n_base + i] for i, f in enumerate(_NB_N_FIELDS)
         })
-    sc_fields = (
-        _SCALAR_FIELDS
-        + (_EG_SCALARS if has_eg else ())
-        + (_NB_SCALARS if has_nb else ())
-        + (_FL_SCALARS if has_fl else ())
-    )
     kw.update({f: sc[i] for i, f in enumerate(sc_fields)})
     return LaneState(
         q_thi=q[0], q_tlo=q[1], q_auxh=q[2], q_auxl=q[3], q_size=q[4],

@@ -84,6 +84,11 @@ class TpuEngine:
         # populated by collect() when flowtrace is on: decoded device
         # ring events + ring-overflow loss count (obs/flowtrace.py)
         self._flowtrace_data = None
+        # populated by collect(): how often the record appends engaged
+        # (lanes._append_rows) — block writes, rows written, and block
+        # writes for merge-tail overflow records, over the whole run;
+        # empty for a program with neither a log nor an egress buffer
+        self.append_stats: dict[str, int] = {}
         if inject_batch is None:
             inject_batch = cfg.experimental.tpu_inject_batch
         n = len(cfg.hosts)
@@ -968,6 +973,10 @@ class TpuEngine:
             ),
             fl_count=jnp.int32(0) if p.flowtrace else (),
             fl_lost=jnp.int32(0) if p.flowtrace else (),
+            **{
+                f: jnp.int32(0) if p.log_capacity or p.external_any else ()
+                for f in lanes._AP_SCALARS
+            },
         )
 
     # -- running -----------------------------------------------------------
@@ -1456,6 +1465,15 @@ class TpuEngine:
             self._netobs_data = self._netobs_collect(s, tv)
         if self.params.flowtrace:
             self._flowtrace_data = self._flowtrace_collect(s)
+        if not isinstance(s.ap_blocks, tuple):
+            # beside the counters, not among them: the oracle has none
+            self.append_stats = {
+                "append_" + f.removeprefix("ap_"): int(getattr(s, f))
+                for f in lanes._AP_SCALARS
+            }
+            if self.obs is not None:
+                for key, val in self.append_stats.items():
+                    self.obs.metrics.gauge(key, val)
 
         return SimResult(
             sim_time_ns=self.params.stop_time,

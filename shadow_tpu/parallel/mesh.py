@@ -82,6 +82,7 @@ REPLICATED_FIELDS = frozenset((
     "egress_min_hi", "egress_min_lo",
     "nb_hist", "nb_win",
     "fl_buf", "fl_count", "fl_lost",
+    *lanes._AP_SCALARS,
 ))
 
 

@@ -22,6 +22,7 @@ CHANGES.md (PR 23).
 """
 
 import os
+import re
 import signal
 
 import jax
@@ -161,22 +162,23 @@ def _relay_chain_engine(tmp_path):
     return cfg, TpuEngine(cfg, external=external)
 
 
+def _empty_inject_block(p):
+    """A host-staged injection block of ``p.inject_batch`` rows, none
+    valid (backend/hybrid.py ``_empty_block``)."""
+    b = p.inject_batch
+    return {"valid": np.zeros(b, dtype=bool),
+            **{k: np.zeros(b, dtype=np.int32)
+               for k in ("dst", "thi", "tlo", "auxh", "auxl", "size")}}
+
+
 def test_hybrid_turn_inject_and_fused_k(one_chip, as_tpu, tmp_path):
     """The hybrid backend's three device entry points at the relay-chain-
     large shape: single-window turn, injection merge, k-window fused turn
     at the configured ``hybrid_fuse_k``."""
     cfg, eng = _relay_chain_engine(tmp_path)
-    p = eng.params
     state = _shapes(eng.initial_state(), one_chip)
-    b = p.inject_batch
     i32 = np.int32
-    inj = {
-        "valid": jax.ShapeDtypeStruct((b,), np.bool_, sharding=one_chip),
-        **{
-            k: jax.ShapeDtypeStruct((b,), i32, sharding=one_chip)
-            for k in ("dst", "thi", "tlo", "auxh", "auxl", "size")
-        },
-    }
+    inj = _shapes(_empty_inject_block(eng.params), one_chip)
     never = int(lanes.NEVER32)
     turn_fn, inject_fn = eng.make_hybrid_fns()
     # the host passes the external bound as Python ints (hybrid.py)
@@ -188,6 +190,54 @@ def test_hybrid_turn_inject_and_fused_k(one_chip, as_tpu, tmp_path):
     fused_fn, _ = eng.make_hybrid_fns(k, slots)
     ext = jax.ShapeDtypeStruct((slots,), i32, sharding=one_chip)
     _fits(fused_fn.lower(state, ext, ext, never, inj, np.int32(k)).compile())
+
+
+def _scatter_update_shapes(stablehlo: str) -> list[str]:
+    """The update operand's ``<shape x dtype>`` of every scatter in a
+    lowered module's text."""
+    lines = stablehlo.splitlines()
+    shapes = []
+    for i, line in enumerate(lines):
+        if "stablehlo.scatter" not in line:
+            continue
+        sig = next(m for m in (
+            re.search(r"\}\) : \(.*tensor<([^>]*)>\) ->", tail)
+            for tail in lines[i:]) if m)
+        shapes.append(sig.group(1))
+    return shapes
+
+
+def test_hybrid_turn_offers_no_candidate_row_scatter(as_tpu, tmp_path):
+    """The hybrid turn at the benchmark cell's shape (lowering alone, no
+    described device): the parent appended to the log and the egress buffer
+    by scattering EVERY candidate row — ``[N x (K + Cx), 6]`` for the merge
+    tail, ``[K x N, 6]`` per iteration, ``[N, 6]`` per slot, int64 — and the
+    chip's scatter costs what it is offered (PERF.md PR 27).  Appends now
+    write blocks of the valid rows (``lanes._append_rows``): no scatter of
+    six-column int64 rows is left, of any length."""
+    cfg, eng = _relay_chain_engine(tmp_path)
+    p = eng.params
+    state, inj = eng.initial_state(), _empty_inject_block(p)
+    never = int(lanes.NEVER32)
+    fuse_k = int(cfg.experimental.hybrid_fuse_k)  # the cell runs this one
+    slots = max(2 * fuse_k, 9)
+    ext = np.full(slots, never, dtype=np.int32)
+    texts = [
+        eng.make_hybrid_fns()[0].lower(
+            state, never, never, never, inj).as_text(),
+        eng.make_hybrid_fns(fuse_k, slots)[0].lower(
+            state, ext, ext, never, inj, np.int32(fuse_k)).as_text(),
+    ]
+    n, k = p.n_lanes, p.pops_per_iter
+    offered = {n * (k + p.cross_cap), n * (2 * k + p.cross_cap), k * n}
+    for text in texts:
+        updates = _scatter_update_shapes(text)
+        assert not [u for u in updates if u.endswith("x6xi64")], updates
+        assert not [u for u in updates  # "i64" alone: a scalar update
+                    if u.split("x")[0] in map(str, offered)], updates
+        # the appends are there: a block write per site (tail, per-slot
+        # records, K unrolled slots' egress), each inside its own trip loop
+        assert text.count("stablehlo.dynamic_update_slice") >= 2 + k
 
 
 def test_sharded_run_fn_on_described_mesh(topo, as_tpu):

@@ -97,6 +97,22 @@ def test_hybrid_managed_parity_with_cpu_oracle(tmp_path):
     assert r_cpu.rounds == r_tpu.rounds
 
 
+def test_append_counters_of_a_sound_run(tmp_path):
+    """The device's record appends say how often they engaged
+    (``sync_stats``, beside the counters and never among them): a sound
+    run writes no block for queue-overflow records, and every record of
+    the device log and every egressed row went out in some block (the
+    log also holds the records the host side wrote)."""
+    r_tpu, eng = _run(_mixed_config(tmp_path, "tpu"))
+    st = eng.sync_stats
+    assert st["append_tail_blocks"] == 0
+    assert 0 < st["append_blocks"] <= st["append_rows"]
+    assert st["egress_rows"] > 0
+    device_records = st["append_rows"] - st["egress_rows"]
+    assert 0 < device_records <= len(r_tpu.event_log)
+    assert not any(k.startswith("append_") for k in r_tpu.counters)
+
+
 def test_hybrid_deterministic(tmp_path):
     r1, _ = _run(_mixed_config(tmp_path / "a", "tpu"))
     r2, _ = _run(_mixed_config(tmp_path / "b", "tpu"))
