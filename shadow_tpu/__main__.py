@@ -105,7 +105,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--event-log",
         action="store_true",
-        help="write the canonical sorted event log (determinism-diff artifact)",
+        help="keep the event log and write it, canonically sorted (the "
+        "determinism-diff artifact); without it the tpu backend runs with "
+        "no device event log — counters and sim-stats.json are the same, "
+        "and a run is not bounded by the log's capacity",
     )
     p.add_argument(
         "--determinism-check",
@@ -132,7 +135,7 @@ def parse_overrides(ns: argparse.Namespace) -> dict[str, object]:
 
 def main(argv: list[str] | None = None) -> int:
     from shadow_tpu.config.options import ConfigError, ConfigOptions
-    from shadow_tpu.engine.sim import Simulation
+    from shadow_tpu.engine.sim import Simulation, device_log_readers
 
     ns = build_parser().parse_args(argv)
     try:
@@ -187,7 +190,11 @@ def main(argv: list[str] | None = None) -> int:
 
     from shadow_tpu.engine.checkpoint import GracefulShutdown
 
-    sim = Simulation(cfg)
+    # a run keeps a device event log only when something will read it:
+    # --event-log, or a configuration whose pcap capture rides the log
+    sim = Simulation(
+        cfg, event_log=ns.event_log or bool(device_log_readers(cfg))
+    )
     try:
         result = sim.run()
     except GracefulShutdown as g:

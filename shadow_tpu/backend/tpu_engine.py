@@ -10,6 +10,7 @@ the CPU engine produces, so the two backends are drop-in comparable.
 
 from __future__ import annotations
 
+import contextlib
 import time as wall_time
 from typing import Optional
 
@@ -89,6 +90,10 @@ class TpuEngine:
         # writes for merge-tail overflow records, over the whole run;
         # empty for a program with neither a log nor an egress buffer
         self.append_stats: dict[str, int] = {}
+        # populated by collect(): the shape of the run that was collected
+        # — lanes, mesh_devices, device_log_capacity, device_log_records
+        # (sim-stats.json's ``lane_plane``, the obs gauges of those names)
+        self.lane_plane: dict[str, int] = {}
         if inject_batch is None:
             inject_batch = cfg.experimental.tpu_inject_batch
         n = len(cfg.hosts)
@@ -999,11 +1004,13 @@ class TpuEngine:
         injected ``backend_stall`` raises on the faulted path: the
         checkpoint-anchored failover resume must replay *through* the
         epoch that killed the first attempt."""
-        if resume_state is not None and precompile:
-            raise LaneCompatError(
-                "precompile is a bench affordance; it is "
-                "not supported together with checkpoint resume"
-            )
+        if resume_state is not None:
+            if precompile:
+                raise LaneCompatError(
+                    "precompile is a bench affordance; it is "
+                    "not supported together with checkpoint resume"
+                )
+            self._check_resume_log(resume_state)
         if self._fault_overlay is not None:
             if precompile:
                 raise LaneCompatError(
@@ -1014,12 +1021,15 @@ class TpuEngine:
                 mode, on_window=on_window, resume_state=resume_state,
                 resume_epoch=resume_epoch, disarm_stalls=disarm_stalls,
             )
-        state = (
-            resume_state if resume_state is not None else self.initial_state()
-        )
-        # with a mesh attached, commit the state to its sharded placement
-        # and compile the driver under the mesh (parallel/mesh.py)
-        state = self.place_state(state)
+        with self._phase("state_build"):
+            state = (
+                resume_state if resume_state is not None
+                else self.initial_state()
+            )
+            # with a mesh attached, commit the state to its sharded
+            # placement and compile the driver under the mesh
+            # (parallel/mesh.py)
+            state = self.place_state(state)
         if mode == "device":
             # cache the program: repeat runs (bench best-of-N) must not
             # retrace/recompile
@@ -1062,7 +1072,8 @@ class TpuEngine:
             t0 = wall_time.perf_counter()
             state = self._drive_steps(round_fn, state, on_window, self.params)
             wall = wall_time.perf_counter() - t0
-        result = self.collect(state, wall)
+        with self._phase("collect"):
+            result = self.collect(state, wall)
         if mode == "device" and self.obs is not None and self.obs.turns is not None:
             # the fused driver's whole run is ONE unforced dispatch: the
             # ledger's free-run baseline, with its actual free-run length
@@ -1072,6 +1083,27 @@ class TpuEngine:
                 "free_run", 0, self.params.stop_time, windows=result.rounds
             )
         return result
+
+    def _phase(self, phase: str):
+        """The obs span of a host-side phase of ``run`` (``state_build``:
+        ``initial_state`` + ``place_state``; ``collect``), which with
+        ``device_turn`` split a run's wall into build / device / collect;
+        nothing when obs is off."""
+        if self.obs is None:
+            return contextlib.nullcontext()
+        return self.obs.phase(phase)
+
+    def _check_resume_log(self, state) -> None:
+        """A checkpointed lane state carries its device log; it resumes
+        only on a program built for a log of that size."""
+        rows = int(np.shape(state.log)[0])
+        if rows != max(self.params.log_capacity, 1):
+            raise LaneCompatError(
+                f"the checkpoint's device event log has {rows} rows, this "
+                f"run's {max(self.params.log_capacity, 1)} (1 = log off): "
+                "resume with the event_log / --event-log choice the "
+                "checkpoint was written under"
+            )
 
     def checkpoint_payload(self):
         """The live lane state as a host-side (numpy) pytree — the whole
@@ -1231,7 +1263,8 @@ class TpuEngine:
         # test drive them through this serial loop too)
         plan = ov.segment_plan(stop, pad_to=getattr(self, "_fault_pad", 0))
         resumed = resume_state is not None
-        state = resume_state if resumed else self.initial_state()
+        with self._phase("state_build"):
+            state = resume_state if resumed else self.initial_state()
         fns = getattr(self, "_seg_fns", None)
         if fns is None:
             fns = self._seg_fns = {}
@@ -1284,7 +1317,8 @@ class TpuEngine:
                     fn, state, on_window, p, first_cause=swap_cause,
                 )
         wall = wall_time.perf_counter() - t0
-        return self.collect(state, wall)
+        with self._phase("collect"):
+            return self.collect(state, wall)
 
     def _write_pcaps(self, event_rows, pcap_rows) -> None:
         """Reconstruct per-host capture files from the device log:
@@ -1391,6 +1425,17 @@ class TpuEngine:
             )
         log_count = int(s.log_count)
         log_lost = int(s.log_lost)
+        self.lane_plane = {
+            "lanes": self.params.n_lanes,
+            "mesh_devices": (
+                int(self._mesh.devices.size) if self._mesh is not None else 1
+            ),
+            "device_log_capacity": self.params.log_capacity,
+            "device_log_records": log_count,
+        }
+        if self.obs is not None:
+            for key, val in self.lane_plane.items():
+                self.obs.metrics.gauge(key, val)
         if log_lost:
             # surface the overflow as a metrics-registry counter BEFORE
             # raising: failed runs still flush partial obs artifacts
@@ -1400,8 +1445,12 @@ class TpuEngine:
                 self.obs.metrics.count("device_log_lost", log_lost)
                 self.obs.metrics.gauge("device_log_overflowed", True)
             raise RuntimeError(
-                f"device event log overflowed ({log_lost} records lost); "
-                "raise log_capacity or disable logging"
+                f"device event log overflowed: the run produced {log_count} "
+                f"records, the log holds {self.params.log_capacity} "
+                f"({log_lost} records lost); run without the device log "
+                "(Simulation(cfg, event_log=False); on the command line, "
+                "drop --event-log) or build the engine with a larger "
+                "log_capacity"
             )
         rows = np.asarray(s.log[: min(log_count, self.params.log_capacity)])
         if self.params.pcap_any:

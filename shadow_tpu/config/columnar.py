@@ -30,7 +30,7 @@ from typing import Sequence
 
 import numpy as np
 
-from ..models.base import create_model
+from ..models.tgen import TgenMesh
 from .options import ConfigOptions, HostOptions, ProcessOptions
 
 __all__ = ["ColumnarHosts", "ColumnarSpec", "columnar_mesh_config"]
@@ -180,7 +180,7 @@ hosts:
     args = ["--interval", str(interval), "--size", str(size)]
     # ONE model instance parses the args — the per-host loop's source of
     # truth for interval/size/stride stays authoritative
-    m = create_model("tgen-mesh", list(args))
+    m = TgenMesh.from_args(list(args))
     procs = [ProcessOptions(path="tgen-mesh", args=args, start_time=0)]
     cfg.hosts = ColumnarHosts([(n_hosts, "peer", 0, procs)])
 

@@ -22,7 +22,7 @@ from pathlib import Path
 from typing import Optional
 
 from ..backend.cpu_engine import OUTCOME_NAMES, CpuEngine, SimResult
-from ..config.options import ConfigOptions
+from ..config.options import ConfigError, ConfigOptions
 from ..core import time as stime
 from .checkpoint import (
     CheckpointError,
@@ -91,15 +91,63 @@ class _CkptHook:
         )
 
 
+#: packet_outcomes of a run that kept no device log: the lane engine's own
+#: totals, one per outcome code the log would have carried ("queue" for
+#: completeness: strict capacity raises on it, so no finished run shows one)
+_LANE_OUTCOME_COUNTERS = (
+    ("delivered", "lane_delivered"),
+    ("loss", "lane_drop_loss"),
+    ("codel", "lane_drop_codel"),
+    ("queue", "lane_drop_queue"),
+)
+
+
+def device_log_readers(cfg: ConfigOptions) -> list[str]:
+    """What in ``cfg`` reads rows of the tpu backend's device event log
+    (besides ``result.event_log`` itself): pcap capture of lane hosts,
+    which rides the log as PCAP_TX records.  Empty for the cpu backend
+    and for hybrid runs, whose logs the facade's ``event_log`` argument
+    does not govern."""
+    if cfg.experimental.network_backend != "tpu":
+        return []
+    from ..backend.hybrid import config_has_managed
+
+    if config_has_managed(cfg):
+        return []
+    pcap = [h.hostname for h in cfg.hosts if h.pcap_enabled]
+    if not pcap:
+        return []
+    more = f" and {len(pcap) - 1} more" if len(pcap) > 1 else ""
+    return [f"pcap capture (host {pcap[0]}{more})"]
+
+
 class Simulation:
     """Owns one simulation run end to end (the reference's Controller +
     Manager collapsed: config in, data directory out)."""
 
     def __init__(
-        self, cfg: ConfigOptions, run_control: Optional[RunControl] = None
+        self, cfg: ConfigOptions, run_control: Optional[RunControl] = None,
+        event_log: bool = True,
     ) -> None:
+        """``event_log``: keep the tpu backend's DEVICE event log (the
+        CLI's ``--event-log``).  A run keeps that log only when something
+        will read it: ``result.event_log``, ``write_event_log`` and pcap
+        capture do, so the default keeps it; ``event_log=False`` runs the
+        lane program without one — counters, rounds and ``sim-stats.json``
+        are unchanged, ``result.event_log`` is empty, and a run whose
+        records outnumber the log's fixed capacity can finish.  The cpu
+        engine's and the hybrid engine's own logs are not governed by it."""
         cfg.validate()
         self.cfg = cfg
+        self.event_log = bool(event_log)
+        if not self.event_log:
+            readers = device_log_readers(cfg)
+            if readers:
+                raise ConfigError(
+                    "event_log=False, but the device event log is read by "
+                    f"{', '.join(readers)}: keep the log "
+                    "(event_log=True / --event-log) or drop what reads it"
+                )
         self.data_dir = Path(cfg.general.data_directory)
         self.run_control = run_control
         if run_control is None and cfg.experimental.run_control:
@@ -624,12 +672,9 @@ class Simulation:
         """Replay the run's suffix on a fresh TPU engine from a verified
         checkpoint, stalls disarmed (the injected fault already fired —
         replaying it would livelock the recovery law)."""
-        from ..backend.tpu_engine import TpuEngine
-
         epoch = int(hdr["epoch_ns"])
         self.restart_work_saved = epoch
-        engine = self.engine = TpuEngine(self.cfg)
-        engine.obs = self.obs
+        engine = self._lane_engine()
         if self.cfg.experimental.perf_logging:
             engine.perf_log = PerfLog()
         self._restore_obs(payload)
@@ -656,6 +701,20 @@ class Simulation:
             resume_epoch=epoch,
             disarm_stalls=True,
         )
+
+    def _lane_engine(self, **kwargs):
+        """The pure-lane ``TpuEngine`` of this run — the ONE place that
+        decides whether it keeps a device event log: only when something
+        will read it (``event_log``; ``__init__`` has already refused a
+        log-off run that captures pcap).  ``None`` is the engine's own
+        fixed capacity, 0 is no log."""
+        from ..backend.tpu_engine import TpuEngine
+
+        engine = self.engine = TpuEngine(
+            self.cfg, log_capacity=None if self.event_log else 0, **kwargs
+        )
+        engine.obs = self.obs
+        return engine
 
     def _run_cpu(self) -> SimResult:
         resume = self._take_resume("cpu")
@@ -711,7 +770,7 @@ class Simulation:
 
     def _run_tpu(self) -> SimResult:
         from ..backend.hybrid import HybridEngine, config_has_managed
-        from ..backend.tpu_engine import LaneCompatError, TpuEngine
+        from ..backend.tpu_engine import LaneCompatError
 
         if config_has_managed(self.cfg):
             if self.cfg.faults.events and any(
@@ -784,13 +843,9 @@ class Simulation:
         # resume, and flowtrace stay single-device.
         n_mesh = parallel.negotiate_from_config(self.cfg, len(self.cfg.hosts))
         multi_mesh = n_mesh > 1
-        engine = self.engine = TpuEngine(
-            self.cfg,
-            # flowtrace stays single-device for now: the device event
-            # ring drains through the unsharded snapshot path
-            flowtrace=False if multi_mesh else None,
-        )
-        engine.obs = self.obs
+        # flowtrace stays single-device for now: the device event ring
+        # drains through the unsharded snapshot path
+        engine = self._lane_engine(flowtrace=False if multi_mesh else None)
         if multi_mesh:
             if self.cfg.faults.events:
                 raise LaneCompatError(
@@ -882,6 +937,7 @@ class Simulation:
             "restart_work_saved": self.restart_work_saved,
             "backend": self.cfg.experimental.network_backend,
             "device": self._device_info(),
+            "lane_plane": self._lane_plane(),
             "num_hosts": len(self.cfg.hosts),
             "seed": self.cfg.general.seed,
             "counters": dict(sorted(result.counters.items())),
@@ -900,11 +956,27 @@ class Simulation:
                     json.dumps(dict(sorted(counters.items())), indent=2) + "\n"
                 )
 
+    def _lane_plane(self) -> Optional[dict]:
+        """``{lanes, mesh_devices, device_log_capacity,
+        device_log_records}`` of the pure-lane engine's last collected
+        run; None for the engines that hold no lane plane of their own."""
+        info = getattr(self.engine, "lane_plane", None)
+        return dict(info) if info else None
+
     def _outcome_counts(self, result: SimResult) -> dict[str, int]:
         out: dict[str, int] = {}
-        for r in result.event_log:
-            name = OUTCOME_NAMES.get(r.outcome, str(r.outcome))
-            out[name] = out.get(name, 0) + 1
+        plane = self._lane_plane()
+        if plane is not None and plane["device_log_capacity"] == 0:
+            # the run kept no device log: the lane engine's own totals
+            # (equal to a walk over the log it would have kept — held by
+            # tests/test_mesh100k_support.py)
+            for name, key in _LANE_OUTCOME_COUNTERS:
+                if result.counters.get(key):
+                    out[name] = result.counters[key]
+        else:
+            for r in result.event_log:
+                name = OUTCOME_NAMES.get(r.outcome, str(r.outcome))
+                out[name] = out.get(name, 0) + 1
         # flows the lTCP sender abandoned after MAX_RTO_BACKOFFS consecutive
         # timeouts (net/ltcp.py): not a wire event, but an outcome operators
         # need next to the drop counts when links stay dark
