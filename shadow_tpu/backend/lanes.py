@@ -384,6 +384,19 @@ class LaneParams:
     def cross_cap(self) -> int:
         return min(self.cross_capacity, self.capacity) or self.capacity
 
+    @property
+    def exchange_entries(self) -> int:
+        """Rows of the [N] exchange's flat sort per iteration: K sends a
+        lane, plus the compacted stream channels (slot-0 sends, RTO arms,
+        bursts) where they ride it (``_merge_append`` holds it to the
+        traced shape)."""
+        m = self.pops_per_iter * self.n_lanes
+        if self.lanes_have_payload and not self.stream_one_to_one:
+            m += self.pops_per_iter * len(self.stream_clients) * (
+                4 + lstr.ltcp.PUMP_BURST
+            )
+        return m
+
     def __post_init__(self) -> None:
         if self.n_lanes > MAX_LANES:
             raise ValueError(
@@ -1587,6 +1600,106 @@ def _window_gather(arrs, start, c):
     return out
 
 
+#: the exchange's segment bounds come from ONE one-hot histogram matmul
+#: while its operands — entries x (ceil((n + 1) / 128) + 128) one-hot
+#: columns — stay inside this many elements (128 MiB of f32); wider
+#: exchanges accumulate the histogram over chunks of the entries.  With K
+#: pops per iteration an [N]-wide exchange has K * n entries, and the first
+#: wide program has 38 837 lanes at K = 2 and 16 384 at K = 8.  A shape the
+#: code observes, not an option: tests patch it
+_ONEHOT_BUDGET = 1 << 25
+
+
+def exchange_bounds_wide(m_entries: int, n: int) -> bool:
+    """Whether an exchange of ``m_entries`` sends over ``n`` lanes is past
+    the one-hot budget and finds its bounds chunk by chunk (static per
+    compiled program: the ``exchange_bounds_wide`` gauge)."""
+    return m_entries * (-(-(n + 1) // 128) + 128) > _ONEHOT_BUDGET
+
+
+def _bounds_by_onehot(dst, n):
+    """``(start, cnt)`` [n] of each lane's slice of the destination-sorted
+    exchange, from the pre-sort column ``dst`` [m] (an invalid send has
+    ``dst == n``; a histogram is order-free).  NOT jnp.searchsorted — its
+    binary search lowers to a lax.while_loop of per-element gathers inside
+    the hot body (18 steps of 100 001 gathers at 100k lanes: 12.0 ms on a
+    v5e).  The counts are a one-hot HISTOGRAM as a single MXU matmul: dst
+    decomposes as (dst >> 7, dst & 127) and counts[q, r] = sum_m
+    oh_q[m, q] * oh_r[m, r] — exact in f32 (counts < 2**24) — then one
+    small 2D cumsum gives the exclusive prefix (= segment starts) with no
+    data-dependent control flow.  The one-hot operands are
+    [m, ceil((n+1)/128)] and [m, 128]: ~6 MB at 10k lanes and K = 2, but
+    m * n / 128 grows with the square of the width (728 MB at 100k), hence
+    _ONEHOT_BUDGET and ``_bounds_by_onehot_chunked``."""
+    dq = -(-(n + 1) // 128)
+    oh_q = (
+        (dst[:, None] >> 7)
+        == jnp.arange(dq, dtype=jnp.int32)[None, :]
+    ).astype(jnp.float32)
+    oh_r = (
+        (dst[:, None] & 127)
+        == jnp.arange(128, dtype=jnp.int32)[None, :]
+    ).astype(jnp.float32)
+    counts_grid = lax.dot_general(
+        oh_q, oh_r, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)  # [dq, 128]
+    row_cum = jnp.cumsum(counts_grid, axis=1)
+    row_tot = row_cum[:, -1]
+    row_off = jnp.cumsum(row_tot) - row_tot  # exclusive row offsets
+    start_grid = row_cum - counts_grid + row_off[:, None]
+    return start_grid.reshape(-1)[:n], counts_grid.reshape(-1)[:n]
+
+
+#: sends per step of the wide law's histogram: 8 192 x (782 + 128) bf16
+#: one-hot elements at 100 000 lanes, 15 MB of temporaries
+_ONEHOT_CHUNK = 8192
+
+
+def _bounds_by_onehot_chunked(dst, n):
+    """The same ``(start, cnt)`` as ``_bounds_by_onehot`` where its one-hot
+    operands would not fit ``_ONEHOT_BUDGET``: the histogram matmul
+    accumulated over chunks of ``_ONEHOT_CHUNK`` sends in one ``fori_loop``
+    — a slice of the column, two compares and an MXU pass per step; no
+    gather, no scatter, no search.  The operands are 0 / 1 in bf16 (exact)
+    and the sums f32 (exact below 2**24).  The low 7 bits are encoded
+    ``<=`` rather than ``==``, so a row of the grid is already its own
+    running sum and ``upto[q, r]`` counts the sends to lanes ``128 q ..
+    128 q + r``: what is left after the loop is the offset of each row, a
+    cumsum over ``ceil((n + 1) / 128)`` row totals.  m * n multiply-adds,
+    as the one-shot law: 0.53 ms at (m, n) = (200 000, 100 000) on a v5e
+    and 1.9 ms at m = 800 000, where the searchsorted it replaced took
+    12.0 and 13.4 (PERF.md §6, PR 29, which also says why not a sort: the
+    chip's peak of memory counts a program's CODE, and a second sort is
+    1.5 MB of it)."""
+    m = dst.shape[0]
+    dq = -(-(n + 1) // 128)
+    steps = -(-m // _ONEHOT_CHUNK)
+    # padding rows fall in grid row dq: they match no q
+    dst = jnp.pad(
+        dst, (0, steps * _ONEHOT_CHUNK - m), constant_values=dq * 128
+    )
+    qs = jnp.arange(dq, dtype=jnp.int32)[:, None]
+    rs = jnp.arange(128, dtype=jnp.int32)[None, :]
+
+    def add_chunk(i, upto):
+        d = lax.dynamic_slice(dst, (i * _ONEHOT_CHUNK,), (_ONEHOT_CHUNK,))
+        oh_q = (qs == (d >> 7)[None, :]).astype(jnp.bfloat16)  # [dq, chunk]
+        le_r = ((d & 127)[:, None] <= rs).astype(jnp.bfloat16)  # [chunk, 128]
+        return upto + jnp.dot(oh_q, le_r, preferred_element_type=jnp.float32)
+
+    upto = lax.fori_loop(
+        0, steps, add_chunk, jnp.zeros((dq, 128), dtype=jnp.float32)
+    ).astype(jnp.int32)
+    row_tot = upto[:, -1]
+    upto = upto + (jnp.cumsum(row_tot) - row_tot)[:, None]
+    # upto.reshape(-1)[d] = sends to lanes 0..d = start[d + 1]
+    bounds = jnp.concatenate(
+        [jnp.zeros(1, dtype=jnp.int32), upto.reshape(-1)[:n]]
+    )
+    return bounds[:n], bounds[1:] - bounds[:n]
+
+
 def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
                   emits: _SlotEmit, divert: bool = False):
     """Append all generated events by **merge**, not scatter (TPU scatters
@@ -1596,8 +1709,13 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
        lane-aligned ``[N, 2K]`` blocks (``[N, K]`` when every model is
        passive) — invalid entries get time=NEVER;
     2. outbound packets take one single-key sort by destination (unstable —
-       the event key is re-sorted below), with each lane's slice bounds from
-       a one-hot histogram matmul + 2D cumsum, into a lane-aligned
+       the event key is re-sorted below); each lane's slice bounds come from
+       a one-hot histogram matmul + 2D cumsum (``_bounds_by_onehot``: one
+       MXU pass over operands that grow with lanes squared) or, past
+       ``_ONEHOT_BUDGET`` — 38 837 lanes at K = 2, 16 384 at K = 8 — from
+       the same histogram accumulated over chunks of the sends
+       (``_bounds_by_onehot_chunked``: the same multiply-adds, a few MB of
+       operands at any width); the slices are gathered into a lane-aligned
        ``[N, Cx]`` block (``Cx = cross_cap``) — the batched equivalent of
        the reference's cross-host queue push (worker.rs:603-615);
     3. one row-sort of ``[old C | self | cross Cx]`` by the 4-word key
@@ -1756,47 +1874,15 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     )
     _dst_s, thi_s, tlo_s, auxh_s, auxl_s, size_s = sorted_ops[:6]
     pay_s = sorted_ops[6:8] if sp and not split_se else None
-    # segment bounds per destination lane.  NOT jnp.searchsorted — the
-    # vmapped binary search lowers to a nested lax.while_loop (~15
-    # sequential sub-iterations with gathers) inside the hot body.  The
-    # counts come instead from a one-hot HISTOGRAM as a single MXU
-    # matmul: dst decomposes as (dst >> 7, dst & 127) and
-    # counts[q, r] = sum_m oh_q[m, q] * oh_r[m, r] — exact in f32
-    # (counts < 2**24) — then one small 2D cumsum gives the exclusive
-    # prefix (= segment starts) with no data-dependent control flow.
-    # The one-hot operands are [M, ceil((n+1)/128)] and [M, 128]: fine at
-    # bench scale (10k lanes, K=2 -> ~6 MB) but quadratic-ish in n, so
-    # past a static budget the bounds fall back to searchsorted on the
-    # sorted keys — paying the nested loop only where the matmul would
-    # blow memory.
-    dst_all = flat_ops[0]  # pre-sort values: the histogram is order-free
-    dq = -(-(n + 1) // 128)
-    m_entries = dst_all.shape[0]
-    if m_entries * (dq + 128) <= (1 << 25):  # <= 128 MiB of f32 one-hots
-        oh_q = (
-            (dst_all[:, None] >> 7)
-            == jnp.arange(dq, dtype=jnp.int32)[None, :]
-        ).astype(jnp.float32)
-        oh_r = (
-            (dst_all[:, None] & 127)
-            == jnp.arange(128, dtype=jnp.int32)[None, :]
-        ).astype(jnp.float32)
-        counts_grid = lax.dot_general(
-            oh_q, oh_r, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(jnp.int32)  # [dq, 128]
-        row_cum = jnp.cumsum(counts_grid, axis=1)
-        row_tot = row_cum[:, -1]
-        row_off = jnp.cumsum(row_tot) - row_tot  # exclusive row offsets
-        start_grid = row_cum - counts_grid + row_off[:, None]
-        start = start_grid.reshape(-1)[:n]
-        cnt = counts_grid.reshape(-1)[:n]
-    else:
-        bounds = jnp.searchsorted(
-            _dst_s, jnp.arange(n + 1, dtype=_dst_s.dtype), side="left"
-        ).astype(jnp.int32)
-        start = bounds[:n]
-        cnt = bounds[1:] - start
+    # segment bounds per destination lane: start[d], cnt[d] of lane d's
+    # slice of the sorted columns.  Two laws give the same integers; the
+    # static shape picks one (exchange_bounds_wide), nothing else differs
+    assert flat_ops[0].shape[0] == p.exchange_entries
+    with jax.named_scope("exchange_bounds"):
+        if exchange_bounds_wide(p.exchange_entries, n):
+            start, cnt = _bounds_by_onehot_chunked(flat_ops[0], n)
+        else:
+            start, cnt = _bounds_by_onehot(flat_ops[0], n)
     cx = p.cross_cap
     r = jnp.arange(cx, dtype=jnp.int32)[None, :]  # [1, Cx]
     in_seg = r < cnt[:, None]

@@ -91,8 +91,9 @@ class TpuEngine:
         # empty for a program with neither a log nor an egress buffer
         self.append_stats: dict[str, int] = {}
         # populated by collect(): the shape of the run that was collected
-        # — lanes, mesh_devices, device_log_capacity, device_log_records
-        # (sim-stats.json's ``lane_plane``, the obs gauges of those names)
+        # — lanes, mesh_devices, device_log_capacity, device_log_records,
+        # exchange_bounds_wide (sim-stats.json's ``lane_plane``, the obs
+        # gauges of those names)
         self.lane_plane: dict[str, int] = {}
         if inject_batch is None:
             inject_batch = cfg.experimental.tpu_inject_batch
@@ -1432,6 +1433,11 @@ class TpuEngine:
             ),
             "device_log_capacity": self.params.log_capacity,
             "device_log_records": log_count,
+            # which law finds the exchange's segment bounds (static per
+            # compiled program): 1 past lanes._ONEHOT_BUDGET
+            "exchange_bounds_wide": int(lanes.exchange_bounds_wide(
+                self.params.exchange_entries, self.params.n_lanes
+            )),
         }
         if self.obs is not None:
             for key, val in self.lane_plane.items():

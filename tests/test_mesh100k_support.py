@@ -77,7 +77,7 @@ def test_facade_without_the_log_runs_past_the_logs_capacity(
     assert stats["packet_outcomes"] == {"delivered": 216_000}
     assert stats["lane_plane"] == {
         "lanes": 2_000, "mesh_devices": 1, "device_log_capacity": 0,
-        "device_log_records": 0}
+        "device_log_records": 0, "exchange_bounds_wide": 0}
 
 
 def test_facade_with_the_log_still_raises_and_names_the_remedy(tmp_path):
@@ -276,7 +276,7 @@ def test_a_run_is_split_into_build_device_collect(tmp_path, mode, event_log):
     assert {k: report["gauges"][k] for k in sim.engine.lane_plane} == {
         "lanes": 64, "mesh_devices": 1,
         "device_log_capacity": 200_000 if event_log else 0,
-        "device_log_records": records}
+        "device_log_records": records, "exchange_bounds_wide": 0}
     assert len(res.event_log) == records
 
 
