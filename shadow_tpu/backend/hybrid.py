@@ -21,10 +21,13 @@ CoDel, and every lane-model host — runs on the device
   the lifecycle) and are queued host-side as DELIVERY events carrying the
   parked payload;
 - the window law stays global and bit-identical to the scalar oracle:
-  the device folds the host side's next event time into every window
-  start (``lanes._build_hybrid_run``), free-runs windows the host has no
-  events in, and returns after completing any window the host
-  participates in — one device call per host sync instead of per round.
+  the device folds the host side's next event times into every window
+  start (``lanes._build_hybrid_fused_run``), free-runs windows the host
+  has no events in, and returns after completing up to
+  ``experimental.hybrid_fuse_k`` windows the host participates in — at
+  most one device call per host sync instead of one per round; the
+  covered rounds are serviced and validated post-hoc
+  (``HybridEngine._fused_turn``).
 
 Two engines drive that seam:
 
@@ -76,6 +79,11 @@ from .cpu_engine import DELIVERED, CpuEngine, Delivery, Host, SimResult
 NEVER = stime.NEVER
 
 log = logging.getLogger("shadow_tpu.hybrid")
+
+# fusion-effectiveness floor (obs_turns runs): warn when the achieved turn
+# collapse falls below this fraction of the ledger's remaining
+# kfusion_headroom_freerun prediction
+_FUSE_WARN_FRACTION = 0.5
 
 
 def config_has_managed(cfg: ConfigOptions) -> bool:
@@ -283,12 +291,12 @@ def _hybrid_worker_main(
     participating in this window (events < window_end, taken after the
     shipped deliveries land and before execution — the identical law the
     serial engine applies, so the parent's ledger is worker-count
-    invariant).  When k-window fusion is on (``peek_slots > 0``), the
-    reply additionally carries the cleanliness flag for the shipped
-    validation range (did this round create an event inside the
-    still-covered fused span?) and the partition's refreshed peek
-    schedule, so the parent can bound the next dispatch's k before any
-    further round trip (docs/hybrid.md "k-window fusion law")."""
+    invariant).  The reply always carries the cleanliness flag for the
+    shipped validation range (did this round create an event inside the
+    still-covered fused span?) and the partition's refreshed
+    ``peek_slots``-wide peek schedule, so the parent can bound the next
+    dispatch's k before any further round trip (docs/hybrid.md "k-window
+    fusion law")."""
     engine = _HybridWorker(cfg, owned)
     if cfg.experimental.perf_logging:
         from ..engine.run_control import BufferedPerfLog
@@ -334,8 +342,7 @@ def _hybrid_worker_main(
                     if engine.perf_log is not None else (),
                     wparts,
                     clean,
-                    engine._peek_head_horizon(peek_slots)
-                    if peek_slots else (),
+                    engine._peek_head_horizon(peek_slots),
                 ))
             elif msg[0] == "finish":
                 engine.finalize()
@@ -417,7 +424,7 @@ class HybridEngine(_HostSideHybrid):
         # Python counters, always on; perf_logging surfaces them per
         # window through PerfLog.hybrid_agg
         self.sync_stats: dict = {
-            "device_turns": 0,      # hybrid_fn calls (windows batched per)
+            "device_turns": 0,      # turn_fn calls (windows batched per)
             "device_sync_s": 0.0,   # blocking scalar-readback wall time
             "syscall_service_s": 0.0,  # host-side window execution wall
             "scalar_reads": 0,      # D2H transfers: packed scalar vectors
@@ -442,9 +449,6 @@ class HybridEngine(_HostSideHybrid):
             "append_rows": 0,         # rows those blocks wrote
             "append_tail_blocks": 0,  # of them, for queue-overflow records
         }
-        # k-window free-run fusion knobs (docs/hybrid.md "k-window fusion
-        # law"): fuse_k == 1 keeps the PR 7 one-dispatch-per-participating-
-        # window law bit-for-bit; >= 2 selects the fused kernel variant.
         exp = cfg.experimental
         # dispatch retry-with-backoff law (docs/robustness.md): a failed
         # fused device dispatch re-dispatches from the pre-turn device
@@ -472,9 +476,13 @@ class HybridEngine(_HostSideHybrid):
                 )
             if stalls:
                 self._stall_after = min(stalls)
+        # the k-window fusion depth cap (docs/hybrid.md "k-window fusion
+        # law"): a dispatch covers at most this many participating
+        # windows.  At a cap of 1 the walk below accepts its one window
+        # unconditionally (no rollback) and there is nothing for an eager
+        # dispatch to overlap, so that stays off
         self._fuse_k = max(1, int(exp.hybrid_fuse_k))
-        self._fuse_on = self._fuse_k >= 2
-        self._async_on = self._fuse_on and bool(exp.hybrid_async_dispatch)
+        self._async_on = self._fuse_k > 1
         # peeked-schedule width: enough slots that multi-event windows do
         # not exhaust the schedule mid-span (last slot = the horizon)
         self._ext_slots = max(2 * self._fuse_k, 9)
@@ -495,22 +503,19 @@ class HybridEngine(_HostSideHybrid):
         # window departs inside that window and cannot arrive earlier
         # than departure + this bound, so a dispatch may cover about
         # L_ext / runahead windows before speculation even begins
-        self._ext_min_lat: Optional[int] = None
-        if self._fuse_on:
-            from ..net.graph import _UNREACHABLE
+        from ..net.graph import _UNREACHABLE
 
-            idx = self.node_index
-            ext_nodes = sorted({idx[h.host_id] for h in self.external_hosts})
-            all_nodes = sorted(set(idx.values()))
-            lat = self.graph.latency_ns[np.ix_(ext_nodes, all_nodes)]
-            ok = lat != _UNREACHABLE
-            if ok.any():
-                self._ext_min_lat = int(lat[ok].min())
+        idx = self.node_index
+        ext_nodes = sorted({idx[h.host_id] for h in self.external_hosts})
+        all_nodes = sorted(set(idx.values()))
+        lat = self.graph.latency_ns[np.ix_(ext_nodes, all_nodes)]
+        ok = lat != _UNREACHABLE
+        self._ext_min_lat: Optional[int] = (
+            int(lat[ok].min()) if ok.any() else None
+        )
         # device-turn ledger plumbing (obs/turns.py; all inert when
-        # obs/turns are off): per-turn dispatch records buffered between
-        # _device_turn and the window law, the round's participant set,
-        # and the pending syscall_service->device_turn trace-flow anchor
-        self._ledger_dispatches = None
+        # obs/turns are off): the round's participant set and the pending
+        # syscall_service->device_turn trace-flow anchor
         self._last_participants: tuple = ()
         self._flow_pending = None
         self._flow_seq = 0
@@ -630,25 +635,17 @@ class HybridEngine(_HostSideHybrid):
         st["egress_bytes"] += span * 6 * 8
         return np.asarray(state.egress[:span])[:count].tolist()
 
-    def _read_egress_obs(self, state, count: int, lost: int,
-                         apply: bool = False):
-        """Egress readback wrapped in the obs ``egress`` span — the span
-        covers the D2H read, plus delivery application when ``apply``
-        (the unfused law's combined semantics, docs/observability.md);
-        the fused walk applies lazily per validated window and passes
-        ``apply=False``.  Empty egress is a no-op read with no span
-        (symmetric with the injection record, no tracer-capacity
-        burn)."""
+    def _read_egress_obs(self, state, count: int, lost: int):
+        """Egress readback wrapped in the obs ``egress`` span: the span
+        covers the D2H read alone — the fused walk applies deliveries
+        lazily per validated window (docs/observability.md).  Empty
+        egress is a no-op read with no span (symmetric with the
+        injection record, no tracer-capacity burn)."""
         obs = self.obs
         if obs is None or count == 0:
-            rows = self._read_egress(state, count, lost)
-            if apply:
-                self._apply_egress(rows)
-            return rows
+            return self._read_egress(state, count, lost)
         with obs.phase("egress", rows=count):
             rows = self._read_egress(state, count, lost)
-            if apply:
-                self._apply_egress(rows)
         obs.metrics.count("egress_rows", count)
         return rows
 
@@ -672,125 +669,6 @@ class HybridEngine(_HostSideHybrid):
                 wall_time.perf_counter() - t_inj, rows=n_staged,
             )
         return state, inj, n_staged
-
-    def _device_turn(self, state, hybrid_fn, inject_fn, next_host_fn):
-        """Inject staged sends, run the device free-run loop, and apply
-        egress — retrying while the device paused mid-window to drain a
-        low egress buffer.  Per completed turn the boundary costs exactly
-        one injection block H2D (zero when nothing staged), one packed
-        scalar D2H, and one egress slice D2H (zero when nothing
-        egressed).
-
-        When the device-turn ledger is on (obs.turns), every dispatch is
-        buffered as ``(dev_we, inject_rows, egress_rows, is_retry)`` for
-        the window law to record with its cause — derived purely from
-        values this loop reads anyway, zero extra transfers."""
-        st = self.sync_stats
-        obs = self.obs
-        turns = obs.turns if obs is not None else None
-        dispatches = [] if turns is not None else None
-        staged = self._staged_merged
-        self._staged_merged = []
-        state, inj, n_staged = self._build_inj(staged, inject_fn, state)
-        ext_used = (
-            lanes.NEVER32 if self._min_used_lat is None else self._min_used_lat
-        )
-        host_next = next_host_fn()
-        first_dispatch = True
-        while True:
-            eh, el = (
-                (lanes.NEVER32, lanes.NEVER32)
-                if host_next >= NEVER
-                else (host_next >> 31, host_next & lanes.MASK31)
-            )
-            t0 = wall_time.perf_counter()
-            state, scalars = hybrid_fn(state, eh, el, ext_used, inj)
-            sc = jax.device_get(scalars)  # the one blocking readback
-            t1 = wall_time.perf_counter()
-            st["device_sync_s"] += t1 - t0
-            st["device_turns"] += 1
-            st["scalar_reads"] += 1
-            lane_min = int(sc[lanes.HYB_LANE_MIN])
-            dev_we = int(sc[lanes.HYB_DEV_WE])
-            dev_used = int(sc[lanes.HYB_MIN_USED])
-            self._dev_min_used = (
-                None if dev_used >= lanes.NEVER32 else dev_used
-            )
-            if obs is not None:
-                obs.record(
-                    "device_turn", None, t0, t1 - t0, window_end=dev_we
-                )
-                obs.metrics.count("device_turns")
-                if (
-                    first_dispatch
-                    and self._flow_pending is not None
-                    and turns is not None
-                    and obs.tracer is not None
-                ):
-                    # trace-flow arrow: the syscall-service span that
-                    # forced this blocking turn -> the turn's span
-                    fid, anchor = self._flow_pending
-                    self._flow_pending = None
-                    tr = obs.tracer
-                    tr.flow("s", fid, "turn_cause", "turn_flow", anchor)
-                    tr.flow(
-                        "f", fid, "turn_cause", "turn_flow",
-                        t0 + (t1 - t0) / 2,
-                    )
-            egress_count = int(sc[lanes.HYB_EGRESS_COUNT])
-            self._read_egress_obs(
-                state, egress_count, int(sc[lanes.HYB_EGRESS_LOST]),
-                apply=True,
-            )
-            if self.perf_log is not None:
-                self.perf_log.hybrid_agg(
-                    "device", dev_we, self.sync_stats
-                )
-            if dispatches is not None:
-                dispatches.append((
-                    dev_we,
-                    n_staged if first_dispatch else 0,
-                    egress_count,
-                    not first_dispatch,
-                ))
-            if lane_min >= dev_we:
-                if dispatches is not None:
-                    self._ledger_dispatches = dispatches
-                return state, lane_min, dev_we
-            # mid-window pause (egress headroom): drain and resume —
-            # the cached empty block keeps the retry transfer-free
-            inj = self._empty_block()
-            host_next = next_host_fn()
-            first_dispatch = False
-
-    # -- device-turn ledger (obs/turns.py) -----------------------------------
-
-    def _record_turn_rows(self, turns, t_start: int, host_in: bool) -> None:
-        """Record the buffered dispatches of one completed device turn
-        with their causes (docs/observability.md classification): the first
-        dispatch carries the turn's primary cause — ``injection`` when it
-        carried staged rows, else ``host_window`` when the completed
-        window has managed participation, else ``free_run`` — and every
-        egress-headroom resumption is its own ``egress_drain`` row.
-        Participants attach after the host round (the mp engine learns
-        them from the worker replies)."""
-        dispatches = self._ledger_dispatches
-        self._ledger_dispatches = None
-        if not dispatches:  # pragma: no cover - defensive
-            return
-        for dev_we, inj_rows, egr_rows, is_retry in dispatches:
-            if is_retry:
-                cause = "egress_drain"
-            elif inj_rows:
-                cause = "injection"
-            elif host_in:
-                cause = "host_window"
-            else:
-                cause = "free_run"
-            turns.turn(
-                cause, t_start, dev_we,
-                inject_rows=inj_rows, egress_rows=egr_rows,
-            )
 
     # -- k-window fused turns (docs/hybrid.md "k-window fusion law") ---------
 
@@ -987,7 +865,9 @@ class HybridEngine(_HostSideHybrid):
           arrival at or past the span's remaining windows cannot change
           their boundaries or contents (it merges at the next dispatch,
           before the window containing it is computed), so the accepted
-          prefix is bit-identical to the unfused law by construction;
+          prefix is bit-identical to one dispatch per window (a cap
+          of 1, whose single window is accepted unconditionally) by
+          construction;
         - on a misprediction the device ROLLS BACK: one rebuild dispatch
           from the pre-turn state with ``k_eff`` = the validated prefix
           reproduces exactly the accepted windows (pure function, same
@@ -997,7 +877,7 @@ class HybridEngine(_HostSideHybrid):
         double-applies a delivery or double-pops a parked payload; the
         rebuild's egress buffer (all rows below the validated frontier,
         already applied) is deliberately never read back.  Returns
-        (state, dev_next) like the unfused turn + round sequence."""
+        (state, dev_next)."""
         st = self.sync_stats
         obs = self.obs
         turns = obs.turns if obs is not None else None
@@ -1201,17 +1081,18 @@ class HybridEngine(_HostSideHybrid):
         """Record one fused dispatch's ledger rows (docs/observability.md)
         under the PR 11 cause precedence (injection > host_window >
         free_run): a dispatch that carried staged rows is an
-        ``injection`` row even when fused — the unfused law would have
+        ``injection`` row even when fused — a cap of 1 would have
         blocked for it, and labeling it ``free_run`` would inflate
         ``strict_free_turns`` and the remaining free-run headroom the
-        ``hybrid_fuse_warn_fraction`` soft check compares against; an
+        ``_FUSE_WARN_FRACTION`` soft check compares against; an
         injection-free dispatch covering >= 2 validated windows is a
         ``free_run`` row.  Either way ``windows`` carries the coverage
         (the fused accounting keys off it, not the cause).
         Single-window dispatches keep the full PR 11 law —
-        ``host_window`` only when the window's round actually ran,
-        matching the unfused law's ``host_in`` test (a passive-inline
-        delivery consumes no round and stays a strict ``free_run``); a
+        ``host_window`` only when the window's round actually ran (a
+        passive-inline delivery consumes no round and stays a strict
+        ``free_run``), ``egress_drain`` for an egress-headroom
+        resumption that completed no window; a
         prefix rebuild adds a ``rollback`` row with ``windows=0`` so the
         conservation law counts every dispatch while the implied-unfused
         accounting counts covered windows once."""
@@ -1329,64 +1210,14 @@ class HybridEngine(_HostSideHybrid):
         (``run_round(until)`` = threaded scheduler round vs worker-pipe
         round).  Returns the final device state for collection.
 
-        ``hybrid_fuse_k >= 2`` swaps in the k-window fused law
-        (docs/hybrid.md); at 1 this loop IS the PR 7 law, bit-for-bit,
-        including the transfer pattern."""
-        if self._fuse_on:
-            return self._window_loop_fused(run_round, on_window)
-        dev = self.device
-        state = dev.place_state(dev.initial_state())
-        hybrid_fn, inject_fn = dev.make_hybrid_fns()
-        dev_next = dev.first_event_time()
-        turns = self.obs.turns if self.obs is not None else None
-        while True:
-            host_next = self.next_event_time()
-            staged_min = min(
-                (e[0] for e in self._staged_merged), default=NEVER
-            )
-            dev_eff = min(dev_next, staged_min)
-            start = min(host_next, dev_eff)
-            if start >= self.stop_time or start == NEVER:
-                return state
-            self._maybe_stall(start)
-            end = min(start + self.current_runahead(), self.stop_time)
-            if self._staged_merged or dev_eff < end:
-                # device turn: complete every window up to (and including)
-                # the first one the host participates in
-                state, dev_next, dev_we = self._device_turn(
-                    state, hybrid_fn, inject_fn, self.next_event_time
-                )
-                host_in = self.next_event_time() < dev_we
-                if turns is not None:
-                    self._record_turn_rows(turns, start, host_in)
-                if host_in:
-                    # host part of the device-completed window
-                    self.window_end = dev_we
-                    run_round(dev_we)
-                    if turns is not None:
-                        turns.attach_participants(self._last_participants)
-                    if on_window is not None:
-                        on_window(start, dev_we, self.next_event_time())
-                continue
-            # host-only window (device idle beyond it, nothing staged)
-            self.window_end = end
-            run_round(end)
-            if turns is not None:
-                turns.host_round()
-            self.host_rounds += 1
-            if on_window is not None:
-                on_window(start, end, self.next_event_time())
-
-    def _window_loop_fused(self, run_round, on_window):
-        """The k-window fused hybrid window law: the same outer loop as
-        ``_window_loop`` with device turns delegated to ``_fused_turn``
-        (one dispatch covers up to ``hybrid_fuse_k`` participating
-        windows; covered rounds are serviced and validated post-hoc) and
-        the double-buffered eager dispatch resolving at adoption
-        barriers.  Host-only windows, the dynamic-runahead law, and the
-        staged-send fold are untouched — the fusion is a pure scheduling
-        change (tests/test_hybrid_fusion.py pins bit-parity with the CPU
-        oracle and the unfused engine)."""
+        Device turns are delegated to ``_fused_turn`` (one dispatch
+        covers up to ``hybrid_fuse_k`` participating windows; covered
+        rounds are serviced and validated post-hoc), with the
+        double-buffered eager dispatch resolving at adoption barriers.
+        Host-only windows, the dynamic-runahead law, and the staged-send
+        fold are the oracle's — the fusion is a pure scheduling change
+        (tests/test_hybrid_fusion.py pins every depth cap to the CPU
+        oracle)."""
         dev = self.device
         state = dev.place_state(dev.initial_state())
         fused_fn, inject_fn = dev.make_hybrid_fns(
@@ -1427,21 +1258,18 @@ class HybridEngine(_HostSideHybrid):
     def _check_fusion_accounting(self) -> None:
         """End-of-run ledger cross-check (ISSUE 13 satellite): the
         fused-turn accounting must conserve — ``turns + turns_saved``
-        equals the unfused turn count implied by the cause rows — and
+        equals the one-window-per-dispatch turn count implied by the
+        cause rows — and
         the achieved collapse is compared against the ledger's remaining
-        free-run headroom prediction (warn, never fail, below the
-        configured fraction)."""
+        free-run headroom prediction (warn, never fail, below
+        ``_FUSE_WARN_FRACTION`` of it)."""
         obs = self.obs
         if obs is None or obs.turns is None:
             return
         from ..obs import turns as tmod
 
         tmod.check_fusion_accounting(
-            obs.turns, self.sync_stats,
-            warn_fraction=(
-                self.cfg.experimental.hybrid_fuse_warn_fraction
-                if self._fuse_on else None
-            ),
+            obs.turns, self.sync_stats, warn_fraction=_FUSE_WARN_FRACTION
         )
 
     def netobs_snapshot(self):
@@ -1620,8 +1448,7 @@ class MpHybridEngine(HybridEngine):
             if wparts:
                 parts_all.extend(wparts)
             clean = clean and wclean
-            if wpeek:
-                self._worker_peeks[w] = wpeek
+            self._worker_peeks[w] = wpeek
         self._round_clean = clean
         t1 = wall_time.perf_counter()
         self.sync_stats["syscall_service_s"] += t1 - t0
@@ -1753,26 +1580,24 @@ class MpHybridEngine(HybridEngine):
             hid: w for w, part in enumerate(parts) for hid in part
         }
         record_turns = self.obs is not None and self.obs.turns is not None
-        peek_slots = self._ext_slots if self._fuse_on else 0
         conns, procs = spawn_cpu_workers(
             _hybrid_worker_main,
-            [(self.cfg, owned, record_turns, peek_slots)
+            [(self.cfg, owned, record_turns, self._ext_slots)
              for owned in parts],
         )
         self._mp = (conns, procs)
         self._pending_rows = [[] for _ in range(self.workers)]
         # initial next-event times from the parent replica (identical
         # deterministic construction — no startup round trip needed);
-        # same for the fused path's initial per-worker peek schedules
+        # same for the initial per-worker peek schedules
         self._eff_next = [
             min((self.hosts[i].queue.next_time() for i in owned),
                 default=NEVER)
             for owned in parts
         ]
-        if self._fuse_on:
-            self._worker_peeks = [
-                self._peek_partition(owned) for owned in parts
-            ]
+        self._worker_peeks = [
+            self._peek_partition(owned) for owned in parts
+        ]
         t0 = wall_time.perf_counter()
         try:
             return self._mp_loop(on_window, t0)

@@ -3770,122 +3770,17 @@ def _inject_merge(p: LaneParams, tb: LaneTables, s: LaneState, inj):
     )
 
 
-def _build_hybrid_run(p: LaneParams, tb: LaneTables):
-    """Device half of the hybrid backend: merge the injection block, then
-    free-run the fused window loop under the EXTERNAL bound.
-
-    The window law becomes ``start = min(lane_min, ext_bound)`` where
-    ``ext_bound = min(ext_min, egress_min)`` — ``ext_min`` is the host
-    side's next managed event and ``egress_min`` the earliest delivery
-    already egressed this call (a pending host event the host hasn't seen
-    yet).  The loop completes the current window whenever the host
-    participates in it (``ext_bound < now_we``) and then RETURNS — the
-    host services its part of that same window, stages its sends, and
-    calls again — but free-runs across windows the host has no events in
-    (the conservative-PDES contract: identical window sequence to the
-    scalar oracle, one device call per host sync instead of per round).
-    Also returns early when the egress buffer runs low on headroom."""
-    iter_fn = _build_iter(p, tb, pure_dataflow=True)
-    stop_hi, stop_lo = p.stop_time >> 31, p.stop_time & MASK31
-    room_floor = p.egress_capacity - p.ext_per_iter
-
-    def ext_bound(st, ext_hi, ext_lo):
-        lt = pair_lt(ext_hi, ext_lo, st.egress_min_hi, st.egress_min_lo)
-        return (
-            jnp.where(lt, ext_hi, st.egress_min_hi),
-            jnp.where(lt, ext_lo, st.egress_min_lo),
-        )
-
-    def hybrid_run(s: LaneState, ext_hi, ext_lo, ext_used, inj):
-        ext_hi = jnp.asarray(ext_hi, dtype=jnp.int32)
-        ext_lo = jnp.asarray(ext_lo, dtype=jnp.int32)
-        if p.dynamic_runahead:
-            s = s._replace(
-                min_used_lat=jnp.minimum(
-                    s.min_used_lat, jnp.asarray(ext_used, dtype=jnp.int32)
-                )
-            )
-        # previous call's egress was consumed by the host
-        s = s._replace(
-            egress_count=jnp.int32(0), egress_lost=jnp.int32(0),
-            egress_min_hi=jnp.int32(NEVER32),
-            egress_min_lo=jnp.int32(NEVER32),
-        )
-        s = _inject_merge(p, tb, s, inj)
-
-        def cond(carry):
-            st = unpack_state(carry)
-            mh, ml = _queue_min(p, st)
-            in_window = pair_lt(mh, ml, st.now_we_hi, st.now_we_lo)
-            bh, bl = ext_bound(st, ext_hi, ext_lo)
-            host_in_cur = pair_lt(bh, bl, st.now_we_hi, st.now_we_lo)
-            nsh, nsl = pair_sel(pair_lt(mh, ml, bh, bl), mh, ml, bh, bl)
-            fresh_ok = (~host_in_cur) & pair_lt(nsh, nsl, stop_hi, stop_lo)
-            room = st.egress_count < room_floor
-            return room & (in_window | fresh_ok)
-
-        def body(carry):
-            st = unpack_state(carry)
-            mn_hi, mn_lo = _queue_min(p, st)
-            bh, bl = ext_bound(st, ext_hi, ext_lo)
-            # the GLOBAL min: host-side events participate in the window law
-            mn_hi, mn_lo = pair_sel(
-                pair_lt(mn_hi, mn_lo, bh, bl), mn_hi, mn_lo, bh, bl
-            )
-            live = pair_lt(mn_hi, mn_lo, stop_hi, stop_lo)
-            fresh = pair_ge(mn_hi, mn_lo, st.now_we_hi, st.now_we_lo) & live
-            if p.netobs:
-                st = _flush_hist(p, st, fresh)
-            c_hi, c_lo = pair_sel(live, mn_hi, mn_lo, stop_hi, stop_lo)
-            c_hi, c_lo = pair_add32(c_hi, c_lo, _effective_runahead(p, st))
-            c_hi, c_lo = pair_sel(
-                pair_lt(c_hi, c_lo, stop_hi, stop_lo),
-                c_hi, c_lo, stop_hi, stop_lo,
-            )
-            st = st._replace(
-                now_we_hi=jnp.where(fresh, c_hi, st.now_we_hi),
-                now_we_lo=jnp.where(fresh, c_lo, st.now_we_lo),
-                rounds=st.rounds + fresh.astype(st.rounds.dtype),
-            )
-            return pack_state(iter_fn(st))
-
-        s = unpack_state(lax.while_loop(cond, body, pack_state(s)))
-        lane_min = t_join(*_queue_min(p, s))
-        # ONE packed scalar vector per device turn: every host-side
-        # decision input (lane_min, completed window end, dynamic-runahead
-        # fold, egress fill/overflow) rides a single [5] int64 transfer —
-        # the host issues one readback per turn instead of six (assumes
-        # a per-transfer, not per-byte, cost at this size; unmeasured on
-        # the attached chip — docs/hybrid.md counts the transfers)
-        scalars = jnp.stack(
-            [
-                lane_min,
-                t_join(s.now_we_hi, s.now_we_lo),
-                (s.min_used_lat if p.dynamic_runahead
-                 else jnp.int32(NEVER32)).astype(jnp.int64),
-                s.egress_count.astype(jnp.int64),
-                s.egress_lost.astype(jnp.int64),
-            ]
-        )
-        return s, scalars
-
-    return hybrid_run
-
-
-# indices into the packed scalar vector returned by make_hybrid_fn
+# indices into the packed scalar vector make_hybrid_fused_fn returns: ONE
+# int64 transfer per device turn carries every host-side decision input
+# (lane_min, completed window end, dynamic-runahead fold, egress
+# fill/overflow), then the consumed-window count and the per-window ends
 HYB_LANE_MIN = 0
 HYB_DEV_WE = 1
 HYB_MIN_USED = 2
 HYB_EGRESS_COUNT = 3
 HYB_EGRESS_LOST = 4
-
-
-def make_hybrid_fn(p: LaneParams, tb: LaneTables):
-    """Jitted hybrid device call: (state, ext_min_hi, ext_min_lo,
-    ext_used_lat, inject_block) -> (state, scalars[5] int64) where
-    scalars = (lane_min, dev_window_end, min_used_lat, egress_count,
-    egress_lost) — see the HYB_* indices."""
-    return jax.jit(_build_hybrid_run(p, tb))
+HYB_K_DONE = 5
+HYB_WE_BASE = 6
 
 
 def make_inject_fn(p: LaneParams, tb: LaneTables):
@@ -3898,24 +3793,27 @@ def make_inject_fn(p: LaneParams, tb: LaneTables):
     return jax.jit(inject)
 
 
-# fused-readback layout (make_hybrid_fused_fn): slots 0..4 are the HYB_*
-# indices above, then the consumed-window count and the per-window ends
-HYB_K_DONE = 5
-HYB_WE_BASE = 6
-
-
 def _build_hybrid_fused_run(p: LaneParams, tb: LaneTables, k_cap: int,
                             ext_slots: int):
-    """The k-window FUSED hybrid device call (docs/hybrid.md "k-window
-    fusion law"): the identical window law to :func:`_build_hybrid_run`,
-    but instead of returning at the FIRST window with external
-    participation, the loop consumes up to ``k_eff`` participating
-    windows from a host-provided schedule of peeked next-event times,
-    recording each consumed window's end for the post-hoc host round
-    servicing (the arrival-frontier validation law lives host-side in
+    """Device half of the hybrid backend, the k-window FUSED call
+    (docs/hybrid.md "k-window fusion law"): merge the injection block,
+    then free-run the window loop under the EXTERNAL bound.
+
+    The window law is ``start = min(lane_min, ext_bound)`` where
+    ``ext_bound = min(ext_min, egress_min)`` — ``ext_min`` is the host
+    side's next managed event and ``egress_min`` the earliest delivery
+    already egressed this call (a pending host event the host hasn't seen
+    yet).  The loop free-runs across windows the host has no events in
+    (the conservative-PDES contract: identical window sequence to the
+    scalar oracle) and COMPLETES every window the host participates in
+    (``ext_bound < now_we``), consuming up to ``k_eff`` such windows from
+    a host-provided schedule of peeked next-event times and recording
+    each consumed window's end for the post-hoc host round servicing
+    (the arrival-frontier validation law lives host-side in
     backend/hybrid.py; a misprediction rolls back by re-running this
     kernel from the pre-dispatch state with ``k_eff`` = the validated
-    prefix, which reproduces the prefix bit-identically).
+    prefix, which reproduces the prefix bit-identically).  Also returns
+    early when the egress buffer runs low on headroom.
 
     ``ext_times`` ([ext_slots] int32 hi/lo pairs, ascending) carries the
     host side's next distinct event times; the LAST slot is the
@@ -3925,14 +3823,15 @@ def _build_hybrid_fused_run(p: LaneParams, tb: LaneTables, k_cap: int,
     free-runs past an external event it was not told about.  Between
     consumed windows the ``egress_min`` free-run guard is RE-ARMED as the
     min pending DELIVERED egress time at or past the consumed frontier —
-    the running-min law of the single-window kernel generalized to a
-    popped fold, so an unserviced host delivery keeps bounding the
-    window law exactly as the oracle's DELIVERY event would.
+    a popped fold of the running min, so an unserviced host delivery
+    keeps bounding the window law exactly as the oracle's DELIVERY event
+    would.
 
     Returns (state, scalars[6 + k_cap] int64): the HYB_* slots, the
     consumed-window count (HYB_K_DONE), and the consumed window ends
-    (HYB_WE_BASE + i).  With ``k_eff = 1`` the dispatch is input- and
-    output-equivalent to :func:`_build_hybrid_run` (the PR 7 law)."""
+    (HYB_WE_BASE + i).  With ``k_eff = 1`` the call returns after the
+    FIRST window with external participation: one device call per host
+    sync (``hybrid_fuse_k: 1``, and every one-window rollback rebuild)."""
     iter_fn = _build_iter(p, tb, pure_dataflow=True)
     stop_hi, stop_lo = p.stop_time >> 31, p.stop_time & MASK31
     room_floor = p.egress_capacity - p.ext_per_iter
@@ -3981,8 +3880,9 @@ def _build_hybrid_fused_run(p: LaneParams, tb: LaneTables, k_cap: int,
         horizon_hi, horizon_lo = ext_thi[ext_slots - 1], ext_tlo[ext_slots - 1]
 
         def inner(pk, ptr):
-            """One fused segment: the single-window kernel's while loop
-            verbatim, bounded by the current schedule slot."""
+            """One fused segment: the window loop up to and including
+            the next window the host participates in, bounded by the
+            current schedule slot."""
             e_hi = ext_thi[jnp.minimum(ptr, ext_slots - 1)]
             e_lo = ext_tlo[jnp.minimum(ptr, ext_slots - 1)]
 

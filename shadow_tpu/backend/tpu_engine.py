@@ -355,7 +355,6 @@ class TpuEngine:
         # events, slower — fine for untimed evidence runs)
         tiered = bool(
             one_to_one
-            and cfg.experimental.tpu_stream_tiered
             and not ext_mask.any()
             and not self._flowtrace_on
         )
@@ -722,18 +721,17 @@ class TpuEngine:
             return p.runahead
         return max(used, max(p.runahead_floor, 1))
 
-    # -- hybrid kernel variants --------------------------------------------
+    # -- hybrid kernel ------------------------------------------------------
 
-    def make_hybrid_fns(self, fuse_k: int = 1, ext_slots: int = 0):
+    def make_hybrid_fns(self, fuse_k: int, ext_slots: int):
         """The hybrid backend's jitted device entry points, built against
         this engine's params/tables: ``(turn_fn, inject_fn)``.
 
-        ``fuse_k == 1`` returns the single-window law
-        (:func:`lanes.make_hybrid_fn` signature); ``fuse_k >= 2`` returns
-        the k-window fused variant (:func:`lanes.make_hybrid_fused_fn`,
-        docs/hybrid.md "k-window fusion law") whose dispatch covers up to
-        ``fuse_k`` participating windows against a host-peeked
-        ``ext_slots``-wide event-time schedule.
+        ``turn_fn`` is the k-window fused call
+        (:func:`lanes.make_hybrid_fused_fn`, docs/hybrid.md "k-window
+        fusion law"): one dispatch covers up to ``fuse_k`` participating
+        windows (the static cap; 1 = one window per dispatch) against a
+        host-peeked ``ext_slots``-wide event-time schedule.
 
         With a mesh attached the same entry points compile SHARDED
         (parallel.make_sharded_hybrid_fns): lane state split on the host
@@ -743,18 +741,14 @@ class TpuEngine:
             from .. import parallel
 
             return parallel.make_sharded_hybrid_fns(
-                self.params, self.tables, self._mesh,
-                fuse_k=fuse_k, ext_slots=ext_slots,
+                self.params, self.tables, self._mesh, fuse_k, ext_slots
             )
-        inject_fn = lanes.make_inject_fn(self.params, self.tables)
-        if fuse_k >= 2:
-            return (
-                lanes.make_hybrid_fused_fn(
-                    self.params, self.tables, fuse_k, ext_slots
-                ),
-                inject_fn,
-            )
-        return lanes.make_hybrid_fn(self.params, self.tables), inject_fn
+        return (
+            lanes.make_hybrid_fused_fn(
+                self.params, self.tables, fuse_k, ext_slots
+            ),
+            lanes.make_inject_fn(self.params, self.tables),
+        )
 
     # -- sweep kernel (shadow_tpu/sweep drives this) -----------------------
 

@@ -151,10 +151,10 @@ class ExperimentalOptions:
     # bit-identical at any mesh shape.  A 1-D ``tpu_mesh_shape`` tuple is
     # the older alias for the same request.
     mesh_devices: int = 0
-    # TIERED stream backend (one-to-one stream configs): stream endpoints
+    # TIERED stream backend (one-to-one stream configs on a pure-lane,
+    # untraced run — TpuEngine decides from the config): stream endpoints
     # run on a dedicated [2S]-row tier with their own queue block and pop
     # rate, keeping the [N]-wide machinery stream-free (docs/tpu-backend.md)
-    tpu_stream_tiered: bool = True
     tpu_stream_events_per_round: int = 8  # tier pops per iteration (K_s)
     tpu_stream_queue_capacity: int = 64  # tier queue width (C2)
     # HYBRID backend (backend/hybrid.py): syscall-servicing worker
@@ -171,20 +171,13 @@ class ExperimentalOptions:
     # many consecutive host-participating windows, with the covered
     # syscall rounds serviced post-hoc under the arrival-frontier
     # validation law (rollback to the validated prefix on a late staged
-    # injection).  1 disables fusion — the exact PR 7 one-dispatch-per-
-    # participating-window law, bit-for-bit.
+    # injection).  It is a depth CAP on the one turn law: at 1 every
+    # dispatch covers one participating window (no rollback, and no eager
+    # dispatch: there is nothing to overlap); at >= 2 the next turn is
+    # also dispatched eagerly while syscall servicing runs, and adopted
+    # only when its inputs match the real ones bit-exact (the
+    # UNCONDITIONAL version is unsound, docs/hybrid.md).
     hybrid_fuse_k: int = 8
-    # double-buffered async dispatch (hybrid, requires fusion): when the
-    # next fused turn's injection is provably empty so far, dispatch it
-    # eagerly and overlap syscall servicing with device compute,
-    # resolving (adopt or discard) at the readback barrier.  The
-    # UNCONDITIONAL version is unsound (docs/hybrid.md); this one only
-    # adopts a result whose inputs were validated bit-exact.
-    hybrid_async_dispatch: bool = True
-    # fusion-effectiveness floor: warn (never fail) when the achieved
-    # turn collapse falls below this fraction of the ledger's remaining
-    # kfusion_headroom_freerun prediction (obs_turns runs only)
-    hybrid_fuse_warn_fraction: float = 0.5
     # --- crash safety (engine/checkpoint.py, docs/robustness.md) ---------
     # write an on-disk checkpoint every N window-clamp boundaries
     # (0 = checkpointing off); pure-lane backends only (cpu, cpu_mp, tpu)
@@ -495,10 +488,6 @@ class ConfigOptions:
                 )
         if self.experimental.hybrid_fuse_k < 1:
             raise ConfigError("experimental.hybrid_fuse_k must be >= 1")
-        if not 0.0 <= self.experimental.hybrid_fuse_warn_fraction <= 1.0:
-            raise ConfigError(
-                "experimental.hybrid_fuse_warn_fraction must be in [0, 1]"
-            )
         if self.experimental.checkpoint_every_windows < 0:
             raise ConfigError(
                 "experimental.checkpoint_every_windows must be >= 0"

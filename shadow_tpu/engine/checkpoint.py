@@ -99,7 +99,7 @@ _EXPERIMENTAL_EXCLUDE_PREFIXES = ("obs_", "checkpoint_", "netobs_")
 _EXPERIMENTAL_EXCLUDE = frozenset({
     "run_control", "perf_logging", "resume_from",
     "worker_heartbeat_s", "worker_restart_max", "dispatch_retry_max",
-    "hybrid_fuse_warn_fraction", "use_cpu_pinning",
+    "use_cpu_pinning",
 })
 
 

@@ -264,8 +264,8 @@ def make_sharded_hybrid_fns(
     p: lanes.LaneParams,
     tb: lanes.LaneTables,
     mesh: Mesh,
-    fuse_k: int = 1,
-    ext_slots: int = 0,
+    fuse_k: int,
+    ext_slots: int,
     axis: str = HOST_AXIS,
 ):
     """The hybrid backend's device entry points compiled under ``mesh``:
@@ -287,18 +287,11 @@ def make_sharded_hybrid_fns(
     inject_fn = _spmd_entry(jax.jit(
         _inject, in_shardings=(sh, repl), out_shardings=sh
     ))
-    if fuse_k >= 2:
-        turn_fn = _spmd_entry(jax.jit(
-            lanes._build_hybrid_fused_run(p, tb, fuse_k, ext_slots),
-            in_shardings=(sh, repl, repl, repl, repl, repl),
-            out_shardings=(sh, repl),
-        ))
-    else:
-        turn_fn = _spmd_entry(jax.jit(
-            lanes._build_hybrid_run(p, tb),
-            in_shardings=(sh, repl, repl, repl, repl),
-            out_shardings=(sh, repl),
-        ))
+    turn_fn = _spmd_entry(jax.jit(
+        lanes._build_hybrid_fused_run(p, tb, fuse_k, ext_slots),
+        in_shardings=(sh, repl, repl, repl, repl, repl),
+        out_shardings=(sh, repl),
+    ))
     return turn_fn, inject_fn
 
 

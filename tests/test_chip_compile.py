@@ -172,24 +172,23 @@ def _empty_inject_block(p):
 
 
 def test_hybrid_turn_inject_and_fused_k(one_chip, as_tpu, tmp_path):
-    """The hybrid backend's three device entry points at the relay-chain-
-    large shape: single-window turn, injection merge, k-window fused turn
-    at the configured ``hybrid_fuse_k``."""
+    """The hybrid backend's device entry points at the relay-chain-large
+    shape: the injection merge, and the k-window fused turn at both depth
+    caps a user can reach — 1 (one window per dispatch) and the
+    configured ``hybrid_fuse_k``."""
     cfg, eng = _relay_chain_engine(tmp_path)
     state = _shapes(eng.initial_state(), one_chip)
-    i32 = np.int32
     inj = _shapes(_empty_inject_block(eng.params), one_chip)
-    never = int(lanes.NEVER32)
-    turn_fn, inject_fn = eng.make_hybrid_fns()
-    # the host passes the external bound as Python ints (hybrid.py)
-    _fits(turn_fn.lower(state, never, never, never, inj).compile())
+    never = int(lanes.NEVER32)  # the host passes it as a Python int
+    k_cfg = int(cfg.experimental.hybrid_fuse_k)
+    assert k_cfg >= 2
+    for k in (1, k_cfg):
+        slots = max(2 * k, 9)  # HybridEngine._ext_slots
+        fused_fn, inject_fn = eng.make_hybrid_fns(k, slots)
+        ext = jax.ShapeDtypeStruct((slots,), np.int32, sharding=one_chip)
+        _fits(fused_fn.lower(
+            state, ext, ext, never, inj, np.int32(k)).compile())
     _fits(inject_fn.lower(state, inj).compile())
-    k = int(cfg.experimental.hybrid_fuse_k)
-    slots = max(2 * k, 9)  # HybridEngine._ext_slots
-    assert k >= 2
-    fused_fn, _ = eng.make_hybrid_fns(k, slots)
-    ext = jax.ShapeDtypeStruct((slots,), i32, sharding=one_chip)
-    _fits(fused_fn.lower(state, ext, ext, never, inj, np.int32(k)).compile())
 
 
 def _scatter_update_shapes(stablehlo: str) -> list[str]:
@@ -219,15 +218,13 @@ def test_hybrid_turn_offers_no_candidate_row_scatter(as_tpu, tmp_path):
     p = eng.params
     state, inj = eng.initial_state(), _empty_inject_block(p)
     never = int(lanes.NEVER32)
-    fuse_k = int(cfg.experimental.hybrid_fuse_k)  # the cell runs this one
-    slots = max(2 * fuse_k, 9)
-    ext = np.full(slots, never, dtype=np.int32)
-    texts = [
-        eng.make_hybrid_fns()[0].lower(
-            state, never, never, never, inj).as_text(),
-        eng.make_hybrid_fns(fuse_k, slots)[0].lower(
-            state, ext, ext, never, inj, np.int32(fuse_k)).as_text(),
-    ]
+    texts = []
+    # a depth cap of 1, and the configured one (the cell runs that one)
+    for fuse_k in (1, int(cfg.experimental.hybrid_fuse_k)):
+        slots = max(2 * fuse_k, 9)
+        ext = np.full(slots, never, dtype=np.int32)
+        texts.append(eng.make_hybrid_fns(fuse_k, slots)[0].lower(
+            state, ext, ext, never, inj, np.int32(fuse_k)).as_text())
     n, k = p.n_lanes, p.pops_per_iter
     offered = {n * (k + p.cross_cap), n * (2 * k + p.cross_cap), k * n}
     for text in texts:

@@ -3,16 +3,20 @@
 
 The contracts under test:
 
-1. **Pure scheduling change** — with fusion + async dispatch at their
-   defaults, the event log, rounds, and workload counters are
-   bit-identical to the ``hybrid_fuse_k=1`` (PR 7) law.  The single
-   intentional exception is the ``lane_iters`` diagnostic: a fused
-   dispatch visits absorbed ext-only windows with no-op device
-   iterations the one-window law never ran, so the iteration *count*
-   (not any event, log byte, or netobs counter) legitimately differs.
-2. **Degenerate law** — ``hybrid_fuse_k=1`` takes the PR 7 code path
-   verbatim: no fused rows, no rollbacks, ``turns_saved == 0``, and (at
-   the SHADOW_TPU_SCALE gate) the pinned 651-turn gate-scale count.
+1. **Pure scheduling change** — at the default depth cap (8, with the
+   eager dispatch it brings), the event log, rounds, and workload
+   counters are bit-identical to a cap of 1 (``hybrid_fuse_k=1``: one
+   participating window per dispatch), and every cap in {1, 2, 8} is
+   held to the CPU oracle directly.  The single intentional exception
+   is the ``lane_iters`` diagnostic: a fused dispatch visits absorbed
+   ext-only windows with no-op device iterations a one-window dispatch
+   never ran, so the iteration *count* (not any event, log byte, or
+   netobs counter) legitimately differs.
+2. **Degenerate law** — ``hybrid_fuse_k=1`` is the SAME turn law and
+   kernel at a cap of 1: the walk accepts its one window
+   unconditionally, so no fused rows, no rollbacks, no eager dispatch,
+   ``turns_saved == 0``, and (at the SHADOW_TPU_SCALE gate) the pinned
+   651-turn gate-scale count.
 3. **Late injection falls back** — the pingpong cadence stages sends
    whose arrivals land inside fused spans, forcing validation failures:
    rollback rebuilds and discarded eager dispatches both occur, and the
@@ -20,7 +24,7 @@ The contracts under test:
 4. **Ledger accounting** — ``turns == sum(cause_counts)`` with
    ``free_run``/``rollback`` rows present, ``turns == device_turns``,
    ``turns + turns_saved == implied_unfused``, and the covered-windows
-   invariant across fused/unfused runs.
+   invariant across the cap-8 and cap-1 runs.
 
 Worker-count invariance and oracle bit-parity with fusion ON ride the
 existing suite (tests/test_hybrid_mp.py, tests/test_turns.py — fusion is
@@ -57,8 +61,7 @@ def native_build():
     )
 
 
-def _cfg(data_dir: Path, workers: int = 1, fuse_k: int = 8,
-         async_dispatch: bool = True) -> ConfigOptions:
+def _cfg(data_dir: Path, workers: int = 1, fuse_k: int = 8) -> ConfigOptions:
     """The test_hybrid_mp mixed scenario (managed pingpong + tcpecho
     pairs over a tgen lane mesh): pingpong's per-round request/response
     cadence stages sends whose arrivals land one window out — the
@@ -80,9 +83,7 @@ def _cfg(data_dir: Path, workers: int = 1, fuse_k: int = 8,
 general: {{stop_time: 2s, seed: 21, data_directory: {data_dir}, heartbeat_interval: null}}
 network: {{graph: {{type: 1_gbit_switch}}}}
 experimental: {{network_backend: tpu, hybrid_workers: {workers},
-                hybrid_fuse_k: {fuse_k},
-                hybrid_async_dispatch: {str(async_dispatch).lower()},
-                obs_turns: true}}
+                hybrid_fuse_k: {fuse_k}, obs_turns: true}}
 hosts:
   cli:
     network_node_id: 0
@@ -181,8 +182,30 @@ def fused(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def unfused(tmp_path_factory):
+    """The same law at a depth cap of 1: one window per dispatch."""
     tmp = tmp_path_factory.mktemp("fusion_off")
     return _run(_cfg(tmp / "d", fuse_k=1))
+
+
+@pytest.fixture(scope="module")
+def congested(tmp_path_factory):
+    """The congested scenario at a depth cap, run once per cap."""
+    tmp = tmp_path_factory.mktemp("congested")
+    runs = {}
+
+    def at(fuse_k: int):
+        if fuse_k not in runs:
+            runs[fuse_k] = _run(_congested_cfg(tmp / f"k{fuse_k}", fuse_k))
+        return runs[fuse_k]
+
+    return at
+
+
+@pytest.fixture(scope="module")
+def congested_oracle(tmp_path_factory):
+    cfg = _congested_cfg(tmp_path_factory.mktemp("congested_cpu") / "d")
+    cfg.experimental.network_backend = "cpu"
+    return _run(cfg)[0]
 
 
 def _counters_mod_iters(r):
@@ -236,14 +259,14 @@ class TestPureSchedulingChange:
 
 
 class TestRollbackEgressParity:
-    def test_congested_rollback_bit_parity(self, tmp_path):
+    def test_congested_rollback_bit_parity(self, congested):
         """Validated-prefix deliveries whose down-bucket queueing delays
         ``t_deliver`` past the last validated window end must survive a
         rollback (re-read from the rebuild's egress buffer) — without
         that, the fused law silently drops them and diverges from the
-        ``hybrid_fuse_k=1`` law under congestion."""
-        rf, ef, _lf = _run(_congested_cfg(tmp_path / "f"))
-        ru, eu, _lu = _run(_congested_cfg(tmp_path / "u", fuse_k=1))
+        ``hybrid_fuse_k=1`` cap under congestion."""
+        rf, ef, _lf = congested(8)
+        ru, eu, _lu = congested(1)
         # the scenario is only probative while it actually rolls back
         assert ef.sync_stats["fuse_rollbacks"] > 0
         assert eu.sync_stats["fuse_rollbacks"] == 0
@@ -251,6 +274,23 @@ class TestRollbackEgressParity:
         assert rf.rounds == ru.rounds
         assert _counters_mod_iters(rf) == _counters_mod_iters(ru)
         assert rf.per_host_counters == ru.per_host_counters
+
+    @pytest.mark.parametrize("fuse_k", [1, 2, 8])
+    def test_depth_cap_equals_cpu_oracle(
+        self, fuse_k, congested, congested_oracle
+    ):
+        """Every depth cap against ``network_backend: cpu`` itself, not
+        against another cap: log, rounds, counters (less the lane
+        plane's own ``lane_*`` bookkeeping, which the oracle has no
+        device to count) and per-host counters."""
+        r, eng, _led = congested(fuse_k)
+        assert (eng.sync_stats["fuse_rollbacks"] > 0) == (fuse_k > 1)
+        assert r.log_tuples() == congested_oracle.log_tuples()
+        assert r.rounds == congested_oracle.rounds
+        assert {
+            k: v for k, v in r.counters.items() if not k.startswith("lane_")
+        } == congested_oracle.counters
+        assert r.per_host_counters == congested_oracle.per_host_counters
 
 
 class TestDegenerateLaw:
@@ -266,6 +306,38 @@ class TestDegenerateLaw:
         assert led.cause_counts["rollback"] == 0
         assert all(row[3] == 1 for row in led.rows)  # every row: 1 window
         assert led.turns_saved() == 0
+
+    @pytest.mark.parametrize("fuse_k", [1, 8])
+    def test_failed_dispatch_is_retried(
+        self, fuse_k, tmp_path, monkeypatch, fused, unfused
+    ):
+        """The dispatch retry law (docs/robustness.md) at both ends of
+        the cap: a device runtime error on a blocking dispatch is
+        re-dispatched from the pre-turn state, and the run's results are
+        those of a run that never failed."""
+        from shadow_tpu.backend.tpu_engine import TpuEngine
+
+        real = TpuEngine.make_hybrid_fns
+        calls = []
+
+        def flaky(self, k, slots):
+            turn_fn, inject_fn = real(self, k, slots)
+
+            def turn(*args):
+                calls.append(1)
+                if len(calls) == 1:  # the first dispatch is never eager
+                    raise RuntimeError("injected device runtime error")
+                return turn_fn(*args)
+
+            return turn, inject_fn
+
+        monkeypatch.setattr(TpuEngine, "make_hybrid_fns", flaky)
+        r, eng, _led = _run(_cfg(tmp_path / "d", fuse_k=fuse_k))
+        assert eng.sync_stats["dispatch_retries"] == 1
+        ref = (unfused if fuse_k == 1 else fused)[0]
+        assert r.log_tuples() == ref.log_tuples()
+        assert r.rounds == ref.rounds
+        assert r.counters == ref.counters
 
     def test_fused_turn_count_drops(self, fused, unfused):
         _rf, ef, _lf = fused

@@ -63,7 +63,7 @@ def _cfg(data_dir: Path, backend: str, workers: int = 1,
 general: {{stop_time: 2s, seed: 21, data_directory: {data_dir}, heartbeat_interval: null}}
 network: {{graph: {{type: 1_gbit_switch}}}}
 experimental: {{network_backend: {backend}, hybrid_workers: {workers},
-                hybrid_fuse_k: 8, hybrid_async_dispatch: true}}
+                hybrid_fuse_k: 8}}
 {faults}
 hosts:
   cli:
