@@ -36,6 +36,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from ..net import ltcp
 from . import lanes_pairs as lp
@@ -91,26 +92,24 @@ class StreamState(NamedTuple):
     sv: jnp.ndarray
 
 
-def _fresh_matrix(n: int) -> jnp.ndarray:
-    m = jnp.zeros((n, N_COLS), dtype=jnp.int32)
-    m = m.at[:, C_CWND].set(ltcp.INIT_CWND_FP)
-    m = m.at[:, C_SSTHRESH].set(ltcp.INIT_SSTHRESH_FP)
-    m = m.at[:, C_SRTT_HI].set(-1)
-    m = m.at[:, C_RTO_HI].set(_RTO_INIT_P[0])
-    m = m.at[:, C_RTO_LO].set(_RTO_INIT_P[1])
-    m = m.at[:, C_RTT_SEQ].set(-1)
-    m = m.at[:, C_RTODL_HI].set(NEVER32)
-    m = m.at[:, C_RTODL_LO].set(NEVER32)
-    m = m.at[:, C_RTOEV_HI].set(NEVER32)
-    m = m.at[:, C_RTOEV_LO].set(NEVER32)
-    m = m.at[:, C_EPOCH_HI].set(NEVER32)
-    m = m.at[:, C_EPOCH_LO].set(NEVER32)
+def _fresh_matrix(n: int) -> np.ndarray:
+    m = np.zeros((n, N_COLS), dtype=np.int32)
+    m[:, C_CWND] = ltcp.INIT_CWND_FP
+    m[:, C_SSTHRESH] = ltcp.INIT_SSTHRESH_FP
+    m[:, C_SRTT_HI] = -1
+    m[:, C_RTO_HI] = _RTO_INIT_P[0]
+    m[:, C_RTO_LO] = _RTO_INIT_P[1]
+    m[:, C_RTT_SEQ] = -1
+    for col in (C_RTODL_HI, C_RTODL_LO, C_RTOEV_HI, C_RTOEV_LO,
+                C_EPOCH_HI, C_EPOCH_LO):
+        m[:, col] = NEVER32
     return m
 
 
 def init_stream_state(n: int) -> StreamState:
-    """Fresh endpoint matrices (transfer-shape tables are static and live
-    in LaneTables, not here)."""
+    """Fresh endpoint matrices, on the HOST (numpy): the engine places the
+    whole initial state in one transfer (``TpuEngine.initial_state``).
+    Transfer-shape tables are static and live in LaneTables, not here."""
     return StreamState(cl=_fresh_matrix(n), sv=_fresh_matrix(n))
 
 
@@ -932,20 +931,21 @@ def init_tier_state(
     up_tokens,
     interval: int,
 ) -> TierState:
-    """Fresh tier state.  ``dn_tokens``/``up_tokens`` are the [2S] initial
-    bucket fills (= burst) of each endpoint's lane; time-state starts at
-    the same values LaneState uses (next_refill = one interval in,
-    CoDel first_above = unset sentinel)."""
-    i32 = jnp.int32
+    """Fresh tier state, on the HOST (numpy, like ``init_stream_state``).
+    ``dn_tokens``/``up_tokens`` are the [2S] initial bucket fills
+    (= burst) of each endpoint's lane; time-state starts at the same
+    values LaneState uses (next_refill = one interval in, CoDel
+    first_above = unset sentinel)."""
+    i32 = np.int32
     s2 = 2 * s_flows
-    q = jnp.zeros((7, s2, capacity), dtype=i32)
-    q = q.at[TQ_THI].set(NEVER32)
-    q = q.at[TQ_TLO].set(NEVER32)
-    v = jnp.zeros((TV_COUNT, s2), dtype=i32)
-    v = v.at[TV_DN_TOK].set(jnp.asarray(dn_tokens, dtype=i32))
-    v = v.at[TV_UP_TOK].set(jnp.asarray(up_tokens, dtype=i32))
-    v = v.at[TV_DN_NRL].set(interval)
-    v = v.at[TV_UP_NRL].set(interval)
+    q = np.zeros((7, s2, capacity), dtype=i32)
+    q[TQ_THI] = NEVER32
+    q[TQ_TLO] = NEVER32
+    v = np.zeros((TV_COUNT, s2), dtype=i32)
+    v[TV_DN_TOK] = np.asarray(dn_tokens).astype(i32)
+    v[TV_UP_TOK] = np.asarray(up_tokens).astype(i32)
+    v[TV_DN_NRL] = interval
+    v[TV_UP_NRL] = interval
     # CD_UNSET mirrors lanes.CD_UNSET (module split avoids the import cycle)
-    v = v.at[TV_CD_FATH].set(-(1 << 31) + 1)
+    v[TV_CD_FATH] = -(1 << 31) + 1
     return TierState(flows=init_stream_state(s_flows), q=q, v=v)

@@ -611,6 +611,7 @@ class TpuEngine:
             ),
         )
         self._local_seq0 = local_seq0
+        self._model_np = model  # [N] app model per lane (collect's masks)
         self._el_np = el_np  # [2S] endpoint lanes (tiered routing/collect)
         self._peer_np = peer_np  # [2S] peer lanes (fault-epoch flow tables)
         self._node_idx = node_idx  # [N] host -> dense node index
@@ -625,6 +626,10 @@ class TpuEngine:
         self._mesh = None
         self._run_fn = None
         self._compiled = None
+        # the initial state run() keeps on the device (_start_state), and
+        # whether the last run started from it (lane_plane's state_reused)
+        self._kept = None
+        self._state_reused = 0
         # the devices the last collected state lived on (device_info)
         self._placed_devices = None
         # [window-agg] telemetry sink (step mode only; set by the facade)
@@ -653,6 +658,7 @@ class TpuEngine:
         self._mesh = mesh
         self._run_fn = None
         self._compiled = None
+        self._kept = None
 
     @property
     def mesh(self):
@@ -776,7 +782,11 @@ class TpuEngine:
 
     # -- state construction ------------------------------------------------
 
-    def initial_state(self) -> lanes.LaneState:
+    def initial_state(self, shardings=None) -> lanes.LaneState:
+        """A FRESH initial lane state per call, built on the host and
+        placed by one ``jax.device_put``: on JAX's default device, or
+        straight onto ``shardings`` (``parallel.state_shardings``).
+        ``run`` builds it once per engine (``_start_state``)."""
         p = self.params
         n, c = p.n_lanes, p.capacity
         q_time = np.full((n, c), NEVER, dtype=np.int64)
@@ -881,103 +891,98 @@ class TpuEngine:
             tq[lstr_mod.TQ_AUXH] = tq_auxh
             tq[lstr_mod.TQ_AUXL] = tq_auxl
             tq[lstr_mod.TQ_SIZE] = tq_size
-            v0 = np.asarray(stream0.v)
-            v0 = v0.copy()
-            v0[lstr_mod.TV_LOCAL_SEQ] = self._local_seq0[self._el_np]
-            stream0 = stream0._replace(
-                q=jnp.asarray(tq), v=jnp.asarray(v0)
-            )
+            stream0.v[lstr_mod.TV_LOCAL_SEQ] = self._local_seq0[self._el_np]
+            stream0 = stream0._replace(q=tq)
         elif p.stream_present:
             stream0 = lstr_mod.init_stream_state(self._s_flows)
         else:
             stream0 = ()
 
-        up_burst = np.asarray(self.tables.up_burst)
-        dn_burst = np.asarray(self.tables.dn_burst)
-        i32 = jnp.int32
-        z32 = np.zeros(n, dtype=np.int32)
+        i32 = np.int32
+
+        def full(shape=(), fill=0, dtype=i32):
+            # every leaf an array of its own: buffers placed from one host
+            # array may alias (XLA:CPU places without a copy)
+            return np.full(shape, fill, dtype=dtype)
+
+        def lane(fill=0, dtype=i32):
+            return full(n, fill, dtype)
+
+        ext = p.external_any
         # bucket state: next_refill starts one interval in (grid-aligned),
         # last_depart at 0 — as pairs (hi, lo); CoDel first_above starts at
         # the UNSET sentinel (the int64 law's time-0 marker)
-        return lanes.LaneState(
-            q_thi=jnp.asarray(q_thi),
-            q_tlo=jnp.asarray(q_tlo),
-            q_auxh=jnp.asarray(q_auxh),
-            q_auxl=jnp.asarray(q_auxl),
-            q_size=jnp.asarray(q_size),
-            q_phi=(
-                jnp.zeros((n, c), dtype=jnp.int32)
-                if p.lanes_have_payload else ()
-            ),
-            q_plo=(
-                jnp.zeros((n, c), dtype=jnp.int32)
-                if p.lanes_have_payload else ()
-            ),
+        host = lanes.LaneState(
+            q_thi=q_thi,
+            q_tlo=q_tlo,
+            q_auxh=q_auxh,
+            q_auxl=q_auxl,
+            q_size=q_size,
+            q_phi=full((n, c)) if p.lanes_have_payload else (),
+            q_plo=full((n, c)) if p.lanes_have_payload else (),
             stream=stream0,
-            send_seq=jnp.asarray(z32),
-            local_seq=jnp.asarray(self._local_seq0, dtype=i32),
-            app_draws=jnp.asarray(z32),
-            up_tokens=jnp.asarray(up_burst, dtype=i32),
-            up_nr_hi=jnp.asarray(z32),
-            up_nr_lo=jnp.full(n, self._interval, dtype=i32),
-            up_ld_hi=jnp.asarray(z32),
-            up_ld_lo=jnp.asarray(z32),
-            dn_tokens=jnp.asarray(dn_burst, dtype=i32),
-            dn_nr_hi=jnp.asarray(z32),
-            dn_nr_lo=jnp.full(n, self._interval, dtype=i32),
-            dn_ld_hi=jnp.asarray(z32),
-            dn_ld_lo=jnp.asarray(z32),
-            cd_fat_hi=jnp.full(n, lanes.CD_UNSET, dtype=i32),
-            cd_fat_lo=jnp.asarray(z32),
-            cd_dnext_hi=jnp.asarray(z32),
-            cd_dnext_lo=jnp.asarray(z32),
-            cd_drop_count=jnp.asarray(z32),
-            cd_dropping=jnp.zeros(n, dtype=bool),
-            m_sent=jnp.asarray(z32),
-            m_peer_offset=jnp.asarray(z32),
-            n_delivered=jnp.asarray(z32),
-            n_loss=jnp.asarray(z32),
-            n_codel=jnp.asarray(z32),
-            n_queue=jnp.asarray(z32),
-            recv_bytes=jnp.asarray(z32),
-            n_sends=jnp.asarray(z32),
-            n_hops=jnp.asarray(z32),
-            log=jnp.zeros((max(self.params.log_capacity, 1), 6), dtype=jnp.int64),
-            log_count=jnp.int32(0),
-            log_lost=jnp.int32(0),
-            rounds=jnp.int32(0),
-            iters=jnp.int32(0),
-            now_we_hi=jnp.int32(0),
-            now_we_lo=jnp.int32(0),
-            min_used_lat=jnp.int32(lanes.NEVER32),
+            send_seq=lane(),
+            local_seq=self._local_seq0.astype(i32),
+            app_draws=lane(),
+            up_tokens=self._up_params[:, 1].astype(i32),
+            up_nr_hi=lane(),
+            up_nr_lo=lane(self._interval),
+            up_ld_hi=lane(),
+            up_ld_lo=lane(),
+            dn_tokens=self._dn_params[:, 1].astype(i32),
+            dn_nr_hi=lane(),
+            dn_nr_lo=lane(self._interval),
+            dn_ld_hi=lane(),
+            dn_ld_lo=lane(),
+            cd_fat_hi=lane(lanes.CD_UNSET),
+            cd_fat_lo=lane(),
+            cd_dnext_hi=lane(),
+            cd_dnext_lo=lane(),
+            cd_drop_count=lane(),
+            cd_dropping=lane(dtype=bool),
+            m_sent=lane(),
+            m_peer_offset=lane(),
+            n_delivered=lane(),
+            n_loss=lane(),
+            n_codel=lane(),
+            n_queue=lane(),
+            recv_bytes=lane(),
+            n_sends=lane(),
+            n_hops=lane(),
+            log=full((max(p.log_capacity, 1), 6), dtype=np.int64),
+            log_count=full(),
+            log_lost=full(),
+            rounds=full(),
+            iters=full(),
+            now_we_hi=full(),
+            now_we_lo=full(),
+            min_used_lat=full(fill=lanes.NEVER32),
             egress=(
-                jnp.zeros((p.egress_capacity, 6), dtype=jnp.int64)
-                if p.external_any else ()
+                full((p.egress_capacity, 6), dtype=np.int64) if ext else ()
             ),
-            egress_count=jnp.int32(0) if p.external_any else (),
-            egress_lost=jnp.int32(0) if p.external_any else (),
-            egress_min_hi=jnp.int32(lanes.NEVER32) if p.external_any else (),
-            egress_min_lo=jnp.int32(lanes.NEVER32) if p.external_any else (),
-            nb_txb=jnp.asarray(z32) if p.netobs else (),
-            nb_rxb=jnp.asarray(z32) if p.netobs else (),
-            nb_thr=jnp.asarray(z32) if p.netobs else (),
-            nb_shed=jnp.asarray(z32) if p.netobs else (),
-            nb_hist=(
-                jnp.zeros(lanes.NB_HIST_BUCKETS, dtype=i32)
-                if p.netobs else ()
-            ),
-            nb_win=jnp.int32(0) if p.netobs else (),
+            egress_count=full() if ext else (),
+            egress_lost=full() if ext else (),
+            egress_min_hi=full(fill=lanes.NEVER32) if ext else (),
+            egress_min_lo=full(fill=lanes.NEVER32) if ext else (),
+            nb_txb=lane() if p.netobs else (),
+            nb_rxb=lane() if p.netobs else (),
+            nb_thr=lane() if p.netobs else (),
+            nb_shed=lane() if p.netobs else (),
+            nb_hist=full(lanes.NB_HIST_BUCKETS) if p.netobs else (),
+            nb_win=full() if p.netobs else (),
             fl_buf=(
-                jnp.zeros((p.flow_capacity, ftr.FT_COLS), dtype=i32)
-                if p.flowtrace else ()
+                full((p.flow_capacity, ftr.FT_COLS)) if p.flowtrace else ()
             ),
-            fl_count=jnp.int32(0) if p.flowtrace else (),
-            fl_lost=jnp.int32(0) if p.flowtrace else (),
+            fl_count=full() if p.flowtrace else (),
+            fl_lost=full() if p.flowtrace else (),
             **{
-                f: jnp.int32(0) if p.log_capacity or p.external_any else ()
+                f: full() if p.log_capacity or ext else ()
                 for f in lanes._AP_SCALARS
             },
         )
+        # ONE transfer of the whole tree, straight onto its placement: no
+        # eager device program, no whole copy on one chip before sharding
+        return jax.device_put(host, shardings)
 
     # -- running -----------------------------------------------------------
 
@@ -1016,19 +1021,13 @@ class TpuEngine:
                 mode, on_window=on_window, resume_state=resume_state,
                 resume_epoch=resume_epoch, disarm_stalls=disarm_stalls,
             )
-        with self._phase("state_build"):
-            state = (
-                resume_state if resume_state is not None
-                else self.initial_state()
-            )
-            # with a mesh attached, commit the state to its sharded
-            # placement and compile the driver under the mesh
-            # (parallel/mesh.py)
-            state = self.place_state(state)
+        # with a mesh attached the state lives on its sharded placement and
+        # the driver compiles under the mesh (parallel/mesh.py)
+        state = self._start_state(resume_state, self._mesh)
         if mode == "device":
             # cache the program: repeat runs (bench best-of-N) must not
             # retrace/recompile
-            run_fn = getattr(self, "_run_fn", None)
+            run_fn = self._run_fn
             if run_fn is None:
                 if self._mesh is not None:
                     from .. import parallel
@@ -1040,10 +1039,10 @@ class TpuEngine:
                     run_fn = self._run_fn = lanes.make_run_fn(
                         self.params, self.tables
                     )
-            if precompile and getattr(self, "_compiled", None) is None:
+            if precompile and self._compiled is None:
                 # AOT-compile so the timed run is the steady-state program
                 self._compiled = run_fn.lower(state).compile()
-            if getattr(self, "_compiled", None) is not None:
+            if self._compiled is not None:
                 run_fn = self._compiled
             t0 = wall_time.perf_counter()
             if self.obs is None:
@@ -1079,11 +1078,38 @@ class TpuEngine:
             )
         return result
 
+    def _start_state(self, resume_state, mesh):
+        """The ``state_build`` phase: the state a run starts from, on its
+        placement (sharded over ``mesh``, or single-device).
+
+        The initial state is a pure function of the engine, so the first
+        run builds it (``initial_state``) and KEEPS it on the device;
+        every later run starts from the kept arrays — jax arrays are
+        immutable and no program of this engine donates its argument, so
+        nothing is built, copied or transferred.  The kept state is this
+        method's own: ``attach_mesh`` drops it, a ``resume_state`` run
+        neither reads nor writes it."""
+        with self._phase("state_build"):
+            self._state_reused = 0
+            if mesh is not None:
+                from .. import parallel
+            if resume_state is not None:
+                if mesh is None:
+                    return resume_state
+                return parallel.shard_state(resume_state, mesh)
+            if self._kept is None:
+                self._kept = self.initial_state(
+                    None if mesh is None else parallel.state_shardings(mesh)
+                )
+            else:
+                self._state_reused = 1
+            return self._kept
+
     def _phase(self, phase: str):
         """The obs span of a host-side phase of ``run`` (``state_build``:
-        ``initial_state`` + ``place_state``; ``collect``), which with
-        ``device_turn`` split a run's wall into build / device / collect;
-        nothing when obs is off."""
+        ``_start_state``; ``collect``), which with ``device_turn`` split a
+        run's wall into build / device / collect; nothing when obs is
+        off."""
         if self.obs is None:
             return contextlib.nullcontext()
         return self.obs.phase(phase)
@@ -1258,8 +1284,8 @@ class TpuEngine:
         # test drive them through this serial loop too)
         plan = ov.segment_plan(stop, pad_to=getattr(self, "_fault_pad", 0))
         resumed = resume_state is not None
-        with self._phase("state_build"):
-            state = resume_state if resumed else self.initial_state()
+        # segments run single-device programs, mesh or none
+        state = self._start_state(resume_state, None)
         fns = getattr(self, "_seg_fns", None)
         if fns is None:
             fns = self._seg_fns = {}
@@ -1376,10 +1402,42 @@ class TpuEngine:
                 )
             w.close()
 
+    def _read_back(self, s: lanes.LaneState) -> lanes.LaneState:
+        """The HOST copy of ``s`` that ``collect`` reads, fetched in ONE
+        batched ``jax.device_get``: the per-lane counters, the scalars,
+        the tier's counter block and the flow matrices, the netobs /
+        flowtrace blocks when on.  Every other leaf is ``None`` — a read
+        ``collect`` grows without listing its field here fails, it does
+        not become one more blocking transfer — but the log, which stays
+        on the device (``collect`` fetches its filled rows, when there
+        are any)."""
+        p = self.params
+        fields = [
+            "send_seq", "local_seq", "m_peer_offset", "n_delivered",
+            "n_loss", "n_codel", "n_queue", "recv_bytes", "n_sends",
+            "n_hops", "log_count", "log_lost", "rounds", "iters",
+        ]
+        if p.netobs:
+            fields += ["nb_txb", "nb_rxb", "nb_thr", "nb_shed", "nb_hist",
+                       "nb_win"]
+        if p.flowtrace:
+            fields += ["fl_buf", "fl_count", "fl_lost"]
+        if not isinstance(s.ap_blocks, tuple):
+            fields += lanes._AP_SCALARS
+        want = {f: getattr(s, f) for f in fields}
+        if p.stream_tiered:
+            want["stream"] = s.stream._replace(q=None)
+        elif p.stream_present:
+            want["stream"] = s.stream
+        blank = jax.tree.map(lambda leaf: None, s)
+        return blank._replace(**jax.device_get(want), log=s.log)
+
     def collect(self, s: lanes.LaneState, wall: float) -> SimResult:
         if isinstance(s.q_thi, jax.Array):
             # where the run actually ran (device_info)
             self._placed_devices = s.q_thi.devices()
+        # from here on every leaf read below is a host copy
+        s = self._read_back(s)
         # int32 counter honesty: every per-lane counter is monotone, so a
         # wrap past 2**31 shows as a negative value — raise instead of
         # reporting garbage (2e9 events per lane is unreachable in any
@@ -1389,7 +1447,7 @@ class TpuEngine:
         if self.params.netobs:
             wrap_check += ["nb_txb", "nb_rxb", "nb_thr"]
         for fname in wrap_check:
-            if int(np.asarray(getattr(s, fname)).min(initial=0)) < 0:
+            if int(getattr(s, fname).min(initial=0)) < 0:
                 raise RuntimeError(
                     f"lane counter {fname} wrapped past 2**31; this run "
                     "exceeds the lane backend's int32 counter range"
@@ -1397,9 +1455,7 @@ class TpuEngine:
         # tiered stream backend: fold the [2S] tier's compact counters
         # into the lane totals (the tier owns stream endpoints' network
         # accounting)
-        tv = (
-            np.asarray(s.stream.v) if self.params.stream_tiered else None
-        )
+        tv = s.stream.v if self.params.stream_tiered else None
         if tv is not None and int(tv[lstr_mod.TV_SEND_SEQ].min(initial=0)) < 0:
             raise RuntimeError(
                 "tier counter send_seq wrapped past 2**31; this run "
@@ -1409,7 +1465,7 @@ class TpuEngine:
         def tier_sum(row: int) -> int:
             return int(tv[row].sum()) if tv is not None else 0
 
-        n_queue_drops = int(np.asarray(s.n_queue).sum()) + tier_sum(
+        n_queue_drops = int(s.n_queue.sum()) + tier_sum(
             lstr_mod.TV_N_QUEUE
         )
         if n_queue_drops and self.strict_capacity:
@@ -1432,6 +1488,9 @@ class TpuEngine:
             "exchange_bounds_wide": int(lanes.exchange_bounds_wide(
                 self.params.exchange_entries, self.params.n_lanes
             )),
+            # 1 when the run started from the initial state an earlier run
+            # of this engine built and kept on the device (_start_state)
+            "state_reused": self._state_reused,
         }
         if self.obs is not None:
             for key, val in self.lane_plane.items():
@@ -1452,7 +1511,13 @@ class TpuEngine:
                 "drop --event-log) or build the engine with a larger "
                 "log_capacity"
             )
-        rows = np.asarray(s.log[: min(log_count, self.params.log_capacity)])
+        # the log's filled rows: the one further transfer, and only of a
+        # run that kept records
+        filled = min(log_count, self.params.log_capacity)
+        rows = (
+            np.asarray(s.log[:filled]) if filled
+            else np.zeros((0, 6), dtype=np.int64)
+        )
         if self.params.pcap_any:
             pcap_rows = rows[rows[:, 5] == lanes.PCAP_TX] if rows.size else rows
             rows = rows[rows[:, 5] != lanes.PCAP_TX] if rows.size else rows
@@ -1461,9 +1526,7 @@ class TpuEngine:
             LogRecord(int(t), int(src), int(dst), int(seq), int(size), int(out))
             for t, src, dst, seq, size, out in rows
         ]
-        model = np.asarray(self.tables.model)
-        recv_bytes = np.asarray(s.recv_bytes)
-        delivered = np.asarray(s.n_delivered)
+        model = self._model_np
         counters: dict[str, int] = {}
 
         def add(key: str, val: int) -> None:
@@ -1471,18 +1534,18 @@ class TpuEngine:
                 counters[key] = counters.get(key, 0) + int(val)
 
         tgen_mask = np.isin(model, [lanes.M_TGEN_MESH, lanes.M_TGEN_CLIENT, lanes.M_TGEN_SERVER])
-        add("tgen_recv_bytes", int(recv_bytes[tgen_mask].sum()))
-        hops = np.asarray(s.n_hops)
-        add("phold_hops", int(hops[model == lanes.M_PHOLD].sum()))
+        add("tgen_recv_bytes", int(s.recv_bytes[tgen_mask].sum()))
+        add("phold_hops", int(s.n_hops[model == lanes.M_PHOLD].sum()))
         add("lane_iters", int(s.iters))
-        add("lane_delivered", int(delivered.sum()) + tier_sum(lstr_mod.TV_N_DEL))
-        add("lane_drop_loss", int(np.asarray(s.n_loss).sum())
-            + tier_sum(lstr_mod.TV_N_LOSS))
-        add("lane_drop_codel", int(np.asarray(s.n_codel).sum())
-            + tier_sum(lstr_mod.TV_N_CODEL))
+        add("lane_delivered",
+            int(s.n_delivered.sum()) + tier_sum(lstr_mod.TV_N_DEL))
+        add("lane_drop_loss",
+            int(s.n_loss.sum()) + tier_sum(lstr_mod.TV_N_LOSS))
+        add("lane_drop_codel",
+            int(s.n_codel.sum()) + tier_sum(lstr_mod.TV_N_CODEL))
         add("lane_drop_queue", n_queue_drops)
-        add("lane_sends", int(np.asarray(s.n_sends).sum())
-            + tier_sum(lstr_mod.TV_N_SENDS))
+        add("lane_sends",
+            int(s.n_sends.sum()) + tier_sum(lstr_mod.TV_N_SENDS))
 
         if self.params.stream_present:
             # compacted flow matrices: every cl row is a client endpoint,
@@ -1490,8 +1553,7 @@ class TpuEngine:
             flows = (
                 s.stream.flows if self.params.stream_tiered else s.stream
             )
-            cl_m = np.asarray(flows.cl)
-            sv_m = np.asarray(flows.sv)
+            cl_m, sv_m = flows.cl, flows.sv
             done = cl_m[:, lstr_mod.C_COMPLETED] != 0
             if done.any():
                 # tx/retransmit totals count at completion, like the CPU

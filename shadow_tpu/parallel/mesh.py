@@ -219,16 +219,6 @@ def _spmd_entry(fn):
     return wrapped
 
 
-def _donate(donate: Optional[bool]) -> tuple:
-    """Sharded-state donation: the free-run consumes its input state, so
-    donating halves peak device memory at scale.  Default on everywhere
-    but the CPU backend, where XLA cannot alias the buffers and every
-    call would warn about unusable donations."""
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
-    return (0,) if donate else ()
-
-
 def make_sharded_round_fn(
     p: lanes.LaneParams, tb: lanes.LaneTables, mesh: Mesh, axis: str = HOST_AXIS
 ):
@@ -244,19 +234,15 @@ def make_sharded_round_fn(
 
 
 def make_sharded_run_fn(
-    p: lanes.LaneParams,
-    tb: lanes.LaneTables,
-    mesh: Mesh,
-    axis: str = HOST_AXIS,
-    donate: Optional[bool] = None,
+    p: lanes.LaneParams, tb: lanes.LaneTables, mesh: Mesh, axis: str = HOST_AXIS
 ):
-    """Jitted full-simulation run (while_loop over rounds), sharded."""
+    """Jitted full-simulation run (while_loop over rounds), sharded.  No
+    donation: the argument is the initial state its engine keeps on the
+    device and starts every run from (``TpuEngine._start_state``), so a
+    run holds that state and its result, as a single-device run does."""
     sh = state_shardings(mesh, axis)
     return _spmd_entry(jax.jit(
-        lanes._build_full_run(p, tb),
-        in_shardings=(sh,),
-        out_shardings=sh,
-        donate_argnums=_donate(donate),
+        lanes._build_full_run(p, tb), in_shardings=(sh,), out_shardings=sh
     ))
 
 

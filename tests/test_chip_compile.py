@@ -3,8 +3,8 @@
 The TPU compiler is installed here and compiles for a DESCRIBED ``v5e:2x2``
 device without a chip attached.  Each case lowers one program of the main
 path at the shape ``chip_smoke.py`` / ``bench.py`` run it, with the
-accelerator branches taken: ``lanes.scan_or_unroll``, the slot body's
-``slot_dataflow`` and sharded donation (``parallel/mesh.py``) all switch on
+accelerator branches taken: ``lanes.scan_or_unroll`` and the slot body's
+``slot_dataflow`` switch on
 ``jax.default_backend() != "cpu"``, which every other test pins to the CPU —
 so each case patches ``jax.default_backend`` to ``"tpu"`` around its trace.
 What the compiler refuses here it would refuse on the chip, at no chip time.
@@ -240,8 +240,9 @@ def test_hybrid_turn_offers_no_candidate_row_scatter(as_tpu, tmp_path):
 def test_sharded_run_fn_on_described_mesh(topo, as_tpu):
     """``parallel.make_sharded_run_fn`` on a Mesh of the four described
     devices: GSPMD must insert collectives for the cross-lane exchange, and
-    the donated lane state must alias (donation is on off-CPU).  1 000
-    lanes here; the 10 000- and 100 000-lane compiles are in CHANGES.md."""
+    the program must leave its argument alone (it is the initial state the
+    engine keeps and starts every run from).  1 000 lanes here; the 10 000-
+    and 100 000-lane compiles are in CHANGES.md."""
     eng = TpuEngine(_pure_cfg(1_000, 150 * MS), log_capacity=0)
     mesh = Mesh(np.array(topo.devices), (parallel.HOST_AXIS,))
     assert mesh.devices.size == 4
@@ -263,7 +264,7 @@ def test_sharded_run_fn_on_described_mesh(topo, as_tpu):
     ]
     assert collectives, "no collective in the sharded program"
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes > 0, "donated state did not alias"
+    assert mem.alias_size_in_bytes == 0, "the kept state was donated"
     # the lane axis really is split: per-device argument bytes are a
     # fraction of the whole state's
     whole = sum(

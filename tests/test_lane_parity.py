@@ -440,8 +440,8 @@ def test_vector_law_keeps_ack_rto_arm_through_opened_pump():
     segs = jnp.array([50], dtype=jnp.int32)
     mss = jnp.array([1448], dtype=jnp.int32)
     last = jnp.array([1448], dtype=jnp.int32)
-    st = lstr.init_stream_state(1)
-    cl = st.cl
+    st = lstr.init_stream_state(1)  # host-side (numpy) matrices
+    cl = jnp.asarray(st.cl)
     for col, val in (
         (lstr.C_STATE, ltcp.ESTAB), (lstr.C_SND_UNA, 5), (lstr.C_SND_NXT, 10),
         (lstr.C_RCV_NXT, 1), (lstr.C_MAX_SENT, 10),

@@ -26,6 +26,7 @@ from shadow_tpu.backend.cpu_engine import CpuEngine
 from shadow_tpu.config.columnar import columnar_mesh_config
 from shadow_tpu.config.options import ConfigError, ConfigOptions
 from shadow_tpu.engine.sim import Simulation, device_log_readers
+from shadow_tpu.obs import Recorder
 
 MS = 1_000_000
 #: lane-engine bookkeeping the oracle does not keep (and one it alone keeps)
@@ -77,7 +78,8 @@ def test_facade_without_the_log_runs_past_the_logs_capacity(
     assert stats["packet_outcomes"] == {"delivered": 216_000}
     assert stats["lane_plane"] == {
         "lanes": 2_000, "mesh_devices": 1, "device_log_capacity": 0,
-        "device_log_records": 0, "exchange_bounds_wide": 0}
+        "device_log_records": 0, "exchange_bounds_wide": 0,
+        "state_reused": 0}
 
 
 def test_facade_with_the_log_still_raises_and_names_the_remedy(tmp_path):
@@ -276,8 +278,24 @@ def test_a_run_is_split_into_build_device_collect(tmp_path, mode, event_log):
     assert {k: report["gauges"][k] for k in sim.engine.lane_plane} == {
         "lanes": 64, "mesh_devices": 1,
         "device_log_capacity": 200_000 if event_log else 0,
-        "device_log_records": records, "exchange_bounds_wide": 0}
+        "device_log_records": records, "exchange_bounds_wide": 0,
+        "state_reused": 0}
     assert len(res.event_log) == records
+
+
+@pytest.mark.parametrize("mode", ["device", "step"])
+def test_two_runs_of_one_engine_are_two_spans_of_each_host_phase(
+        tmp_path, mode):
+    eng = tpu_engine.TpuEngine(
+        _mesh_cfg(tmp_path, hosts=64, stop_ms=100), log_capacity=0)
+    eng.obs = Recorder()
+    first, second = eng.run(mode=mode), eng.run(mode=mode)
+    report = eng.obs.finalize()["report"]
+    spans = {k: v["spans"] for k, v in report["phases"].items()}
+    assert spans["state_build"] == spans["collect"] == 2
+    assert all(report["phase_wall_s"][k] > 0 for k in spans)
+    assert report["gauges"]["state_reused"] == 1
+    assert (second.rounds, second.counters) == (first.rounds, first.counters)
 
 
 def test_resume_refuses_a_state_of_another_log_capacity(tmp_path):
