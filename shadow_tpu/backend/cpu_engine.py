@@ -223,12 +223,10 @@ class Host:
         return self.engine.seed
 
     def rand_u32(self) -> int:
-        v = int(
-            rng_mod.rand_u32(
-                self.engine.seed,
-                self.host_id | rng_mod.APP_STREAM,
-                self.app_draws,
-            )
+        v = rng_mod.rand_u32_scalar(
+            self.engine.seed,
+            self.host_id | rng_mod.APP_STREAM,
+            self.app_draws,
         )
         self.app_draws += 1
         return v
@@ -615,7 +613,7 @@ class CpuEngine:
         ):
             src_host.min_used_lat = lat_ns
         if t >= self.bootstrap_end and thresh > 0:
-            u = int(rng_mod.rand_u32(self.seed, s | rng_mod.LOSS_STREAM, seq))
+            u = rng_mod.rand_u32_scalar(self.seed, s | rng_mod.LOSS_STREAM, seq)
             if u < thresh:
                 if no is not None:
                     no.on_loss(s)
@@ -623,6 +621,9 @@ class CpuEngine:
                     ft.emit(s, t, we, ftr.FT_DROP, s, d, seq, size_bytes,
                             ftr.CAUSE_LOSS)
                 src_host.log_buf.append(LogRecord(t, s, d, seq, size_bytes, DROP_LOSS))
+                # under the lane engine's name: a comparison of counter
+                # sets holds the two backends' drop totals to each other
+                src_host.count("lane_drop_loss")
                 return seq, None
 
         arr = max(t_dep + lat_ns, self.window_end)
@@ -721,6 +722,7 @@ class CpuEngine:
             dst_host.log_buf.append(
                 LogRecord(t_deliver, ev.src_host, dst_host.host_id, ev.seq, size_bytes, DROP_CODEL)
             )
+            dst_host.count("lane_drop_codel")
             return
         if no is not None:
             no.on_delivered(dst_host.host_id, size_bytes)

@@ -1174,24 +1174,25 @@ def _process_slot(
         )
 
     # loss (bootstrap window is loss-free; loss-free graphs skip the draw)
-    my_node = tb.node_of
-    dst_node = tb.node_of[dst]
-    lat = tb.lat[my_node, dst_node]  # int32
-    if p.has_loss:
-        u = rand_u32_lane(
-            _seed_keys(p, tb),
-            (lanes.astype(jnp.uint32) | jnp.uint32(rng_mod.LOSS_STREAM)),
-            snd_seq,
-        )
-        bs_hi, bs_lo = p.bootstrap_end >> 31, p.bootstrap_end & MASK31
-        past_bootstrap = pair_ge(thi, tlo, bs_hi, bs_lo)
-        lost = do_send & past_bootstrap & (
-            tb.thresh_all[my_node, dst_node]
-            | (u < tb.thresh_u32[my_node, dst_node])
-        )
-        s = s._replace(n_loss=s.n_loss + lost)
-    else:
-        lost = false_n
+    with jax.named_scope("path_lookup"):
+        my_node = tb.node_of
+        dst_node = tb.node_of[dst]
+        lat = tb.lat[my_node, dst_node]  # int32
+        if p.has_loss:
+            u = rand_u32_lane(
+                _seed_keys(p, tb),
+                (lanes.astype(jnp.uint32) | jnp.uint32(rng_mod.LOSS_STREAM)),
+                snd_seq,
+            )
+            bs_hi, bs_lo = p.bootstrap_end >> 31, p.bootstrap_end & MASK31
+            past_bootstrap = pair_ge(thi, tlo, bs_hi, bs_lo)
+            lost = do_send & past_bootstrap & (
+                tb.thresh_all[my_node, dst_node]
+                | (u < tb.thresh_u32[my_node, dst_node])
+            )
+            s = s._replace(n_loss=s.n_loss + lost)
+        else:
+            lost = false_n
 
     if p.dynamic_runahead:
         # the smallest path latency of this slot's sends (the CPU law
@@ -2445,15 +2446,21 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
     carries the mesh spray the [N] exchange diverted to stream lanes.
 
     Delivery elision: a delivered packet whose t_deliver lands INSIDE the
-    current window applies the law inline at t_deliver instead of
-    self-inserting a DELIVERY event.  Exact for one-to-one flows: the
-    popped prefix holds no LOCALs (the prefix rule stops at them), every
-    flow-relevant delivery at a row shares one src (its single peer), dn
-    departures are FIFO (inline order = the oracle's delivery order),
-    and the law's send/arm emissions touch state disjoint from later
-    pops' dn charges.  t_deliver >= window_end falls back to a real
-    DELIVERY insert, which keeps the WINDOW-LAW sequence bit-identical
-    too (a pending delivery bounds the next window on both backends)."""
+    current window, BEFORE the row's earliest queued LOCAL and with no
+    DELIVERY queued (or being queued this iteration) behind it, applies
+    the law inline at t_deliver instead of self-inserting a DELIVERY
+    event.  Exact for one-to-one flows: nothing the oracle's heap would
+    pop between the packet and its delivery touches the flow (the gate
+    above), every flow-relevant delivery at a row shares one src (its
+    single peer), dn departures are FIFO (inline order = the oracle's
+    delivery order), and the law's send/arm emissions touch state
+    disjoint from later pops' dn charges.  Every other delivery falls
+    back to a real DELIVERY insert, which keeps the WINDOW-LAW sequence
+    bit-identical too (a pending delivery bounds the next window on both
+    backends; an RTO that the oracle pops before a same-instant delivery
+    re-arms or fires exactly as there).  A LOCAL may lead a co-popped
+    prefix but never interrupt one, and a LOCAL tied with the row's head
+    is rotated in front of it first (see below)."""
     ts = s.stream
     q, v = ts.q, ts.v
     k = p.stream_pops
@@ -2468,15 +2475,55 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
     false_c = jnp.zeros(s_flows, dtype=bool)
     cl_sl = slice(0, s_flows)
 
+    if p.stream_wide_pop:
+        # ---- a LOCAL tied with the row's head leads it -------------------
+        # The oracle's heap pops same-instant PACKETs, then the LOCAL, then
+        # the packets' DELIVERYs.  A packet pop touches only the dn bucket
+        # and CoDel, the LOCAL only the flow, so they commute: handling the
+        # LOCAL FIRST and the packets' deliveries inline after it is the
+        # same order of everything that does not.  (Arrivals are bumped to
+        # window ends and an RTO fires a whole RTO_MIN after the ACK that
+        # armed it, so on a graph whose latencies are multiples of the
+        # window such ties are the rule, not the exception.)  Only this
+        # function sees the rotated rows: the merge below re-sorts them.
+        cols = jnp.arange(c2, dtype=i32)[None, :]
+        loc_all = (q[lstr.TQ_THI] != NEVER32) & (
+            (q[lstr.TQ_AUXH] >> AUX_KIND_SHIFT) == LOCAL)
+        first_loc = jnp.argmax(loc_all, axis=1).astype(i32)[:, None]
+        at_loc = loc_all & (cols == first_loc)  # one-hot, or all False
+
+        def at_first_loc(plane):
+            return jnp.sum(jnp.where(at_loc, plane, 0), axis=1, keepdims=True,
+                           dtype=plane.dtype)
+
+        lead = (
+            (first_loc > 0) & jnp.any(loc_all, axis=1, keepdims=True)
+            & (at_first_loc(q[lstr.TQ_THI]) == q[lstr.TQ_THI, :, :1])
+            & (at_first_loc(q[lstr.TQ_TLO]) == q[lstr.TQ_TLO, :, :1])
+        )
+
+        def rotate(plane):
+            shifted = jnp.concatenate([plane[:, :1], plane[:, :-1]], axis=1)
+            return jnp.where(
+                lead & (cols == 0), at_first_loc(plane),
+                jnp.where(lead & (cols <= first_loc), shifted, plane))
+
+        q = jnp.stack([rotate(q[i]) for i in range(q.shape[0])])
+        ts = ts._replace(q=q)
+
     # ---- pop prefix ------------------------------------------------------
     thi_b = q[lstr.TQ_THI, :, :k]
     tlo_b = q[lstr.TQ_TLO, :, :k]
     kind_cols = q[lstr.TQ_AUXH, :, :k] >> AUX_KIND_SHIFT
     first_col = (jnp.arange(k) == 0)[None, :]
     if p.stream_wide_pop:
-        # any non-LOCAL within-window prefix (see the elision note above;
-        # the engine guarantees every window ends before RTO_MIN)
-        prefix = jnp.cumprod(kind_cols != LOCAL, axis=1).astype(bool)
+        # any within-window prefix that no LOCAL interrupts: a LOCAL may
+        # LEAD one (it is handled first, and what it emits — sends, an RTO
+        # arm at now + rto — lands past every possible window, which the
+        # engine guarantees ends before RTO_MIN), never follow a packet
+        # whose delivery might have to wait for it (the elision gate)
+        prefix = jnp.cumprod(
+            (kind_cols != LOCAL) | first_col, axis=1).astype(bool)
     else:
         same_t = (thi_b == thi_b[:, :1]) & (tlo_b == tlo_b[:, :1])
         pkt_prefix = jnp.cumprod(kind_cols == PACKET, axis=1).astype(bool)
@@ -2493,6 +2540,22 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
         )
     q = q.at[lstr.TQ_THI, :, :k].set(jnp.where(act_b, NEVER32, thi_b))
     q = q.at[lstr.TQ_TLO, :, :k].set(jnp.where(act_b, NEVER32, tlo_b))
+    if p.stream_wide_pop:
+        # what an elided delivery may not overtake (see tier_slot's gate):
+        # the row's earliest queued LOCAL behind the leading column, and
+        # any DELIVERY still queued behind the popped column
+        live_q = ts.q[lstr.TQ_THI] != NEVER32
+        kind_q = ts.q[lstr.TQ_AUXH] >> AUX_KIND_SHIFT
+        loc_q = live_q & (kind_q == LOCAL) & (jnp.arange(c2) > 0)[None, :]
+        loc_hi = jnp.min(
+            jnp.where(loc_q, ts.q[lstr.TQ_THI], NEVER32), axis=1)
+        loc_lo = jnp.min(
+            jnp.where(loc_q & (ts.q[lstr.TQ_THI] == loc_hi[:, None]),
+                      ts.q[lstr.TQ_TLO], NEVER32), axis=1)
+        del_q = (live_q & (kind_q == DELIVERY)).astype(i32)
+        del_behind = (
+            del_q.sum(axis=1, keepdims=True) - jnp.cumsum(del_q[:, :k], axis=1)
+        ) > 0  # [2S, K]
 
     f = lstr.endpoint_cols(
         ts.flows, tb.flow_segs, tb.flow_mss, tb.flow_last, tb.flow_cc
@@ -2514,9 +2577,11 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
         "plo": jnp.moveaxis(ts.q[lstr.TQ_PLO, :, :k], 1, 0),
         "act": act_b.T,
     }
+    if p.stream_wide_pop:
+        xs["del_behind"] = del_behind.T
 
     def tier_slot(carry, x):
-        f, v, mul = carry
+        f, v, mul, held = carry
         thi, tlo = x["thi"], x["tlo"]
         auxh, auxl, size = x["auxh"], x["auxl"], x["size"]
         phi, plo = x["phi"], x["plo"]
@@ -2562,12 +2627,23 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
             v = v.at[lstr.TV_NB_THR].add(dn_wait)
 
         # -- delivery elision gate ----------------------------------------
-        # elide only under the wide-pop guarantee (window < RTO_MIN): it
-        # proves no armed LOCAL can sort below an in-window t_deliver, so
-        # inline processing cannot jump an RTO.  Otherwise (huge-latency
-        # graphs) every delivery takes the exact queued path.
+        # elide only under the wide-pop guarantee (window < RTO_MIN): no
+        # LOCAL armed in THIS window can sort below an in-window t_deliver.
+        # One armed in an earlier window can (an RTO that fires at or
+        # before t_deliver: LOCAL sorts below DELIVERY on a tie), and so
+        # can a fallback DELIVERY of an earlier packet still queued behind
+        # this one (the dn bucket held it past its window); the oracle
+        # handles either first, so such a delivery takes the exact queued
+        # path.  So does every delivery on huge-latency graphs.
         if p.stream_wide_pop:
-            del_now = deliver & pair_lt(td_hi, td_lo, we_hi, we_lo)
+            del_now = (
+                deliver & pair_lt(td_hi, td_lo, we_hi, we_lo)
+                & pair_lt(td_hi, td_lo, loc_hi, loc_lo)
+                & ~x["del_behind"] & ~held
+            )
+            # dn departures are FIFO: once a row queues one delivery, the
+            # deliveries of this iteration's later slots queue behind it
+            held = held | (deliver & ~del_now)
         else:
             del_now = false_e
         ins_valid = deliver & ~del_now  # fallback DELIVERY self-insert
@@ -2619,15 +2695,16 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
         )
         se_seq = v[lstr.TV_SEND_SEQ]
         if p.has_loss:
-            e_past_bs = pair_ge(sh, sl, bs_hi, bs_lo)
-            eu = rand_u32_lane(
-                _seed_keys(p, tb),
-                (el.astype(jnp.uint32) | jnp.uint32(rng_mod.LOSS_STREAM)),
-                se_seq,
-            )
-            se_lost = st_send & e_past_bs & (
-                tb.flow_thresh_all | (eu < tb.flow_thresh_u32)
-            )
+            with jax.named_scope("path_lookup"):
+                e_past_bs = pair_ge(sh, sl, bs_hi, bs_lo)
+                eu = rand_u32_lane(
+                    _seed_keys(p, tb),
+                    (el.astype(jnp.uint32) | jnp.uint32(rng_mod.LOSS_STREAM)),
+                    se_seq,
+                )
+                se_lost = st_send & e_past_bs & (
+                    tb.flow_thresh_all | (eu < tb.flow_thresh_u32)
+                )
         else:
             se_lost = false_e
         if p.dynamic_runahead:
@@ -2655,7 +2732,7 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
         def bstep(carry, cols, first: bool):
             (tok, nrh, nrl, ldh, ldl, nloss, mu, sent_before,
              btxb, bthr) = carry
-            bm, bflags, bunit, back, bsize = cols
+            bm, bflags, bunit, back, bsize, *bdraw = cols
             bbits = (bsize + FRAME_OVERHEAD_BYTES) * 8
             if first:
                 tok, nrh, nrl, ldh, ldl, bdep_hi, bdep_lo, bwait = (
@@ -2679,14 +2756,7 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
                 bthr = bthr + bwait
             bseq = se_seq[cl_sl] + sent_before
             if p.has_loss:
-                bu = rand_u32_lane(
-                    _seed_keys(p, tb),
-                    (cl_lanes_u32 | jnp.uint32(rng_mod.LOSS_STREAM)),
-                    bseq,
-                )
-                blost = bm & e_past_bs[cl_sl] & (
-                    tb.flow_thresh_all[cl_sl] | (bu < tb.flow_thresh_u32[cl_sl])
-                )
+                blost = bm & bdraw[0]
                 nloss = nloss + blost
             else:
                 blost = false_c
@@ -2715,6 +2785,26 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
         # first five burst columns only (the sixth is the flowtrace
         # retransmit marker; flowtrace forbids the tier — see LaneParams)
         st_burst_c = jax.tree.map(lambda a: a[:, cl_sl], tuple(st_burst[:5]))
+        if p.has_loss:
+            # burst validity is a PREFIX (pump_epilogue_vec), so valid
+            # unit j's send seq is the control send's plus j: the whole
+            # burst's loss draws are ONE [B, S] threefry outside the
+            # bucket chain instead of one per unit inside it
+            with jax.named_scope("path_lookup"):
+                b_units = jnp.arange(st_burst_c[0].shape[0], dtype=i32)
+                bseq_all = (
+                    se_seq[cl_sl] + st_send[cl_sl].astype(i32)
+                )[None, :] + b_units[:, None]
+                bu_all = rand_u32_lane(
+                    _seed_keys(p, tb),
+                    (cl_lanes_u32 | jnp.uint32(rng_mod.LOSS_STREAM))[None, :],
+                    bseq_all,
+                )
+                bdraw_all = e_past_bs[cl_sl][None, :] & (
+                    tb.flow_thresh_all[cl_sl][None, :]
+                    | (bu_all < tb.flow_thresh_u32[cl_sl][None, :])
+                )
+            st_burst_c = st_burst_c + (bdraw_all,)
         first_cols = jax.tree.map(lambda a: a[0], st_burst_c)
         rest_cols = jax.tree.map(lambda a: a[1:], st_burst_c)
         carry, out0 = bstep(carry0, first_cols, True)
@@ -2808,10 +2898,10 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
                 out["bpc_time"] = t_join(bdep_hi_all, bdep_lo_all)
                 out["bpc_seq"] = bo_auxl.astype(i64)
                 out["bpc_size"] = bo_size.astype(i64)
-        return (f, v, mul), out
+        return (f, v, mul, held), out
 
-    (f, v, mul), outs = scan_or_unroll(
-        tier_slot, (f, v, mul), xs, k
+    (f, v, mul, _held), outs = scan_or_unroll(
+        tier_slot, (f, v, mul, false_e), xs, k
     )
     ts = ts._replace(flows=lstr.endpoint_split(f), v=v)
     s = s._replace(min_used_lat=mul)
@@ -3664,8 +3754,19 @@ def _build_full_run(p: LaneParams, tb: LaneTables, dynamic_stop=None):
 
 def make_run_fn(p: LaneParams, tb: LaneTables):
     """Jitted full-simulation run — the bench hot path (one device call per
-    simulation)."""
-    return jax.jit(_build_full_run(p, tb))
+    simulation): ``run_fn(state)``, or ``run_fn(state, seed_lo, seed_hi)``
+    with the master seed's two uint32 words as ARGUMENTS of the program
+    (the sweep path's traced ``LaneTables`` leaves) in place of constants
+    in it.  The seed words are the only thing a program whose network
+    loses packets holds of ``general.seed``, so handed over they leave one
+    compiled program — and one entry of the persistent compile cache — for
+    every seed; the draws are bit-identical either way (``_seed_keys``)."""
+
+    def full_run(s: LaneState, *seed) -> LaneState:
+        t = tb._replace(seed_lo=seed[0], seed_hi=seed[1]) if seed else tb
+        return _build_full_run(p, t)(s)
+
+    return jax.jit(full_run)
 
 
 def make_sweep_fn(p: LaneParams):

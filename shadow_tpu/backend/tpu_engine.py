@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config.options import ConfigOptions
+from ..core import rng as _rng
 from ..core import time as stime
 from ..models.base import create_model
 from ..models.phold import Phold
@@ -92,8 +93,11 @@ class TpuEngine:
         self.append_stats: dict[str, int] = {}
         # populated by collect(): the shape of the run that was collected
         # — lanes, mesh_devices, device_log_capacity, device_log_records,
-        # exchange_bounds_wide (sim-stats.json's ``lane_plane``, the obs
-        # gauges of those names)
+        # exchange_bounds_wide, state_reused; the network the program was
+        # compiled for (graph_nodes, window_ns, max_path_latency_ns,
+        # has_loss, stream_wide_pop) and what it cost the run
+        # (lane_drop_loss, stream_retransmits) (sim-stats.json's
+        # ``lane_plane``, the obs gauges of those names)
         self.lane_plane: dict[str, int] = {}
         if inject_batch is None:
             inject_batch = cfg.experimental.tpu_inject_batch
@@ -373,6 +377,8 @@ class TpuEngine:
             max_lat = max(max_lat, self._fault_overlay.max_latency_ns())
         max_window = max(runahead, max_lat)
         stream_wide_pop = max_window < ltcp_mod.RTO_MIN
+        # the longest routed path, for collect()'s lane_plane
+        self._max_path_latency_ns = max_lat
 
         lane_pcap = np.array([h.pcap_enabled for h in cfg.hosts], dtype=bool)
         # external lanes' pcap is written host-side (the host knows the
@@ -626,6 +632,9 @@ class TpuEngine:
         self._mesh = None
         self._run_fn = None
         self._compiled = None
+        # what the single-device run program takes after the state: the
+        # seed's two words where the network loses packets, else nothing
+        self._seed_args = ()
         # the initial state run() keeps on the device (_start_state), and
         # whether the last run started from it (lane_plane's state_reused)
         self._kept = None
@@ -658,6 +667,7 @@ class TpuEngine:
         self._mesh = mesh
         self._run_fn = None
         self._compiled = None
+        self._seed_args = ()
         self._kept = None
 
     @property
@@ -772,8 +782,6 @@ class TpuEngine:
         from the config seed (core.rng ``_split_seed`` semantics — the
         exact key words the static path compiles in), and ``snap`` (a
         faults Snapshot) re-gathers the epoch's latency/loss tables."""
-        from ..core import rng as _rng
-
         tb = self.tables if snap is None else self._segment_tables(snap)
         s_lo, s_hi = _rng._split_seed(self.params.seed)
         return tb._replace(
@@ -1039,20 +1047,30 @@ class TpuEngine:
                     run_fn = self._run_fn = lanes.make_run_fn(
                         self.params, self.tables
                     )
+                    if self.params.has_loss:
+                        # only the loss draw reads the seed: as arguments
+                        # (placed once), its words leave ONE compiled
+                        # program, and one compile-cache entry, for every
+                        # seed of this network
+                        self._seed_args = tuple(
+                            jnp.uint32(w)
+                            for w in _rng._split_seed(self.params.seed)
+                        )
+            args = (state, *self._seed_args)
             if precompile and self._compiled is None:
                 # AOT-compile so the timed run is the steady-state program
-                self._compiled = run_fn.lower(state).compile()
+                self._compiled = run_fn.lower(*args).compile()
             if self._compiled is not None:
                 run_fn = self._compiled
             t0 = wall_time.perf_counter()
             if self.obs is None:
-                state = jax.block_until_ready(run_fn(state))
+                state = jax.block_until_ready(run_fn(*args))
             else:
                 # the fused loop is one opaque device call: attribute it
                 # as a single device_turn span (per-window spans need the
                 # step driver — run-control/perf-logging select it)
                 with self.obs.phase("device_turn", name="device_free_run"):
-                    state = jax.block_until_ready(run_fn(state))
+                    state = jax.block_until_ready(run_fn(*args))
             wall = wall_time.perf_counter() - t0
         else:
             if self._mesh is not None:
@@ -1491,6 +1509,16 @@ class TpuEngine:
             # 1 when the run started from the initial state an earlier run
             # of this engine built and kept on the device (_start_state)
             "state_reused": self._state_reused,
+            # the network this program was compiled for: nodes of the
+            # [G, G] latency / loss tables, the lookahead window, the
+            # longest routed path, whether the loss draw is compiled in,
+            # and which co-pop law the stream lanes take (1 = wide:
+            # every window ends before RTO_MIN)
+            "graph_nodes": int(self.tables.lat.shape[0]),
+            "window_ns": int(self.params.runahead),
+            "max_path_latency_ns": self._max_path_latency_ns,
+            "has_loss": int(self.params.has_loss),
+            "stream_wide_pop": int(self.params.stream_wide_pop),
         }
         if self.obs is not None:
             for key, val in self.lane_plane.items():
@@ -1571,6 +1599,12 @@ class TpuEngine:
                 "stream_flows_done",
                 int((sv_m[:, lstr_mod.C_COMPLETED] != 0).sum()),
             )
+
+        # what the network cost this run, beside its shape
+        for key in ("lane_drop_loss", "stream_retransmits"):
+            self.lane_plane[key] = counters.get(key, 0)
+            if self.obs is not None:
+                self.obs.metrics.gauge(key, self.lane_plane[key])
 
         if self.params.netobs:
             self._netobs_data = self._netobs_collect(s, tv)

@@ -1,4 +1,5 @@
-"""Managed-process scenario factories (real OS binaries under the shim).
+"""Scenario factories: managed-process scenarios (real OS binaries under
+the shim) and the routed, lossy all-TCP network (lane models only).
 
 The BASELINE.md evaluation ladder's config #5 is a Tor-shaped relay
 topology (the reference's 500-relay chutney networks,
@@ -21,6 +22,7 @@ workload class the reference's 6.38x was measured on,
 
 from __future__ import annotations
 
+import random
 from pathlib import Path
 
 from .options import ConfigOptions
@@ -199,3 +201,90 @@ def managed_relay_chains_gate(
         backend=backend,
         hybrid_workers=hybrid_workers,
     )
+
+
+#: edge ``packet_loss`` values of :func:`routed_graph_gml` and their weights
+ROUTED_EDGE_LOSS = ((0.0, 0.001, 0.005), (50, 35, 15))
+
+
+def routed_graph_gml(
+    graph_nodes: int, graph_seed: int, bandwidth: str = "1 Gbit"
+) -> str:
+    """A city-level latency/loss graph as GML text (the shape of upstream
+    Shadow's tornettools graphs, which this tree does not carry): every
+    node a 2 ms self-edge, a ring plus two seeded chords per node,
+    undirected; edge latency log-uniform on [2, 40] ms in whole ms; edge
+    loss drawn from ``ROUTED_EDGE_LOSS``.  Depends on ``graph_seed``
+    alone."""
+    rnd = random.Random(graph_seed)
+    g = graph_nodes
+    out = ["graph [", "  directed 0"]
+    for n in range(g):
+        out.append(
+            f'  node [ id {n} host_bandwidth_up "{bandwidth}" '
+            f'host_bandwidth_down "{bandwidth}" ]'
+        )
+        out.append(f'  edge [ source {n} target {n} latency "2 ms" ]')
+    edges = set()
+    for n in range(g):
+        for m in ((n + 1) % g, rnd.randrange(g), rnd.randrange(g)):
+            if m != n:
+                edges.add((min(n, m), max(n, m)))
+    values, weights = ROUTED_EDGE_LOSS
+    for a, b in sorted(edges):
+        lat = int(2 * 20 ** rnd.random())
+        loss = rnd.choices(values, weights)[0]
+        out.append(
+            f'  edge [ source {a} target {b} latency "{lat} ms"'
+            + (f" packet_loss {loss}" if loss else "") + " ]"
+        )
+    return "\n".join(out + ["]", ""])
+
+
+def routed_tcp_mesh_config(
+    n_hosts: int,
+    graph_nodes: int,
+    graph_seed: int = 1,
+    stream_bytes: int = 1 << 20,
+    start_spread_ms: int = 1000,
+    bandwidth: str = "1 Gbit",
+    seed: int = 1,
+) -> ConfigOptions:
+    """The routed, lossy, all-TCP network (BASELINE.md north-star config
+    #3: "1k-host tgen mesh, full TCP stack + latency/loss graph"):
+    ``n_hosts // 2`` lane-TCP flows of ``stream_bytes``, client ``i`` ->
+    server ``i``, hosts placed uniformly over :func:`routed_graph_gml`'s
+    nodes, clients starting at whole ms uniform on [0,
+    ``start_spread_ms``).  The graph goes through ``net/gml.py`` and
+    ``net/graph.py``'s shortest-path routing as a user's file would.
+
+    Graph, placement and start times depend on ``graph_seed`` alone — the
+    deployment is ONE network; ``seed`` drives the loss draws.  Stop time
+    (5 sim-s, nearly every flow's whole life) and backend (``tpu``) are the
+    caller's to set on the result, as for any configuration."""
+    if n_hosts < 2 or n_hosts % 2:
+        raise ValueError("n_hosts must be a positive even number")
+    gml = routed_graph_gml(graph_nodes, graph_seed, bandwidth)
+    # a stream of its own, so the graph does not move with the host count
+    rnd = random.Random(f"hosts-{graph_seed}")
+    hosts = {}
+    for i in range(n_hosts // 2):
+        hosts[f"sc{i:05d}"] = {
+            "network_node_id": rnd.randrange(graph_nodes),
+            "processes": [{
+                "path": "stream-client",
+                "args": ["--server", f"ss{i:05d}", "--size", str(stream_bytes)],
+                "start_time": f"{rnd.randrange(max(start_spread_ms, 1))} ms",
+            }],
+        }
+        hosts[f"ss{i:05d}"] = {
+            "network_node_id": rnd.randrange(graph_nodes),
+            "processes": [{"path": "stream-server", "start_time": "0 s"}],
+        }
+    return ConfigOptions.from_dict({
+        "general": {"stop_time": "5 s", "seed": seed,
+                    "heartbeat_interval": None},
+        "network": {"graph": {"type": "gml", "inline": gml}},
+        "experimental": {"network_backend": "tpu"},
+        "hosts": hosts,
+    })

@@ -11,7 +11,8 @@ One implementation, written against the array-API surface shared by ``numpy``
 and ``jax.numpy``, is used by both the CPU reference backend and the TPU lane
 backend; the bit-identical outputs are what make cross-backend deterministic
 replay possible (the property the reference gates with its determinism tests,
-src/test/determinism/CMakeLists.txt:1-45).
+src/test/determinism/CMakeLists.txt:1-45).  The CPU backend's one-at-a-time
+draws take the same cipher on Python ints (``rand_u32_scalar``).
 
 Stream-id conventions (keep in one place so backends can't disagree):
 
@@ -87,6 +88,28 @@ def _split_seed(seed: int) -> Tuple[int, int]:
 def rand_u32(seed: int, stream: Any, counter: Any, xp: Any = np) -> Any:
     """One uniform uint32 per (stream, counter) pair; shapes broadcast."""
     return rand_u32_pair(seed, stream, counter, xp)[0]
+
+
+def rand_u32_scalar(seed: int, stream: int, counter: int) -> int:
+    """``int(rand_u32(seed, stream, counter))`` for ONE draw, on Python
+    ints: the same cipher, word for word (tests/test_rng_scalar.py holds the two
+    equal), without numpy's 0-d scalar arithmetic, which costs about 30
+    times as much per draw.  The CPU oracle draws once per packet on a
+    lossy path, so there the difference is most of its run time."""
+    m = 0xFFFFFFFF
+    s_lo, s_hi = _split_seed(seed)
+    counter = int(counter) & ((1 << 64) - 1)
+    ks = (s_lo, (int(stream) ^ s_hi) & m)
+    ks += (ks[0] ^ ks[1] ^ _PARITY,)
+    x0 = ((counter & m) + ks[0]) & m
+    x1 = ((counter >> 32) + ks[1]) & m
+    for i in range(5):
+        for r in _ROTATIONS[i % 2]:
+            x0 = (x0 + x1) & m
+            x1 = (((x1 << r) & m) | (x1 >> (32 - r))) ^ x0
+        x0 = (x0 + ks[(i + 1) % 3]) & m
+        x1 = (x1 + ks[(i + 2) % 3] + i + 1) & m
+    return x0
 
 
 def rand_u32_pair(seed: int, stream: Any, counter: Any, xp: Any = np) -> Tuple[Any, Any]:

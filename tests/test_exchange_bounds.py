@@ -115,9 +115,10 @@ def _oracle(cfg):
     return CpuEngine(cfg).run()
 
 
-#: lane-engine bookkeeping the oracle does not keep (and one it alone keeps)
-OWN = {"lane_iters", "lane_delivered", "lane_sends", "lane_drop_loss",
-       "lane_drop_codel", "lane_drop_queue", "tgen_sent_bytes"}
+#: lane-engine bookkeeping the oracle does not keep (and one it alone keeps);
+#: ``lane_drop_loss`` / ``lane_drop_codel`` are the oracle's too since PR 32
+OWN = {"lane_iters", "lane_delivered", "lane_sends", "lane_drop_queue",
+       "tgen_sent_bytes"}
 
 
 def _shared(counters):

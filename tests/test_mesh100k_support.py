@@ -29,9 +29,17 @@ from shadow_tpu.engine.sim import Simulation, device_log_readers
 from shadow_tpu.obs import Recorder
 
 MS = 1_000_000
-#: lane-engine bookkeeping the oracle does not keep (and one it alone keeps)
-OWN = {"lane_iters", "lane_delivered", "lane_sends", "lane_drop_loss",
-       "lane_drop_codel", "lane_drop_queue", "tgen_sent_bytes"}
+#: lane-engine bookkeeping the oracle does not keep (and one it alone keeps);
+#: ``lane_drop_loss`` / ``lane_drop_codel`` are the oracle's too since PR 32
+OWN = {"lane_iters", "lane_delivered", "lane_sends", "lane_drop_queue",
+       "tgen_sent_bytes"}
+#: the network ``lane_plane`` reports for these meshes (ISSUE 32): one graph
+#: node, the 10 ms link as window and longest path, no loss draw compiled
+#: in, no stream lanes
+ONE_SWITCH = {"graph_nodes": 1, "window_ns": 10 * MS,
+              "max_path_latency_ns": 10 * MS, "has_loss": 0,
+              "stream_wide_pop": 1, "lane_drop_loss": 0,
+              "stream_retransmits": 0}
 
 
 def _mesh_cfg(tmp_path, hosts=2_000, stop_ms=1_100, mesh_devices=0):
@@ -79,7 +87,7 @@ def test_facade_without_the_log_runs_past_the_logs_capacity(
     assert stats["lane_plane"] == {
         "lanes": 2_000, "mesh_devices": 1, "device_log_capacity": 0,
         "device_log_records": 0, "exchange_bounds_wide": 0,
-        "state_reused": 0}
+        "state_reused": 0, **ONE_SWITCH}
 
 
 def test_facade_with_the_log_still_raises_and_names_the_remedy(tmp_path):
@@ -279,7 +287,7 @@ def test_a_run_is_split_into_build_device_collect(tmp_path, mode, event_log):
         "lanes": 64, "mesh_devices": 1,
         "device_log_capacity": 200_000 if event_log else 0,
         "device_log_records": records, "exchange_bounds_wide": 0,
-        "state_reused": 0}
+        "state_reused": 0, **ONE_SWITCH}
     assert len(res.event_log) == records
 
 
