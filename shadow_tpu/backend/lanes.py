@@ -1566,22 +1566,39 @@ def _window_gather(arrs, start, c):
     rolls vectorize.  ``arrs`` is a list of flat [m] arrays sharing ``start``;
     entries past m are garbage the caller must mask (segment counts do).
     Arrays are processed in same-dtype groups at their NATIVE width — the
-    barrel passes are memory-bound, so int32 operands move half the bytes."""
+    barrel passes are memory-bound, so int32 operands move half the bytes.
+
+    The layout rule (ISSUE 33): **a gathered row must be at least a tile
+    wide, and lanes go minor exactly once.**  The chip stores an array in
+    (8, 128) tiles, so a gather whose rows are ``v`` = 8 wide writes a
+    buffer padded sixteen-fold, and putting the lane axis minor afterwards
+    took two relayout copies of it (82 MB at 10 000 lanes, 820 MB at
+    100 000: a fifth to a third of a mesh program's device time).  So the
+    table holds ALL operands and BOTH aligned rows of a window in one row —
+    ``tab2[q] = [arr_0[qv : qv+2v], arr_1[qv : qv+2v], ...]``, A*2v wide —
+    the gather takes one index per lane, and ONE 2-D transpose puts the
+    lanes minor, the layout the barrel shift and the merge run in.  One
+    law at every width; a row wider than a tile simply spans tiles."""
     m = arrs[0].shape[0]
     # the barrel shift decomposes the offset over bits, so the row width
     # must be a power of two >= c (c itself is any user-chosen capacity)
     v = 1 << max(c - 1, 1).bit_length()
     pad = (-m) % v
     nrow = (m + pad) // v
-    q = jnp.clip(start // v, 0, nrow - 1)
-    rows = jnp.stack([q, jnp.clip(q + 1, 0, nrow - 1)], axis=1)  # [N, 2]
+    q = jnp.clip(start // v, 0, nrow - 1)  # [N]: one index per lane
+    sh = (start % v).astype(jnp.int32)
 
     def gather_group(group):
         a = len(group)
         tab = jnp.stack(group)  # [A, m], uniform dtype
         tab = jnp.pad(tab, ((0, 0), (0, pad))).reshape(a, nrow, v)
-        block = tab[:, rows].reshape(a, -1, 2 * v)  # [A, N, 2v]
-        sh = (start % v).astype(jnp.int32)
+        # a window reaches into the next aligned row; past the last row
+        # that is the last row again (entries past m: garbage)
+        nxt = jnp.concatenate([tab[:, 1:], tab[:, -1:]], axis=1)
+        tab2 = jnp.concatenate([tab, nxt], axis=2)  # [A, nrow, 2v]
+        tab2 = tab2.transpose(1, 0, 2).reshape(nrow, a * 2 * v)
+        block = tab2[q].T.reshape(a, 2 * v, -1)  # [A, 2v, N]: lanes minor
+        block = block.transpose(0, 2, 1)  # [A, N, 2v] (no data moves)
         b = v >> 1
         while b:
             rolled = jnp.concatenate([block[:, :, b:], block[:, :, :b]], axis=2)
@@ -1594,11 +1611,25 @@ def _window_gather(arrs, start, c):
     for i, a in enumerate(arrs):
         by_dtype.setdefault(a.dtype, []).append((i, a))
     out = [None] * len(arrs)
-    for _dt, items in by_dtype.items():
-        gathered = gather_group([a for _i, a in items])
-        for (i, _a), g in zip(items, gathered):
-            out[i] = g
+    with jax.named_scope("window_gather"):
+        for _dt, items in by_dtype.items():
+            gathered = gather_group([a for _i, a in items])
+            for (i, _a), g in zip(items, gathered):
+                out[i] = g
     return out
+
+
+def _cross_block(ops, start, cnt, cx):
+    """Each lane's slice ``[start, start + cnt)`` of destination-sorted
+    columns as a lane-aligned block: ``in_seg`` [L, Cx] and every operand's
+    window masked to it — the two time words (``ops[:2]``) to NEVER, the
+    rest to 0.  A lane with more than ``cx`` entries keeps the first cx."""
+    in_seg = jnp.arange(cx, dtype=jnp.int32)[None, :] < cnt[:, None]
+    words = [
+        jnp.where(in_seg, w, NEVER32 if i < 2 else 0).astype(jnp.int32)
+        for i, w in enumerate(_window_gather(ops, start, cx))
+    ]
+    return in_seg, words
 
 
 #: the exchange's segment bounds come from ONE one-hot histogram matmul
@@ -1717,8 +1748,10 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
        the same histogram accumulated over chunks of the sends
        (``_bounds_by_onehot_chunked``: the same multiply-adds, a few MB of
        operands at any width); the slices are gathered into a lane-aligned
-       ``[N, Cx]`` block (``Cx = cross_cap``) — the batched equivalent of
-       the reference's cross-host queue push (worker.rs:603-615);
+       ``[N, Cx]`` block (``Cx = cross_cap``; ``_window_gather``: tile-wide
+       rows, lanes minor once) — the batched equivalent of the reference's
+       cross-host queue push (worker.rs:603-615); stream-endpoint lanes'
+       slices reach the tier by the same gather over ``[2S]`` lanes;
     3. one row-sort of ``[old C | self | cross Cx]`` by the 4-word key
        keeps the first C per lane — the queue's sorted invariant is
        maintained, so the pop phase needs no sort at all.
@@ -1885,23 +1918,15 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
         else:
             start, cnt = _bounds_by_onehot(flat_ops[0], n)
     cx = p.cross_cap
-    r = jnp.arange(cx, dtype=jnp.int32)[None, :]  # [1, Cx]
-    in_seg = r < cnt[:, None]
     has_pay_flat = sp and not split_se
     gather_ops = [thi_s, tlo_s, auxh_s, auxl_s, size_s] + (
         list(pay_s) if has_pay_flat else []
     )
-    gathered = _window_gather(gather_ops, start, cx)
-    g_thi, g_tlo, g_auxh, g_auxl, g_size = gathered[:5]
-    cross_thi = jnp.where(in_seg, g_thi, NEVER32).astype(jnp.int32)
-    cross_tlo = jnp.where(in_seg, g_tlo, NEVER32).astype(jnp.int32)
-    cross_auxh = jnp.where(in_seg, g_auxh, 0).astype(jnp.int32)
-    cross_auxl = jnp.where(in_seg, g_auxl, 0).astype(jnp.int32)
-    cross_size = jnp.where(in_seg, g_size, 0).astype(jnp.int32)
+    _in_seg, words = _cross_block(gather_ops, start, cnt, cx)
+    cross_thi, cross_tlo, cross_auxh, cross_auxl, cross_size = words[:5]
     if sp:
         if has_pay_flat:
-            cross_phi = jnp.where(in_seg, gathered[5], 0)
-            cross_plo = jnp.where(in_seg, gathered[6], 0)
+            cross_phi, cross_plo = words[5:]
         else:
             # split exchange: the [N] channel never carries payloads
             cross_phi = jnp.zeros((n, cx), dtype=jnp.int32)
@@ -1912,19 +1937,22 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
 
     # tiered stream backend: entries destined to stream-endpoint lanes
     # divert into the [2S] tier merge (their [N] queue rows are dead) —
-    # a [2S]-row gather of the cross block, then NEVER-mask those lanes
-    # out of the [N] merge below
+    # the endpoint lanes' windows by a gather of their own, then NEVER-mask
+    # those lanes out of the [N] merge below.  NOT ``cross_thi[el]``: rows
+    # picked out of the [N, Cx] block are Cx-wide row gathers (the padded
+    # rows _window_gather's layout rule forbids), and XLA then lays the
+    # whole barrel shift above them rows-major (the mixed 10k mesh's
+    # iteration: 1.05 -> 0.81 ms on a v5e, PERF.md §6, PR 33)
     tier_cross = None
     if divert:
         el = tb.flow_lanes
-        tier_cross = {
-            "valid": in_seg[el],
-            "thi": cross_thi[el],
-            "tlo": cross_tlo[el],
-            "auxh": cross_auxh[el],
-            "auxl": cross_auxl[el],
-            "size": cross_size[el],
-        }
+        t_valid, t_words = _cross_block(
+            gather_ops[:5], start[el], cnt[el], cx
+        )
+        tier_cross = dict(
+            zip(("thi", "tlo", "auxh", "auxl", "size"), t_words),
+            valid=t_valid,
+        )
         keep = ~tb.lane_stream[:, None]
         cross_thi = jnp.where(keep, cross_thi, NEVER32)
         cross_tlo = jnp.where(keep, cross_tlo, NEVER32)
@@ -3832,14 +3860,9 @@ def _inject_merge(p: LaneParams, tb: LaneTables, s: LaneState, inj):
     ).astype(jnp.int32)
     start, cnt = bounds[:n], bounds[1:] - bounds[:n]
     cxi = min(p.inject_cross or c, c)
-    r = jnp.arange(cxi, dtype=jnp.int32)[None, :]
-    in_seg = r < cnt[:, None]
-    g = _window_gather([thi_s, tlo_s, auxh_s, auxl_s, size_s], start, cxi)
-    cross_thi = jnp.where(in_seg, g[0], NEVER32)
-    cross_tlo = jnp.where(in_seg, g[1], NEVER32)
-    cross_auxh = jnp.where(in_seg, g[2], 0)
-    cross_auxl = jnp.where(in_seg, g[3], 0)
-    cross_size = jnp.where(in_seg, g[4], 0)
+    _in_seg, (cross_thi, cross_tlo, cross_auxh, cross_auxl, cross_size) = (
+        _cross_block([thi_s, tlo_s, auxh_s, auxl_s, size_s], start, cnt, cxi)
+    )
     lost_pre = jnp.maximum(cnt - cxi, 0)
 
     mthi = jnp.concatenate([s.q_thi, cross_thi], axis=1)

@@ -273,6 +273,63 @@ def test_sharded_run_fn_on_described_mesh(topo, as_tpu):
     assert mem.argument_size_in_bytes < whole
 
 
+_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\{([\d,]*)(?::T\(([\d,]+)\))?"
+)
+
+
+def _padded_result_bytes(text: str) -> int:
+    """The largest array any instruction of a compiled TPU program writes,
+    in bytes AS STORED: the chip keeps an array in tiles (``T(8,128)`` in
+    the layout), so the two minor dimensions round up to the tile."""
+    worst = 0
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m or not m.group(2):
+            continue
+        dtype, dims, order, tile = m.groups()
+        dims = [int(d) for d in dims.split(",")]
+        order = [int(d) for d in order.split(",")] if order else []
+        tile = [int(t) for t in tile.split(",")] if tile else []
+        # minor-to-major order; the tile covers the minor-most dimensions
+        for t, d in zip(reversed(tile), order):
+            dims[d] = -(-dims[d] // t) * t
+        bits = int(re.search(r"\d+", dtype).group()) if dtype != "pred" else 8
+        worst = max(worst, int(np.prod(dims)) * bits // 8)
+    return worst
+
+
+@pytest.mark.parametrize(
+    "n,k,a,c",
+    [
+        (10_000, 2, 5, 8),    # the 10k cells' exchange: rows of A*2v = 80
+        (100_000, 2, 5, 8),   # the 100k cells'
+        (10_000, 2, 7, 16),   # payload operands at a wider cross_cap: rows
+    ],                        # of 224 span two tiles
+)
+def test_window_gather_row_fills_its_tile(one_chip, n, k, a, c):
+    """ISSUE 33's layout rule in the compiled text: the exchange's window
+    gather writes no buffer larger than twice its lane-minor ``[A, N, 2v]``
+    block.  (Gathering rows ``v`` = 8 wide, the chip padded them sixteen-
+    fold and relaid the padded buffer out twice: ``copy.47`` / ``copy.48``,
+    82 MB at 10 000 lanes and 820 MB at 100 000 against a block of 3.2 /
+    32 MB.)  The helper alone, at the shapes the cells give it; its values
+    are ``tests/test_window_gather.py``'s."""
+    arr = jax.ShapeDtypeStruct((n * k,), np.int32, sharding=one_chip)
+    start = jax.ShapeDtypeStruct((n,), np.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda xs, s: lanes._window_gather(xs, s, c)
+    ).lower([arr] * a, start).compile()
+    text = compiled.as_text()
+    v = 1 << max(c - 1, 1).bit_length()
+    block = a * 2 * v * n * 4
+    assert "window_gather" in text  # the stage's name in a trace
+    assert _padded_result_bytes(text) <= 2 * block, _padded_result_bytes(text)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * block
+    # one index a lane: no instruction carries the two-row [2N, ...] shape
+    assert not re.search(rf"\[{2 * n},{a},{v}\]", text)
+
+
 def test_sweep_kernel_bench_shape(one_chip, as_tpu):
     """The fleet-sweep kernel at bench.py's sweep shape: 8 scenarios x
     1 000 lanes, tables/stop bounds/states all traced and stacked."""
