@@ -278,6 +278,16 @@ class LaneState(NamedTuple):
     ap_blocks: Any = ()  # block writes
     ap_rows: Any = ()  # rows those blocks wrote
     ap_tail_blocks: Any = ()  # block writes for merge-tail overflow records
+    # the run's shape peaks, int32 ``[3]`` (``PK_*``; collect()'s
+    # ``lane_plane``): the most live events any lane's merged row ``[old C
+    # | self | cross]`` held in any merge, overflow tail included (above
+    # the capacity: the queue shed); the largest segment the exchange
+    # offered any lane in any iteration (above ``cross_cap``: the cross
+    # block shed); and how many events the cross block shed in all (the
+    # part of ``n_queue`` no wider queue would have saved).  Three
+    # reductions an iteration: () — nothing traced, the program unchanged
+    # — where every lane's model is passive (``LaneParams.all_passive``)
+    peaks: Any = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1115,13 +1125,14 @@ def _process_slot(
     # phold peer draw (consumes an app draw only where it happens; traced
     # only when phold lanes exist — the threefry is ~50 ops per slot)
     if M_PHOLD in mp:
-        draw = rand_u32_lane(
-            _seed_keys(p, tb),
-            (lanes.astype(jnp.uint32) | jnp.uint32(rng_mod.APP_STREAM)),
-            s.app_draws,
-        )
-        r = rng_mod.u32_below(draw, max(n - 1, 1), xp=jnp).astype(i32)
-        phold_dst = jnp.where(n == 1, lanes, (lanes + 1 + r) % n)
+        with jax.named_scope("phold_draw"):
+            draw = rand_u32_lane(
+                _seed_keys(p, tb),
+                (lanes.astype(jnp.uint32) | jnp.uint32(rng_mod.APP_STREAM)),
+                s.app_draws,
+            )
+            r = rng_mod.u32_below(draw, max(n - 1, 1), xp=jnp).astype(i32)
+            phold_dst = jnp.where(n == 1, lanes, (lanes + 1 + r) % n)
         s = s._replace(app_draws=s.app_draws + send_phold)
     else:
         phold_dst = lanes
@@ -1732,6 +1743,13 @@ def _bounds_by_onehot_chunked(dst, n):
     return bounds[:n], bounds[1:] - bounds[:n]
 
 
+def _row_fill(mthi):
+    """Live events per row of a merged block ``[rows, C + ...]`` (an empty
+    slot's time is the NEVER pair): what ``queue_peak`` is the maximum
+    of."""
+    return (mthi != NEVER32).sum(axis=1, dtype=jnp.int32)
+
+
 def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
                   emits: _SlotEmit, divert: bool = False):
     """Append all generated events by **merge**, not scatter (TPU scatters
@@ -1959,23 +1977,24 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
 
     # -- merge [N, C + self + Cx], keep first C ---------------------------
     # queue state is ALREADY the int32 4-word key: no conversions at all
-    mthi = jnp.concatenate([s.q_thi, self_thi, cross_thi], axis=1)
-    mtlo = jnp.concatenate([s.q_tlo, self_tlo, cross_tlo], axis=1)
-    mh = jnp.concatenate([s.q_auxh, self_auxh, cross_auxh], axis=1)
-    ml = jnp.concatenate([s.q_auxl, self_auxl, cross_auxl], axis=1)
-    ms = jnp.concatenate([s.q_size, self_size, cross_size], axis=1)
-    if sp:
-        mphi = jnp.concatenate([s.q_phi, self_phi, cross_phi], axis=1)
-        mplo = jnp.concatenate([s.q_plo, self_plo, cross_plo], axis=1)
-        mthi, mtlo, mh, ml, ms, mphi, mplo = lax.sort(
-            (mthi, mtlo, mh, ml, ms, mphi, mplo), dimension=1, num_keys=4,
-            is_stable=False,
-        )
-    else:
-        mthi, mtlo, mh, ml, ms = lax.sort(
-            (mthi, mtlo, mh, ml, ms), dimension=1, num_keys=4,
-            is_stable=False,
-        )
+    with jax.named_scope("row_merge"):
+        mthi = jnp.concatenate([s.q_thi, self_thi, cross_thi], axis=1)
+        mtlo = jnp.concatenate([s.q_tlo, self_tlo, cross_tlo], axis=1)
+        mh = jnp.concatenate([s.q_auxh, self_auxh, cross_auxh], axis=1)
+        ml = jnp.concatenate([s.q_auxl, self_auxl, cross_auxl], axis=1)
+        ms = jnp.concatenate([s.q_size, self_size, cross_size], axis=1)
+        if sp:
+            mphi = jnp.concatenate([s.q_phi, self_phi, cross_phi], axis=1)
+            mplo = jnp.concatenate([s.q_plo, self_plo, cross_plo], axis=1)
+            mthi, mtlo, mh, ml, ms, mphi, mplo = lax.sort(
+                (mthi, mtlo, mh, ml, ms, mphi, mplo), dimension=1,
+                num_keys=4, is_stable=False,
+            )
+        else:
+            mthi, mtlo, mh, ml, ms = lax.sort(
+                (mthi, mtlo, mh, ml, ms), dimension=1, num_keys=4,
+                is_stable=False,
+            )
     tail_mask = mthi[:, c:] != NEVER32
     s = s._replace(
         q_thi=mthi[:, :c],
@@ -1986,6 +2005,17 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
         n_queue=s.n_queue + tail_mask.sum(axis=1, dtype=jnp.int32)
         + lost_pre,
     )
+    if not p.all_passive:
+        # the run's shape peaks: three reductions an iteration (~50 KB of
+        # code and ~1.5 % of the compiler's cycles in the 10 000-lane
+        # mesh, PERF.md 6, PR 35), so a program of passive lanes alone —
+        # fixed peers, the shapes its configuration was measured at —
+        # carries none and reports the shapes only
+        s = s._replace(peaks=jnp.stack([
+            jnp.maximum(s.peaks[PK_QUEUE], _row_fill(mthi).max()),
+            jnp.maximum(s.peaks[PK_CROSS], cnt.max()),
+            s.peaks[PK_CROSS_SHED] + lost_pre.sum(dtype=jnp.int32),
+        ]))
     if p.netobs:
         # cross-block sheds stay inside n_queue (the strict-mode total)
         # but carry their own cause counter so the netobs drop classification
@@ -3648,6 +3678,8 @@ _NB_SCALARS = ("nb_win",)
 _FL_SCALARS = ("fl_count", "fl_lost")
 # append engage counters (present when the log or the egress buffer is)
 _AP_SCALARS = ("ap_blocks", "ap_rows", "ap_tail_blocks")
+# rows of LaneState.peaks
+PK_QUEUE, PK_CROSS, PK_CROSS_SHED = range(3)
 
 
 def pack_state(s: LaneState):
@@ -3671,7 +3703,8 @@ def pack_state(s: LaneState):
     sc = jnp.stack(
         [jnp.asarray(getattr(s, f), dtype=jnp.int32) for f in sc_fields]
     )
-    return (q, c32, sc, s.log, s.stream, s.egress, s.nb_hist, s.fl_buf)
+    return (q, c32, sc, s.log, s.stream, s.egress, s.nb_hist, s.fl_buf,
+            s.peaks)
 
 
 def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
@@ -3686,7 +3719,7 @@ def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
 
 
 def unpack_state(carry) -> LaneState:
-    q, c32, sc, log, stream, egress, nb_hist, fl_buf = carry
+    q, c32, sc, log, stream, egress, nb_hist, fl_buf, peaks = carry
     has_pay = q.shape[0] == 7
     # the optional blocks' own carry leaves say which are live; the append
     # counters have none, so the scalar count left over tells
@@ -3708,7 +3741,8 @@ def unpack_state(carry) -> LaneState:
         q_phi=q[5] if has_pay else (), q_plo=q[6] if has_pay else (),
         stream=stream,
         cd_dropping=c32[len(_I32_N_FIELDS)].astype(bool),
-        log=log, egress=egress, nb_hist=nb_hist, fl_buf=fl_buf, **kw,
+        log=log, egress=egress, nb_hist=nb_hist, fl_buf=fl_buf,
+        peaks=peaks, **kw,
     )
 
 
@@ -3887,11 +3921,20 @@ def _inject_merge(p: LaneParams, tb: LaneTables, s: LaneState, inj):
     tail = (mthi[:, c:] != NEVER32).sum(axis=1, dtype=jnp.int32)
     if p.netobs:
         s = s._replace(nb_shed=s.nb_shed + lost_pre)
-    return s._replace(
+    s = s._replace(
         q_thi=mthi[:, :c], q_tlo=mtlo[:, :c], q_auxh=mh[:, :c],
         q_auxl=ml[:, :c], q_size=ms[:, :c],
         n_queue=s.n_queue + tail + lost_pre,
     )
+    if not p.all_passive:
+        # the injection block is ``capacity`` wide (``cxi``), not
+        # ``cross_cap``: what it sheds is cured by the QUEUE's option, so
+        # ``lost_pre`` counts toward the queue's peak — the most events one
+        # lane was handed — and never toward PK_CROSS_SHED, whose cure
+        # (tpu_cross_capacity) would not save it
+        s = s._replace(peaks=s.peaks.at[PK_QUEUE].max(
+            (_row_fill(mthi) + lost_pre).max()))
+    return s
 
 
 # indices into the packed scalar vector make_hybrid_fused_fn returns: ONE

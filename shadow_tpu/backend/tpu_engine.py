@@ -987,6 +987,7 @@ class TpuEngine:
                 f: full() if p.log_capacity or ext else ()
                 for f in lanes._AP_SCALARS
             },
+            peaks=() if p.all_passive else full(3),
         )
         # ONE transfer of the whole tree, straight onto its placement: no
         # eager device program, no whole copy on one chip before sharding
@@ -1435,6 +1436,8 @@ class TpuEngine:
             "n_loss", "n_codel", "n_queue", "recv_bytes", "n_sends",
             "n_hops", "log_count", "log_lost", "rounds", "iters",
         ]
+        if not p.all_passive:
+            fields.append("peaks")
         if p.netobs:
             fields += ["nb_txb", "nb_rxb", "nb_thr", "nb_shed", "nb_hist",
                        "nb_win"]
@@ -1483,18 +1486,57 @@ class TpuEngine:
         def tier_sum(row: int) -> int:
             return int(tv[row].sum()) if tv is not None else 0
 
-        n_queue_drops = int(s.n_queue.sum()) + tier_sum(
-            lstr_mod.TV_N_QUEUE
-        )
+        n_tier_drops = tier_sum(lstr_mod.TV_N_QUEUE)
+        n_queue_drops = int(s.n_queue.sum()) + n_tier_drops
+        # the shapes this program was compiled at and, where the program
+        # keeps them (lanes.LaneState.peaks: some lane's model is active),
+        # how far the run filled them: a run that did not raise states its
+        # headroom
+        p = self.params
+        shapes = {
+            "queue_capacity": p.capacity,
+            "cross_capacity": p.cross_cap,
+            "pops_per_iter": p.pops_per_iter,
+        }
+        queue_peak = cross_peak = n_cross = 0
+        if not p.all_passive:
+            queue_peak, cross_peak, n_cross = (int(x) for x in s.peaks)
+            shapes.update(queue_peak=queue_peak, cross_peak=cross_peak)
         if n_queue_drops and self.strict_capacity:
+            # name the block that overflowed, each with the option that
+            # cures it: the cross block sheds before the merge sees the
+            # event (lanes._merge_append's lost_pre), so no queue width
+            # saves it
+            tail_why = (
+                f"off the tail of a lane QUEUE (it holds {p.capacity}) or "
+                f"by the CROSS block (it holds {p.cross_cap}; a program of "
+                "passive lanes keeps one count for both); raise "
+                "experimental.tpu_lane_queue_capacity, and "
+                "experimental.tpu_cross_capacity where it is set below it"
+            ) if p.all_passive else (
+                "off the tail of a lane QUEUE (one lane's merged row held "
+                f"{queue_peak} events, the queue holds {p.capacity}); raise "
+                "experimental.tpu_lane_queue_capacity"
+            )
+            causes = [
+                (n_cross, "by the CROSS block (the exchange offered one "
+                 f"lane {cross_peak} events in one iteration, the block "
+                 f"holds {p.cross_cap}); raise "
+                 "experimental.tpu_cross_capacity"),
+                (n_queue_drops - n_cross - n_tier_drops, tail_why),
+                (n_tier_drops, "off the tail of a stream-tier QUEUE (it "
+                 f"holds {p.stream_capacity}); raise "
+                 "experimental.tpu_stream_queue_capacity"),
+            ]
             raise RuntimeError(
-                f"{n_queue_drops} events dropped on lane-queue overflow; raise "
-                "experimental.tpu_lane_queue_capacity (results would silently "
-                "diverge from the cpu backend)"
+                f"{n_queue_drops} events dropped on capacity overflow: "
+                + "; ".join(f"{k} {why}" for k, why in causes if k)
+                + " (results would silently diverge from the cpu backend)"
             )
         log_count = int(s.log_count)
         log_lost = int(s.log_lost)
         self.lane_plane = {
+            **shapes,
             "lanes": self.params.n_lanes,
             "mesh_devices": (
                 int(self._mesh.devices.size) if self._mesh is not None else 1

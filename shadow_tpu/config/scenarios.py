@@ -1,5 +1,6 @@
 """Scenario factories: managed-process scenarios (real OS binaries under
-the shim) and the routed, lossy all-TCP network (lane models only).
+the shim), the routed, lossy all-TCP network and the PHOLD mesh (lane
+models only).
 
 The BASELINE.md evaluation ladder's config #5 is a Tor-shaped relay
 topology (the reference's 500-relay chutney networks,
@@ -22,6 +23,7 @@ workload class the reference's 6.38x was measured on,
 
 from __future__ import annotations
 
+import math
 import random
 from pathlib import Path
 
@@ -287,4 +289,120 @@ def routed_tcp_mesh_config(
         "network": {"graph": {"type": "gml", "inline": gml}},
         "experimental": {"network_backend": "tpu"},
         "hosts": hosts,
+    })
+
+
+# -- PHOLD: random destinations, an active lane model ------------------------
+
+#: windows the PHOLD shape law budgets for: 10 sim-s at a 10 ms lookahead,
+#: the horizon of this repo's presets (the stop time is the caller's, set
+#: after the factory returns, and the tail moves with the LOG of this number)
+PHOLD_LAW_WINDOWS = 1000
+#: pops an iteration of a PHOLD program: every other deployment's.  4 pops
+#: take 15 % fewer iterations at 2.3x the iteration (PERF.md 6, PR 35)
+PHOLD_POPS = 2
+#: the engine's own headroom over a lane's queued events
+#: (``TpuEngine.__init__``: capacity >= initial events + 8)
+QUEUE_HEADROOM = 8
+
+
+def poisson_tail_quantile(mean: float, p: float) -> int:
+    """The smallest ``k`` with ``P(X > k) < p`` for ``X ~ Poisson(mean)``:
+    the width that one draw overflows with probability under ``p``."""
+    k = int(mean)
+    while True:
+        j = k + 1
+        term = math.exp(-mean + j * math.log(mean) - math.lgamma(j + 1))
+        tail = 0.0
+        while term > tail * 1e-17:
+            tail += term
+            j += 1
+            term *= mean / j
+        if tail < p:
+            return k
+        k += 1
+
+
+def phold_shape_law(
+    n_hosts: int, messages: int, windows: int = PHOLD_LAW_WINDOWS,
+    pops: int = PHOLD_POPS,
+) -> tuple[int, int]:
+    """``(tpu_lane_queue_capacity, tpu_cross_capacity)`` for a PHOLD mesh
+    of ``n_hosts`` lanes and ``messages`` messages a lane, run for
+    ``windows`` lookahead windows at ``pops`` pops an iteration.
+
+    Destinations are uniform draws, so what a lane is handed is a Poisson
+    count, and a width is a TAIL QUANTILE of it, taken so that one run
+    overflows anywhere with probability about 1 / 1 000:
+
+    - a window's arrivals at one lane are ~Poisson(messages); each costs
+      two dependent pops (the PACKET, then the DELIVERY it inserts, whose
+      pop is the send), so the fullest lane of a window sets the
+      iterations: ``iters = ceil(2 q / pops)`` with ``q`` the quantile at
+      1 / (1 000 x lanes x windows);
+    - a QUEUE holds what is left of this window's arrivals plus what the
+      merge has already filed for the next: ~Poisson(2 x messages), at
+      1 / (1 000 x lanes x windows x iters), plus the engine's headroom;
+    - a CROSS segment holds one iteration's fan-in: every lane sends at
+      most ``pops``, so ~Poisson(<= pops) (reached at start-up, when every
+      lane pops ``pops`` initial messages), at the same tail.
+
+    The merge's row is ``capacity + 2 pops + cross`` columns and its sort
+    pads to a power of two (PERF.md 4), so the columns left under that
+    power go to the queue: they cost nothing.  Strict capacity is the
+    backstop: a run past the tail raises and names the block."""
+    if min(n_hosts, messages, windows, pops) < 1:
+        raise ValueError("n_hosts, messages, windows and pops must be >= 1")
+    draws = 1000 * n_hosts * windows
+    iters = -(-2 * poisson_tail_quantile(messages, 1 / draws) // pops)
+    p = 1 / (draws * iters)
+    queue = poisson_tail_quantile(2 * messages, p) + QUEUE_HEADROOM
+    cross = poisson_tail_quantile(pops, p)
+    row = queue + 2 * pops + cross
+    return (1 << (row - 1).bit_length()) - 2 * pops - cross, cross
+
+
+def phold_mesh_config(
+    n_hosts: int,
+    messages: int = 4,
+    size: int = 256,
+    latency: str = "10 ms",
+    bandwidth: str = "1 Gbit",
+    seed: int = 1,
+) -> ConfigOptions:
+    """PHOLD (Fujimoto 1990) as upstream Shadow ships it (the reference's
+    ``src/test/phold/test_phold.c``): ``n_hosts`` hosts on one graph
+    node, each one process ``phold --messages <messages> --size <size>``
+    (``models/phold.py``: every received datagram answered by one to a
+    peer drawn uniformly from the others), self-edge ``latency`` (the
+    lookahead), ``bandwidth`` up and down, zero loss — so the population
+    of ``n_hosts x messages`` datagrams is conserved.
+
+    The lane program's shapes are set here by :func:`phold_shape_law`
+    (random fan-in has no hand-set safe width); stop time and backend
+    (``tpu``) are the caller's to set on the result."""
+    queue, cross = phold_shape_law(n_hosts, messages)
+    return ConfigOptions.from_dict({
+        "general": {"stop_time": "10 s", "seed": seed,
+                    "heartbeat_interval": None},
+        "network": {"graph": {"type": "gml", "inline": (
+            "graph [\n"
+            f'  node [ id 0 host_bandwidth_up "{bandwidth}" '
+            f'host_bandwidth_down "{bandwidth}" ]\n'
+            f'  edge [ source 0 target 0 latency "{latency}" ]\n'
+            "]\n")}},
+        "experimental": {
+            "network_backend": "tpu",
+            "tpu_lane_queue_capacity": queue,
+            "tpu_cross_capacity": cross,
+            "tpu_events_per_round": PHOLD_POPS,
+        },
+        "hosts": {"lp": {
+            "count": n_hosts, "network_node_id": 0,
+            "processes": [{
+                "path": "phold",
+                "args": ["--messages", str(messages), "--size", str(size)],
+                "start_time": "0 s",
+            }],
+        }},
     })

@@ -78,6 +78,7 @@ LANE_FIELDS = frozenset((
 REPLICATED_FIELDS = frozenset((
     "log", "log_count", "log_lost", "rounds", "iters",
     "now_we_hi", "now_we_lo", "min_used_lat", "stream",
+    "peaks",
     "egress", "egress_count", "egress_lost",
     "egress_min_hi", "egress_min_lo",
     "nb_hist", "nb_win",

@@ -117,6 +117,29 @@ def test_udp_flagship_run_fn_full_width(one_chip, as_tpu):
     _fits(compiled)
 
 
+def test_phold_run_fn_full_width(one_chip, as_tpu):
+    """The 10 000-LP PHOLD mesh at the factory's shapes (42 / 18 / 2: a
+    64-column merge row), log off, as cell ``phold10k_m4`` times it: the
+    first program with the active model's ``ins_*`` channel, the per-send
+    peer draw and the shape peaks, and its scopes in the compiled text
+    (``scripts/hlo_stats.py phold10k_m4 --scope phold_draw``)."""
+    from shadow_tpu.config.scenarios import phold_mesh_config
+
+    cfg = phold_mesh_config(10_000, 4, 256, "10 ms", "1 Gbit")
+    cfg.general.stop_time = 500 * MS
+    eng = TpuEngine(cfg, log_capacity=0)
+    p = eng.params
+    assert (p.capacity, p.cross_cap, p.pops_per_iter) == (42, 18, 2)
+    assert not p.all_passive
+    state = _shapes(eng.initial_state(), one_chip)
+    compiled = lanes.make_run_fn(p, eng.tables).lower(state).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    for scope in ("phold_draw", "row_merge", "window_gather",
+                  "exchange_bounds"):
+        assert f"/{scope}/" in text, scope
+
+
 def test_udp_round_fn_step_driver(one_chip, as_tpu):
     """The step driver's one-round kernel (run-control / checkpointing),
     kept at 1 000 lanes (same body; the full width is the case above)."""

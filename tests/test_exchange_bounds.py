@@ -187,7 +187,7 @@ def test_strict_capacity_raises_on_a_cross_block_shed(
     monkeypatch.setattr(lanes, "_ONEHOT_BUDGET", budget)
     cfg = ConfigOptions.from_yaml(_FAN_IN.format(data=tmp_path / "f"))
     eng = TpuEngine(cfg)
-    with pytest.raises(RuntimeError, match="lane-queue overflow") as e:
+    with pytest.raises(RuntimeError, match="or by the CROSS block .it holds 2; .* experimental.tpu_cross_capacity") as e:
         eng.run(mode="device")
     shed = int(re.match(r"(\d+) events dropped", str(e.value)).group(1))
     loose = TpuEngine(cfg, strict_capacity=False).run(mode="device")

@@ -346,7 +346,7 @@ hosts:
 """
     from shadow_tpu.backend.tpu_engine import TpuEngine as TE
 
-    with pytest.raises(RuntimeError, match="lane-queue overflow"):
+    with pytest.raises(RuntimeError, match="capacity overflow"):
         TE(ConfigOptions.from_yaml(yaml)).run(mode="step")
 
 

@@ -36,10 +36,13 @@ OWN = {"lane_iters", "lane_delivered", "lane_sends", "lane_drop_queue",
 #: the network ``lane_plane`` reports for these meshes (ISSUE 32): one graph
 #: node, the 10 ms link as window and longest path, no loss draw compiled
 #: in, no stream lanes
+#: ... and (ISSUE 35) the shapes ``_mesh_cfg`` compiles at (no peaks: a
+#: program of passive lanes compiles none)
 ONE_SWITCH = {"graph_nodes": 1, "window_ns": 10 * MS,
               "max_path_latency_ns": 10 * MS, "has_loss": 0,
               "stream_wide_pop": 1, "lane_drop_loss": 0,
-              "stream_retransmits": 0}
+              "stream_retransmits": 0,
+              "queue_capacity": 16, "cross_capacity": 8, "pops_per_iter": 2}
 
 
 def _mesh_cfg(tmp_path, hosts=2_000, stop_ms=1_100, mesh_devices=0):
@@ -184,7 +187,7 @@ def test_a_queue_drop_never_reaches_packet_outcomes(tmp_path, event_log):
     so no finished facade run reports one."""
     cfg = ConfigOptions.from_yaml(_DROPPY.format(
         data=tmp_path / "q", backend="tpu", cap=12))
-    with pytest.raises(RuntimeError, match="lane-queue overflow"):
+    with pytest.raises(RuntimeError, match="off the tail of a lane QUEUE"):
         Simulation(cfg, event_log=event_log).run(write_data=False)
 
 

@@ -354,9 +354,9 @@ def _bad_tier(row):
     ("plain", _bad("recv_bytes"), "counter recv_bytes wrapped"),
     ("plain", _bad("m_peer_offset"), "counter m_peer_offset wrapped"),
     ("mixed", _bad_tier(lstr.TV_SEND_SEQ), "tier counter send_seq wrapped"),
-    ("plain", _bad("n_queue", 2), "2 events dropped on lane-queue overflow"),
+    ("plain", _bad("n_queue", 2), "2 events dropped on capacity overflow: 2 off the tail of a lane QUEUE \\(it holds 16\\) or by the CROSS block"),
     ("mixed", _bad_tier(lstr.TV_N_QUEUE),
-     "3 events dropped on lane-queue overflow"),
+     "3 events dropped on capacity overflow: 3 off the tail of a stream-tier QUEUE"),
     ("plain", _bad("log_lost", 7),
      "event log overflowed: .* \\(7 records lost\\)"),
 ], ids=["send_seq", "local_seq", "n_delivered", "n_sends", "recv_bytes",
