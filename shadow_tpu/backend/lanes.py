@@ -234,6 +234,10 @@ class LaneState(NamedTuple):
     # round bookkeeping (scalars)
     rounds: jnp.ndarray  # int32
     iters: jnp.ndarray  # int32: while-loop iterations (perf visibility)
+    # int32: pops (of either tier) in which some lane took CoDel's
+    # dropping-branch table lookup (codel_offer_arrays' ``looked``); read
+    # into collect()'s ``lane_plane``, never into the counters
+    codel_lookup_pops: jnp.ndarray
     now_we_hi: jnp.ndarray  # int32 pair: current round's window end
     now_we_lo: jnp.ndarray
     min_used_lat: jnp.ndarray  # int32 scalar: smallest latency sent over
@@ -624,15 +628,28 @@ def bucket_charge_chained_vec(
 CD_UNSET = -(1 << 31) + 1
 
 
+@jax.named_scope("codel_offer")
 def codel_offer_arrays(
     fat_hi, fat_lo, dn_hi, dn_lo, dcount, dropping,
     td_hi, td_lo, sojourn, active, codel_div,
 ):
     """Masked PAIR form of CoDel.offer on explicit state arrays; returns
-    ``(fat_hi', fat_lo', dnext_hi', dnext_lo', dcount', dropping', drop)``.
-    ``sojourn`` is an int32 clamped difference — exact for every compare
-    in the law (values past the clamp are far above TARGET either way).
-    Shape-generic: the [N] lane tier and the [2S] stream tier share it."""
+    ``(fat_hi', fat_lo', dnext_hi', dnext_lo', dcount', dropping', drop,
+    looked)``.  ``sojourn`` is an int32 clamped difference — exact for
+    every compare in the law (values past the clamp are far above TARGET
+    either way).  Shape-generic: the [N] lane tier and the [2S] stream
+    tier share it.
+
+    The control-law interval ``codel_div[k]`` costs the chip a serial
+    per-element gather (~6.6 ns an element, PERF.md §6 PR 36), so it is
+    fetched only where it can be kept.  Entering an episode, ``k`` is 1 or
+    2: a select of two constants.  Inside one, ``k`` is general but the
+    entry is kept only on lanes with ``drop_in_dropping``: ``looked`` (a
+    scalar: some lane has it in this pop) guards the gather with a
+    ``lax.cond`` whose operands are the index and the table alone — no
+    lane state crosses it.  A quiet network never takes the branch.
+    Guarded at every length, no rule and no option (the readings that
+    decided it: PERF.md §6 PR 36)."""
     unset = fat_hi == CD_UNSET
     below = sojourn < codel_mod.TARGET_NS
     ent_hi, ent_lo = pair_add32(td_hi, td_lo, codel_mod.INTERVAL_NS)
@@ -648,7 +665,14 @@ def codel_offer_arrays(
     )
     dcount_d = dcount + drop_in_dropping.astype(dcount.dtype)
     div_idx_d = jnp.minimum(dcount_d, codel_mod.DIV_TABLE_SIZE - 1)
-    dnd_hi, dnd_lo = pair_add32(dn_hi, dn_lo, codel_div[div_idx_d])
+    looked = jnp.any(drop_in_dropping)
+    div_d = lax.cond(
+        looked,
+        lambda table, idx: table[idx],
+        lambda table, idx: jnp.zeros_like(idx),
+        codel_div, div_idx_d,
+    )
+    dnd_hi, dnd_lo = pair_add32(dn_hi, dn_lo, div_d)
     dnd_hi = jnp.where(drop_in_dropping, dnd_hi, dn_hi)
     dnd_lo = jnp.where(drop_in_dropping, dnd_lo, dn_lo)
 
@@ -665,9 +689,14 @@ def codel_offer_arrays(
         )
     )
     recent = pair_lt(td_hi, td_lo, dni_hi, dni_lo)
-    dcount_e = jnp.where((dcount > 2) & recent, 2, 1).astype(dcount.dtype)
-    div_idx_e = jnp.minimum(dcount_e, codel_mod.DIV_TABLE_SIZE - 1)
-    dne_hi, dne_lo = pair_add32(td_hi, td_lo, codel_div[div_idx_e])
+    resume = (dcount > 2) & recent
+    dcount_e = jnp.where(resume, 2, 1).astype(dcount.dtype)
+    div_e = jnp.where(
+        resume,
+        jnp.int32(codel_mod.CODEL_DIV[2]),
+        jnp.int32(codel_mod.CODEL_DIV[1]),
+    )
+    dne_hi, dne_lo = pair_add32(td_hi, td_lo, div_e)
 
     drop = drop_in_dropping | enter
     fat_out_hi = jnp.where(active, fatn_hi, fat_hi)
@@ -679,12 +708,13 @@ def codel_offer_arrays(
     dn_out_hi = jnp.where(enter, dne_hi, dnd_hi)
     dn_out_lo = jnp.where(enter, dne_lo, dnd_lo)
     return (fat_out_hi, fat_out_lo, dn_out_hi, dn_out_lo, dcount_out,
-            dropping_out, drop)
+            dropping_out, drop, looked)
 
 
 def codel_offer_vec(state, td_hi, td_lo, sojourn, active, codel_div):
     """LaneState wrapper of :func:`codel_offer_arrays`."""
-    fat_hi, fat_lo, dn_hi, dn_lo, dcount, dropping, drop = codel_offer_arrays(
+    (fat_hi, fat_lo, dn_hi, dn_lo, dcount, dropping, drop,
+     looked) = codel_offer_arrays(
         state.cd_fat_hi, state.cd_fat_lo, state.cd_dnext_hi,
         state.cd_dnext_lo, state.cd_drop_count, state.cd_dropping,
         td_hi, td_lo, sojourn, active, codel_div,
@@ -696,6 +726,7 @@ def codel_offer_vec(state, td_hi, td_lo, sojourn, active, codel_div):
         cd_dnext_lo=dn_lo,
         cd_drop_count=dcount,
         cd_dropping=dropping,
+        codel_lookup_pops=state.codel_lookup_pops + looked,
     )
     return state, drop
 
@@ -2658,7 +2689,8 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
             )
         )
         sojourn = pair_sub_clamp(td_hi, td_lo, thi, tlo, NEVER32)
-        (cd_fh, cd_fl, cd_dh, cd_dl, cd_cnt, cd_drop_state, codel_drop) = (
+        (cd_fh, cd_fl, cd_dh, cd_dl, cd_cnt, cd_drop_state, codel_drop,
+         cd_looked) = (
             codel_offer_arrays(
                 v[lstr.TV_CD_FATH], v[lstr.TV_CD_FATL], v[lstr.TV_CD_DNH],
                 v[lstr.TV_CD_DNL], v[lstr.TV_CD_CNT],
@@ -2922,6 +2954,7 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
             "bo_valid": bo_valid, "bo_thi": bo_thi, "bo_tlo": bo_tlo,
             "bo_auxl": bo_auxl, "bo_size": bo_size, "bo_phi": bo_phi,
             "bo_plo": bo_plo,
+            "cd_looked": cd_looked,
         }
         if log_on:
             t64d = t_join(td_hi, td_lo)
@@ -2962,7 +2995,11 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
         tier_slot, (f, v, mul, false_e), xs, k
     )
     ts = ts._replace(flows=lstr.endpoint_split(f), v=v)
-    s = s._replace(min_used_lat=mul)
+    s = s._replace(
+        min_used_lat=mul,
+        codel_lookup_pops=s.codel_lookup_pops
+        + outs["cd_looked"].sum(dtype=i32),
+    )
 
     # ---- merge: queue + all slot channels + diverted mesh cross ----------
     def stack(key):  # [K, 2S] -> [2S, K]
@@ -3138,8 +3175,12 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
     ``pure_dataflow=True`` (the fused device run) removes every
     ``lax.cond`` skip path in favour of unconditional masked work, on
     the assumption that a device-side branch costs more than the work it
-    skips (cond-vs-mask is unmeasured on the attached chip).  The step
-    driver keeps the skips — on CPU they pay.
+    skips.  The price of a branch was read on the chip (PERF.md §6
+    PR 36: a few microseconds for a scalar predicate reduced from an
+    ``[N]`` mask plus one ``lax.cond`` in this body), so the assumption
+    holds only for work cheaper than that: ``codel_offer_arrays`` guards
+    its table gather with one, and the masked skips here stand untested
+    one by one.  The step driver keeps the skips — on CPU they pay.
 
     TIERED mode: the [N] machinery runs with a derived params view whose
     model set excludes the stream models (the whole stream slot body,
@@ -3261,8 +3302,9 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
         # the stream tier's slot body is large: inlining it per slot blows
         # up XLA:CPU compile time, so slot-level conds stay there.  On the
         # accelerator the body is inlined and masked instead, on the same
-        # cond-vs-mask assumption as pure_dataflow above (unmeasured on the
-        # attached chip); compile tolerates the inlined body
+        # cond-vs-mask assumption as pure_dataflow above (a slot-level
+        # cond here is not yet read against the branch's price, PERF.md
+        # §6 PR 36); compile tolerates the inlined body
         slot_dataflow = pure_dataflow and (
             not p_lane.stream_present or jax.default_backend() != "cpu"
         )
@@ -3663,7 +3705,8 @@ _I32_N_FIELDS = (
     "n_delivered", "n_loss", "n_codel", "n_queue", "recv_bytes",
     "n_sends", "n_hops",
 )
-_SCALAR_FIELDS = ("log_count", "log_lost", "rounds", "iters", "now_we_hi", "now_we_lo",
+_SCALAR_FIELDS = ("log_count", "log_lost", "rounds", "iters",
+                  "codel_lookup_pops", "now_we_hi", "now_we_lo",
                   "min_used_lat")
 # hybrid-backend scalar extension (present only when egress is live)
 _EG_SCALARS = ("egress_count", "egress_lost", "egress_min_hi",

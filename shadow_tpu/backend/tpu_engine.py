@@ -962,6 +962,7 @@ class TpuEngine:
             log_lost=full(),
             rounds=full(),
             iters=full(),
+            codel_lookup_pops=full(),
             now_we_hi=full(),
             now_we_lo=full(),
             min_used_lat=full(fill=lanes.NEVER32),
@@ -1435,6 +1436,7 @@ class TpuEngine:
             "send_seq", "local_seq", "m_peer_offset", "n_delivered",
             "n_loss", "n_codel", "n_queue", "recv_bytes", "n_sends",
             "n_hops", "log_count", "log_lost", "rounds", "iters",
+            "codel_lookup_pops",
         ]
         if not p.all_passive:
             fields.append("peaks")
@@ -1551,6 +1553,11 @@ class TpuEngine:
             # 1 when the run started from the initial state an earlier run
             # of this engine built and kept on the device (_start_state)
             "state_reused": self._state_reused,
+            # pops (of either tier; a run makes lane_iters x pops of each)
+            # in which some lane took CoDel's dropping-branch table lookup
+            # (lanes.codel_offer_arrays): 0 in a network with no drop
+            # episode, whose program then never runs that gather
+            "codel_lookup_pops": int(s.codel_lookup_pops),
             # the network this program was compiled for: nodes of the
             # [G, G] latency / loss tables, the lookahead window, the
             # longest routed path, whether the loss draw is compiled in,

@@ -76,7 +76,7 @@ LANE_FIELDS = frozenset((
 # sharded lanes: GSPMD lowers the scatter-adds as shard-then-reduce,
 # which is exact for the integer counters they carry.
 REPLICATED_FIELDS = frozenset((
-    "log", "log_count", "log_lost", "rounds", "iters",
+    "log", "log_count", "log_lost", "rounds", "iters", "codel_lookup_pops",
     "now_we_hi", "now_we_lo", "min_used_lat", "stream",
     "peaks",
     "egress", "egress_count", "egress_lost",

@@ -107,14 +107,86 @@ def _fits(compiled, limit_bytes: int = 16 << 30) -> int:
     return total
 
 
-def test_udp_flagship_run_fn_full_width(one_chip, as_tpu):
+@pytest.fixture(scope="module")
+def udp_flagship_compiled(one_chip):
     """The 10 000-lane UDP flagship, fused free-run, exactly as
-    chip_smoke.py phase a sends it through the facade (device log on)."""
-    eng = TpuEngine(_pure_cfg(10_000, 150 * MS))
-    assert eng.params.n_lanes == 10_000
-    state = _shapes(eng.initial_state(), one_chip)
-    compiled = lanes.make_run_fn(eng.params, eng.tables).lower(state).compile()
-    _fits(compiled)
+    chip_smoke.py phase a sends it through the facade (device log on):
+    ONE compile for the cases that read it."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")  # as ``as_tpu``
+        eng = TpuEngine(_pure_cfg(10_000, 150 * MS))
+        assert eng.params.n_lanes == 10_000
+        state = _shapes(eng.initial_state(), one_chip)
+        return lanes.make_run_fn(eng.params, eng.tables).lower(
+            state).compile()
+
+
+def test_udp_flagship_run_fn_full_width(udp_flagship_compiled):
+    _fits(udp_flagship_compiled)
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[\w.\-]+) \(.*\{$")
+_CALLED = re.compile(
+    r"(?:calls|to_apply|body|condition|true_computation|false_computation)"
+    r"=(%[\w.\-]+)"
+)
+
+
+def _unguarded_table_gathers(text: str) -> list[str]:
+    """Gathers out of an ``s32[1025]`` table (CoDel's ``codel_div``) in a
+    computation the program reaches WITHOUT entering a conditional's
+    branch: every such gather runs on every trip of the loop."""
+    comps: dict[str, list[str]] = {}
+    entry = name = None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+            if line.startswith("ENTRY"):
+                entry = name
+        elif name is not None:
+            comps[name].append(line)
+    assert entry is not None
+    always, todo = set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp in always:
+            continue
+        always.add(comp)
+        for line in comps[comp]:
+            # a conditional's branch_computations are NOT followed
+            todo += _CALLED.findall(line)
+    bad = []
+    for comp in always:
+        tables = {
+            m.group(1) for line in comps[comp]
+            if (m := re.match(r"\s*(%[\w.\-]+) = s32\[1025\]", line))
+        }
+        for line in comps[comp]:
+            m = re.search(r" gather\((%[\w.\-]+),", line)
+            if m and m.group(1) in tables:
+                bad.append(f"{comp}: {line.strip()[:120]}")
+    return bad
+
+
+def test_codel_table_gather_only_inside_a_conditional(udp_flagship_compiled):
+    """No ``gather(s32[1025], s32[N])`` stands in the loop body outside a
+    conditional's branch (PR 36: four of them were over half of every wide
+    cell's wall): the enter branch selects two constants, the dropping
+    branch gathers under ``lax.cond``.  The parser is held to a program
+    that does gather unconditionally, so an empty list means something."""
+    text = udp_flagship_compiled.as_text()
+    assert "/codel_offer/cond/" in text
+    assert " conditional(" in text
+    assert _unguarded_table_gathers(text) == []
+
+
+def test_the_gather_parser_sees_an_unguarded_table_gather(one_chip):
+    table = jax.ShapeDtypeStruct((1025,), np.int32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((10_000,), np.int32, sharding=one_chip)
+    text = jax.jit(lambda t, i: t[i]).lower(table, idx).compile().as_text()
+    assert len(_unguarded_table_gathers(text)) == 1
 
 
 def test_phold_run_fn_full_width(one_chip, as_tpu):

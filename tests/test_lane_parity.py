@@ -13,10 +13,16 @@ from shadow_tpu.backend.tpu_engine import LaneCompatError, TpuEngine
 from shadow_tpu.config.options import ConfigOptions
 
 
-def both_logs(yaml: str, mode: str = "step"):
+def both_runs(yaml: str, mode: str = "step"):
+    """Both backends' results and the TPU engine's collect-time gauges."""
     cpu = CpuEngine(ConfigOptions.from_yaml(yaml)).run()
-    tpu = TpuEngine(ConfigOptions.from_yaml(yaml)).run(mode=mode)
-    return cpu, tpu
+    eng = TpuEngine(ConfigOptions.from_yaml(yaml))
+    tpu = eng.run(mode=mode)
+    return cpu, tpu, eng.lane_plane
+
+
+def both_logs(yaml: str, mode: str = "step"):
+    return both_runs(yaml, mode)[:2]
 
 
 PHOLD_SMALL = """
@@ -70,11 +76,14 @@ hosts:
 
 
 def test_tgen_lossy_parity():
-    cpu, tpu = both_logs(TGEN_PAIR)
+    cpu, tpu, plane = both_runs(TGEN_PAIR)
     assert len(cpu.event_log) > 30
     assert any(r.outcome == 1 for r in cpu.event_log)  # some loss happened
     assert cpu.log_tuples() == tpu.log_tuples()
     assert cpu.counters["tgen_recv_bytes"] == tpu.counters["tgen_recv_bytes"]
+    # a quiet downlink: no lane ever in a CoDel drop episode, so no pop
+    # took the control law's table lookup
+    assert plane["codel_lookup_pops"] == 0
 
 
 TGEN_FAULTED = TGEN_PAIR + """
@@ -209,9 +218,14 @@ hosts:
 
 def test_codel_bottleneck_parity():
     # saturated downlink: token-bucket queueing + CoDel drops on both backends
-    cpu, tpu = both_logs(BOTTLENECK)
+    cpu, tpu, plane = both_runs(BOTTLENECK)
     assert any(r.outcome == 2 for r in cpu.event_log)  # codel drops happened
     assert cpu.log_tuples() == tpu.log_tuples()
+    # drops inside an episode take the table lookup, and the counter of the
+    # pops that did is not among the counters held to the oracle's
+    assert 0 < plane["codel_lookup_pops"] <= (
+        tpu.counters["lane_iters"] * plane["pops_per_iter"])
+    assert "codel_lookup_pops" not in tpu.counters
 
 
 def test_bootstrap_parity():
