@@ -354,6 +354,7 @@ def _hybrid_rate():
         sync = {
             k: (round(v, 3) if isinstance(v, float) else int(v))
             for k, v in getattr(eng, "sync_stats", {}).items()
+            if isinstance(v, (int, float))  # not phase_s, not the turn ring
         }
         phase_wall = {
             k: round(v, 3)

@@ -55,7 +55,13 @@ The host<->device sync-cost accounting (``sync_stats``: per-turn transfer
 counts/bytes, blocking device-sync seconds, syscall-service seconds) is
 always on — the counters are a handful of Python ints per window — and is
 surfaced per window through the perf-log plumbing when
-``experimental.perf_logging`` is set (docs/hybrid.md).
+``experimental.perf_logging`` is set (docs/hybrid.md).  So is the clock
+every host phase of a turn is a span of (``TURN_PHASES`` below,
+:mod:`shadow_tpu.obs.clock`): the seconds per phase
+(``sync_stats["phase_s"]``), one row per turn
+(``sync_stats["turn_spans"]``), the same pairs to the obs Recorder when
+there is one, and ``hybrid/<phase>`` annotations on the profiler's clock
+(docs/observability.md "Reading a turn").
 """
 
 from __future__ import annotations
@@ -73,12 +79,56 @@ from ..core import time as stime
 from ..core.event import Event, EventKind
 from ..core.event_queue import EventQueue
 from ..engine.supervisor import recv_with_deadline, worker_recv
+from ..obs.clock import TurnClock
 from . import lanes
 from .cpu_engine import DELIVERED, CpuEngine, Delivery, Host, SimResult
 
 NEVER = stime.NEVER
 
 log = logging.getLogger("shadow_tpu.hybrid")
+
+# The host phases of a turn (docs/observability.md "reading a turn"), each
+# a span of the engine's clock where the work happens; ``walk`` is the
+# turn's own residual: scalar decode, the validation walk's Python,
+# ledger calls, rollback bookkeeping.
+TURN_PHASES = (
+    "inject",           # _build_inj: packing, jnp.array copies, overflow
+    "peek",             # _fuse_depth, _peek_ext_times, _ext_pairs
+    "dispatch",         # fused_fn(...) until it RETURNS (and the eager one)
+    "device_wait",      # the blocking device_get: device_sync_s
+    "egress_read",      # _read_egress: the slice, D2H, .tolist()
+    "egress_apply",     # every _apply_egress call
+    "service_ship",     # _mp_round's send leg
+    "service_collect",  # its receive leg; the serial engine's whole round
+    "callback",         # on_window: the caller's hook, never the engine's
+    "walk",
+)
+# What a turn's row says beside its seconds: the last window end the
+# device reached, the slowest worker's execution wall summed over the
+# turn's rounds, device dispatches (2 when it rolled back: what
+# device_turns counts), and what caused it — staged rows injected, egress
+# rows read, windows the primary dispatch completed, whether it resumed a
+# mid-window egress drain, whether it rolled back.
+TURN_NOTES = (
+    "window_end_ns", "worker_exec_max_s", "dispatches", "n_staged",
+    "egress_rows", "k_done", "retry", "rolled",
+)
+# clock phase -> (obs phase, obs span name, what the span's detail is
+# called): obs's documented phases keep their names; ``device_turn`` is
+# the blocking wait alone and ``dispatch`` the call before it, so obs's
+# phases tile a turn as the clock's do (docs/observability.md)
+_OBS_PHASE = {
+    "inject": ("injection", None, "rows"),
+    "peek": ("peek", None, None),
+    "dispatch": ("dispatch", None, None),
+    "device_wait": ("device_turn", None, "window_end"),
+    "egress_read": ("egress", None, "rows"),
+    "egress_apply": ("egress_apply", None, "rows"),
+    "service_ship": ("worker_pipe", "pipe_ship", None),
+    "service_collect": ("syscall_service", None, "window_end"),
+    "callback": ("callback", None, None),
+    "walk": ("walk", None, None),
+}
 
 # fusion-effectiveness floor (obs_turns runs): warn when the achieved turn
 # collapse falls below this fraction of the ledger's remaining
@@ -296,7 +346,9 @@ def _hybrid_worker_main(
     still-covered fused span?) and the partition's refreshed
     ``peek_slots``-wide peek schedule, so the parent can bound the next
     dispatch's k before any further round trip (docs/hybrid.md "k-window
-    fusion law")."""
+    fusion law"), and last the wall of the round's own execution (owned
+    hosts + barrier merge): what of the parent's collect leg is this
+    worker computing, the rest being pickling, pipes and wake-ups."""
     engine = _HybridWorker(cfg, owned)
     if cfg.experimental.perf_logging:
         from ..engine.run_control import BufferedPerfLog
@@ -325,9 +377,11 @@ def _hybrid_worker_main(
                         h.host_id for h in engine.owned_hosts
                         if h.queue.next_time() < window_end
                     )
+                t_exec = wall_time.perf_counter()
                 for h in engine.owned_hosts:
                     h.execute(window_end)
                 engine._barrier_merge()
+                exec_s = wall_time.perf_counter() - t_exec
                 clean = (
                     not probe
                     or engine._range_count(window_end, we_final) == pre_range
@@ -343,6 +397,7 @@ def _hybrid_worker_main(
                     wparts,
                     clean,
                     engine._peek_head_horizon(peek_slots),
+                    exec_s,
                 ))
             elif msg[0] == "finish":
                 engine.finalize()
@@ -425,6 +480,8 @@ class HybridEngine(_HostSideHybrid):
         # window through PerfLog.hybrid_agg
         self.sync_stats: dict = {
             "device_turns": 0,      # turn_fn calls (windows batched per)
+            # the two walls accepted readers divide by; the clock below
+            # keeps them equal to their phases' sums
             "device_sync_s": 0.0,   # blocking scalar-readback wall time
             "syscall_service_s": 0.0,  # host-side window execution wall
             "scalar_reads": 0,      # D2H transfers: packed scalar vectors
@@ -448,7 +505,26 @@ class HybridEngine(_HostSideHybrid):
             "append_blocks": 0,       # block writes, log and egress
             "append_rows": 0,         # rows those blocks wrote
             "append_tail_blocks": 0,  # of them, for queue-overflow records
+            # the syscall workers' own round walls (each reply carries
+            # its worker's): per round the slowest worker's, and all of
+            # them summed; the serial engine books its round to both
+            "worker_exec_max_s": 0.0,
+            "worker_exec_sum_s": 0.0,
         }
+        # the host-phase clock (obs/clock.py, always on): every host
+        # phase of a turn is a span of it.  It keeps device_sync_s and
+        # syscall_service_s (above) as the sums of their phases, the
+        # per-phase totals in phase_s and one row per turn in turn_spans
+        self.clock = TurnClock(
+            self, "hybrid", TURN_PHASES, notes=TURN_NOTES,
+            turn_phase="walk", obs_map=_OBS_PHASE,
+            totals=(self.sync_stats, {
+                "device_sync_s": ("device_wait",),
+                "syscall_service_s": ("service_ship", "service_collect"),
+            }),
+        )
+        self.sync_stats["phase_s"] = self.clock.phase_s
+        self.sync_stats["turn_spans"] = self.clock.ring
         exp = cfg.experimental
         # dispatch retry-with-backoff law (docs/robustness.md): a failed
         # fused device dispatch re-dispatches from the pre-turn device
@@ -543,12 +619,15 @@ class HybridEngine(_HostSideHybrid):
     # -- egress application -------------------------------------------------
 
     def _apply_egress(self, rows) -> None:
-        for t, src, dst, seq, size, outcome in rows:
-            t, src, dst, seq, size = int(t), int(src), int(dst), int(seq), int(size)
-            payload = self._parked.pop((src, seq), None)
-            if int(outcome) != DELIVERED:
-                continue  # device-side drop: payload released, no event
-            self._route_delivery(t, src, dst, seq, size, payload)
+        with self.clock.span("egress_apply", len(rows)):
+            for t, src, dst, seq, size, outcome in rows:
+                t, src, dst, seq, size = (
+                    int(t), int(src), int(dst), int(seq), int(size)
+                )
+                payload = self._parked.pop((src, seq), None)
+                if int(outcome) != DELIVERED:
+                    continue  # device-side drop: payload released, no event
+                self._route_delivery(t, src, dst, seq, size, payload)
 
     def _route_delivery(self, t, src, dst, seq, size, payload) -> None:
         self._apply_delivery_row(t, src, dst, seq, size, payload)
@@ -615,6 +694,10 @@ class HybridEngine(_HostSideHybrid):
         return self._empty_inj
 
     def _read_egress(self, state, count: int, lost: int) -> list:
+        """The egress readback, the ``egress_read`` span: the D2H read
+        alone — the fused walk applies deliveries lazily per validated
+        window (``egress_apply``).  Empty egress is no read and no
+        span."""
         if lost:
             raise RuntimeError(
                 "hybrid egress buffer overflowed despite the headroom "
@@ -622,51 +705,39 @@ class HybridEngine(_HostSideHybrid):
             )
         if count == 0:
             return []
-        # pad the slice length to a power of two: distinct slice sizes
-        # compile distinct device programs, so this caps churn at log2(E)
-        cap = self.device.params.egress_capacity
-        span = 1
-        while span < count:
-            span <<= 1
-        span = min(span, cap)
-        st = self.sync_stats
-        st["egress_reads"] += 1
-        st["egress_rows"] += count
-        st["egress_bytes"] += span * 6 * 8
-        return np.asarray(state.egress[:span])[:count].tolist()
-
-    def _read_egress_obs(self, state, count: int, lost: int):
-        """Egress readback wrapped in the obs ``egress`` span: the span
-        covers the D2H read alone — the fused walk applies deliveries
-        lazily per validated window (docs/observability.md).  Empty
-        egress is a no-op read with no span (symmetric with the
-        injection record, no tracer-capacity burn)."""
-        obs = self.obs
-        if obs is None or count == 0:
-            return self._read_egress(state, count, lost)
-        with obs.phase("egress", rows=count):
-            rows = self._read_egress(state, count, lost)
-        obs.metrics.count("egress_rows", count)
+        with self.clock.span("egress_read", count):
+            # pad the slice length to a power of two: distinct slice
+            # sizes compile distinct device programs, so this caps churn
+            # at log2(E)
+            cap = self.device.params.egress_capacity
+            span = 1
+            while span < count:
+                span <<= 1
+            span = min(span, cap)
+            st = self.sync_stats
+            st["egress_reads"] += 1
+            st["egress_rows"] += count
+            st["egress_bytes"] += span * 6 * 8
+            rows = np.asarray(state.egress[:span])[:count].tolist()
+        self.clock.add("egress_rows", count)
+        if self.obs is not None:
+            self.obs.metrics.count("egress_rows", count)
         return rows
 
     def _build_inj(self, staged, inject_fn, state):
         """Pack staged sends into the injection block.  Oversized
         staging: overflow blocks dispatch eagerly — JAX's async dispatch
         overlaps their H2D + queue merge with the host-side packing of
-        the next block.  The injection span covers packing + dispatch;
+        the next block.  The ``inject`` span covers packing + dispatch;
         the transfer itself overlaps the device call."""
         b = self.device.params.inject_batch
-        obs = self.obs
-        t_inj = wall_time.perf_counter() if obs is not None else 0.0
         n_staged = len(staged)
-        while len(staged) > b:
-            state = inject_fn(state, self._inj_block(staged[:b], b))
-            staged = staged[b:]
-        inj = self._inj_block(staged, b) if staged else self._empty_block()
-        if obs is not None and n_staged:
-            obs.record(
-                "injection", None, t_inj,
-                wall_time.perf_counter() - t_inj, rows=n_staged,
+        with self.clock.span("inject", n_staged):
+            while len(staged) > b:
+                state = inject_fn(state, self._inj_block(staged[:b], b))
+                staged = staged[b:]
+            inj = (
+                self._inj_block(staged, b) if staged else self._empty_block()
             )
         return state, inj, n_staged
 
@@ -730,52 +801,56 @@ class HybridEngine(_HostSideHybrid):
         dispatch inputs match the speculated ones bit-exact — the
         provably-empty-injection condition that makes the (otherwise
         unsound, docs/hybrid.md) double-buffering a pure overlap."""
-        ext = self._peek_ext_times(floor_t)
-        host_next = ext[0]
-        start = min(host_next, lane_min)  # staged-empty speculation
-        if start >= self.stop_time or start == NEVER:
-            return
-        end = min(start + self.current_runahead(), self.stop_time)
-        if lane_min >= end:
-            return  # next window would be host-only: nothing to overlap
-        used_enc = (
-            lanes.NEVER32 if self._min_used_lat is None
-            else self._min_used_lat
-        )
-        k_eff = self._fuse_depth()
-        if self._eager_pred < 2:
-            # cold predictor: record the speculation's inputs WITHOUT
-            # device work — its would-have-hit outcome re-trains the
-            # predictor at the next dispatch
-            self._eager = {
-                "base": state, "ext": ext, "used": used_enc, "k": k_eff,
-                "state": None, "sc": None, "t0": 0.0,
-            }
-            return
-        ehi, elo = self._ext_pairs(ext)
-        t0 = wall_time.perf_counter()
-        state2, scalars = fused_fn(
-            state, ehi, elo, used_enc, self._empty_block(),
-            np.int32(k_eff),
-        )
+        clock = self.clock
+        with clock.span("peek"):
+            ext = self._peek_ext_times(floor_t)
+            host_next = ext[0]
+            start = min(host_next, lane_min)  # staged-empty speculation
+            if start >= self.stop_time or start == NEVER:
+                return
+            end = min(start + self.current_runahead(), self.stop_time)
+            if lane_min >= end:
+                return  # next window would be host-only: nothing to overlap
+            used_enc = (
+                lanes.NEVER32 if self._min_used_lat is None
+                else self._min_used_lat
+            )
+            k_eff = self._fuse_depth()
+            if self._eager_pred < 2:
+                # cold predictor: record the speculation's inputs WITHOUT
+                # device work — its would-have-hit outcome re-trains the
+                # predictor at the next dispatch
+                self._eager = {
+                    "base": state, "ext": ext, "used": used_enc, "k": k_eff,
+                    "state": None, "sc": None,
+                }
+                return
+            ehi, elo = self._ext_pairs(ext)
+        with clock.span("dispatch"):
+            state2, scalars = fused_fn(
+                state, ehi, elo, used_enc, self._empty_block(),
+                np.int32(k_eff),
+            )
         self._eager = {
             "base": state, "ext": ext, "used": used_enc, "k": k_eff,
-            "state": state2, "sc": scalars, "t0": t0,
+            "state": state2, "sc": scalars,
         }
 
-    def _dispatch_fused(self, state, fused_fn, ext, used_enc, inj,
+    def _dispatch_fused(self, state, fused_fn, ext, pairs, used_enc, inj,
                         n_staged: int, k_eff: int):
         """Dispatch (or adopt the eagerly dispatched) fused device call
         and block on its packed readback.  Adoption requires the real
         inputs to equal the speculated ones bit-exact: same base state
-        object, same peeked schedule, same dynamic-runahead fold, and an
-        empty injection — then the eager result IS the dispatch result
+        object, same peeked schedule (``ext``; ``pairs`` is its device
+        encoding), same dynamic-runahead fold, and an empty injection — then the eager result IS the dispatch result
         by functional purity, and the readback blocks only for whatever
-        device compute the overlapped syscall servicing did not hide."""
+        device compute the overlapped syscall servicing did not hide (its
+        ``dispatch`` was booked to the turn that issued it).  Returns
+        (state, scalars, the closed ``device_wait`` span)."""
         st = self.sync_stats
+        clock = self.clock
         e = self._eager
         state2 = scalars = None
-        t0 = 0.0
         if e is not None:
             self._eager = None
             match = (
@@ -789,26 +864,24 @@ class HybridEngine(_HostSideHybrid):
                 pass  # phantom speculation: predictor trained, no result
             elif match:
                 st["async_dispatch_hits"] += 1
-                t0 = e["t0"]
                 state2, scalars = e["state"], e["sc"]
             else:
                 st["async_dispatch_misses"] += 1
         if scalars is None:
-            ehi, elo = self._ext_pairs(ext)
-            t0 = wall_time.perf_counter()
-            state2, scalars = fused_fn(
-                state, ehi, elo, used_enc, inj, np.int32(k_eff)
-            )
-        t_b0 = wall_time.perf_counter()
-        sc = jax.device_get(scalars)  # the one blocking readback
-        t1 = wall_time.perf_counter()
-        st["device_sync_s"] += t1 - t_b0
+            with clock.span("dispatch"):
+                state2, scalars = fused_fn(
+                    state, *pairs, used_enc, inj, np.int32(k_eff)
+                )
+        with clock.span("device_wait") as wait:
+            sc = jax.device_get(scalars)  # the one blocking readback
+            wait.detail = int(sc[lanes.HYB_DEV_WE])
+        clock.add("dispatches", 1)
         st["device_turns"] += 1
         st["scalar_reads"] += 1
-        return state2, sc, t0, t1
+        return state2, sc, wait
 
-    def _dispatch_retrying(self, checkpoint, fused_fn, ext, used_enc, inj,
-                           n_staged: int, k_eff: int):
+    def _dispatch_retrying(self, checkpoint, fused_fn, ext, pairs, used_enc,
+                           inj, n_staged: int, k_eff: int):
         """The dispatch retry-with-backoff law (docs/robustness.md): a
         failed fused dispatch (device runtime error raised at dispatch or
         at the blocking readback) re-dispatches from the pre-turn device
@@ -824,8 +897,8 @@ class HybridEngine(_HostSideHybrid):
         while True:
             try:
                 return self._dispatch_fused(
-                    checkpoint, fused_fn, ext, used_enc, inj, n_staged,
-                    k_eff,
+                    checkpoint, fused_fn, ext, pairs, used_enc, inj,
+                    n_staged, k_eff,
                 )
             except BackendStallError:
                 raise
@@ -852,6 +925,27 @@ class HybridEngine(_HostSideHybrid):
 
     def _fused_turn(self, state, fused_fn, inject_fn, run_round,
                     on_window, t_start: int):
+        """FUSED device turns until the span at ``t_start`` is through:
+        one turn (``_turn``: one primary dispatch, with its rebuild if it
+        rolls back), and one more for each mid-window egress-headroom
+        pause — the device paused for room; covered rounds may have
+        staged, so the next turn repacks and resumes (the cached empty
+        block keeps a stage-free resume transfer-free).  Every turn is a
+        turn of the clock and leaves one row.  Returns (state,
+        dev_next)."""
+        is_retry = False
+        while True:
+            with self.clock.turn():
+                state, lane_min, t_start, paused = self._turn(
+                    state, fused_fn, inject_fn, run_round, on_window,
+                    t_start, is_retry,
+                )
+            if not paused:
+                return state, lane_min
+            is_retry = True
+
+    def _turn(self, state, fused_fn, inject_fn, run_round, on_window,
+              t_start: int, is_retry: bool):
         """One FUSED device turn: dispatch up to ``hybrid_fuse_k``
         consecutive participating windows in one device call, then
         service the covered syscall rounds window-by-window under the
@@ -876,204 +970,198 @@ class HybridEngine(_HostSideHybrid):
         Egress rows apply lazily per accepted window so a rollback never
         double-applies a delivery or double-pops a parked payload; the
         rebuild's egress buffer (all rows below the validated frontier,
-        already applied) is deliberately never read back.  Returns
-        (state, dev_next)."""
+        already applied) is deliberately never read back.  Runs inside
+        the clock's open turn: every phase below is a span of it, what is
+        left is its ``walk``.  Returns (state, dev_next, the last
+        accepted window end, whether the device paused mid-window)."""
         st = self.sync_stats
+        clock = self.clock
         obs = self.obs
         turns = obs.turns if obs is not None else None
         staged = self._staged_merged
         self._staged_merged = []
         state, inj, n_staged = self._build_inj(staged, inject_fn, state)
-        is_retry = False
         prev_we = t_start
-        while True:
+        with clock.span("peek"):
             k_eff = self._fuse_depth()
             ext = self._peek_ext_times()
-            used_enc = (
-                lanes.NEVER32 if self._min_used_lat is None
-                else self._min_used_lat
+            # encoded here, once: a rebuild dispatches the same pair (an
+            # adopted eager dispatch, ~1 turn in 100, does not use it)
+            pairs = self._ext_pairs(ext)
+        used_enc = (
+            lanes.NEVER32 if self._min_used_lat is None
+            else self._min_used_lat
+        )
+        checkpoint = state
+        state, sc, wait = self._dispatch_retrying(
+            state, fused_fn, ext, pairs, used_enc, inj, n_staged, k_eff
+        )
+        lane_min = int(sc[lanes.HYB_LANE_MIN])
+        dev_we = int(sc[lanes.HYB_DEV_WE])
+        dev_used = int(sc[lanes.HYB_MIN_USED])
+        self._dev_min_used = (
+            None if dev_used >= lanes.NEVER32 else dev_used
+        )
+        k_done = int(sc[lanes.HYB_K_DONE])
+        we_list = [
+            int(sc[lanes.HYB_WE_BASE + i]) for i in range(k_done)
+        ]
+        clock.note("window_end_ns", dev_we)
+        clock.note("n_staged", n_staged)
+        clock.note("k_done", k_done)
+        clock.note("retry", int(is_retry))
+        if obs is not None:
+            obs.metrics.count("device_turns")
+            if (
+                not is_retry
+                and self._flow_pending is not None
+                and turns is not None
+                and obs.tracer is not None
+            ):
+                fid, anchor = self._flow_pending
+                self._flow_pending = None
+                tr = obs.tracer
+                tr.flow("s", fid, "turn_cause", "turn_flow", anchor)
+                tr.flow(
+                    "f", fid, "turn_cause", "turn_flow",
+                    wait.t0 + wait.dur / 2,
+                )
+        egress_count = int(sc[lanes.HYB_EGRESS_COUNT])
+        rows = self._read_egress(
+            state, egress_count, int(sc[lanes.HYB_EGRESS_LOST])
+        )
+        retry = lane_min < dev_we  # mid-window egress-headroom pause
+        if self._async_on and not retry and we_list:
+            self._issue_eager(fused_fn, state, lane_min, we_list[-1])
+        # ---- the validated servicing walk ------------------------------
+        w_valid = 0
+        rounds_run = 0
+        frontier = NEVER
+        pend = rows
+        parts_buf = []
+        for j, we_j in enumerate(we_list):
+            if we_j > frontier:
+                break  # a staged arrival lands inside this window
+            apply_now = [r for r in pend if int(r[0]) < we_j]
+            if apply_now:
+                pend = [r for r in pend if int(r[0]) >= we_j]
+                self._apply_egress(apply_now)
+            if self.next_event_time() < we_j:
+                rounds_run += 1
+                pre_len = len(self._staged_merged)
+                pre_mul = self._min_used_lat
+                self.window_end = we_j
+                self._fuse_we_final = we_list[-1]
+                try:
+                    run_round(we_j)
+                finally:
+                    self._fuse_we_final = None
+                if turns is not None:
+                    parts_buf.append(self._last_participants)
+                new = self._staged_merged[pre_len:]
+                if new:
+                    a = min(int(e[0]) for e in new)
+                    if a < frontier:
+                        frontier = a
+                if not self._round_clean or (
+                    pre_mul != self._min_used_lat
+                ):
+                    # the round created an event inside the covered
+                    # span, or moved the dynamic-runahead fold: later
+                    # window boundaries are unreproducible
+                    frontier = min(frontier, we_j)
+            w_valid = j + 1
+            if on_window is not None:
+                with clock.span("callback"):
+                    on_window(prev_we, we_j, self.next_event_time())
+            prev_we = we_j
+        if w_valid < k_done:
+            # misprediction: rebuild the validated prefix from the
+            # checkpoint (same inputs + k_eff = prefix -> the prefix
+            # windows reproduce bit-identically); the original
+            # dispatch's unapplied egress rows are discarded (the rows
+            # its invalidated windows generated must not land) and the
+            # staged injection rides the next turn
+            clock.note("rolled", 1)
+            if self._eager is not None:
+                # the eager speculation rode the invalidated timeline —
+                # discard it without training the predictor: its miss
+                # signals "rollback", not "the next injection will not
+                # be empty"
+                if self._eager["sc"] is not None:
+                    st["async_dispatch_misses"] += 1
+                self._eager = None
+            st["fuse_rollbacks"] += 1
+            if w_valid >= 2:
+                st["fused_dispatches"] += 1
+                st["fused_windows"] += w_valid
+            st["turns_saved"] += w_valid - 2
+            # the rebuild dispatch goes through the same timed
+            # dispatch/readback bookkeeping as a primary dispatch and
+            # books to the same phases of this turn (the eager buffer
+            # was dropped above, so no adoption)
+            state, sc_r, _wait = self._dispatch_retrying(
+                checkpoint, fused_fn, ext, pairs, used_enc, inj, n_staged,
+                w_valid,
             )
-            checkpoint = state
-            state, sc, t0, t1 = self._dispatch_retrying(
-                state, fused_fn, ext, used_enc, inj, n_staged, k_eff
+            assert int(sc_r[lanes.HYB_K_DONE]) == w_valid, (
+                "fused prefix rebuild diverged from the original "
+                "dispatch (determinism violation)"
             )
-            lane_min = int(sc[lanes.HYB_LANE_MIN])
-            dev_we = int(sc[lanes.HYB_DEV_WE])
-            dev_used = int(sc[lanes.HYB_MIN_USED])
+            lane_min = int(sc_r[lanes.HYB_LANE_MIN])
+            dev_we = int(sc_r[lanes.HYB_DEV_WE])
+            dev_used = int(sc_r[lanes.HYB_MIN_USED])
             self._dev_min_used = (
                 None if dev_used >= lanes.NEVER32 else dev_used
             )
-            k_done = int(sc[lanes.HYB_K_DONE])
-            we_list = [
-                int(sc[lanes.HYB_WE_BASE + i]) for i in range(k_done)
-            ]
+            clock.note("window_end_ns", dev_we)
             if obs is not None:
-                obs.record(
-                    "device_turn", None, t0, t1 - t0, window_end=dev_we
-                )
                 obs.metrics.count("device_turns")
-                if (
-                    not is_retry
-                    and self._flow_pending is not None
-                    and turns is not None
-                    and obs.tracer is not None
-                ):
-                    fid, anchor = self._flow_pending
-                    self._flow_pending = None
-                    tr = obs.tracer
-                    tr.flow("s", fid, "turn_cause", "turn_flow", anchor)
-                    tr.flow(
-                        "f", fid, "turn_cause", "turn_flow",
-                        t0 + (t1 - t0) / 2,
-                    )
-            egress_count = int(sc[lanes.HYB_EGRESS_COUNT])
-            rows = self._read_egress_obs(
-                state, egress_count, int(sc[lanes.HYB_EGRESS_LOST])
+            # the rebuild regenerated the validated prefix
+            # bit-identically, so its egress buffer holds exactly the
+            # prefix-generated rows; those at or past the last validated
+            # window end never passed the walk's apply filter
+            # (down-bucket/CoDel queueing delays t_deliver into the
+            # invalidated span) — apply them now, like the validated
+            # path's trailing pend rows.  Invalidated-window rows exist
+            # only in the original buffer and stay dropped: the rebuilt
+            # device state still carries their packets in flight
+            egr_r = int(sc_r[lanes.HYB_EGRESS_COUNT])
+            rows_r = self._read_egress(
+                state, egr_r, int(sc_r[lanes.HYB_EGRESS_LOST])
             )
-            retry = lane_min < dev_we  # mid-window egress-headroom pause
-            if self._async_on and not retry and we_list:
-                self._issue_eager(fused_fn, state, lane_min, we_list[-1])
-            # ---- the validated servicing walk --------------------------
-            w_valid = 0
-            rounds_run = 0
-            frontier = NEVER
-            pend = rows
-            parts_buf = []
-            for j, we_j in enumerate(we_list):
-                if we_j > frontier:
-                    break  # a staged arrival lands inside this window
-                apply_now = [r for r in pend if int(r[0]) < we_j]
-                if apply_now:
-                    pend = [r for r in pend if int(r[0]) >= we_j]
-                    self._apply_egress(apply_now)
-                if self.next_event_time() < we_j:
-                    rounds_run += 1
-                    pre_len = len(self._staged_merged)
-                    pre_mul = self._min_used_lat
-                    self.window_end = we_j
-                    self._fuse_we_final = we_list[-1]
-                    try:
-                        run_round(we_j)
-                    finally:
-                        self._fuse_we_final = None
-                    if turns is not None:
-                        parts_buf.append(self._last_participants)
-                    new = self._staged_merged[pre_len:]
-                    if new:
-                        a = min(int(e[0]) for e in new)
-                        if a < frontier:
-                            frontier = a
-                    if not self._round_clean or (
-                        pre_mul != self._min_used_lat
-                    ):
-                        # the round created an event inside the covered
-                        # span, or moved the dynamic-runahead fold:
-                        # later window boundaries are unreproducible
-                        frontier = min(frontier, we_j)
-                w_valid = j + 1
-                if on_window is not None:
-                    on_window(prev_we, we_j, self.next_event_time())
-                prev_we = we_j
-            rolled = w_valid < k_done
-            if rolled:
-                # misprediction: rebuild the validated prefix from the
-                # checkpoint (same inputs + k_eff = prefix -> the prefix
-                # windows reproduce bit-identically); the original
-                # dispatch's unapplied egress rows are discarded (the
-                # rows its invalidated windows generated must not land)
-                # and the staged injection rides the next turn
-                if self._eager is not None:
-                    # the eager speculation rode the invalidated
-                    # timeline — discard it without training the
-                    # predictor: its miss signals "rollback", not "the
-                    # next injection will not be empty"
-                    if self._eager["sc"] is not None:
-                        st["async_dispatch_misses"] += 1
-                    self._eager = None
-                st["fuse_rollbacks"] += 1
-                if w_valid >= 2:
-                    st["fused_dispatches"] += 1
-                    st["fused_windows"] += w_valid
-                st["turns_saved"] += w_valid - 2
-                # the rebuild dispatch goes through the same timed
-                # dispatch/readback bookkeeping as a primary dispatch
-                # (the eager buffer was dropped above, so no adoption)
-                state, sc_r, t0r, t1r = self._dispatch_retrying(
-                    checkpoint, fused_fn, ext, used_enc, inj, n_staged,
-                    w_valid,
-                )
-                assert int(sc_r[lanes.HYB_K_DONE]) == w_valid, (
-                    "fused prefix rebuild diverged from the original "
-                    "dispatch (determinism violation)"
-                )
-                lane_min = int(sc_r[lanes.HYB_LANE_MIN])
-                dev_we = int(sc_r[lanes.HYB_DEV_WE])
-                dev_used = int(sc_r[lanes.HYB_MIN_USED])
-                self._dev_min_used = (
-                    None if dev_used >= lanes.NEVER32 else dev_used
-                )
-                if obs is not None:
-                    obs.record(
-                        "device_turn", None, t0r, t1r - t0r,
-                        window_end=dev_we,
-                    )
-                    obs.metrics.count("device_turns")
-                # the rebuild regenerated the validated prefix
-                # bit-identically, so its egress buffer holds exactly
-                # the prefix-generated rows; those at or past the last
-                # validated window end never passed the walk's apply
-                # filter (down-bucket/CoDel queueing delays t_deliver
-                # into the invalidated span) — apply them now, like the
-                # validated path's trailing pend rows.  Invalidated-
-                # window rows exist only in the original buffer and
-                # stay dropped: the rebuilt device state still carries
-                # their packets in flight
-                egr_r = int(sc_r[lanes.HYB_EGRESS_COUNT])
-                rows_r = self._read_egress_obs(
-                    state, egr_r, int(sc_r[lanes.HYB_EGRESS_LOST])
-                )
-                late = [
-                    r for r in rows_r
-                    if int(r[0]) >= we_list[w_valid - 1]
-                ]
-                if late:
-                    self._apply_egress(late)
-                if turns is not None:
-                    self._ledger_fused_rows(
-                        turns, t_start, dev_we, w_valid, n_staged,
-                        egress_count, is_retry, parts_buf, rollback=True,
-                        rollback_egr=egr_r, rounds_run=rounds_run,
-                    )
-                return state, lane_min
-            # ---- span fully validated ----------------------------------
-            if k_done >= 2:
-                st["fused_dispatches"] += 1
-                st["fused_windows"] += k_done
-                st["turns_saved"] += k_done - 1
+            late = [
+                r for r in rows_r
+                if int(r[0]) >= we_list[w_valid - 1]
+            ]
+            if late:
+                self._apply_egress(late)
             if turns is not None:
                 self._ledger_fused_rows(
                     turns, t_start, dev_we, w_valid, n_staged,
-                    egress_count, is_retry, parts_buf, rollback=False,
-                    rounds_run=rounds_run,
+                    egress_count, is_retry, parts_buf, rollback=True,
+                    rollback_egr=egr_r, rounds_run=rounds_run,
                 )
-            if pend:
-                # trailing rows: deliveries of the in-progress (retry) or
-                # post-span windows — host events the next dispatch's
-                # peek schedule folds
-                self._apply_egress(pend)
-            if self.perf_log is not None:
-                self.perf_log.hybrid_agg("device", dev_we, self.sync_stats)
-            if not retry:
-                return state, lane_min
-            # drain continuation: the device paused mid-window for
-            # egress headroom; covered rounds may have staged — repack
-            # and resume (the cached empty block keeps a stage-free
-            # resume transfer-free)
-            staged = self._staged_merged
-            self._staged_merged = []
-            state, inj, n_staged = self._build_inj(staged, inject_fn, state)
-            is_retry = True
-            t_start = prev_we
+            return state, lane_min, prev_we, False
+        # ---- span fully validated ----------------------------------------
+        if k_done >= 2:
+            st["fused_dispatches"] += 1
+            st["fused_windows"] += k_done
+            st["turns_saved"] += k_done - 1
+        if turns is not None:
+            self._ledger_fused_rows(
+                turns, t_start, dev_we, w_valid, n_staged,
+                egress_count, is_retry, parts_buf, rollback=False,
+                rounds_run=rounds_run,
+            )
+        if pend:
+            # trailing rows: deliveries of the in-progress (paused) or
+            # post-span windows — host events the next dispatch's peek
+            # schedule folds
+            self._apply_egress(pend)
+        if self.perf_log is not None:
+            self.perf_log.hybrid_agg("device", dev_we, self.sync_stats)
+        return state, lane_min, prev_we, retry
 
     def _ledger_fused_rows(self, turns, t_start, t_end, w_valid,
                            inj_rows, egr_rows, is_retry, parts_buf,
@@ -1126,44 +1214,50 @@ class HybridEngine(_HostSideHybrid):
     # -- the hybrid round loop ----------------------------------------------
 
     def _service_round(self, scheduler, until: int) -> None:
-        """One host-side syscall-service round + barrier, timed into
-        sync_stats (and per-window through the perf log / obs spans).
-        Inside a fused span (``_fuse_we_final`` set past the window) the
-        round also runs the cleanliness probe: a changed event count in
-        ``[until, we_final)`` means the round created an event inside the
+        """One host-side syscall-service round + barrier: the
+        ``service_collect`` span (which keeps ``syscall_service_s``; per
+        window through the perf log / obs spans).  Inside a fused span
+        (``_fuse_we_final`` set past the window) the round also runs the
+        cleanliness probe: a changed event count in ``[until,
+        we_final)`` means the round created an event inside the
         still-covered span — the fused-turn walk rolls back there."""
-        t0 = wall_time.perf_counter()
         obs = self.obs
-        wf = self._fuse_we_final
-        probe = wf is not None and wf > until
-        pre_range = self._range_count(until, wf) if probe else 0
-        if obs is not None and obs.turns is not None:
-            # the turn ledger's participant set, taken BEFORE execution
-            # mutates the queues: managed hosts with events inside the
-            # window — the identical law the mp workers apply, so the
-            # ledger is bit-identical at any worker count
-            self._last_participants = tuple(
-                h.host_id for h in self._next_hosts
-                if h.queue.next_time() < until
-            )
-        scheduler.run_round(until)
-        self._barrier_merge()
-        self._round_clean = (
-            not probe or self._range_count(until, wf) == pre_range
-        )
-        t1 = wall_time.perf_counter()
-        self.sync_stats["syscall_service_s"] += t1 - t0
-        if obs is not None:
-            obs.record(
-                "syscall_service", None, t0, t1 - t0, window_end=until
-            )
-            if obs.turns is not None and obs.tracer is not None:
-                self._flow_seq += 1
-                self._flow_pending = (
-                    self._flow_seq, t0 + (t1 - t0) / 2,
+        with self.clock.span("service_collect", until) as rnd:
+            wf = self._fuse_we_final
+            probe = wf is not None and wf > until
+            pre_range = self._range_count(until, wf) if probe else 0
+            if obs is not None and obs.turns is not None:
+                # the turn ledger's participant set, taken BEFORE
+                # execution mutates the queues: managed hosts with events
+                # inside the window — the identical law the mp workers
+                # apply, so the ledger is bit-identical at any worker
+                # count
+                self._last_participants = tuple(
+                    h.host_id for h in self._next_hosts
+                    if h.queue.next_time() < until
                 )
+            scheduler.run_round(until)
+            self._barrier_merge()
+            self._round_clean = (
+                not probe or self._range_count(until, wf) == pre_range
+            )
+        # this process IS the one worker: its round's wall is both sums
+        self._book_worker_exec(rnd.dur, rnd.dur)
+        if obs is not None and obs.turns is not None \
+                and obs.tracer is not None:
+            self._flow_seq += 1
+            self._flow_pending = (self._flow_seq, rnd.t0 + rnd.dur / 2)
         if self.perf_log is not None:
             self.perf_log.hybrid_agg("host", until, self.sync_stats)
+
+    def _book_worker_exec(self, slowest_s: float, sum_s: float) -> None:
+        """A round's worker execution walls: the slowest worker's (what
+        of the collect leg is syscalls being serviced) and all of them
+        summed (balance), cumulative and into the open turn's row."""
+        st = self.sync_stats
+        st["worker_exec_max_s"] += slowest_s
+        st["worker_exec_sum_s"] += sum_s
+        self.clock.add("worker_exec_max_s", slowest_s)
 
     def run(self, on_window=None) -> SimResult:
         from ..engine.scheduler import HostScheduler
@@ -1248,12 +1342,13 @@ class HybridEngine(_HostSideHybrid):
             # and cannot match — discard before the round runs
             self._drop_eager()
             self.window_end = end
-            run_round(end)
+            run_round(end)  # its spans book to the totals: no turn is open
             if turns is not None:
                 turns.host_round()
             self.host_rounds += 1
             if on_window is not None:
-                on_window(start, end, self.next_event_time())
+                with self.clock.span("callback"):
+                    on_window(start, end, self.next_event_time())
 
     def _check_fusion_accounting(self) -> None:
         """End-of-run ledger cross-check (ISSUE 13 satellite): the
@@ -1414,67 +1509,66 @@ class MpHybridEngine(HybridEngine):
         cleanliness probe over their owned partition and ship their
         refreshed peek schedules, so the parent's next-event folds arrive
         early enough to bound the next dispatch's k."""
-        t0 = wall_time.perf_counter()
         obs = self.obs
+        clock = self.clock
         conns, procs = self._mp
         self._round_no += 1
-        wf = self._fuse_we_final
-        for w, conn in enumerate(conns):
-            conn.send((
-                "round", window_end, self._pending_rows[w],
-                wf if wf is not None else window_end,
-            ))
-            self._pending_rows[w] = []
-        t_ship = wall_time.perf_counter()
-        staged = self._staged_merged
-        perf_lines: list[str] = []
-        parts_all: list[int] = []
-        clean = True
-        for w, conn in enumerate(conns):
-            next_t, out, mul, wlines, wparts, wclean, wpeek = (
-                recv_with_deadline(
-                    conn, procs[w], self._heartbeat_s, w, self._round_no,
-                    "round",
+        with clock.span("service_ship"):
+            wf = self._fuse_we_final
+            for w, conn in enumerate(conns):
+                conn.send((
+                    "round", window_end, self._pending_rows[w],
+                    wf if wf is not None else window_end,
+                ))
+                self._pending_rows[w] = []
+        # disjoint attribution (same law as cpu_mp): the ship leg is obs's
+        # worker_pipe, the collect leg its syscall_service — the barrier
+        # wait that holds the workers' syscall execution (the slowest
+        # worker's own wall says how much of it).  The two tile the round,
+        # so phase sums never double-count; sync_stats' syscall_service_s
+        # is their sum (the [hybrid-agg] counter)
+        with clock.span("service_collect", window_end) as collect:
+            staged = self._staged_merged
+            perf_lines: list[str] = []
+            parts_all: list[int] = []
+            clean = True
+            exec_max = exec_sum = 0.0
+            for w, conn in enumerate(conns):
+                next_t, out, mul, wlines, wparts, wclean, wpeek, wexec = (
+                    recv_with_deadline(
+                        conn, procs[w], self._heartbeat_s, w,
+                        self._round_no, "round",
+                    )
                 )
-            )
-            self._eff_next[w] = next_t
-            if mul is not None and (
-                self._min_used_lat is None or mul < self._min_used_lat
-            ):
-                self._min_used_lat = mul
-            staged.extend(out)
-            if wlines:
-                perf_lines.extend(wlines)
-            if wparts:
-                parts_all.extend(wparts)
-            clean = clean and wclean
-            self._worker_peeks[w] = wpeek
-        self._round_clean = clean
-        t1 = wall_time.perf_counter()
-        self.sync_stats["syscall_service_s"] += t1 - t0
-        if obs is not None and obs.turns is not None:
-            # the partition interleaves host ids round-robin across
-            # workers; sorting normalizes the union to the serial
-            # engine's host-id order (ledger worker-count invariance)
-            self._last_participants = tuple(sorted(parts_all))
-            if obs.tracer is not None:
-                self._flow_seq += 1
-                self._flow_pending = (
-                    self._flow_seq, t_ship + (t1 - t_ship) / 2,
-                )
+                self._eff_next[w] = next_t
+                if mul is not None and (
+                    self._min_used_lat is None or mul < self._min_used_lat
+                ):
+                    self._min_used_lat = mul
+                staged.extend(out)
+                if wlines:
+                    perf_lines.extend(wlines)
+                if wparts:
+                    parts_all.extend(wparts)
+                clean = clean and wclean
+                self._worker_peeks[w] = wpeek
+                exec_sum += wexec
+                if wexec > exec_max:
+                    exec_max = wexec
+            self._round_clean = clean
+        self._book_worker_exec(exec_max, exec_sum)
         if obs is not None:
-            # disjoint attribution (same law as cpu_mp): worker_pipe is
-            # the ship leg, syscall_service the collect leg — the barrier
-            # wait that IS the workers' syscall execution wall.  The two
-            # tile the round exactly, so phase sums never double-count
-            # (sync_stats' syscall_service_s keeps covering the whole
-            # round, ship included — the legacy [hybrid-agg] counter)
-            obs.record("worker_pipe", "pipe_ship", t0, t_ship - t0)
-            obs.record(
-                "syscall_service", None, t_ship, t1 - t_ship,
-                window_end=window_end,
-            )
             obs.metrics.count("pipe_messages", 2 * len(conns))
+            if obs.turns is not None:
+                # the partition interleaves host ids round-robin across
+                # workers; sorting normalizes the union to the serial
+                # engine's host-id order (ledger worker-count invariance)
+                self._last_participants = tuple(sorted(parts_all))
+                if obs.tracer is not None:
+                    self._flow_seq += 1
+                    self._flow_pending = (
+                        self._flow_seq, collect.t0 + collect.dur / 2,
+                    )
         # worker-process perf lines route through the parent's locked
         # sink, in (round, worker-id) order — one coherent stream
         if perf_lines and self.perf_log is not None:

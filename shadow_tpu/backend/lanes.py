@@ -1952,9 +1952,10 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     # for a given compiled program, and strict mode (the default) raises
     # on any shed; non-strict overflow was already documented as
     # non-parity (see tpu_engine.py's strict_capacity note).
-    sorted_ops = lax.sort(
-        tuple(flat_ops), dimension=0, num_keys=1, is_stable=False
-    )
+    with jax.named_scope("exchange_sort"):
+        sorted_ops = lax.sort(
+            tuple(flat_ops), dimension=0, num_keys=1, is_stable=False
+        )
     _dst_s, thi_s, tlo_s, auxh_s, auxl_s, size_s = sorted_ops[:6]
     pay_s = sorted_ops[6:8] if sp and not split_se else None
     # segment bounds per destination lane: start[d], cnt[d] of lane d's
@@ -2238,6 +2239,7 @@ def _merge_stream_rows(p: LaneParams, tb: LaneTables, s: LaneState,
 _APPEND_BLOCK = 256
 
 
+@jax.named_scope("record_append")
 def _append_rows(buf, count, valid, rows_at):
     """Append the valid candidates of a flat batch to ``buf`` at ``count``,
     in flat order: the j-th valid candidate lands on row ``count + j``, rows
@@ -2485,6 +2487,7 @@ def _append_egress(p: LaneParams, s: LaneState, valid, delivered,
     )
 
 
+@jax.named_scope("window_min")
 def _queue_min(p: LaneParams, s: LaneState):
     """Scalar pair: the earliest event over ALL queues ([N] lanes, plus
     the [2S] tier block when the tiered stream backend is live)."""
@@ -3085,18 +3088,19 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
     cphi = jnp.concatenate(cand_phi, axis=1)
     cplo = jnp.concatenate(cand_plo, axis=1)
 
-    mthi, mtlo, mh, ml, ms, mphi, mplo = lax.sort(
-        (
-            jnp.concatenate([q[lstr.TQ_THI], cthi], axis=1),
-            jnp.concatenate([q[lstr.TQ_TLO], ctlo], axis=1),
-            jnp.concatenate([q[lstr.TQ_AUXH], cauxh], axis=1),
-            jnp.concatenate([q[lstr.TQ_AUXL], cauxl], axis=1),
-            jnp.concatenate([q[lstr.TQ_SIZE], csize], axis=1),
-            jnp.concatenate([q[lstr.TQ_PHI], cphi], axis=1),
-            jnp.concatenate([q[lstr.TQ_PLO], cplo], axis=1),
-        ),
-        dimension=1, num_keys=4, is_stable=False,
-    )
+    with jax.named_scope("tier_row_sort"):
+        mthi, mtlo, mh, ml, ms, mphi, mplo = lax.sort(
+            (
+                jnp.concatenate([q[lstr.TQ_THI], cthi], axis=1),
+                jnp.concatenate([q[lstr.TQ_TLO], ctlo], axis=1),
+                jnp.concatenate([q[lstr.TQ_AUXH], cauxh], axis=1),
+                jnp.concatenate([q[lstr.TQ_AUXL], cauxl], axis=1),
+                jnp.concatenate([q[lstr.TQ_SIZE], csize], axis=1),
+                jnp.concatenate([q[lstr.TQ_PHI], cphi], axis=1),
+                jnp.concatenate([q[lstr.TQ_PLO], cplo], axis=1),
+            ),
+            dimension=1, num_keys=4, is_stable=False,
+        )
     tail_mask = mthi[:, c2:] != NEVER32
     v = v.at[lstr.TV_N_QUEUE].add(tail_mask.sum(axis=1, dtype=i32))
     q = jnp.stack([
@@ -3383,7 +3387,10 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
         # made tiny parity runs hundreds of times slower.
         # spmd_unroll: emits stack [K, N] on the lane axis — the one walk
         # the sharded build must take in loop form
-        s, emits = scan_or_unroll(scan_body, s, slots, k, spmd_unroll=True)
+        with jax.named_scope("slot_walk"):
+            s, emits = scan_or_unroll(
+                scan_body, s, slots, k, spmd_unroll=True
+            )
 
         if tiered:
             # unconditional merge (the tier needs the diverted cross rows

@@ -10,7 +10,6 @@ the CPU engine produces, so the two backends are drop-in comparable.
 
 from __future__ import annotations
 
-import contextlib
 import time as wall_time
 from typing import Optional
 
@@ -28,11 +27,18 @@ from ..models.tgen import Ping, TgenClient, TgenMesh, TgenServer
 from ..net import codel as codel_mod
 from ..net.token_bucket import bucket_params
 from ..obs import flowtrace as ftr
+from ..obs.clock import TurnClock
 from . import lanes
 from . import lanes_stream as lstr_mod
 from .cpu_engine import LogRecord, SimResult
 
 NEVER = stime.NEVER
+
+# A run's host phases (spans of the engine's clock; ``fused/<phase>`` in a
+# profiler trace): the start state, the device call until it returns, the
+# wait for its result, the collect.  The step driver books every round's
+# call and wait to the same two.
+FUSED_PHASES = ("state_build", "dispatch", "device_wait", "collect")
 
 
 class LaneCompatError(ValueError):
@@ -643,9 +649,21 @@ class TpuEngine:
         self._placed_devices = None
         # [window-agg] telemetry sink (step mode only; set by the facade)
         self.perf_log = None
-        # obs Recorder (shadow_tpu/obs/): device_turn spans per round in
-        # step mode, one fused span in device mode; None = zero overhead
+        # obs Recorder (shadow_tpu/obs/), handed over by the facade; the
+        # clock below forwards its spans there when there is one
         self.obs = None
+        # the host-phase clock (obs/clock.py, always on): a run's host
+        # phases, on the profiler's clock as fused/<phase>.  obs gets
+        # them under its own names: device_turn is the blocking wait
+        self.clock = TurnClock(
+            self, "fused", FUSED_PHASES,
+            obs_map={
+                "state_build": ("state_build", None, None),
+                "dispatch": ("dispatch", None, None),
+                "device_wait": ("device_turn", None, "active"),
+                "collect": ("collect", None, None),
+            },
+        )
 
     def _resolve(self, hostname: str, n: int) -> int:
         return self.dns.resolve(hostname)
@@ -1065,14 +1083,13 @@ class TpuEngine:
             if self._compiled is not None:
                 run_fn = self._compiled
             t0 = wall_time.perf_counter()
-            if self.obs is None:
-                state = jax.block_until_ready(run_fn(*args))
-            else:
-                # the fused loop is one opaque device call: attribute it
-                # as a single device_turn span (per-window spans need the
-                # step driver — run-control/perf-logging select it)
-                with self.obs.phase("device_turn", name="device_free_run"):
-                    state = jax.block_until_ready(run_fn(*args))
+            # the fused loop is one opaque device call: the call until it
+            # returns, then the wait for its result (per-window spans
+            # need the step driver — run-control/perf-logging select it)
+            with self.clock.span("dispatch"):
+                state = run_fn(*args)
+            with self.clock.span("device_wait", name="device_free_run"):
+                state = jax.block_until_ready(state)
             wall = wall_time.perf_counter() - t0
         else:
             if self._mesh is not None:
@@ -1126,13 +1143,11 @@ class TpuEngine:
             return self._kept
 
     def _phase(self, phase: str):
-        """The obs span of a host-side phase of ``run`` (``state_build``:
-        ``_start_state``; ``collect``), which with ``device_turn`` split a
-        run's wall into build / device / collect; nothing when obs is
-        off."""
-        if self.obs is None:
-            return contextlib.nullcontext()
-        return self.obs.phase(phase)
+        """The clock's span of a host-side phase of ``run``
+        (``state_build``: ``_start_state``; ``collect``), which with
+        ``dispatch`` and ``device_wait`` split a run's wall into build /
+        device / collect, in every run."""
+        return self.clock.span(phase)
 
     def _check_resume_log(self, state) -> None:
         """A checkpointed lane state carries its device log; it resumes
@@ -1183,8 +1198,10 @@ class TpuEngine:
             else None
         )
         obs = self.obs
+        clock = self.clock
         turns = obs.turns if obs is not None else None
         turn_cause = first_cause
+        active = None
         while True:
             self._live_state = state
             if on_window is not None or self.perf_log is not None or obs is not None:
@@ -1202,9 +1219,10 @@ class TpuEngine:
                         tq[lstr_mod.TQ_TLO, :, 0],
                     ))
                     active += int((tier_next < we_pred).sum())
-            t_round = wall_time.perf_counter()
-            state, done = round_fn(state)
-            done = bool(done)  # forces the device sync the timing needs
+            with clock.span("dispatch") as call:
+                state, done = round_fn(state)
+            with clock.span("device_wait", active, name="device_round") as wait:
+                done = bool(done)  # forces the device sync the timing needs
             # refresh the live-state handle POST-round: netobs_lines and
             # checkpoint capture both read it at on_window time, when the
             # obs accumulators already reflect this round — a stale
@@ -1212,14 +1230,9 @@ class TpuEngine:
             # state from its obs state (one window double-counted on
             # resume)
             self._live_state = state
-            t_done = wall_time.perf_counter()
             if wd is not None:
-                wd.observe(t_done - t_round)
+                wd.observe(wait.t0 + wait.dur - call.t0)
             if obs is not None:
-                obs.record(
-                    "device_turn", "device_round", t_round, t_done - t_round,
-                    active=active,
-                )
                 m = obs.metrics
                 m.count("device_turns")
                 m.observe("window_active_hosts", active)

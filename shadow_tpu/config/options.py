@@ -97,13 +97,13 @@ class ExperimentalOptions:
     perf_logging: bool = False
     # observability (shadow_tpu/obs/, docs/observability.md): per-phase
     # wall metrics -> METRICS_*.json, span tracing -> Chrome-trace JSON,
-    # optional JSONL event stream and jax.profiler annotation
-    # pass-through.  All default off = zero overhead; event ordering is
-    # bit-identical with everything on (docs/determinism.md)
+    # optional JSONL event stream.  All default off; event ordering is
+    # bit-identical with everything on (docs/determinism.md).  (The
+    # drivers' host phases are on the profiler's clock in every run:
+    # obs/clock.py)
     obs_metrics: bool = False
     obs_trace: bool = False
     obs_jsonl: bool = False
-    obs_jax_annotations: bool = False
     obs_dir: Optional[str] = None  # None = general.data_directory
     # device-turn ledger (obs/turns.py): causal per-turn accounting
     # (cause classification + conservation law) and fusable-run-length

@@ -274,7 +274,6 @@ class Simulation:
             out_dir=out_dir,
             trace=exp.obs_trace,
             jsonl=exp.obs_jsonl,
-            jax_annotations=exp.obs_jax_annotations,
             turns=exp.obs_turns,
         )
 
@@ -346,7 +345,12 @@ class Simulation:
             }
             sync = getattr(self.engine, "sync_stats", None)
             if sync is not None:
-                extra["hybrid_sync"] = dict(sync)
+                # the totals (phase_s among them); the per-turn ring
+                # stays in memory (with --obs-trace its spans are in
+                # trace_*.json already)
+                extra["hybrid_sync"] = {
+                    k: v for k, v in sync.items() if k != "turn_spans"
+                }
             self._write_netobs(extra)
             self._write_flows(extra)
             fin = self.obs.finalize(extra=extra)

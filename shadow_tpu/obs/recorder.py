@@ -11,31 +11,40 @@ event, from the *same* ``perf_counter`` pair, so the trace's summed span
 wall per phase and the METRICS report's ``phase_wall_s`` agree by
 construction (the acceptance cross-check in tests/test_obs.py).
 
-The engine-facing phase vocabulary (docs/observability.md):
+The engine-facing phase vocabulary (docs/observability.md).  The two
+device drivers do not call the Recorder themselves: every host phase of
+theirs is a span of the host-phase clock (:mod:`.clock`), which hands the
+pair it took to ``record`` under these names —
 
 - ``window_compute``  — host-side window execution + barrier (cpu; the
   parent's collect wall on cpu_mp, which IS the workers' execution);
-- ``device_turn``     — one blocking device call + packed-scalar
-  readback (tpu step driver, hybrid; the whole fused call in device
-  mode);
+- ``dispatch``        — a device call until it RETURNS (tpu device and
+  step mode, hybrid: the jit dispatch of the whole lane-state pytree);
+- ``device_turn``     — the blocking wait for that call's result: the
+  packed-scalar readback (hybrid, ``sync_stats.device_sync_s``), the
+  round's ``done`` flag (step driver), ``block_until_ready`` of the whole
+  fused run (device mode);
+- ``state_build`` / ``collect`` — the fused driver's two host phases;
 - ``injection``       — staged-send block packing + H2D dispatch
   (hybrid; the transfer itself overlaps the next device call under JAX
   async dispatch);
-- ``egress``          — egress-slice D2H read + delivery application
-  (hybrid);
+- ``egress``          — egress-slice D2H read (hybrid; deliveries are
+  applied per validated window, in ``egress_apply``);
+- ``peek``            — the fused dispatch's external-event schedule and
+  its encoding (hybrid);
 - ``syscall_service`` — managed hosts' syscall-plane round, barrier
   included (hybrid; on the multiprocess engine this is the collect leg
-  of the round — the barrier wait that IS the workers' execution wall);
+  of the round — the barrier wait that holds the workers' execution);
 - ``worker_pipe``     — the pipe ship (broadcast) leg of a multiprocess
   round (cpu_mp, hybrid mp); disjoint from the collect-leg phase, so
   phase walls tile the round without double-counting;
+- ``callback``        — the caller's ``on_window`` hook inside a hybrid
+  turn (a sampler, the run-control console): never the engine's time;
+- ``walk``            — a hybrid turn's own residual (scalar decode, the
+  validation walk's Python, ledger calls, rollback bookkeeping): a
+  LENGTH drawn from the turn's start, not an interval;
 - ``fault_swap``      — fault-table epoch application at a window
   boundary (cpu backend).
-
-``jax_annotations=True`` additionally wraps every span in
-``jax.profiler.TraceAnnotation`` so the same phase names appear inside
-device profiles captured with ``jax.profiler.trace`` — a pass-through,
-not a second measurement.
 """
 
 from __future__ import annotations
@@ -49,17 +58,24 @@ from .tracer import Tracer
 
 PHASES = (
     "window_compute",
+    "dispatch",
     "device_turn",
+    "state_build",
+    "collect",
     "injection",
     "egress",
+    "egress_apply",
+    "peek",
     "syscall_service",
     "worker_pipe",
+    "callback",
+    "walk",
     "fault_swap",
 )
 
 
 class _PhaseSpan:
-    __slots__ = ("_rec", "phase", "name", "args", "_t0", "_ann")
+    __slots__ = ("_rec", "phase", "name", "args", "_t0")
 
     def __init__(
         self, rec: "Recorder", phase: str, name: Optional[str], args: dict
@@ -68,21 +84,14 @@ class _PhaseSpan:
         self.phase = phase
         self.name = name or phase
         self.args = args
-        self._ann = None
 
     def __enter__(self) -> "_PhaseSpan":
-        rec = self._rec
-        if rec._annotate is not None:
-            self._ann = rec._annotate(self.name)
-            self._ann.__enter__()
         self._t0 = wall_time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         t0 = self._t0
         dur = wall_time.perf_counter() - t0
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
         self._rec._record(self.phase, self.name, t0, dur, self.args)
 
 
@@ -99,7 +108,6 @@ class Recorder:
         out_dir: Optional[str | Path] = None,
         trace: bool = False,
         jsonl: bool = False,
-        jax_annotations: bool = False,
         trace_capacity: Optional[int] = None,
         turns: bool = False,
     ) -> None:
@@ -123,14 +131,6 @@ class Recorder:
             from .turns import TurnLedger
 
             self.turns = TurnLedger()
-        self._annotate = None
-        if jax_annotations:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                self._annotate = TraceAnnotation
-            except Exception:  # profiler unavailable: annotations are
-                self._annotate = None  # best-effort pass-through only
         self.finalized: Optional[dict] = None
         # queued JSON artifacts (name -> payload), written at finalize —
         # the subsystem-report seam (sweep/report.py's SWEEP_* files ride

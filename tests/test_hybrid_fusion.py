@@ -252,10 +252,11 @@ class TestPureSchedulingChange:
         r2, e2, _led2 = _run(_cfg(tmp_path / "d"))
         assert r2.log_tuples() == rf.log_tuples()
         assert r2.counters == rf.counters
+        # the counts; the walls (floats, phase_s, the turn ring) differ
         assert {k: v for k, v in e2.sync_stats.items()
-                if not isinstance(v, float)} == \
+                if isinstance(v, int)} == \
                {k: v for k, v in ef.sync_stats.items()
-                if not isinstance(v, float)}
+                if isinstance(v, int)}
 
 
 class TestRollbackEgressParity:
