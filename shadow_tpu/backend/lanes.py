@@ -81,6 +81,26 @@ NEVER = stime.NEVER
 # backends elide identically so event logs stay bit-identical
 PASSIVE_MODELS = frozenset({M_NONE, M_TGEN_MESH, M_TGEN_CLIENT, M_TGEN_SERVER})
 STREAM_MODELS = frozenset({M_STREAM_CLIENT, M_STREAM_SERVER})
+# active DATAGRAM models whose DELIVERY handler is WINDOW-INERT, the static
+# property the pop phase (pop_mask) widens an active lane's co-pop on.
+# Three points, each read off _process_slot:
+# (i)   it inserts no event on its own lane: the handler's one effect is a
+#       send (``del_send_phold`` -> ``do_send``), which leaves through the
+#       ``out_*`` channel and the exchange (phold draws its peer from the
+#       other n - 1; a lone host's send to itself lands by (ii)); the only
+#       self-insert of a slot is the ``ins_*`` DELIVERY of a PACKET pop,
+#       and the model arms no timer;
+# (ii)  everything it sends lands at or after the window's end:
+#       ``arr = pair_max(dep + lat, we)``;
+# (iii) it reads and writes no word a PACKET pop reads or writes: a
+#       DELIVERY pop touches ``n_hops``, ``app_draws``, ``send_seq``,
+#       ``n_sends``, the UP bucket (and ``n_loss``, ``min_used_lat``); a
+#       PACKET pop the DOWN bucket, CoDel, ``n_delivered`` / ``n_codel``
+#       (the netobs counters both add to commute).
+# A model joins only with these three points argued from its handler and
+# tests/test_lane_parity.py green (M_PING_SERVER, an echo, is the
+# candidate); the stream models arm timers and keep their own rule.
+WINDOW_INERT_MODELS = frozenset({M_PHOLD})
 
 # LOCAL size marker: a non-driving process's start event on a
 # multi-process lane host — anchors the window like any start, drives
@@ -292,6 +312,13 @@ class LaneState(NamedTuple):
     # reductions an iteration: () — nothing traced, the program unchanged
     # — where every lane's model is passive (``LaneParams.all_passive``)
     peaks: Any = ()
+    # int32 scalar: pop slots consumed under the window-inert co-pop rule
+    # (pop_mask) that the same-instant rule would have refused; its share
+    # of ``iters x pops_per_iter x n_lanes`` is how often the rule
+    # engages.  Read into collect()'s ``lane_plane``, never into the
+    # counters.  () — nothing traced — where no lane's model is in
+    # WINDOW_INERT_MODELS (``LaneParams.copop_inert``)
+    copop_wide_pops: Any = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -393,6 +420,12 @@ class LaneParams:
     @property
     def all_passive(self) -> bool:
         return set(self.models_present) <= PASSIVE_MODELS
+
+    @property
+    def copop_inert(self) -> bool:
+        """Some lane's model is window-inert: the pop phase compiles the
+        DELIVERY* PACKET* co-pop and its engage counter."""
+        return bool(set(self.models_present) & WINDOW_INERT_MODELS)
 
     @property
     def cross_cap(self) -> int:
@@ -3170,6 +3203,119 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
     return s
 
 
+def pop_mask(p: LaneParams, model, thi, tlo, kind_cols, we_hi, we_lo):
+    """The pop phase's mask over the first K columns of the sorted queue
+    rows: ``(act, wide)`` — ``act[n, j]``: lane ``n`` pops column ``j``
+    this iteration; ``wide``: how many of them only the window-inert rule
+    admits (``()`` where the program does not compile it).
+
+    The per-lane pop-safety class is STATIC (a property each model
+    declares, read off ``p.models_present``):
+
+    - passive lanes (``PASSIVE_MODELS``) co-pop ANY prefix — their packet
+      handling (inline counters, dst-side bucket/CoDel) and timer ticks
+      (src-side bucket, cross-window sends) touch disjoint state and
+      commute, so heap-order interleaving cannot be observed;
+    - active lanes (phold/ping/stream) may generate same-window events
+      (pump arms, DELIVERY inserts) that the CPU heap pops before later
+      queue entries, so they co-pop only same-instant PACKET prefixes (a
+      packet pop generates nothing that sorts before a same-time PACKET),
+      or their first column alone;
+    - window-inert lanes (``WINDOW_INERT_MODELS``: active datagram models
+      whose DELIVERY pop puts nothing back inside the window) also co-pop
+      the longest prefix of the form DELIVERY* PACKET*, at any times;
+    - stream lanes have their own wide rule where ``stream_wide_pop``.
+
+    Every class is cut at the window's end."""
+    k = thi.shape[1]
+    mp_r = set(p.models_present)
+    same_t = (thi == thi[:, :1]) & (tlo == tlo[:, :1])
+    pkt_prefix = jnp.cumprod(kind_cols == PACKET, axis=1).astype(bool)
+    first_col = (jnp.arange(k) == 0)[None, :]
+    passive_lane = jnp.zeros(p.n_lanes, dtype=bool)
+    for _mid in sorted(PASSIVE_MODELS & mp_r):
+        passive_lane = passive_lane | (model == _mid)
+    allowed = passive_lane[:, None] | (same_t & (pkt_prefix | first_col))
+    if p.stream_present and p.stream_wide_pop:
+        # Stream lanes may co-pop WITHIN-WINDOW queue prefixes beyond
+        # the same-instant rule (distinct times included):
+        # - PACKET pops touch only per-lane network state (dn bucket,
+        #   CoDel) and insert DELIVERYs whose relative order the merge
+        #   preserves; they COMMUTE with DELIVERY pops (which touch
+        #   only flow state), so the CPU heap's interleaving of an
+        #   inserted DELIVERY between two queued events is
+        #   unobservable;
+        # - DELIVERY pops emit sends that arrive >= window end and RTO
+        #   arms at now + rto >= now + RTO_MIN, which the engine
+        #   guarantees lies beyond every possible window
+        #   (stream_wide_pop is set only then) — and the burst law
+        #   queues no same-instant pump events at all;
+        # - a DELIVERY inserted by an in-prefix PACKET lands at the
+        #   bucket's FIFO departure time, >= every queued delivery
+        #   time, so it never overtakes a co-popped event — EXCEPT on
+        #   an exact tie, where (src, seq) breaks order.  In
+        #   one-to-one mode every flow-state-relevant delivery at a
+        #   lane shares one src (its single peer; foreign datagrams
+        #   are no-ops), making ties benign: MIXED packet/delivery
+        #   prefixes are safe.  In star mode ties across clients are
+        #   real, so prefixes stay single-kind.
+        # - LOCAL-interrupted prefixes fall back to slot 0.
+        stream_lane = (model == M_STREAM_CLIENT) | (
+            model == M_STREAM_SERVER
+        )
+        if p.stream_one_to_one:
+            stream_prefix = jnp.cumprod(
+                kind_cols != LOCAL, axis=1
+            ).astype(bool)
+        else:
+            stream_prefix = pkt_prefix | jnp.cumprod(
+                kind_cols == DELIVERY, axis=1
+            ).astype(bool)
+        allowed = allowed | (stream_lane[:, None] & stream_prefix)
+    in_window = pair_lt(thi, tlo, we_hi, we_lo)
+    if not p.copop_inert:
+        return allowed & in_window, ()
+    # The window-inert class (points (i)-(iii) are argued at
+    # WINDOW_INERT_MODELS).  Why each shape of DELIVERY* PACKET* is what
+    # the oracle's heap does, the slot walk running the columns in key order:
+    # - [D, D]: a DELIVERY pop inserts nothing on its lane (i) and what it
+    #   sends lands at or after the window's end (ii), so nothing can
+    #   sort between the two;
+    # - [D, P]: the same, then the packet;
+    # - [P, P'] at distinct instants: the heap may pop P's own DELIVERY
+    #   (inserted at its dn-bucket departure) BEFORE P'; the two touch
+    #   disjoint words (iii), so the interleaving is unobservable, and
+    #   that DELIVERY is in the sorted queue before any LATER DELIVERY
+    #   is popped: dn departures are FIFO, so it sorts at or after every
+    #   DELIVERY already queued, and ties fall to the row sort's
+    #   (src, seq);
+    # - [P, D'] stays REFUSED: P's DELIVERY can tie D' in time and sort
+    #   BELOW it by (src, seq) — sources are random, the tie is real —
+    #   and a co-popped D' would then run first.  (At EQUAL times
+    #   PACKET < LOCAL < DELIVERY, so a D' behind P in the row is never
+    #   earlier than P: only the tie with P's OWN delivery refuses it.)
+    # - LOCAL keeps the same-instant rule (first column only): a phold
+    #   LOCAL is an initial message's send, a start anchors the window.
+    narrow = allowed
+    inert_lane = jnp.zeros(p.n_lanes, dtype=bool)
+    for _mid in sorted(WINDOW_INERT_MODELS & mp_r):
+        inert_lane = inert_lane | (model == _mid)
+
+    def run_and(cols):
+        # a running AND over the K columns, unrolled so that it fuses with
+        # the compares around it: two jnp.cumprod here cost the
+        # 10 000-lane program 2.6x the generated code this form does
+        out = [cols[:, 0]]
+        for j in range(1, k):
+            out.append(out[-1] & cols[:, j])
+        return jnp.stack(out, axis=1)
+
+    del_prefix = run_and(kind_cols == DELIVERY)
+    dp_prefix = run_and(del_prefix | (kind_cols == PACKET))
+    act = (allowed | (inert_lane[:, None] & dp_prefix)) & in_window
+    return act, (act & ~narrow).sum(dtype=jnp.int32)
+
+
 def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
     """Build the raw one-ITERATION advance (pop ≤K, process, merge) against
     the window already in ``state.now_we_hi/lo``.  The step driver wraps
@@ -3208,67 +3354,16 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
 
     k = p.pops_per_iter
 
-    # per-lane pop-safety class (static): passive lanes co-pop ANY prefix —
-    # their packet handling (inline counters, dst-side bucket/CoDel) and
-    # timer ticks (src-side bucket, cross-window sends) touch disjoint state
-    # and commute, so heap-order interleaving cannot be observed.  Active
-    # lanes (phold/ping/stream) may generate same-window events (pump arms,
-    # DELIVERY inserts) that the CPU heap pops before later queue entries,
-    # so they co-pop only same-instant PACKET prefixes (a packet pop
-    # generates nothing that sorts before a same-time PACKET).
-    mp_r = set(p_lane.models_present)
-    passive_ids = sorted(PASSIVE_MODELS & mp_r)
-
     def iter_body(s: LaneState) -> LaneState:
         # queue rows are kept sorted by the 4-word key — the pop is a slice
         we_hi, we_lo = s.now_we_hi, s.now_we_lo
         thi = s.q_thi[:, :k]
         tlo = s.q_tlo[:, :k]
         kind_cols = s.q_auxh[:, :k] >> AUX_KIND_SHIFT
-        same_t = (thi == thi[:, :1]) & (tlo == tlo[:, :1])
-        pkt_prefix = jnp.cumprod(kind_cols == PACKET, axis=1).astype(bool)
-        first_col = (jnp.arange(k) == 0)[None, :]
-        passive_lane = jnp.zeros(p.n_lanes, dtype=bool)
-        for _mid in passive_ids:
-            passive_lane = passive_lane | (tb.model == _mid)
-        allowed = passive_lane[:, None] | (same_t & (pkt_prefix | first_col))
-        if p_lane.stream_present and p_lane.stream_wide_pop:
-            # Stream lanes may co-pop WITHIN-WINDOW queue prefixes beyond
-            # the same-instant rule (distinct times included):
-            # - PACKET pops touch only per-lane network state (dn bucket,
-            #   CoDel) and insert DELIVERYs whose relative order the merge
-            #   preserves; they COMMUTE with DELIVERY pops (which touch
-            #   only flow state), so the CPU heap's interleaving of an
-            #   inserted DELIVERY between two queued events is
-            #   unobservable;
-            # - DELIVERY pops emit sends that arrive >= window end and RTO
-            #   arms at now + rto >= now + RTO_MIN, which the engine
-            #   guarantees lies beyond every possible window
-            #   (stream_wide_pop is set only then) — and the burst law
-            #   queues no same-instant pump events at all;
-            # - a DELIVERY inserted by an in-prefix PACKET lands at the
-            #   bucket's FIFO departure time, >= every queued delivery
-            #   time, so it never overtakes a co-popped event — EXCEPT on
-            #   an exact tie, where (src, seq) breaks order.  In
-            #   one-to-one mode every flow-state-relevant delivery at a
-            #   lane shares one src (its single peer; foreign datagrams
-            #   are no-ops), making ties benign: MIXED packet/delivery
-            #   prefixes are safe.  In star mode ties across clients are
-            #   real, so prefixes stay single-kind.
-            # - LOCAL-interrupted prefixes fall back to slot 0.
-            stream_lane = (tb.model == M_STREAM_CLIENT) | (
-                tb.model == M_STREAM_SERVER
-            )
-            if p_lane.stream_one_to_one:
-                stream_prefix = jnp.cumprod(
-                    kind_cols != LOCAL, axis=1
-                ).astype(bool)
-            else:
-                stream_prefix = pkt_prefix | jnp.cumprod(
-                    kind_cols == DELIVERY, axis=1
-                ).astype(bool)
-            allowed = allowed | (stream_lane[:, None] & stream_prefix)
-        act = allowed & pair_lt(thi, tlo, we_hi, we_lo)
+        act, wide = pop_mask(p_lane, tb.model, thi, tlo, kind_cols,
+                             we_hi, we_lo)
+        if p_lane.copop_inert:
+            s = s._replace(copop_wide_pops=s.copop_wide_pops + wide)
         kcol, srccol = unpack_aux_hi(s.q_auxh[:, :k])
         popped = {
             "thi": thi,
@@ -3754,7 +3849,7 @@ def pack_state(s: LaneState):
         [jnp.asarray(getattr(s, f), dtype=jnp.int32) for f in sc_fields]
     )
     return (q, c32, sc, s.log, s.stream, s.egress, s.nb_hist, s.fl_buf,
-            s.peaks)
+            s.peaks, s.copop_wide_pops)
 
 
 def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
@@ -3769,7 +3864,8 @@ def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
 
 
 def unpack_state(carry) -> LaneState:
-    q, c32, sc, log, stream, egress, nb_hist, fl_buf, peaks = carry
+    (q, c32, sc, log, stream, egress, nb_hist, fl_buf, peaks,
+     copop_wide_pops) = carry
     has_pay = q.shape[0] == 7
     # the optional blocks' own carry leaves say which are live; the append
     # counters have none, so the scalar count left over tells
@@ -3792,7 +3888,7 @@ def unpack_state(carry) -> LaneState:
         stream=stream,
         cd_dropping=c32[len(_I32_N_FIELDS)].astype(bool),
         log=log, egress=egress, nb_hist=nb_hist, fl_buf=fl_buf,
-        peaks=peaks, **kw,
+        peaks=peaks, copop_wide_pops=copop_wide_pops, **kw,
     )
 
 

@@ -1007,6 +1007,7 @@ class TpuEngine:
                 for f in lanes._AP_SCALARS
             },
             peaks=() if p.all_passive else full(3),
+            copop_wide_pops=full() if p.copop_inert else (),
         )
         # ONE transfer of the whole tree, straight onto its placement: no
         # eager device program, no whole copy on one chip before sharding
@@ -1453,6 +1454,8 @@ class TpuEngine:
         ]
         if not p.all_passive:
             fields.append("peaks")
+        if p.copop_inert:
+            fields.append("copop_wide_pops")
         if p.netobs:
             fields += ["nb_txb", "nb_rxb", "nb_thr", "nb_shed", "nb_hist",
                        "nb_win"]
@@ -1582,6 +1585,11 @@ class TpuEngine:
             "has_loss": int(self.params.has_loss),
             "stream_wide_pop": int(self.params.stream_wide_pop),
         }
+        if p.copop_inert:
+            # pop slots (a run offers lane_iters x pops x lanes of them)
+            # that only the window-inert co-pop rule consumed
+            # (lanes.pop_mask): present where the program compiles it
+            self.lane_plane["copop_wide_pops"] = int(s.copop_wide_pops)
         if self.obs is not None:
             for key, val in self.lane_plane.items():
                 self.obs.metrics.gauge(key, val)

@@ -339,7 +339,11 @@ def phold_shape_law(
       two dependent pops (the PACKET, then the DELIVERY it inserts, whose
       pop is the send), so the fullest lane of a window sets the
       iterations: ``iters = ceil(2 q / pops)`` with ``q`` the quantile at
-      1 / (1 000 x lanes x windows);
+      1 / (1 000 x lanes x windows).  That IS what a run takes since the
+      lanes co-pop any DELIVERY* PACKET* prefix (``lanes.pop_mask``, the
+      window-inert class): ``q`` arrivals are ``q / 2`` packet pairs and
+      ``q / 2`` delivery pairs at 2 pops; under the same-instant rule
+      before it a DELIVERY popped alone and a window took ``1.5 q``;
     - a QUEUE holds what is left of this window's arrivals plus what the
       merge has already filed for the next: ~Poisson(2 x messages), at
       1 / (1 000 x lanes x windows x iters), plus the engine's headroom;

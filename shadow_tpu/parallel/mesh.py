@@ -78,7 +78,7 @@ LANE_FIELDS = frozenset((
 REPLICATED_FIELDS = frozenset((
     "log", "log_count", "log_lost", "rounds", "iters", "codel_lookup_pops",
     "now_we_hi", "now_we_lo", "min_used_lat", "stream",
-    "peaks",
+    "peaks", "copop_wide_pops",
     "egress", "egress_count", "egress_lost",
     "egress_min_hi", "egress_min_lo",
     "nb_hist", "nb_win",

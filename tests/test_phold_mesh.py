@@ -14,7 +14,10 @@ engine can fake.
     plus microseconds, so ``phold_hops == hosts x messages x (windows - 1)``
     and every delivery is a hop;
 (d) the shapes and the peaks reach ``lane_plane``, ``sim-stats.json`` and
-    the obs gauges.
+    the obs gauges;
+(e) the window-inert co-pop (ISSUE 38): the predicate alone on hand-written
+    rows, the iterations it saves and its engage counter, the delivery
+    ties it exists for against the oracle, and that the law is static.
 """
 
 import functools
@@ -24,9 +27,11 @@ import math
 import pytest
 
 from shadow_tpu import parallel
+from shadow_tpu.backend import lanes
 from shadow_tpu.backend.cpu_engine import CpuEngine
 from shadow_tpu.backend.tpu_engine import TpuEngine
 from shadow_tpu.config import scenarios
+from shadow_tpu.config.options import ConfigOptions
 from shadow_tpu.config.scenarios import (
     phold_mesh_config, phold_shape_law, poisson_tail_quantile,
 )
@@ -84,12 +89,16 @@ def test_the_lane_backend_equals_the_oracle(mode):
     _assert_equals_oracle(res, _oracle(64, 4, 10), 64 * 4 * 9)
 
 
-def test_the_facade_with_the_log_off_equals_the_oracles_counters(tmp_path):
-    cfg = _cfg(64, 4, 10)
+def _facade_run(hosts, messages, windows, tmp_path):
+    cfg = _cfg(hosts, messages, windows)
     cfg.general.data_directory = str(tmp_path / "data")
     cfg.experimental.obs_metrics = True
     sim = Simulation(cfg, event_log=False)
-    res = sim.run()
+    return sim, sim.run()
+
+
+def test_the_facade_with_the_log_off_equals_the_oracles_counters(tmp_path):
+    sim, res = _facade_run(64, 4, 10, tmp_path)
     oracle = _oracle(64, 4, 10)
     assert res.event_log == []
     assert _shared(res.counters) == _shared(oracle.counters)
@@ -259,8 +268,6 @@ def test_an_injection_shed_is_the_queues_and_the_message_says_so():
     toward the cross block's, whose option would not cure it."""
     import numpy as np
 
-    from shadow_tpu.backend import lanes
-
     eng = TpuEngine(_cfg(64, 4, 5), log_capacity=0)
     p = eng.params
     assert not p.all_passive and p.cross_cap < p.capacity
@@ -306,19 +313,244 @@ def test_the_population_is_conserved(hosts, messages, windows):
 # -- (d) the gauges -----------------------------------------------------------
 
 
-def test_a_program_of_passive_lanes_reports_its_shapes_and_no_peaks():
-    """The peaks are three reductions an iteration, compiled only where
-    some lane's model is active (``LaneParams.all_passive``): the
-    permutation meshes' programs do not pay for them."""
+def _passive_mesh():
     from shadow_tpu.config.columnar import columnar_mesh_config
 
     cfg = columnar_mesh_config(200, sim_seconds=1, queue_capacity=16,
                                pops_per_round=2)
     cfg.general.stop_time = 50 * MS
     cfg.experimental.tpu_cross_capacity = 8
-    eng = TpuEngine(cfg, log_capacity=0)
+    return cfg
+
+
+def test_a_program_of_passive_lanes_reports_its_shapes_and_no_peaks():
+    """The peaks are three reductions an iteration, compiled only where
+    some lane's model is active (``LaneParams.all_passive``): the
+    permutation meshes' programs do not pay for them."""
+    eng = TpuEngine(_passive_mesh(), log_capacity=0)
     eng.run(mode="device")
     assert eng.params.all_passive
     assert {k: eng.lane_plane.get(k) for k in SHAPES} == {
         "queue_capacity": 16, "cross_capacity": 8, "pops_per_iter": 2,
         "queue_peak": None, "cross_peak": None}
+
+
+# -- (e) the window-inert co-pop ----------------------------------------------
+
+P, L, D = lanes.PACKET, lanes.LOCAL, lanes.DELIVERY
+WINDOW_END = 100
+#: (model, kinds, times) -> the columns popped; the window ends at 100
+ROWS = [
+    # a PHOLD lane co-pops the longest DELIVERY* PACKET* prefix, any times
+    (lanes.M_PHOLD, [D, D], [10, 20], [1, 1]),
+    (lanes.M_PHOLD, [D, P], [10, 20], [1, 1]),
+    (lanes.M_PHOLD, [P, P], [10, 20], [1, 1]),
+    (lanes.M_PHOLD, [P, D], [10, 20], [1, 0]),  # P's own D may tie D'
+    (lanes.M_PHOLD, [P, D], [10, 10], [1, 0]),
+    (lanes.M_PHOLD, [P, L], [10, 20], [1, 0]),
+    (lanes.M_PHOLD, [L, P], [10, 20], [1, 0]),
+    (lanes.M_PHOLD, [D, L], [10, 20], [1, 0]),
+    (lanes.M_PHOLD, [L, L], [10, 10], [1, 0]),  # today's rule: first column
+    (lanes.M_PHOLD, [P, P], [10, 10], [1, 1]),  # the same-instant rule
+    # never across the window's end
+    (lanes.M_PHOLD, [D, D], [10, 100], [1, 0]),
+    (lanes.M_PHOLD, [D, P], [99, 150], [1, 0]),
+    (lanes.M_PHOLD, [D, D], [100, 110], [0, 0]),
+    # a passive lane pops any prefix, a stream lane (star mode, the wide
+    # law) single-kind prefixes, a ping lane same-instant packets: as before
+    (lanes.M_TGEN_MESH, [P, L], [10, 20], [1, 1]),
+    (lanes.M_TGEN_MESH, [L, P], [10, 100], [1, 0]),
+    (lanes.M_STREAM_SERVER, [D, D], [10, 20], [1, 1]),
+    (lanes.M_STREAM_SERVER, [P, P], [10, 20], [1, 1]),
+    (lanes.M_STREAM_SERVER, [D, P], [10, 20], [1, 0]),
+    (lanes.M_STREAM_CLIENT, [P, D], [10, 20], [1, 0]),
+    (lanes.M_PING_SERVER, [D, D], [10, 20], [1, 0]),
+    (lanes.M_PING_SERVER, [P, P], [10, 20], [1, 0]),
+    (lanes.M_PING_SERVER, [P, P], [10, 10], [1, 1]),
+]
+#: four pops: the prefix ends at the first DELIVERY behind a PACKET
+ROWS4 = [
+    (lanes.M_PHOLD, [D, D, P, P], [10, 20, 30, 40], [1, 1, 1, 1]),
+    (lanes.M_PHOLD, [D, P, D, P], [10, 20, 30, 40], [1, 1, 0, 0]),
+    (lanes.M_PHOLD, [P, P, D, P], [10, 20, 30, 40], [1, 1, 0, 0]),
+    (lanes.M_PHOLD, [D, D, L, D], [10, 20, 30, 40], [1, 1, 0, 0]),
+    (lanes.M_PHOLD, [D, P, P, P], [10, 20, 99, 100], [1, 1, 1, 0]),
+    (lanes.M_TGEN_MESH, [P, L, P, L], [10, 20, 30, 40], [1, 1, 1, 1]),
+]
+
+
+def _pop_mask(rows):
+    import numpy as np
+
+    models = np.array([r[0] for r in rows], dtype=np.int32)
+    kinds = np.array([r[1] for r in rows], dtype=np.int32)
+    tlo = np.array([r[2] for r in rows], dtype=np.int32)
+    p = lanes.LaneParams(
+        n_lanes=len(rows), capacity=8, pops_per_iter=kinds.shape[1],
+        log_capacity=0, seed=1, stop_time=10 * MS, bootstrap_end=0,
+        runahead=WINDOW_END, models_present=tuple(sorted(set(models))),
+        stream_wide_pop=True)
+    act, wide = lanes.pop_mask(
+        p, models, np.zeros_like(tlo), tlo, kinds, np.int32(0),
+        np.int32(WINDOW_END))
+    return np.asarray(act).astype(int).tolist(), wide
+
+
+@pytest.mark.parametrize("rows", [ROWS, ROWS4], ids=["2pops", "4pops"])
+def test_the_pop_predicate_on_hand_written_rows(rows, monkeypatch):
+    act, wide = _pop_mask(rows)
+    for (model, kinds, times, want), got in zip(rows, act):
+        assert got == want, (model, kinds, times)
+    # the same-instant law, which is what the model set emptied leaves:
+    # only PHOLD's rows differ, and the counter counts exactly those slots
+    monkeypatch.setattr(lanes, "WINDOW_INERT_MODELS", frozenset())
+    old, none = _pop_mask(rows)
+    assert none == ()
+    for (model, kinds, times, _want), got, was in zip(rows, act, old):
+        if model != lanes.M_PHOLD:
+            assert got == was, (model, kinds, times)
+        assert was == [int(a and b) for a, b in zip(got, was)]
+        assert was[0] == got[0]
+    assert int(wide) == sum(map(sum, act)) - sum(map(sum, old)) > 0
+
+
+@pytest.mark.parametrize("hosts, messages, windows", SMALL)
+def test_the_co_pop_saves_a_quarter_of_the_iterations(
+        hosts, messages, windows, tmp_path, monkeypatch):
+    sim, res = _facade_run(hosts, messages, windows, tmp_path)
+    plane = sim.engine.lane_plane
+    slots = res.counters["lane_iters"] * plane["pops_per_iter"] * hosts
+    assert 0 < plane["copop_wide_pops"] < slots
+    stats = json.loads((sim.data_dir / "sim-stats.json").read_text())
+    assert stats["lane_plane"]["copop_wide_pops"] == plane["copop_wide_pops"]
+    assert (sim.obs.finalized["report"]["gauges"]["copop_wide_pops"]
+            == plane["copop_wide_pops"])
+    assert "copop_wide_pops" not in res.counters
+    # the same-instant law: the same run in a third more iterations
+    monkeypatch.setattr(lanes, "WINDOW_INERT_MODELS", frozenset())
+    eng = TpuEngine(_cfg(hosts, messages, windows), log_capacity=0)
+    old = eng.run(mode="device")
+    assert "copop_wide_pops" not in eng.lane_plane
+    assert _shared(old.counters) == _shared(res.counters)
+    assert res.counters["lane_iters"] <= 0.75 * old.counters["lane_iters"]
+    if (hosts, messages, windows) == (512, 4, 5):
+        assert old.counters["lane_iters"] == 74  # ISSUE 38's count
+        assert res.counters["lane_iters"] <= 55
+
+
+def _slow_downlink_mesh(backend):
+    """64 x 16 at 10 Mbit: a datagram takes 0.24 ms of a downlink that
+    refills every millisecond, so deliveries leave the bucket in bursts
+    at the SAME instant from different sources — the tie [P, D'] is
+    refused for."""
+    cfg = phold_mesh_config(64, 16, 256, "10 ms", "10 Mbit", seed=7)
+    cfg.general.stop_time = 60 * MS
+    cfg.experimental.network_backend = backend
+    return cfg
+
+
+def _lossy_routed_graph(backend):
+    hosts = "\n".join(
+        f"  h{i}: {{network_node_id: {i % 3}, processes: "
+        f"[{{path: phold, args: [--messages, '6']}}]}}" for i in range(12))
+    return ConfigOptions.from_yaml(f"""
+general: {{stop_time: 150 ms, seed: 11}}
+network:
+  graph:
+    type: gml
+    inline: |
+      graph [
+        directed 0
+        node [ id 0 host_bandwidth_up "10 Mbit" host_bandwidth_down "10 Mbit" ]
+        node [ id 1 host_bandwidth_up "10 Mbit" host_bandwidth_down "5 Mbit" ]
+        node [ id 2 host_bandwidth_up "10 Mbit" host_bandwidth_down "10 Mbit" ]
+        edge [ source 0 target 0 latency "2 ms" ]
+        edge [ source 0 target 1 latency "5 ms" packet_loss 0.02 ]
+        edge [ source 1 target 1 latency "2 ms" ]
+        edge [ source 1 target 2 latency "3 ms" packet_loss 0.01 ]
+        edge [ source 2 target 2 latency "2 ms" ]
+      ]
+experimental: {{network_backend: {backend}, tpu_events_per_round: 2}}
+hosts:
+{hosts}
+""")
+
+
+TIES = {"slow_downlink": _slow_downlink_mesh, "lossy_routed": _lossy_routed_graph}
+
+
+@functools.lru_cache(maxsize=None)
+def _ties_oracle(name):
+    return CpuEngine(TIES[name]("cpu")).run()
+
+
+def _assert_ties_equal_the_oracle(name, eng, res):
+    oracle = _ties_oracle(name)
+    assert len(oracle.event_log) > 1000
+    assert res.log_tuples() == oracle.log_tuples()
+    assert _shared(res.counters) == _shared(oracle.counters)
+    assert res.rounds == oracle.rounds
+    # the rule engaged, and deliveries did tie in time across sources
+    assert eng.lane_plane["copop_wide_pops"] > 0
+    times = {}
+    for t, src, dst, _seq, _size, outcome in oracle.log_tuples():
+        if outcome == lanes.DELIVERED:
+            times.setdefault((t, dst), set()).add(src)
+    assert any(len(srcs) > 1 for srcs in times.values())
+
+
+@pytest.mark.parametrize("mode", ["device", "step"])
+@pytest.mark.parametrize("name", sorted(TIES))
+def test_delivery_ties_equal_the_oracle(name, mode):
+    eng = TpuEngine(TIES[name]("tpu"))
+    _assert_ties_equal_the_oracle(name, eng, eng.run(mode=mode))
+
+
+@pytest.mark.multichip
+@pytest.mark.parametrize("devices", [1, 2, 4])
+@pytest.mark.parametrize("name", sorted(TIES))
+def test_delivery_ties_equal_the_oracle_at_any_mesh_shape(name, devices):
+    eng = TpuEngine(TIES[name]("tpu"))
+    eng.attach_mesh(parallel.make_mesh(devices))
+    _assert_ties_equal_the_oracle(name, eng, eng.run(mode="device"))
+
+
+def _lowered(cfg):
+    eng = TpuEngine(cfg, log_capacity=0)
+    return lanes.make_run_fn(eng.params, eng.tables).lower(
+        eng.initial_state()).as_text()
+
+
+def _one_to_one_streams():
+    return ConfigOptions.from_yaml("""
+general: {stop_time: 1s, seed: 5}
+experimental: {network_backend: tpu, tpu_lane_queue_capacity: 128}
+network:
+  graph:
+    type: gml
+    inline: |
+      graph [
+        directed 0
+        node [ id 0 host_bandwidth_up "20 Mbit" host_bandwidth_down "20 Mbit" ]
+        node [ id 1 host_bandwidth_up "20 Mbit" host_bandwidth_down "20 Mbit" ]
+        edge [ source 0 target 1 latency "15 ms" ]
+      ]
+hosts:
+  c: {network_node_id: 0, processes: [{path: stream-client, args: [--server, s, --size, 200kB]}]}
+  s: {network_node_id: 1, processes: [{path: stream-server}]}
+""")
+
+
+@pytest.mark.parametrize("name, differs", [
+    ("passive_mesh", False), ("one_to_one_streams", False), ("phold", True)])
+def test_the_co_pop_law_is_static(name, differs, monkeypatch):
+    """The rule is a term of the programs whose lanes run a window-inert
+    model and of no other: with the model set emptied a passive mesh's
+    and a one-to-one stream program's lowered text is the same text,
+    PHOLD's is not."""
+    make = {"passive_mesh": _passive_mesh,
+            "one_to_one_streams": _one_to_one_streams,
+            "phold": lambda: _cfg(64, 4, 5)}[name]
+    with_rule = _lowered(make())
+    monkeypatch.setattr(lanes, "WINDOW_INERT_MODELS", frozenset())
+    assert (_lowered(make()) != with_rule) == differs
