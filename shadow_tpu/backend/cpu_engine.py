@@ -19,7 +19,8 @@ from ..core import rng as rng_mod
 from ..core import time as stime
 from ..core.event import Event, EventKind, Task
 from ..core.event_queue import EventQueue
-from ..models import phold as _phold  # noqa: F401  (register built-ins)
+from ..models import gossip as _gossip  # noqa: F401  (register built-ins)
+from ..models import phold as _phold  # noqa: F401
 from ..models import tcpflow as _tcpflow  # noqa: F401
 from ..models import tgen as _tgen  # noqa: F401
 from ..models import tgen_tcp as _tgen_tcp  # noqa: F401

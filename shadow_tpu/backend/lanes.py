@@ -73,7 +73,7 @@ NEVER = stime.NEVER
 
 # lane-supported app models
 (M_NONE, M_PHOLD, M_TGEN_MESH, M_TGEN_CLIENT, M_TGEN_SERVER, M_PING_CLIENT,
- M_PING_SERVER, M_STREAM_CLIENT, M_STREAM_SERVER) = range(9)
+ M_PING_SERVER, M_STREAM_CLIENT, M_STREAM_SERVER, M_GOSSIP) = range(10)
 
 # models whose delivery handling is PASSIVE (counters only — no sends, no
 # timers): their DELIVERY events are elided and applied inline at packet
@@ -100,7 +100,27 @@ STREAM_MODELS = frozenset({M_STREAM_CLIENT, M_STREAM_SERVER})
 # A model joins only with these three points argued from its handler and
 # tests/test_lane_parity.py green (M_PING_SERVER, an echo, is the
 # candidate); the stream models arm timers and keep their own rule.
-WINDOW_INERT_MODELS = frozenset({M_PHOLD})
+#
+# M_GOSSIP (models/gossip.py), point by point:
+# (i)   its DELIVERY handler tests and sets one bit of the lane's seen
+#       bitmap, bumps the lane's gossip counters and sends up to D
+#       datagrams to its mesh peers, none of them itself (the mesh is
+#       simple: no self-loop), all through the ``out_*`` channel; it arms
+#       no timer (the publish timers are in the queue from the start);
+# (ii)  every one of the D sends lands at ``pair_max(dep + lat, we)``;
+# (iii) a DELIVERY pop touches the seen bitmap, the gossip counters,
+#       ``send_seq``, ``n_sends``, the UP bucket, ``n_loss`` and
+#       ``min_used_lat``: no word of a PACKET pop.
+# What is NEW with gossip is that the ORDER of two DELIVERY pops of one
+# lane is observable (the first copy of a message is the one forwarded,
+# and its source the one peer left out; PHOLD's handler ignores both).
+# The rule never reorders DELIVERY pops: the slot walk runs the
+# co-popped columns in key order, the oracle's heap order, and the one
+# shape in which a DELIVERY not yet in the row could sort below a
+# co-popped one — [P, D'], P's own DELIVERY tying D' in time — is the
+# shape pop_mask refuses.  tests/test_gossip_mesh.py holds the event
+# log, the counters and the rounds to the oracle's with the rule on.
+WINDOW_INERT_MODELS = frozenset({M_PHOLD, M_GOSSIP})
 
 # LOCAL size marker: a non-driving process's start event on a
 # multi-process lane host — anchors the window like any start, drives
@@ -319,6 +339,21 @@ class LaneState(NamedTuple):
     # counters.  () — nothing traced — where no lane's model is in
     # WINDOW_INERT_MODELS (``LaneParams.copop_inert``)
     copop_wide_pops: Any = ()
+    # gossip lanes' app state (a ``GossipState`` of per-lane arrays; ()
+    # — nothing traced — where no lane runs M_GOSSIP)
+    gossip: Any = ()
+
+
+class GossipState(NamedTuple):
+    """Per-lane state of the gossip model (models/gossip.py), all int32
+    and all leading with the lane axis."""
+
+    seen: jnp.ndarray  # [N, ceil(M / 32)] bitmap of message ids seen
+    sends: jnp.ndarray  # [N] datagrams pushed (publishes and forwards)
+    first: jnp.ndarray  # [N] first deliveries
+    dups: jnp.ndarray  # [N] duplicate deliveries
+    last_hi: jnp.ndarray  # [N] pair: time of the last first delivery
+    last_lo: jnp.ndarray
 
 
 @dataclasses.dataclass(frozen=True)
@@ -406,16 +441,31 @@ class LaneParams:
     ext_per_iter: int = 0  # worst-case egress appends per iteration
     inject_batch: int = 0  # B (rows per injection block)
     inject_cross: int = 0  # per-lane injection fan-in per call (0 = C)
+    # mesh peers of a gossip lane (the ``[N, D]`` peer table's width; 0
+    # where no lane runs M_GOSSIP)
+    gossip_degree: int = 0
 
     @property
     def stream_present(self) -> bool:
         return bool(set(self.models_present) & STREAM_MODELS)
 
     @property
+    def sends_per_pop(self) -> int:
+        """F: the most datagrams one popped event's handler sends through
+        the [N] send channel — a static property of the models present, as
+        ``copop_inert`` is: a gossip pop pushes to its D mesh peers, every
+        other model sends at most once.  ``_process_slot`` emits ``[F,
+        N]`` sends and the exchange sorts ``pops x F x lanes`` rows."""
+        return self.gossip_degree if M_GOSSIP in self.models_present else 1
+
+    @property
     def lanes_have_payload(self) -> bool:
-        """The [N] queues carry payload columns only when stream events
-        ride them — the tiered backend moves those to the [2S] block."""
-        return self.stream_present and not self.stream_tiered
+        """The [N] queues carry payload columns when stream events ride
+        them (the tiered backend moves those to the [2S] block) or gossip
+        datagrams do (the message id)."""
+        return (self.stream_present and not self.stream_tiered) or (
+            M_GOSSIP in self.models_present
+        )
 
     @property
     def all_passive(self) -> bool:
@@ -433,12 +483,13 @@ class LaneParams:
 
     @property
     def exchange_entries(self) -> int:
-        """Rows of the [N] exchange's flat sort per iteration: K sends a
-        lane, plus the compacted stream channels (slot-0 sends, RTO arms,
-        bursts) where they ride it (``_merge_append`` holds it to the
-        traced shape)."""
-        m = self.pops_per_iter * self.n_lanes
-        if self.lanes_have_payload and not self.stream_one_to_one:
+        """Rows of the [N] exchange's flat sort per iteration: K pops of F
+        sends a lane, plus the compacted stream channels (slot-0 sends,
+        RTO arms, bursts) where they ride it (``_merge_append`` holds it
+        to the traced shape)."""
+        m = self.pops_per_iter * self.sends_per_pop * self.n_lanes
+        if (self.stream_present and not self.stream_tiered
+                and not self.stream_one_to_one):
             m += self.pops_per_iter * len(self.stream_clients) * (
                 4 + lstr.ltcp.PUMP_BURST
             )
@@ -462,6 +513,16 @@ class LaneParams:
             raise ValueError(
                 f"flowtrace requires flow_capacity > 0 (got {self.flow_capacity})"
             )
+        if M_GOSSIP in self.models_present:
+            if self.gossip_degree < 1:
+                raise ValueError("gossip lanes need gossip_degree >= 1")
+            # the payload words are the stream tier's or the message id's,
+            # and the pcap / flowtrace channels carry one send a pop
+            if self.stream_present or self.pcap_any or self.flowtrace:
+                raise ValueError(
+                    "gossip lanes beside stream lanes, pcap capture or "
+                    "flowtrace are not lane-compiled"
+                )
 
 
 class LaneTables(NamedTuple):
@@ -534,6 +595,10 @@ class LaneTables(NamedTuple):
     # _split_seed semantics), so the two forms are bit-identical.
     seed_lo: Any = ()
     seed_hi: Any = ()
+    # [N, D] int32: a gossip lane's mesh peers in forwarding order
+    # (models/gossip.py gossip_mesh; rows of other lanes are zeros); ()
+    # where no lane runs M_GOSSIP
+    g_peers: Any = ()
 
 
 # --------------------------------------------------------------------------
@@ -1179,8 +1244,40 @@ def _process_slot(
         st_send = sem.send_valid & stream_stim
         st_rto = sem.rto_valid & stream_stim
 
-    # ---- unified send channel (≤1 send per lane per slot; stream lanes
-    # send through the compacted channels below, not this one) ------------
+    # ---- gossip (models/gossip.py): a publish timer's message id rides
+    # the LOCAL's size word, a datagram's the payload word; a message new
+    # to the lane (or published by it) is pushed to the mesh peers through
+    # the send channel below, a known one counts a duplicate ---------------
+    if M_GOSSIP in mp:
+        gs = s.gossip
+        g_lane = model == M_GOSSIP
+        g_pub = is_timer & g_lane
+        g_del = is_del & g_lane
+        g_mid = jnp.where(g_pub, size, plo)
+        with jax.named_scope("gossip_seen"):
+            # one word of the lane's bitmap row, picked by compare (the
+            # row is ceil(M / 32) words: no gather)
+            g_hit = (
+                jnp.arange(gs.seen.shape[1], dtype=i32)[None, :]
+                == (g_mid >> 5)[:, None]
+            ) & (g_pub | g_del)[:, None]
+            g_bit = jnp.where(g_hit, (jnp.int32(1) << (g_mid & 31))[:, None], 0)
+            g_known = jnp.any((gs.seen & g_bit) != 0, axis=1)
+            g_seen = gs.seen | g_bit
+        g_first = g_del & ~g_known
+        g_push = g_pub | g_first
+        gl_hi, gl_lo = pair_max(gs.last_hi, gs.last_lo, thi, tlo)
+        s = s._replace(gossip=gs._replace(
+            seen=g_seen,
+            first=gs.first + g_first,
+            dups=gs.dups + (g_del & g_known),
+            last_hi=jnp.where(g_first, gl_hi, gs.last_hi),
+            last_lo=jnp.where(g_first, gl_lo, gs.last_lo),
+        ))
+
+    # ---- unified send channel (≤F sends per lane per slot, F =
+    # ``sends_per_pop``; stream lanes send through the compacted channels
+    # below, not this one) -------------------------------------------------
     send_phold = del_send_phold | loc_send_phold
     do_send = (
         send_phold | del_send_echo | mesh_tick | client_tick | ping_tick
@@ -1223,74 +1320,129 @@ def _process_slot(
     ).astype(i32)
     out_size = jnp.where(del_send_echo, size, tb.p_size).astype(i32)
     out_phi = out_plo = jnp.zeros(n, dtype=i32)
+    if M_GOSSIP in mp:
+        out_plo = jnp.where(g_push, g_mid, 0)
 
-    # per-send sequence numbers
-    snd_seq = s.send_seq
-    s = s._replace(send_seq=s.send_seq + do_send, n_sends=s.n_sends + do_send)
+    def one_send(s, do_send, dst):
+        """One datagram a lane, as the oracle's ``send_packet``: the next
+        sequence number, the up bucket's charge, the path's latency and
+        its own loss draw — in the order of the calls."""
 
-    # up bucket
-    out_bits = (out_size + FRAME_OVERHEAD_BYTES) * 8
-    (up_tokens, up_nr_hi, up_nr_lo, up_ld_hi, up_ld_lo, dep_hi, dep_lo,
-     up_wait) = (
-        bucket_charge_vec(
-            s.up_tokens, s.up_nr_hi, s.up_nr_lo, s.up_ld_hi, s.up_ld_lo,
-            tb.up_rate, tb.up_burst, tb.up_kfull, tb.up_kfi,
-            thi, tlo, out_bits, do_send, p.bucket_interval,
+        # per-send sequence numbers
+        snd_seq = s.send_seq
+        s = s._replace(send_seq=s.send_seq + do_send, n_sends=s.n_sends + do_send)
+
+        # up bucket
+        out_bits = (out_size + FRAME_OVERHEAD_BYTES) * 8
+        (up_tokens, up_nr_hi, up_nr_lo, up_ld_hi, up_ld_lo, dep_hi, dep_lo,
+         up_wait) = (
+            bucket_charge_vec(
+                s.up_tokens, s.up_nr_hi, s.up_nr_lo, s.up_ld_hi, s.up_ld_lo,
+                tb.up_rate, tb.up_burst, tb.up_kfull, tb.up_kfi,
+                thi, tlo, out_bits, do_send, p.bucket_interval,
+            )
         )
-    )
-    s = s._replace(
-        up_tokens=up_tokens, up_nr_hi=up_nr_hi, up_nr_lo=up_nr_lo,
-        up_ld_hi=up_ld_hi, up_ld_lo=up_ld_lo,
-    )
-    if p.netobs:
         s = s._replace(
-            nb_thr=s.nb_thr + up_wait,
-            nb_txb=s.nb_txb + jnp.where(do_send, out_size, 0),
+            up_tokens=up_tokens, up_nr_hi=up_nr_hi, up_nr_lo=up_nr_lo,
+            up_ld_hi=up_ld_hi, up_ld_lo=up_ld_lo,
         )
+        if p.netobs:
+            s = s._replace(
+                nb_thr=s.nb_thr + up_wait,
+                nb_txb=s.nb_txb + jnp.where(do_send, out_size, 0),
+            )
 
-    # loss (bootstrap window is loss-free; loss-free graphs skip the draw)
-    with jax.named_scope("path_lookup"):
-        my_node = tb.node_of
-        dst_node = tb.node_of[dst]
-        lat = tb.lat[my_node, dst_node]  # int32
-        if p.has_loss:
-            u = rand_u32_lane(
-                _seed_keys(p, tb),
-                (lanes.astype(jnp.uint32) | jnp.uint32(rng_mod.LOSS_STREAM)),
-                snd_seq,
+        # loss (bootstrap window is loss-free; loss-free graphs skip the draw)
+        with jax.named_scope("path_lookup"):
+            my_node = tb.node_of
+            dst_node = tb.node_of[dst]
+            lat = tb.lat[my_node, dst_node]  # int32
+            if p.has_loss:
+                u = rand_u32_lane(
+                    _seed_keys(p, tb),
+                    (lanes.astype(jnp.uint32) | jnp.uint32(rng_mod.LOSS_STREAM)),
+                    snd_seq,
+                )
+                bs_hi, bs_lo = p.bootstrap_end >> 31, p.bootstrap_end & MASK31
+                past_bootstrap = pair_ge(thi, tlo, bs_hi, bs_lo)
+                lost = do_send & past_bootstrap & (
+                    tb.thresh_all[my_node, dst_node]
+                    | (u < tb.thresh_u32[my_node, dst_node])
+                )
+                s = s._replace(n_loss=s.n_loss + lost)
+            else:
+                lost = false_n
+
+        if p.dynamic_runahead:
+            # the smallest path latency of this slot's sends (the CPU law
+            # records EVERY send, before the loss draw — mirror exactly)
+            s = s._replace(
+                min_used_lat=jnp.minimum(
+                    s.min_used_lat, jnp.min(jnp.where(do_send, lat, NEVER32))
+                )
             )
-            bs_hi, bs_lo = p.bootstrap_end >> 31, p.bootstrap_end & MASK31
-            past_bootstrap = pair_ge(thi, tlo, bs_hi, bs_lo)
-            lost = do_send & past_bootstrap & (
-                tb.thresh_all[my_node, dst_node]
-                | (u < tb.thresh_u32[my_node, dst_node])
-            )
-            s = s._replace(n_loss=s.n_loss + lost)
+        arr_hi, arr_lo = pair_max(*pair_add32(dep_hi, dep_lo, lat), we_hi, we_lo)
+        out_valid = do_send & ~lost
+        out_auxh = pack_aux_hi(jnp.full(n, PACKET, dtype=i32), lanes)
+        out_auxl = snd_seq
+
+        # outbound pcap capture at DEPARTURE (pre-loss, like the CPU path)
+        if p.pcap_any:
+            pc_valid = do_send & tb.lane_pcap
+            pc_time = t_join(dep_hi, dep_lo)
+            pc_dst = dst.astype(i64)
+            pc_seq = snd_seq.astype(i64)
+            pc_size = out_size.astype(i64)
         else:
-            lost = false_n
-
-    if p.dynamic_runahead:
-        # the smallest path latency of this slot's sends (the CPU law
-        # records EVERY send, before the loss draw — mirror exactly)
-        s = s._replace(
-            min_used_lat=jnp.minimum(
-                s.min_used_lat, jnp.min(jnp.where(do_send, lat, NEVER32))
-            )
+            pc_valid = pc_time = pc_dst = pc_seq = pc_size = ()
+        return s, (
+            do_send, dst, snd_seq, dep_hi, dep_lo, lost, arr_hi, arr_lo,
+            out_valid, out_auxh, out_auxl,
+            pc_valid, pc_time, pc_dst, pc_seq, pc_size,
         )
-    arr_hi, arr_lo = pair_max(*pair_add32(dep_hi, dep_lo, lat), we_hi, we_lo)
-    out_valid = do_send & ~lost
-    out_auxh = pack_aux_hi(jnp.full(n, PACKET, dtype=i32), lanes)
-    out_auxl = snd_seq
 
-    # outbound pcap capture at DEPARTURE (pre-loss, like the CPU path)
-    if p.pcap_any:
-        pc_valid = do_send & tb.lane_pcap
-        pc_time = t_join(dep_hi, dep_lo)
-        pc_dst = dst.astype(i64)
-        pc_seq = snd_seq.astype(i64)
-        pc_size = out_size.astype(i64)
+    # the handler's sends keep the oracle's order: F calls, state threaded
+    # through (sequence numbers, bucket charges and loss draws in k order);
+    # send 0 is every other model's one send.  [N] where a pop sends once
+    # (the program every other model compiles to), [F, N] where it fans out
+    n_f = p.sends_per_pop
+    if n_f == 1:
+        s, sent = one_send(s, do_send, dst)
     else:
-        pc_valid = pc_time = pc_dst = pc_seq = pc_size = ()
+        chain = ("send_seq", "n_sends", "up_tokens", "up_nr_hi", "up_nr_lo",
+                 "up_ld_hi", "up_ld_lo", "n_loss", "min_used_lat", "nb_thr",
+                 "nb_txb")
+
+        def fan_step(carry, x):
+            # gossipsub forwards to the mesh less the peer the message
+            # came from; a publish goes to all of it
+            first_k, peer_k = x
+            g_k = g_push & (g_pub | (peer_k != src))
+            st, o = one_send(
+                s._replace(**dict(zip(chain, carry[0]))),
+                (do_send & first_k) | g_k, jnp.where(g_k, peer_k, dst),
+            )
+            return (tuple(getattr(st, f) for f in chain),
+                    carry[1] + g_k), o
+
+        # a rolled scan on XLA:CPU, where the unrolled chain of charges
+        # costs 35x more with every send (1.6 ms a slot at F = 4, 2 s at
+        # 6); the accelerator takes the loop form, as the stream burst does
+        with jax.named_scope("gossip_fanout"):
+            (chained, g_sends), sent = scan_or_unroll(
+                fan_step,
+                (tuple(getattr(s, f) for f in chain), s.gossip.sends),
+                (jnp.arange(n_f) == 0, tb.g_peers.T), n_f, spmd_unroll=True,
+            )
+        s = s._replace(**dict(zip(chain, chained)),
+                       gossip=s.gossip._replace(sends=g_sends))
+    (do_send, dst, snd_seq, dep_hi, dep_lo, lost, arr_hi, arr_lo,
+     out_valid, out_auxh, out_auxl,
+     pc_valid, pc_time, pc_dst, pc_seq, pc_size) = sent
+    if n_f > 1:
+        out_size, out_phi, out_plo = (
+            jnp.broadcast_to(a, (n_f, n)) for a in (out_size, out_phi, out_plo)
+        )
 
     # ---- compacted stream send/arm channels ([2S] and [B, S]) ------------
     # Slot-0 control send, then the burst's data segments, charging the
@@ -1601,17 +1753,21 @@ def _process_slot(
     else:
         ft = ()
 
-    # ---- log record (≤1 per slot: packet outcome, or send loss) ----------
-    rec_valid = pk_rec_valid | lost
+    # ---- log record (≤1 per send channel row: packet outcome, or send
+    # loss; a PACKET pop's one record rides row 0 of a fan-out) ------------
+    pk_rows = pk_rec_valid if n_f == 1 else (
+        pk_rec_valid[None, :] & (jnp.arange(n_f) == 0)[:, None]
+    )
+    rec_valid = pk_rows | lost
     if p.log_capacity:
-        rec_time = jnp.where(pk_rec_valid, t_join(td_hi, td_lo), t64)
-        rec_src = jnp.where(pk_rec_valid, src, lanes).astype(i64)
-        rec_dst = jnp.where(pk_rec_valid, lanes, dst).astype(i64)
-        rec_seq = jnp.where(pk_rec_valid, seq, snd_seq).astype(i64)
-        rec_size = jnp.where(pk_rec_valid, size, out_size).astype(i64)
-        rec_outcome = jnp.where(pk_rec_valid, pk_rec_outcome, DROP_LOSS).astype(i64)
+        rec_time = jnp.where(pk_rows, t_join(td_hi, td_lo), t64)
+        rec_src = jnp.where(pk_rows, src, lanes).astype(i64)
+        rec_dst = jnp.where(pk_rows, lanes, dst).astype(i64)
+        rec_seq = jnp.where(pk_rows, seq, snd_seq).astype(i64)
+        rec_size = jnp.where(pk_rows, size, out_size).astype(i64)
+        rec_outcome = jnp.where(pk_rows, pk_rec_outcome, DROP_LOSS).astype(i64)
     else:
-        z64 = jnp.zeros(n, dtype=i64)
+        z64 = jnp.zeros(rec_valid.shape, dtype=i64)
         rec_time = rec_src = rec_dst = rec_seq = rec_size = rec_outcome = z64
 
     emit = _SlotEmit(
@@ -1849,6 +2005,7 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     """
     n, c = p.n_lanes, p.capacity
     sp = p.stream_present
+    pay = p.lanes_have_payload  # the rows carry the two payload words
 
     # -- same-lane block [N, 2K] (3K with the stream RTO channel; K when
     # every model is passive — the DELIVERY self-insert channel is then
@@ -1897,9 +2054,11 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     # static.  Which path an event rides is unobservable — placement is
     # by the keyed merge either way.
     split_se = sp and p.stream_one_to_one
-    if sp and not split_se:
+    has_pay_flat = pay and not split_se
+    if has_pay_flat:
         flat_ops.append(emits.out_phi.reshape(-1))
         flat_ops.append(emits.out_plo.reshape(-1))
+    if sp and not split_se:
         # the COMPACTED stream channels join the exchange here: slot-0
         # control sends (dst = peer lane), burst data segments (dst =
         # server lane), and RTO self-arms (dst = OWN lane, kind LOCAL) —
@@ -1990,7 +2149,7 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
             tuple(flat_ops), dimension=0, num_keys=1, is_stable=False
         )
     _dst_s, thi_s, tlo_s, auxh_s, auxl_s, size_s = sorted_ops[:6]
-    pay_s = sorted_ops[6:8] if sp and not split_se else None
+    pay_s = sorted_ops[6:8] if has_pay_flat else None
     # segment bounds per destination lane: start[d], cnt[d] of lane d's
     # slice of the sorted columns.  Two laws give the same integers; the
     # static shape picks one (exchange_bounds_wide), nothing else differs
@@ -2001,13 +2160,12 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
         else:
             start, cnt = _bounds_by_onehot(flat_ops[0], n)
     cx = p.cross_cap
-    has_pay_flat = sp and not split_se
     gather_ops = [thi_s, tlo_s, auxh_s, auxl_s, size_s] + (
         list(pay_s) if has_pay_flat else []
     )
     _in_seg, words = _cross_block(gather_ops, start, cnt, cx)
     cross_thi, cross_tlo, cross_auxh, cross_auxl, cross_size = words[:5]
-    if sp:
+    if pay:
         if has_pay_flat:
             cross_phi, cross_plo = words[5:]
         else:
@@ -2048,7 +2206,7 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
         mh = jnp.concatenate([s.q_auxh, self_auxh, cross_auxh], axis=1)
         ml = jnp.concatenate([s.q_auxl, self_auxl, cross_auxl], axis=1)
         ms = jnp.concatenate([s.q_size, self_size, cross_size], axis=1)
-        if sp:
+        if pay:
             mphi = jnp.concatenate([s.q_phi, self_phi, cross_phi], axis=1)
             mplo = jnp.concatenate([s.q_plo, self_plo, cross_plo], axis=1)
             mthi, mtlo, mh, ml, ms, mphi, mplo = lax.sort(
@@ -2086,7 +2244,7 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
         # but carry their own cause counter so the netobs drop classification
         # can split queue overflow from exchange-width shed
         s = s._replace(nb_shed=s.nb_shed + lost_pre)
-    if sp:
+    if pay:
         s = s._replace(q_phi=mphi[:, :c], q_plo=mplo[:, :c])
     if p.flowtrace:
         # queue-overflow drops for sampled flows, from the merge tail's
@@ -3372,12 +3530,12 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
             "src": srccol,
             "seq": s.q_auxl[:, :k],
             "size": s.q_size[:, :k],
-            # without the stream tier there is no payload column at all
+            # without stream or gossip lanes there is no payload column at all
             # (dead carry costs per-iteration wall time); slots still see
             # zeros operands, which XLA folds
-            "phi": s.q_phi[:, :k] if p_lane.stream_present
+            "phi": s.q_phi[:, :k] if p_lane.lanes_have_payload
             else jnp.zeros((p.n_lanes, k), dtype=jnp.int32),
-            "plo": s.q_plo[:, :k] if p_lane.stream_present
+            "plo": s.q_plo[:, :k] if p_lane.lanes_have_payload
             else jnp.zeros((p.n_lanes, k), dtype=jnp.int32),
             "act": act,
         }
@@ -3462,13 +3620,19 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                     pc = (nb, z64, z64, z64, z64)
                 else:
                     pc = ((), (), (), (), ())
+                # the send and record channels are [F, N] under a fan-out
+                n_f = p_lane.sends_per_pop
+                fan = (n_f, p.n_lanes) if n_f > 1 else (p.n_lanes,)
+                fb = jnp.zeros(fan, dtype=bool)
+                f32 = jnp.zeros(fan, dtype=jnp.int32)
+                f64 = jnp.zeros(fan, dtype=jnp.int64)
                 return st_, _SlotEmit(
                     nb, z32, z32, z32, z32, z32, z32, z32,
                     nb, z32, z32, z32, z32, z32, z32,
-                    nb, z32, z32, z32, z32, z32, z32, z32, z32,
+                    fb, f32, f32, f32, f32, f32, f32, f32, f32,
                     *se, *sa, *bo, *srec, *brec, *spc, *bpc,
                     *pc,
-                    nb, z64, z64, z64, z64, z64, z64,
+                    fb, f64, f64, f64, f64, f64, f64,
                     _ft_dead(p_lane),
                 )
 
@@ -3518,7 +3682,7 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                 return _merge_append(p, tb, st, emits)
 
             def do_sort(st: LaneState) -> LaneState:
-                return _sort_queues(st, with_pay=p_lane.stream_present)
+                return _sort_queues(st, with_pay=p_lane.lanes_have_payload)
 
             s = lax.cond(any_new, do_merge, do_sort, s)
 
@@ -3849,7 +4013,7 @@ def pack_state(s: LaneState):
         [jnp.asarray(getattr(s, f), dtype=jnp.int32) for f in sc_fields]
     )
     return (q, c32, sc, s.log, s.stream, s.egress, s.nb_hist, s.fl_buf,
-            s.peaks, s.copop_wide_pops)
+            s.peaks, s.copop_wide_pops, s.gossip)
 
 
 def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
@@ -3865,7 +4029,7 @@ def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
 
 def unpack_state(carry) -> LaneState:
     (q, c32, sc, log, stream, egress, nb_hist, fl_buf, peaks,
-     copop_wide_pops) = carry
+     copop_wide_pops, gossip) = carry
     has_pay = q.shape[0] == 7
     # the optional blocks' own carry leaves say which are live; the append
     # counters have none, so the scalar count left over tells
@@ -3888,7 +4052,7 @@ def unpack_state(carry) -> LaneState:
         stream=stream,
         cd_dropping=c32[len(_I32_N_FIELDS)].astype(bool),
         log=log, egress=egress, nb_hist=nb_hist, fl_buf=fl_buf,
-        peaks=peaks, copop_wide_pops=copop_wide_pops, **kw,
+        peaks=peaks, copop_wide_pops=copop_wide_pops, gossip=gossip, **kw,
     )
 
 
@@ -4050,7 +4214,7 @@ def _inject_merge(p: LaneParams, tb: LaneTables, s: LaneState, inj):
     mh = jnp.concatenate([s.q_auxh, cross_auxh], axis=1)
     ml = jnp.concatenate([s.q_auxl, cross_auxl], axis=1)
     ms = jnp.concatenate([s.q_size, cross_size], axis=1)
-    if p.stream_present:
+    if p.lanes_have_payload:
         zpad = jnp.zeros((n, cxi), dtype=jnp.int32)
         mphi = jnp.concatenate([s.q_phi, zpad], axis=1)
         mplo = jnp.concatenate([s.q_plo, zpad], axis=1)

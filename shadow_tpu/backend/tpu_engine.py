@@ -21,6 +21,7 @@ from ..config.options import ConfigOptions
 from ..core import rng as _rng
 from ..core import time as stime
 from ..models.base import create_model
+from ..models.gossip import Gossip, gossip_mesh
 from ..models.phold import Phold
 from ..models.tcpflow import StreamClient, StreamServer
 from ..models.tgen import Ping, TgenClient, TgenMesh, TgenServer
@@ -142,6 +143,10 @@ class TpuEngine:
         local_seq0 = np.ones(n, dtype=np.int64)
 
         recv_mult = np.zeros(n, dtype=np.int32)
+        # gossip lanes (models/gossip.py): each one's row of its mesh, and
+        # the most message ids any of them can meet (the seen bitmap's bits)
+        g_rows: dict[int, np.ndarray] = {}
+        g_messages = 0
 
         def assign_tgen(hid: int, a) -> None:
             """One source of truth for tgen model/table assignment —
@@ -237,6 +242,24 @@ class TpuEngine:
                 for i in range(app.messages):
                     init_events.append((hid, t0, lanes.LOCAL, hid, i, 0))
                 local_seq0[hid] = max(app.messages, 1)
+            elif isinstance(app, Gossip):
+                model[hid] = lanes.M_GOSSIP
+                p_size[hid] = app.size
+                g_rows[hid] = gossip_mesh(n, app.degree, app.mesh_seed)[hid]
+                g_messages = max(g_messages, len(app.bursts) * app.messages)
+                # the start marker, then one publish timer a publication
+                # in message order (the oracle's on_start arms them so);
+                # the message id rides the LOCAL's size word
+                init_events.append((hid, t0, lanes.LOCAL, hid, 0, -1))
+                pubs = app.publications(hid, n)
+                for i, (t, m) in enumerate(pubs):
+                    if t <= t0:
+                        raise LaneCompatError(
+                            f"host {hopt.hostname!r}: gossip burst at {t} "
+                            f"ns is not after the process start ({t0} ns)"
+                        )
+                    init_events.append((hid, t, lanes.LOCAL, hid, 1 + i, m))
+                local_seq0[hid] = 1 + len(pubs)
             elif isinstance(app, (TgenMesh, TgenClient, TgenServer)):
                 assign_tgen(hid, app)
                 init_events.append((hid, t0, lanes.LOCAL, hid, 0, -1))
@@ -401,6 +424,20 @@ class TpuEngine:
         # records through their compacted channels at departure, and both
         # backends synthesize stream bodies from sizes alone
 
+        g_degrees = {len(row) for row in g_rows.values()}
+        if len(g_degrees) > 1:
+            raise LaneCompatError(
+                f"gossip lanes of different degrees {sorted(g_degrees)}: the "
+                "sends of a pop are one static width; use the cpu backend"
+            )
+        if g_rows and (client_ids.size or server_ids or pcap_any
+                       or self._flowtrace_on):
+            raise LaneCompatError(
+                "gossip lanes beside stream lanes, pcap capture or flowtrace "
+                "are not lane-compiled yet; use the cpu backend"
+            )
+        self._gossip_words = -(-max(g_messages, 1) // 32)
+
         ft_thresh, ft_all = ftr.sample_thresh(cfg.experimental.flowtrace_sample)
         self.params = lanes.LaneParams(
             n_lanes=n,
@@ -461,6 +498,7 @@ class TpuEngine:
             ),
             inject_batch=inject_batch if ext_mask.any() else 0,
             inject_cross=capacity if ext_mask.any() else 0,
+            gossip_degree=g_degrees.pop() if g_degrees else 0,
         )
 
         up = np.array([bucket_params(int(b)) for b in bw_up], dtype=np.int64)
@@ -621,6 +659,7 @@ class TpuEngine:
             lane_stream=(
                 jnp.asarray(np.isin(np.arange(n), el_np)) if tiered else ()
             ),
+            g_peers=self._gossip_peers(g_rows, n),
         )
         self._local_seq0 = local_seq0
         self._model_np = model  # [N] app model per lane (collect's masks)
@@ -667,6 +706,17 @@ class TpuEngine:
 
     def _resolve(self, hostname: str, n: int) -> int:
         return self.dns.resolve(hostname)
+
+    @staticmethod
+    def _gossip_peers(g_rows: dict, n: int):
+        """``LaneTables.g_peers``: the ``[N, D]`` peer table (a lane of
+        another model keeps a row of zeros it never reads), or () where no
+        lane runs gossip."""
+        if not g_rows:
+            return ()
+        peers = np.zeros((n, len(next(iter(g_rows.values())))), np.int32)
+        peers[list(g_rows)] = np.stack(list(g_rows.values()))
+        return jnp.asarray(peers)
 
     # -- multi-chip plane (parallel/mesh.py) -------------------------------
 
@@ -1008,6 +1058,10 @@ class TpuEngine:
             },
             peaks=() if p.all_passive else full(3),
             copop_wide_pops=full() if p.copop_inert else (),
+            gossip=lanes.GossipState(
+                seen=full((n, self._gossip_words)), sends=lane(),
+                first=lane(), dups=lane(), last_hi=lane(), last_lo=lane(),
+            ) if p.gossip_degree else (),
         )
         # ONE transfer of the whole tree, straight onto its placement: no
         # eager device program, no whole copy on one chip before sharding
@@ -1456,6 +1510,8 @@ class TpuEngine:
             fields.append("peaks")
         if p.copop_inert:
             fields.append("copop_wide_pops")
+        if p.gossip_degree:
+            fields.append("gossip")
         if p.netobs:
             fields += ["nb_txb", "nb_rxb", "nb_thr", "nb_shed", "nb_hist",
                        "nb_win"]
@@ -1515,6 +1571,8 @@ class TpuEngine:
             "queue_capacity": p.capacity,
             "cross_capacity": p.cross_cap,
             "pops_per_iter": p.pops_per_iter,
+            # the [N] send channel's width: datagrams one pop may send
+            "sends_per_pop": p.sends_per_pop,
         }
         queue_peak = cross_peak = n_cross = 0
         if not p.all_passive:
@@ -1634,6 +1692,21 @@ class TpuEngine:
         tgen_mask = np.isin(model, [lanes.M_TGEN_MESH, lanes.M_TGEN_CLIENT, lanes.M_TGEN_SERVER])
         add("tgen_recv_bytes", int(s.recv_bytes[tgen_mask].sum()))
         add("phold_hops", int(s.n_hops[model == lanes.M_PHOLD].sum()))
+        if p.gossip_degree:
+            g = s.gossip
+            add("gossip_sends", int(g.sends.sum()))
+            add("gossip_first", int(g.first.sum()))
+            add("gossip_duplicates", int(g.dups.sum()))
+            # the time the last node first saw a message: how long the
+            # run's floods took to cross the mesh (0: nothing delivered)
+            last = (g.last_hi.astype(np.int64) << 31) | g.last_lo
+            self.lane_plane.update(
+                gossip_degree=p.gossip_degree,
+                gossip_last_first_ns=int(last.max(initial=0)),
+            )
+            if self.obs is not None:
+                for key in ("gossip_degree", "gossip_last_first_ns"):
+                    self.obs.metrics.gauge(key, self.lane_plane[key])
         add("lane_iters", int(s.iters))
         add("lane_delivered",
             int(s.n_delivered.sum()) + tier_sum(lstr_mod.TV_N_DEL))

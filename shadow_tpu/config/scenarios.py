@@ -1,6 +1,6 @@
 """Scenario factories: managed-process scenarios (real OS binaries under
-the shim), the routed, lossy all-TCP network and the PHOLD mesh (lane
-models only).
+the shim), the routed, lossy all-TCP network, the PHOLD mesh and the
+gossip mesh (lane models only).
 
 The BASELINE.md evaluation ladder's config #5 is a Tor-shaped relay
 topology (the reference's 500-relay chutney networks,
@@ -27,6 +27,9 @@ import math
 import random
 from pathlib import Path
 
+import numpy as np
+
+from . import units
 from .options import ConfigOptions
 
 REPO = Path(__file__).resolve().parents[2]
@@ -406,6 +409,119 @@ def phold_mesh_config(
             "processes": [{
                 "path": "phold",
                 "args": ["--messages", str(messages), "--size", str(size)],
+                "start_time": "0 s",
+            }],
+        }},
+    })
+
+
+# -- gossip: one pop, D sends, a whole-network same-instant burst ------------
+
+#: pops an iteration of a gossip program: every other deployment's (the
+#: exchange is ``pops x degree x lanes`` rows wide)
+GOSSIP_POPS = 2
+
+
+def gossip_flood_hops(n_hosts: int, degree: int) -> int:
+    """Hops a flood is budgeted to last: twice the depth of a
+    ``(degree - 1)``-ary tree over the nodes, plus two (a ring: half way
+    round).  Bursts closer together than this many link latencies count as
+    ONE burst in the shape law."""
+    if degree <= 2:
+        return n_hosts // 2 + 1
+    return 2 * math.ceil(math.log(n_hosts) / math.log(degree - 1)) + 2
+
+
+def gossip_shape_law(
+    degree: int, concurrent: int, publications: int = 1,
+    pops: int = GOSSIP_POPS,
+) -> tuple[int, int]:
+    """``(tpu_lane_queue_capacity, tpu_cross_capacity)`` for a gossip mesh
+    of ``degree`` peers a node with at most ``concurrent`` messages in
+    flight at once, some node publishing ``publications`` times in all.
+
+    The bounds are DETERMINISTIC, not quantiles: a node is sent each
+    message at most once by each of its ``degree`` peers (a peer forwards
+    a message once, on its first receipt), so
+
+    - a CROSS segment — one iteration's fan-in — holds at most ``degree x
+      pops`` events: each peer pops ``pops`` events and sends this lane at
+      most one datagram for each;
+    - a QUEUE holds at most ``degree x concurrent`` arrivals (each a
+      PACKET, then the DELIVERY it becomes), beside its own start marker
+      and ``publications`` publish timers, plus the engine's headroom.
+
+    As PHOLD's law, the merge's row ``capacity + 2 pops + cross`` pads to
+    a power of two and the columns left under it go to the queue.  Strict
+    capacity is the backstop: bursts closer than the law budgets (see
+    :func:`gossip_flood_hops`) raise and name the block."""
+    if min(degree, concurrent, pops) < 1 or publications < 0:
+        raise ValueError(
+            "degree, concurrent and pops must be >= 1, publications >= 0")
+    cross = degree * pops
+    queue = degree * concurrent + 1 + publications + QUEUE_HEADROOM
+    row = queue + 2 * pops + cross
+    return (1 << (row - 1).bit_length()) - 2 * pops - cross, cross
+
+
+def gossip_mesh_config(
+    n_hosts: int,
+    degree: int = 8,
+    mesh_seed: int = 1,
+    bursts=("1 s", "5 s", "9 s"),
+    messages: int = 8,
+    size: int = 512,
+    latency: str = "10 ms",
+    bandwidth: str = "1 Gbit",
+    seed: int = 1,
+) -> ConfigOptions:
+    """Ethereum-style gossip (libp2p gossipsub's eager push over a static
+    mesh, ``models/gossip.py``): ``n_hosts`` nodes on one graph node, each
+    ONE process ``gossip`` with one argument list — every node finds its
+    row of ``gossip_mesh(n_hosts, degree, mesh_seed)`` and its own
+    publications by its host id, so there is no per-host document.  At
+    each instant of ``bursts`` (times after the start at 0 s) ``messages``
+    distinct nodes publish one ``size``-byte message each; self-edge
+    ``latency`` (the lookahead), ``bandwidth`` up and down, zero loss.
+
+    The lane program's shapes are :func:`gossip_shape_law`'s; stop time
+    and backend (``tpu``) are the caller's to set on the result."""
+    from ..models.gossip import gossip_publishers
+
+    times = sorted(units.parse_time(b) for b in bursts)
+    span = gossip_flood_hops(n_hosts, degree) * units.parse_time(latency)
+    concurrent = messages * max(
+        sum(1 for u in times if t <= u < t + span) for t in times
+    )
+    pubs = gossip_publishers(n_hosts, len(times), messages, mesh_seed)
+    queue, cross = gossip_shape_law(
+        degree, concurrent,
+        publications=int(np.bincount(pubs.reshape(-1)).max()),
+    )
+    return ConfigOptions.from_dict({
+        "general": {"stop_time": "12 s", "seed": seed,
+                    "heartbeat_interval": None},
+        "network": {"graph": {"type": "gml", "inline": (
+            "graph [\n"
+            f'  node [ id 0 host_bandwidth_up "{bandwidth}" '
+            f'host_bandwidth_down "{bandwidth}" ]\n'
+            f'  edge [ source 0 target 0 latency "{latency}" ]\n'
+            "]\n")}},
+        "experimental": {
+            "network_backend": "tpu",
+            "tpu_lane_queue_capacity": queue,
+            "tpu_cross_capacity": cross,
+            "tpu_events_per_round": GOSSIP_POPS,
+        },
+        "hosts": {"node": {
+            "count": n_hosts, "network_node_id": 0,
+            "processes": [{
+                "path": "gossip",
+                "args": [
+                    "--degree", str(degree), "--mesh-seed", str(mesh_seed),
+                    "--bursts", ",".join(f"{t} ns" for t in times),
+                    "--messages", str(messages), "--size", str(size),
+                ],
                 "start_time": "0 s",
             }],
         }},

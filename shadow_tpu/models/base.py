@@ -11,7 +11,7 @@ A model reacts to three stimuli, always at a definite simulation time:
 
 - ``on_start(api)``        — process start (config ``start_time``)
 - ``on_timer(api, t)``     — a timer it armed fired
-- ``on_delivery(api, t, src, seq, size)`` — a datagram arrived
+- ``on_delivery(api, t, src, seq, size, payload=None)`` — a datagram arrived
 
 and acts through the :class:`HostApi`: ``send``, ``set_timer``,
 ``rand_u32`` (deterministic APP_STREAM draws), and counters.
@@ -28,9 +28,13 @@ class HostApi(Protocol):
     host_id: int
     num_hosts: int
 
-    def send(self, dst: int, size_bytes: int) -> int:
+    def send(self, dst: int, size_bytes: int, payload: object = None) -> int:
         """Send a datagram (IP size incl. 28 header bytes) at current time;
-        returns its per-host sequence number."""
+        returns its per-host sequence number.  ``payload`` is opaque cargo
+        handed to the receiver's ``on_delivery(..., payload=)``: it never
+        affects event ordering or the event log, which record sizes only
+        (``gossip`` rides its message id here; on the lane backend the
+        payload is one int32 word of the queue row)."""
 
     def set_timer(self, t_abs_ns: int) -> None:
         """Arm a timer local event at absolute sim time."""

@@ -66,6 +66,10 @@ LANE_FIELDS = frozenset((
     # netobs per-host counter block (PR 10): [N] int32 counters travel
     # with their lanes; collect() gathers them for the oracle diff
     "nb_txb", "nb_rxb", "nb_thr", "nb_shed",
+    # the gossip model's per-lane block (lanes.GossipState: the seen
+    # bitmap, three counters, the last first-delivery): every leaf leads
+    # with the lane axis
+    "gossip",
 ))
 
 # LaneState fields that replicate.  The stream matrices are COMPACTED per
