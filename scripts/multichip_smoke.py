@@ -166,8 +166,9 @@ def main() -> int:
         assert r2.log_tuples() == r0.log_tuples(), (
             "hybrid event log diverges under the mesh"
         )
-        keys = ("device_turns", "inject_blocks", "inject_rows",
-                "inject_bytes", "egress_reads", "egress_rows",
+        keys = ("device_turns", "scalar_reads", "h2d_copies",
+                "inject_blocks", "inject_rows", "inject_bytes",
+                "egress_head_reads", "egress_reads", "egress_rows",
                 "egress_bytes")
         a, b = dict(s0.engine.sync_stats), dict(s2.engine.sync_stats)
         for k in keys:

@@ -39,8 +39,9 @@ Two engines drive that seam:
   which its 6.38x headline used at parallelism 16) and run their syscall
   plane concurrently, while the parent owns the device and the window
   law.  Staged sends and egressed deliveries ride the worker pipes at
-  round barriers, so the host<->device boundary stays one injection
-  block + one egress drain per device turn regardless of worker count.
+  round barriers, so the host<->device boundary stays one packed block
+  in + one packed readback out per device turn regardless of worker
+  count.
 
 Event ordering is worker-count-invariant by construction: event queues
 order by the total (time, kind, src, seq) key, injection decomposition is
@@ -92,11 +93,12 @@ log = logging.getLogger("shadow_tpu.hybrid")
 # turn's own residual: scalar decode, the validation walk's Python,
 # ledger calls, rollback bookkeeping.
 TURN_PHASES = (
-    "inject",           # _build_inj: packing, jnp.array copies, overflow
-    "peek",             # _fuse_depth, _peek_ext_times, _ext_pairs
-    "dispatch",         # fused_fn(...) until it RETURNS (and the eager one)
+    "inject",           # _build_inj: host packing (overflow blocks ship)
+    "peek",             # _fuse_depth, _peek_ext_times, _pack_schedule
+    "dispatch",         # fused_fn(state, block) until it RETURNS, the
+                        # block's ONE transfer in it (and the eager one)
     "device_wait",      # the blocking device_get: device_sync_s
-    "egress_read",      # _read_egress: the slice, D2H, .tolist()
+    "egress_read",      # _read_egress: the head's rows; a tail's slice, D2H
     "egress_apply",     # every _apply_egress call
     "service_ship",     # _mp_round's send leg
     "service_collect",  # its receive leg; the serial engine's whole round
@@ -442,9 +444,9 @@ class HybridEngine(_HostSideHybrid):
     """CpuEngine for the external (managed) hosts; TPU lanes for the rest.
 
     Owns the device, the window law, and the batched host<->device
-    boundary: one injection block in, one packed scalar vector + one
-    egress drain out per device turn (``sync_stats`` records the exact
-    transfer counts/bytes)."""
+    boundary: one packed block in (``lanes.TurnBlock``), one packed
+    vector out — the scalars and the egress buffer's head — per device
+    turn (``sync_stats`` records the exact transfer counts/bytes)."""
 
     def __init__(
         self, cfg: ConfigOptions, log_capacity: Optional[int] = None
@@ -470,11 +472,6 @@ class HybridEngine(_HostSideHybrid):
         # popped when the device egresses the delivery
         self._parked: dict = {}
         self._dev_min_used: Optional[int] = None
-        # reused host-side injection staging buffers (allocated once) and
-        # the cached device-resident empty block: turns that stage nothing
-        # (mid-window egress-drain retries) transfer nothing
-        self._inj_np = None
-        self._empty_inj = None
         # host<->device sync-cost accounting (docs/hybrid.md): cheap
         # Python counters, always on; perf_logging surfaces them per
         # window through PerfLog.hybrid_agg
@@ -484,13 +481,16 @@ class HybridEngine(_HostSideHybrid):
             # keeps them equal to their phases' sums
             "device_sync_s": 0.0,   # blocking scalar-readback wall time
             "syscall_service_s": 0.0,  # host-side window execution wall
-            "scalar_reads": 0,      # D2H transfers: packed scalar vectors
-            "inject_blocks": 0,     # H2D transfers: injection blocks
+            "scalar_reads": 0,      # D2H transfers: packed scalars + head
+            "h2d_copies": 0,        # H2D transfers: turn blocks, one per
+                                    # fused call and per overflow block
+            "inject_blocks": 0,     # of them, blocks that carried rows
             "inject_rows": 0,       # staged sends carried by those blocks
-            "inject_bytes": 0,      # H2D bytes (7 arrays x B rows)
-            "egress_reads": 0,      # D2H transfers: egress buffer slices
-            "egress_rows": 0,       # delivery rows carried by those reads
-            "egress_bytes": 0,      # D2H bytes (padded [span, 6] int64)
+            "inject_bytes": 0,      # H2D bytes (int32[W] a copy)
+            "egress_head_reads": 0,  # egress drains the readback's head held
+            "egress_reads": 0,      # D2H transfers: egress TAIL slices
+            "egress_rows": 0,       # delivery rows either way
+            "egress_bytes": 0,      # D2H bytes (every head, padded tails)
             # k-window fusion + async dispatch (docs/hybrid.md):
             "fused_dispatches": 0,  # dispatches covering >= 2 validated windows
             "fused_windows": 0,     # validated windows those covered
@@ -562,6 +562,15 @@ class HybridEngine(_HostSideHybrid):
         # peeked-schedule width: enough slots that multi-event windows do
         # not exhaust the schedule mid-span (last slot = the horizon)
         self._ext_slots = max(2 * self._fuse_k, 9)
+        # the turn's one host->device block and its two reused staging
+        # arrays: the turn's own (a rollback's rebuild re-ships it with
+        # the validated depth) and the eager dispatch's, whose injection
+        # part stays empty and which the turn's packing never touches
+        self._lay = lanes.TurnBlock(
+            self.device.params.inject_batch, self._ext_slots
+        )
+        self._turn_np = self._lay.empty()
+        self._eager_np = self._lay.empty()
         self._fuse_we_final = None  # covered-round validation range end
         self._round_clean = True    # set by _service_round/_mp_round
         self._eager = None          # double-buffered speculative dispatch
@@ -634,30 +643,17 @@ class HybridEngine(_HostSideHybrid):
 
     # -- device turn --------------------------------------------------------
 
-    def _inj_block(self, staged, b: int):
-        """Pack staged sends into the fixed-size injection block, reusing
-        the host-side staging arrays across turns (one H2D transfer per
-        block; payloads are parked here, keyed (src, seq))."""
-        import jax.numpy as jnp
-
-        if self._inj_np is None:
-            self._inj_np = {
-                "valid": np.zeros(b, dtype=bool),
-                "dst": np.zeros(b, dtype=np.int32),
-                "thi": np.full(b, lanes.NEVER32, dtype=np.int32),
-                "tlo": np.full(b, lanes.NEVER32, dtype=np.int32),
-                "auxh": np.zeros(b, dtype=np.int32),
-                "auxl": np.zeros(b, dtype=np.int32),
-                "size": np.zeros(b, dtype=np.int32),
-            }
-        buf = self._inj_np
-        buf["valid"][:] = False
-        buf["thi"][:] = lanes.NEVER32
-        buf["tlo"][:] = lanes.NEVER32
+    def _inj_block(self, staged) -> None:
+        """Pack staged sends (at most ``inject_batch``) into the turn
+        block's injection columns (payloads are parked here, keyed
+        (src, seq))."""
+        block = self._turn_np
+        self._lay.clear(block)
+        buf = self._lay.columns(block)
         for i, (arr, src, seq, sz, d, payload) in enumerate(staged):
             if payload is not None:
                 self._parked[(src, seq)] = payload
-            buf["valid"][i] = True
+            buf["valid"][i] = 1
             buf["dst"][i] = d
             buf["thi"][i] = arr >> 31
             buf["tlo"][i] = arr & lanes.MASK31
@@ -666,38 +662,32 @@ class HybridEngine(_HostSideHybrid):
             )
             buf["auxl"][i] = seq
             buf["size"][i] = sz
+        if staged:
+            st = self.sync_stats
+            st["inject_blocks"] += 1
+            st["inject_rows"] += len(staged)
+
+    def _ship(self, block):
+        """A turn block on its way to the device: the ONE array a fused
+        call (or an overflow merge) is handed, and the turn's one
+        host->device transfer.  A host COPY, because the staging arrays
+        are repacked while the previous dispatch may still be reading;
+        handed over as numpy, because the jitted call's own argument path
+        is the cheapest of the three ways to place it on the chip
+        (PERF.md section 6, PR 40: ``jnp.array`` first costs ~0.4 ms
+        more, ``jax.device_put`` ~0.15)."""
         st = self.sync_stats
-        st["inject_blocks"] += 1
-        st["inject_rows"] += len(staged)
-        st["inject_bytes"] += b * (1 + 6 * 4)
-        # jnp.array COPIES (asarray may zero-copy-alias the numpy buffer
-        # on the CPU backend, and the overflow path repacks these same
-        # buffers while the previous block's dispatch is still in flight)
-        return {k: jnp.array(v) for k, v in buf.items()}
+        st["h2d_copies"] += 1
+        st["inject_bytes"] += block.nbytes
+        return block.copy()
 
-    def _empty_block(self):
-        """The no-op injection block, built on device ONCE: egress-drain
-        retries and zero-staged turns re-use it without any H2D hop."""
-        if self._empty_inj is None:
-            import jax.numpy as jnp
-
-            b = self.device.params.inject_batch
-            self._empty_inj = {
-                "valid": jnp.zeros(b, dtype=bool),
-                "dst": jnp.zeros(b, dtype=jnp.int32),
-                "thi": jnp.full(b, lanes.NEVER32, dtype=jnp.int32),
-                "tlo": jnp.full(b, lanes.NEVER32, dtype=jnp.int32),
-                "auxh": jnp.zeros(b, dtype=jnp.int32),
-                "auxl": jnp.zeros(b, dtype=jnp.int32),
-                "size": jnp.zeros(b, dtype=jnp.int32),
-            }
-        return self._empty_inj
-
-    def _read_egress(self, state, count: int, lost: int) -> list:
-        """The egress readback, the ``egress_read`` span: the D2H read
-        alone — the fused walk applies deliveries lazily per validated
-        window (``egress_apply``).  Empty egress is no read and no
-        span."""
+    def _read_egress(self, state, count: int, lost: int, head) -> list:
+        """The turn's egress rows, the ``egress_read`` span: ``head`` is
+        the buffer's first rows as the scalar readback brought them
+        (``lanes.hyb_egress_rows``); only a turn that egressed more reads
+        the device again, for the tail alone.  The fused walk applies
+        deliveries lazily per validated window (``egress_apply``).  Empty
+        egress is no read and no span."""
         if lost:
             raise RuntimeError(
                 "hybrid egress buffer overflowed despite the headroom "
@@ -706,54 +696,61 @@ class HybridEngine(_HostSideHybrid):
         if count == 0:
             return []
         with self.clock.span("egress_read", count):
-            # pad the slice length to a power of two: distinct slice
-            # sizes compile distinct device programs, so this caps churn
-            # at log2(E)
-            cap = self.device.params.egress_capacity
-            span = 1
-            while span < count:
-                span <<= 1
-            span = min(span, cap)
             st = self.sync_stats
-            st["egress_reads"] += 1
             st["egress_rows"] += count
-            st["egress_bytes"] += span * 6 * 8
-            rows = np.asarray(state.egress[:span])[:count].tolist()
+            n_head = len(head)
+            rows = head[:count].tolist()
+            if count <= n_head:
+                st["egress_head_reads"] += 1
+            else:
+                # pad the slice's end to a power of two: distinct slice
+                # sizes compile distinct device programs, so this caps
+                # churn at log2(E)
+                span = 1
+                while span < count:
+                    span <<= 1
+                span = min(span, self.device.params.egress_capacity)
+                st["egress_reads"] += 1
+                st["egress_bytes"] += (span - n_head) * 6 * 8
+                rows += np.asarray(
+                    state.egress[n_head:span]
+                )[:count - n_head].tolist()
         self.clock.add("egress_rows", count)
         if self.obs is not None:
             self.obs.metrics.count("egress_rows", count)
         return rows
 
     def _build_inj(self, staged, inject_fn, state):
-        """Pack staged sends into the injection block.  Oversized
-        staging: overflow blocks dispatch eagerly — JAX's async dispatch
-        overlaps their H2D + queue merge with the host-side packing of
-        the next block.  The ``inject`` span covers packing + dispatch;
-        the transfer itself overlaps the device call."""
+        """Pack staged sends into the turn block's injection columns:
+        the ``inject`` span, host work alone (the block's copy is the
+        dispatch's).  Oversized staging: overflow blocks ship and
+        dispatch eagerly — JAX's async dispatch overlaps their H2D +
+        queue merge with the host-side packing of the next block."""
         b = self.device.params.inject_batch
         n_staged = len(staged)
         with self.clock.span("inject", n_staged):
             while len(staged) > b:
-                state = inject_fn(state, self._inj_block(staged[:b], b))
+                self._inj_block(staged[:b])
+                state = inject_fn(state, self._ship(self._turn_np))
                 staged = staged[b:]
-            inj = (
-                self._inj_block(staged, b) if staged else self._empty_block()
-            )
-        return state, inj, n_staged
+            self._inj_block(staged)
+        return state, n_staged
 
     # -- k-window fused turns (docs/hybrid.md "k-window fusion law") ---------
 
-    def _ext_pairs(self, times):
-        """Encode a peeked-time schedule as device (hi, lo) int32 pairs
-        (NEVER maps to the (NEVER32, NEVER32) sentinel pair)."""
-        import jax.numpy as jnp
-
+    def _pack_schedule(self, block, times, used_enc: int,
+                       k_eff: int) -> None:
+        """Write a peeked-time schedule into ``block`` as (hi, lo) int32
+        words (NEVER maps to the (NEVER32, NEVER32) sentinel pair), and
+        the dynamic-runahead fold and the depth beside it."""
+        lay = self._lay
         t = np.asarray(times, dtype=np.int64)
         inf = t >= NEVER
-        hi = np.where(inf, lanes.NEVER32, t >> 31).astype(np.int32)
-        lo = np.where(inf, lanes.NEVER32, t & lanes.MASK31).astype(np.int32)
-        # jnp.array COPIES (same aliasing hazard as _inj_block)
-        return jnp.array(hi), jnp.array(lo)
+        hi, lo = lay.schedule(block)
+        hi[:] = np.where(inf, lanes.NEVER32, t >> 31)
+        lo[:] = np.where(inf, lanes.NEVER32, t & lanes.MASK31)
+        block[lay.used_at] = used_enc
+        block[lay.k_at] = k_eff
 
     def _peek_ext_times(self, floor_t: int = 0) -> list:
         """The fused dispatch's external-event schedule: the next
@@ -825,24 +822,23 @@ class HybridEngine(_HostSideHybrid):
                     "state": None, "sc": None,
                 }
                 return
-            ehi, elo = self._ext_pairs(ext)
+            self._pack_schedule(self._eager_np, ext, used_enc, k_eff)
         with clock.span("dispatch"):
-            state2, scalars = fused_fn(
-                state, ehi, elo, used_enc, self._empty_block(),
-                np.int32(k_eff),
-            )
+            state2, scalars = fused_fn(state, self._ship(self._eager_np))
         self._eager = {
             "base": state, "ext": ext, "used": used_enc, "k": k_eff,
             "state": state2, "sc": scalars,
         }
 
-    def _dispatch_fused(self, state, fused_fn, ext, pairs, used_enc, inj,
+    def _dispatch_fused(self, state, fused_fn, ext, used_enc,
                         n_staged: int, k_eff: int):
         """Dispatch (or adopt the eagerly dispatched) fused device call
-        and block on its packed readback.  Adoption requires the real
-        inputs to equal the speculated ones bit-exact: same base state
-        object, same peeked schedule (``ext``; ``pairs`` is its device
-        encoding), same dynamic-runahead fold, and an empty injection — then the eager result IS the dispatch result
+        and block on its packed readback.  The turn block holds the
+        inputs; ``ext``, ``used_enc``, ``n_staged`` and ``k_eff`` are
+        their HOST values, which adoption compares: it requires the real
+        inputs to equal the speculated ones bit-exact — same base state
+        object, same peeked schedule, same dynamic-runahead fold, and an
+        empty injection — then the eager result IS the dispatch result
         by functional purity, and the readback blocks only for whatever
         device compute the overlapped syscall servicing did not hide (its
         ``dispatch`` was booked to the turn that issued it).  Returns
@@ -869,19 +865,18 @@ class HybridEngine(_HostSideHybrid):
                 st["async_dispatch_misses"] += 1
         if scalars is None:
             with clock.span("dispatch"):
-                state2, scalars = fused_fn(
-                    state, *pairs, used_enc, inj, np.int32(k_eff)
-                )
+                state2, scalars = fused_fn(state, self._ship(self._turn_np))
         with clock.span("device_wait") as wait:
             sc = jax.device_get(scalars)  # the one blocking readback
             wait.detail = int(sc[lanes.HYB_DEV_WE])
         clock.add("dispatches", 1)
         st["device_turns"] += 1
         st["scalar_reads"] += 1
+        st["egress_bytes"] += 6 * 8 * lanes.HYB_EGRESS_HEAD  # the head
         return state2, sc, wait
 
-    def _dispatch_retrying(self, checkpoint, fused_fn, ext, pairs, used_enc,
-                           inj, n_staged: int, k_eff: int):
+    def _dispatch_retrying(self, checkpoint, fused_fn, ext, used_enc,
+                           n_staged: int, k_eff: int):
         """The dispatch retry-with-backoff law (docs/robustness.md): a
         failed fused dispatch (device runtime error raised at dispatch or
         at the blocking readback) re-dispatches from the pre-turn device
@@ -897,8 +892,7 @@ class HybridEngine(_HostSideHybrid):
         while True:
             try:
                 return self._dispatch_fused(
-                    checkpoint, fused_fn, ext, pairs, used_enc, inj,
-                    n_staged, k_eff,
+                    checkpoint, fused_fn, ext, used_enc, n_staged, k_eff,
                 )
             except BackendStallError:
                 raise
@@ -929,8 +923,7 @@ class HybridEngine(_HostSideHybrid):
         one turn (``_turn``: one primary dispatch, with its rebuild if it
         rolls back), and one more for each mid-window egress-headroom
         pause — the device paused for room; covered rounds may have
-        staged, so the next turn repacks and resumes (the cached empty
-        block keeps a stage-free resume transfer-free).  Every turn is a
+        staged, so the next turn repacks and resumes.  Every turn is a
         turn of the clock and leaves one row.  Returns (state,
         dev_next)."""
         is_retry = False
@@ -980,21 +973,19 @@ class HybridEngine(_HostSideHybrid):
         turns = obs.turns if obs is not None else None
         staged = self._staged_merged
         self._staged_merged = []
-        state, inj, n_staged = self._build_inj(staged, inject_fn, state)
+        state, n_staged = self._build_inj(staged, inject_fn, state)
         prev_we = t_start
-        with clock.span("peek"):
-            k_eff = self._fuse_depth()
-            ext = self._peek_ext_times()
-            # encoded here, once: a rebuild dispatches the same pair (an
-            # adopted eager dispatch, ~1 turn in 100, does not use it)
-            pairs = self._ext_pairs(ext)
         used_enc = (
             lanes.NEVER32 if self._min_used_lat is None
             else self._min_used_lat
         )
+        with clock.span("peek"):
+            k_eff = self._fuse_depth()
+            ext = self._peek_ext_times()
+            self._pack_schedule(self._turn_np, ext, used_enc, k_eff)
         checkpoint = state
         state, sc, wait = self._dispatch_retrying(
-            state, fused_fn, ext, pairs, used_enc, inj, n_staged, k_eff
+            state, fused_fn, ext, used_enc, n_staged, k_eff
         )
         lane_min = int(sc[lanes.HYB_LANE_MIN])
         dev_we = int(sc[lanes.HYB_DEV_WE])
@@ -1028,7 +1019,8 @@ class HybridEngine(_HostSideHybrid):
                 )
         egress_count = int(sc[lanes.HYB_EGRESS_COUNT])
         rows = self._read_egress(
-            state, egress_count, int(sc[lanes.HYB_EGRESS_LOST])
+            state, egress_count, int(sc[lanes.HYB_EGRESS_LOST]),
+            lanes.hyb_egress_rows(sc, self._fuse_k),
         )
         retry = lane_min < dev_we  # mid-window egress-headroom pause
         if self._async_on and not retry and we_list:
@@ -1096,13 +1088,14 @@ class HybridEngine(_HostSideHybrid):
                 st["fused_dispatches"] += 1
                 st["fused_windows"] += w_valid
             st["turns_saved"] += w_valid - 2
-            # the rebuild dispatch goes through the same timed
-            # dispatch/readback bookkeeping as a primary dispatch and
-            # books to the same phases of this turn (the eager buffer
-            # was dropped above, so no adoption)
+            # the rebuild dispatch re-ships the turn's block (nothing
+            # has repacked it since) with the validated depth, and goes
+            # through the same timed dispatch/readback bookkeeping as a
+            # primary dispatch, booking to the same phases of this turn
+            # (the eager buffer was dropped above, so no adoption)
+            self._turn_np[self._lay.k_at] = w_valid
             state, sc_r, _wait = self._dispatch_retrying(
-                checkpoint, fused_fn, ext, pairs, used_enc, inj, n_staged,
-                w_valid,
+                checkpoint, fused_fn, ext, used_enc, n_staged, w_valid,
             )
             assert int(sc_r[lanes.HYB_K_DONE]) == w_valid, (
                 "fused prefix rebuild diverged from the original "
@@ -1128,7 +1121,8 @@ class HybridEngine(_HostSideHybrid):
             # device state still carries their packets in flight
             egr_r = int(sc_r[lanes.HYB_EGRESS_COUNT])
             rows_r = self._read_egress(
-                state, egr_r, int(sc_r[lanes.HYB_EGRESS_LOST])
+                state, egr_r, int(sc_r[lanes.HYB_EGRESS_LOST]),
+                lanes.hyb_egress_rows(sc_r, self._fuse_k),
             )
             late = [
                 r for r in rows_r

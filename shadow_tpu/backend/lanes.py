@@ -50,6 +50,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..core import rng as rng_mod
@@ -4258,16 +4259,115 @@ HYB_EGRESS_COUNT = 3
 HYB_EGRESS_LOST = 4
 HYB_K_DONE = 5
 HYB_WE_BASE = 6
+# ... and, behind the ``k_cap`` window ends, the first HYB_EGRESS_HEAD rows
+# of the egress buffer, flattened: the turn's deliveries ride the one
+# readback, and a second read fetches only what lies past the head.  The
+# smallest power of two that covers a turn's egress count in >= 99 % of
+# the turns of ``hybrid151_chains`` (PERF.md section 3 has the counts)
+HYB_EGRESS_HEAD = 128
+
+
+def hyb_egress_rows(scalars, k_cap: int):
+    """The egress head, ``[rows, 6]``, of a fused call's packed vector."""
+    return scalars[HYB_WE_BASE + k_cap:].reshape(-1, 6)
+
+
+class TurnBlock(NamedTuple):
+    """The layout of the ONE ``int32[width]`` block a hybrid turn carries
+    from host to device, known here and nowhere else: the seven injection
+    columns of ``inject_batch`` rows each (``valid`` as 0/1 words) lead
+    it, so their offsets depend on ``inject_batch`` alone; then the
+    peeked schedule's ``hi`` and ``lo`` words (``ext_slots`` each), then
+    ``ext_used`` and ``k_eff``.  The host fills a numpy block through
+    ``columns`` / ``schedule`` (views) and the two word offsets; the
+    device entry points slice the same block back with ``unpack`` /
+    ``injection``."""
+
+    inject_batch: int
+    ext_slots: int
+
+    COLUMNS = ("valid", "dst", "thi", "tlo", "auxh", "auxl", "size")
+
+    @property
+    def used_at(self) -> int:
+        return 7 * self.inject_batch + 2 * self.ext_slots
+
+    @property
+    def k_at(self) -> int:
+        return self.used_at + 1
+
+    @property
+    def width(self) -> int:
+        return self.used_at + 2
+
+    def columns(self, block) -> dict:
+        """The injection columns as slices of ``block`` (views of a numpy
+        block), all int32."""
+        b = self.inject_batch
+        return {
+            name: block[i * b:(i + 1) * b]
+            for i, name in enumerate(self.COLUMNS)
+        }
+
+    def schedule(self, block):
+        """The schedule's (hi, lo) words as slices of ``block``."""
+        o, e = 7 * self.inject_batch, self.ext_slots
+        return block[o:o + e], block[o + e:o + 2 * e]
+
+    def empty(self) -> np.ndarray:
+        """A host block that injects nothing and schedules nothing."""
+        block = np.zeros(self.width, dtype=np.int32)
+        self.clear(block)
+        hi, lo = self.schedule(block)
+        hi[:] = lo[:] = block[self.used_at] = NEVER32
+        return block
+
+    def clear(self, block) -> None:
+        """Empty a host block's injection part."""
+        cols = self.columns(block)
+        cols["valid"][:] = 0
+        cols["thi"][:] = cols["tlo"][:] = NEVER32
+
+    def pack(self, inj, ext_hi, ext_lo, ext_used, k_eff) -> np.ndarray:
+        """A fresh host block from the values ``unpack`` gives back."""
+        block = self.empty()
+        for name, col in self.columns(block).items():
+            col[:] = inj[name]
+        hi, lo = self.schedule(block)
+        hi[:], lo[:] = ext_hi, ext_lo
+        block[self.used_at], block[self.k_at] = ext_used, k_eff
+        return block
+
+    def injection(self, block) -> dict:
+        """The ``inj`` dict ``_inject_merge`` takes."""
+        inj = self.columns(block)
+        inj["valid"] = inj["valid"] != 0
+        return inj
+
+    def unpack(self, block):
+        """(inj, ext_thi, ext_tlo, ext_used, k_eff) of ``block``."""
+        hi, lo = self.schedule(block)
+        return (
+            self.injection(block), hi, lo,
+            block[self.used_at], block[self.k_at],
+        )
+
+
+def _build_inject(p: LaneParams, tb: LaneTables):
+    """The standalone injection merge (used when the host stages more
+    than one batch worth of sends between device turns): it takes the
+    turn's block (``TurnBlock``) and reads its injection part alone."""
+    lay = TurnBlock(p.inject_batch, 0)  # the columns lead the block
+
+    def inject(s: LaneState, turn_in):
+        return _inject_merge(p, tb, s, lay.injection(turn_in))
+
+    return inject
 
 
 def make_inject_fn(p: LaneParams, tb: LaneTables):
-    """Jitted standalone injection merge (used when the host stages more
-    than one batch worth of sends between device turns)."""
-
-    def inject(s: LaneState, inj):
-        return _inject_merge(p, tb, s, inj)
-
-    return jax.jit(inject)
+    """Jitted ``_build_inject``."""
+    return jax.jit(_build_inject(p, tb))
 
 
 def _build_hybrid_fused_run(p: LaneParams, tb: LaneTables, k_cap: int,
@@ -4292,7 +4392,9 @@ def _build_hybrid_fused_run(p: LaneParams, tb: LaneTables, k_cap: int,
     prefix, which reproduces the prefix bit-identically).  Also returns
     early when the egress buffer runs low on headroom.
 
-    ``ext_times`` ([ext_slots] int32 hi/lo pairs, ascending) carries the
+    ``turn_in`` is the turn's ONE host-to-device block (``TurnBlock``):
+    the injection columns, the schedule, ``ext_used`` and ``k_eff``.  Its
+    schedule ([ext_slots] int32 hi/lo pairs, ascending) carries the
     host side's next distinct event times; the LAST slot is the
     **horizon** — the first external time the schedule does NOT cover
     (NEVER when the schedule is exhaustive).  Participation at or past
@@ -4304,16 +4406,20 @@ def _build_hybrid_fused_run(p: LaneParams, tb: LaneTables, k_cap: int,
     keeps bounding the window law exactly as the oracle's DELIVERY event
     would.
 
-    Returns (state, scalars[6 + k_cap] int64): the HYB_* slots, the
-    consumed-window count (HYB_K_DONE), and the consumed window ends
-    (HYB_WE_BASE + i).  With ``k_eff = 1`` the call returns after the
-    FIRST window with external participation: one device call per host
-    sync (``hybrid_fuse_k: 1``, and every one-window rollback rebuild)."""
+    Returns (state, scalars[6 + k_cap + 6 * HYB_EGRESS_HEAD] int64): the
+    HYB_* slots, the consumed-window count (HYB_K_DONE), the consumed
+    window ends (HYB_WE_BASE + i) and the egress buffer's first
+    HYB_EGRESS_HEAD rows (the buffer holds at least 1 024), so that the
+    host's one blocking read brings the turn's deliveries.  With
+    ``k_eff = 1`` the call returns after the FIRST window with external
+    participation: one device call per host sync (``hybrid_fuse_k: 1``,
+    and every one-window rollback rebuild)."""
     iter_fn = _build_iter(p, tb, pure_dataflow=True)
     stop_hi, stop_lo = p.stop_time >> 31, p.stop_time & MASK31
     room_floor = p.egress_capacity - p.ext_per_iter
     eg_idx = jnp.arange(p.egress_capacity, dtype=jnp.int32)
     never64 = (NEVER32 << 31) | NEVER32  # the (NEVER32, NEVER32) pair
+    lay = TurnBlock(p.inject_batch, ext_slots)
 
     def ext_bound(st, ext_hi, ext_lo):
         lt = pair_lt(ext_hi, ext_lo, st.egress_min_hi, st.egress_min_lo)
@@ -4337,15 +4443,11 @@ def _build_hybrid_fused_run(p: LaneParams, tb: LaneTables, k_cap: int,
             tmin & MASK31
         ).astype(jnp.int32)
 
-    def fused_run(s: LaneState, ext_thi, ext_tlo, ext_used, inj, k_eff):
-        ext_thi = jnp.asarray(ext_thi, dtype=jnp.int32)
-        ext_tlo = jnp.asarray(ext_tlo, dtype=jnp.int32)
-        k_eff = jnp.asarray(k_eff, dtype=jnp.int32)
+    def fused_run(s: LaneState, turn_in):
+        inj, ext_thi, ext_tlo, ext_used, k_eff = lay.unpack(turn_in)
         if p.dynamic_runahead:
             s = s._replace(
-                min_used_lat=jnp.minimum(
-                    s.min_used_lat, jnp.asarray(ext_used, dtype=jnp.int32)
-                )
+                min_used_lat=jnp.minimum(s.min_used_lat, ext_used)
             )
         # previous call's egress was consumed by the host
         s = s._replace(
@@ -4460,6 +4562,7 @@ def _build_hybrid_fused_run(p: LaneParams, tb: LaneTables, k_cap: int,
                 kd.astype(jnp.int64),
             ]),
             we_arr,
+            s.egress[:HYB_EGRESS_HEAD].reshape(-1),
         ])
         return s, scalars
 
@@ -4468,10 +4571,9 @@ def _build_hybrid_fused_run(p: LaneParams, tb: LaneTables, k_cap: int,
 
 def make_hybrid_fused_fn(p: LaneParams, tb: LaneTables, k_cap: int,
                          ext_slots: int):
-    """Jitted k-window fused hybrid device call: (state, ext_times_hi,
-    ext_times_lo, ext_used_lat, inject_block, k_eff) -> (state,
-    scalars[6 + k_cap] int64) — the HYB_* slots plus HYB_K_DONE and the
-    consumed window ends at HYB_WE_BASE + i.  ``k_cap`` and ``ext_slots``
-    are static (array widths); ``k_eff`` is a traced scalar, so varying
-    the per-dispatch fusion depth never recompiles."""
+    """Jitted k-window fused hybrid device call: (state, turn_block) ->
+    (state, scalars int64) — the HYB_* slots, HYB_K_DONE, the consumed
+    window ends at HYB_WE_BASE + i and the egress head.  ``k_cap`` and
+    ``ext_slots`` are static (array widths); ``k_eff`` is a word of the
+    block, so varying the per-dispatch fusion depth never recompiles."""
     return jax.jit(_build_hybrid_fused_run(p, tb, k_cap, ext_slots))

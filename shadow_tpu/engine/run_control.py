@@ -597,9 +597,11 @@ class PerfLog:
             f"device_sync_ns={int(s['device_sync_s'] * 1e9)} "
             f"syscall_service_ns={int(s['syscall_service_s'] * 1e9)} "
             f"scalar_reads={s['scalar_reads']} "
+            f"h2d_copies={s['h2d_copies']} "
             f"inject_blocks={s['inject_blocks']} "
             f"inject_rows={s['inject_rows']} "
             f"inject_bytes={s['inject_bytes']} "
+            f"egress_head_reads={s['egress_head_reads']} "
             f"egress_reads={s['egress_reads']} "
             f"egress_rows={s['egress_rows']} "
             f"egress_bytes={s['egress_bytes']}"

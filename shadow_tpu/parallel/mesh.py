@@ -261,10 +261,10 @@ def make_sharded_hybrid_fns(
 ):
     """The hybrid backend's device entry points compiled under ``mesh``:
     ``(turn_fn, inject_fn)`` with the lane state sharded on the host axis
-    and everything at the host<->device boundary — the injection block,
-    the external-schedule scalars, the packed scalar readback, and the
-    (replicated) egress buffer — placed whole on every shard, so the
-    ≤2-transfers-per-turn law and the sync_stats byte accounting are
+    and everything at the host<->device boundary — the turn's one block
+    (``lanes.TurnBlock``), the packed readback with its egress head, and
+    the (replicated) egress buffer — placed whole on every shard, so the
+    one-copy-in, one-read-out law and the sync_stats byte accounting are
     unchanged by sharding (tests/test_multichip.py pins the counts).
 
     No donation: the fused walk's rollback re-dispatches from the
@@ -272,15 +272,13 @@ def make_sharded_hybrid_fns(
     sh = state_shardings(mesh, axis)
     repl = NamedSharding(mesh, P())
 
-    def _inject(s: lanes.LaneState, inj):
-        return lanes._inject_merge(p, tb, s, inj)
-
     inject_fn = _spmd_entry(jax.jit(
-        _inject, in_shardings=(sh, repl), out_shardings=sh
+        lanes._build_inject(p, tb), in_shardings=(sh, repl),
+        out_shardings=sh,
     ))
     turn_fn = _spmd_entry(jax.jit(
         lanes._build_hybrid_fused_run(p, tb, fuse_k, ext_slots),
-        in_shardings=(sh, repl, repl, repl, repl, repl),
+        in_shardings=(sh, repl),
         out_shardings=(sh, repl),
     ))
     return turn_fn, inject_fn

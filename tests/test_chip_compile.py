@@ -257,13 +257,14 @@ def _relay_chain_engine(tmp_path):
     return cfg, TpuEngine(cfg, external=external)
 
 
-def _empty_inject_block(p):
-    """A host-staged injection block of ``p.inject_batch`` rows, none
-    valid (backend/hybrid.py ``_empty_block``)."""
-    b = p.inject_batch
-    return {"valid": np.zeros(b, dtype=bool),
-            **{k: np.zeros(b, dtype=np.int32)
-               for k in ("dst", "thi", "tlo", "auxh", "auxl", "size")}}
+def _empty_turn_block(p, slots, k):
+    """The turn's one host-to-device block (``lanes.TurnBlock``) as
+    backend/hybrid.py ships it: no row valid, nothing scheduled, the
+    dynamic-runahead fold untouched, depth ``k``."""
+    lay = lanes.TurnBlock(p.inject_batch, slots)
+    block = lay.empty()
+    block[lay.k_at] = k
+    return block
 
 
 def test_hybrid_turn_inject_and_fused_k(one_chip, as_tpu, tmp_path):
@@ -273,17 +274,19 @@ def test_hybrid_turn_inject_and_fused_k(one_chip, as_tpu, tmp_path):
     configured ``hybrid_fuse_k``."""
     cfg, eng = _relay_chain_engine(tmp_path)
     state = _shapes(eng.initial_state(), one_chip)
-    inj = _shapes(_empty_inject_block(eng.params), one_chip)
-    never = int(lanes.NEVER32)  # the host passes it as a Python int
     k_cfg = int(cfg.experimental.hybrid_fuse_k)
     assert k_cfg >= 2
     for k in (1, k_cfg):
         slots = max(2 * k, 9)  # HybridEngine._ext_slots
         fused_fn, inject_fn = eng.make_hybrid_fns(k, slots)
-        ext = jax.ShapeDtypeStruct((slots,), np.int32, sharding=one_chip)
-        _fits(fused_fn.lower(
-            state, ext, ext, never, inj, np.int32(k)).compile())
-    _fits(inject_fn.lower(state, inj).compile())
+        block = _shapes(_empty_turn_block(eng.params, slots, k), one_chip)
+        compiled = fused_fn.lower(state, block).compile()
+        _fits(compiled)
+        # one block in; out, beside the state, one packed vector that
+        # carries the egress head (no second read-back program)
+        assert compiled.out_info[1].shape == (
+            lanes.HYB_WE_BASE + k + 6 * lanes.HYB_EGRESS_HEAD,)
+    _fits(inject_fn.lower(state, block).compile())
 
 
 def _scatter_update_shapes(stablehlo: str) -> list[str]:
@@ -311,15 +314,13 @@ def test_hybrid_turn_offers_no_candidate_row_scatter(as_tpu, tmp_path):
     six-column int64 rows is left, of any length."""
     cfg, eng = _relay_chain_engine(tmp_path)
     p = eng.params
-    state, inj = eng.initial_state(), _empty_inject_block(p)
-    never = int(lanes.NEVER32)
+    state = eng.initial_state()
     texts = []
     # a depth cap of 1, and the configured one (the cell runs that one)
     for fuse_k in (1, int(cfg.experimental.hybrid_fuse_k)):
         slots = max(2 * fuse_k, 9)
-        ext = np.full(slots, never, dtype=np.int32)
         texts.append(eng.make_hybrid_fns(fuse_k, slots)[0].lower(
-            state, ext, ext, never, inj, np.int32(fuse_k)).as_text())
+            state, _empty_turn_block(p, slots, fuse_k)).as_text())
     n, k = p.n_lanes, p.pops_per_iter
     offered = {n * (k + p.cross_cap), n * (2 * k + p.cross_cap), k * n}
     for text in texts:

@@ -294,6 +294,70 @@ class TestRollbackEgressParity:
         assert r.per_host_counters == congested_oracle.per_host_counters
 
 
+class TestOneBlockEveryPath:
+    def test_eager_and_rebuild_blocks_equal_a_fresh_pack(
+        self, tmp_path, monkeypatch, fused
+    ):
+        """The turn's block reaches the device by three routes (ISSUE
+        40): the primary dispatch ships the turn's staging array, an
+        ADOPTED eager dispatch shipped its own array a turn earlier, and
+        a rollback's REBUILD re-ships the turn's array with the depth
+        word patched.  On every dispatch of a run, a block packed afresh
+        from the turn's HOST values and dispatched from the same base
+        state must give the same packed read-back — scalars and egress
+        head — and the same state, leaf for leaf."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from shadow_tpu.backend import lanes
+        from shadow_tpu.backend.hybrid import NEVER, HybridEngine
+
+        real = HybridEngine._dispatch_fused
+        seen = {"primary": 0, "adopted": 0, "rebuild": 0}
+        rolled = [0]
+
+        def checked(self, state, fused_fn, ext, used_enc, n_staged, k_eff):
+            st, lay = self.sync_stats, self._lay
+            hits = st["async_dispatch_hits"]
+            state2, sc, wait = real(
+                self, state, fused_fn, ext, used_enc, n_staged, k_eff)
+            if st["fuse_rollbacks"] > rolled[0]:
+                rolled[0] = st["fuse_rollbacks"]
+                kind = "rebuild"
+            else:
+                kind = ("adopted" if st["async_dispatch_hits"] > hits
+                        else "primary")
+            seen[kind] += 1
+            t = np.asarray(ext, dtype=np.int64)
+            hi = np.where(t >= NEVER, lanes.NEVER32, t >> 31)
+            lo = np.where(t >= NEVER, lanes.NEVER32, t & lanes.MASK31)
+            inj = lay.injection(self._turn_np)
+            assert int(inj["valid"].sum()) == min(
+                n_staged, lay.inject_batch)
+            ref_state, ref_sc = fused_fn(state, jnp.array(
+                lay.pack(inj, hi, lo, used_enc, k_eff)))
+            assert np.array_equal(sc, np.asarray(ref_sc)), kind
+            for a, b in zip(jax.tree.leaves(state2),
+                            jax.tree.leaves(ref_state)):
+                assert np.array_equal(np.asarray(a), np.asarray(b)), kind
+            count = int(sc[lanes.HYB_EGRESS_COUNT])
+            head = lanes.hyb_egress_rows(sc, self._fuse_k)
+            assert np.array_equal(
+                head[:count], np.asarray(state2.egress[:count])), kind
+            return state2, sc, wait
+
+        monkeypatch.setattr(HybridEngine, "_dispatch_fused", checked)
+        r, eng, _led = _run(_cfg(tmp_path / "d"))
+        assert min(seen.values()) > 0, seen
+        s = eng.sync_stats
+        assert seen["adopted"] == s["async_dispatch_hits"]
+        assert seen["rebuild"] == s["fuse_rollbacks"]
+        assert sum(seen.values()) == s["device_turns"]
+        assert r.log_tuples() == fused[0].log_tuples()
+        assert r.counters == fused[0].counters
+
+
 class TestDegenerateLaw:
     def test_fuse1_has_no_fusion_artifacts(self, unfused):
         _r, eng, led = unfused

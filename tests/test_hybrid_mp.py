@@ -23,6 +23,7 @@ from pathlib import Path
 
 import pytest
 
+from shadow_tpu.backend import lanes
 from shadow_tpu.config.options import ConfigOptions
 from shadow_tpu.engine.sim import Simulation
 
@@ -131,6 +132,43 @@ def test_hybrid_mp_parity_with_cpu_oracle(tmp_path, cpu_oracle):
     assert s["inject_rows"] > 0 and s["egress_rows"] > 0
     assert s["device_sync_s"] > 0 and s["syscall_service_s"] > 0
     assert s["inject_blocks"] <= s["device_turns"]
+    _assert_transfer_law(eng, overflow_blocks=0)
+
+
+def _assert_transfer_law(eng, overflow_blocks: int) -> None:
+    """A turn crosses the boundary once each way (ISSUE 40): one
+    host->device copy per fused call — the blocking dispatches less the
+    adopted eager ones, plus every eager dispatch issued — and per
+    overflow block; one blocking read per device turn, which brings the
+    egress rows unless a turn egressed more than the head holds."""
+    s = eng.sync_stats
+    assert s["dispatch_retries"] == 0
+    fused_calls = s["device_turns"] + s["async_dispatch_misses"]
+    assert s["h2d_copies"] == fused_calls + overflow_blocks
+    width = eng._lay.width
+    assert width == 7 * eng.device.params.inject_batch \
+        + 2 * eng._ext_slots + 2
+    assert s["inject_bytes"] == 4 * width * s["h2d_copies"]
+    assert s["scalar_reads"] == s["device_turns"]
+    assert s["egress_head_reads"] > 0 and s["egress_reads"] == 0
+    assert s["egress_bytes"] == 48 * lanes.HYB_EGRESS_HEAD * s["scalar_reads"]
+
+
+def test_oversized_staging_ships_overflow_blocks(tmp_path, cpu_oracle):
+    """An injection batch of TWO rows: a turn that stages n > 2 sends
+    ships its first blocks through the standalone merge, in the turn
+    block's own format, and the run is still the oracle's."""
+    cfg = _mixed_config(tmp_path, "b2", "tpu", workers=1)
+    cfg.experimental.tpu_inject_batch = 2
+    r, eng = _run(cfg)
+    _assert_matches(r, cpu_oracle)
+    staged = [row.n_staged for row in eng.sync_stats["turn_spans"]]
+    assert len(staged) == eng.clock.turns  # the ring kept every turn
+    blocks = sum(-(-n // 2) for n in staged)
+    overflow = blocks - sum(1 for n in staged if n)
+    assert overflow > 0
+    assert eng.sync_stats["inject_blocks"] == blocks
+    _assert_transfer_law(eng, overflow_blocks=overflow)
 
 
 @pytest.mark.slow

@@ -308,8 +308,9 @@ def test_columnar_100k_scale_builds_fast():
 # -- 5. hybrid transfer invariance under mesh ------------------------------
 
 
-TRANSFER_KEYS = ("device_turns", "inject_blocks", "inject_rows",
-                 "inject_bytes", "egress_reads", "egress_rows",
+TRANSFER_KEYS = ("device_turns", "scalar_reads", "h2d_copies",
+                 "inject_blocks", "inject_rows", "inject_bytes",
+                 "egress_head_reads", "egress_reads", "egress_rows",
                  "egress_bytes")
 
 

@@ -44,12 +44,14 @@ SYNC_STATS = {
     "device_sync_s": 0.25,
     "syscall_service_s": 0.125,
     "scalar_reads": 3,
+    "h2d_copies": 4,
     "inject_blocks": 1,
     "inject_rows": 7,
-    "inject_bytes": 12800,
-    "egress_reads": 2,
-    "egress_rows": 9,
-    "egress_bytes": 96,
+    "inject_bytes": 57568,
+    "egress_head_reads": 2,
+    "egress_reads": 1,
+    "egress_rows": 139,
+    "egress_bytes": 18816,
 }
 
 
@@ -85,9 +87,10 @@ class TestPerfLogGoldenFormats:
         assert out.getvalue() == (
             "[hybrid-agg] kind=device window_end_ns=102000000 "
             "device_turns=3 device_sync_ns=250000000 "
-            "syscall_service_ns=125000000 scalar_reads=3 "
-            "inject_blocks=1 inject_rows=7 inject_bytes=12800 "
-            "egress_reads=2 egress_rows=9 egress_bytes=96\n"
+            "syscall_service_ns=125000000 scalar_reads=3 h2d_copies=4 "
+            "inject_blocks=1 inject_rows=7 inject_bytes=57568 "
+            "egress_head_reads=2 egress_reads=1 egress_rows=139 "
+            "egress_bytes=18816\n"
         )
 
     def test_emit_is_atomic_under_threads(self):

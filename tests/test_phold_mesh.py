@@ -20,6 +20,7 @@ engine can fake.
     ties it exists for against the oracle, and that the law is static.
 """
 
+import dataclasses
 import functools
 import json
 import math
@@ -282,7 +283,12 @@ def test_an_injection_shed_is_the_queues_and_the_message_says_so():
         "auxl": np.arange(b, dtype=np.int32),
         "size": np.full(b, 256, dtype=np.int32),
     }
-    s = lanes.make_inject_fn(p, eng.tables)(eng.initial_state(), inj)
+    # the injection rides the turn's one block (lanes.TurnBlock), whose
+    # injection part is all that the standalone merge reads; this
+    # engine has no external lane, so the batch is given here
+    p = dataclasses.replace(p, inject_batch=b)
+    block = lanes.TurnBlock(b, 0).pack(inj, (), (), lanes.NEVER32, 0)
+    s = lanes.make_inject_fn(p, eng.tables)(eng.initial_state(), block)
     assert int(s.n_queue.sum()) == p.capacity + 4
     assert [int(x) for x in s.peaks] == [b + 4, 0, 0]
     with pytest.raises(RuntimeError) as e:

@@ -517,6 +517,6 @@ class TestNetobsHybrid:
         sim_off = Simulation(cfg_off)
         sim_off.run(write_data=False)
         off = sim_off.engine.sync_stats
-        for key in ("scalar_reads", "inject_blocks", "egress_reads",
-                    "device_turns"):
+        for key in ("scalar_reads", "h2d_copies", "inject_blocks",
+                    "egress_reads", "device_turns"):
             assert off[key] == syncs[0][key] == syncs[1][key], key

@@ -265,9 +265,10 @@ hosts:
 """)
 
 
-TRANSFER_KEYS = ("device_turns", "scalar_reads", "inject_blocks",
-                 "inject_rows", "inject_bytes", "egress_reads",
-                 "egress_rows", "egress_bytes")
+TRANSFER_KEYS = ("device_turns", "scalar_reads", "h2d_copies",
+                 "inject_blocks", "inject_rows", "inject_bytes",
+                 "egress_head_reads", "egress_reads", "egress_rows",
+                 "egress_bytes")
 
 
 @pytest.mark.hybrid
