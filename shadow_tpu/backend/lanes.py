@@ -55,6 +55,7 @@ from jax import lax
 
 from ..core import rng as rng_mod
 from ..core import time as stime
+from ..models.gossip import AGE_EDGES_NS
 from ..net import codel as codel_mod
 from ..net.token_bucket import DEFAULT_INTERVAL_NS, FRAME_OVERHEAD_BYTES
 from ..obs import flowtrace as ftr
@@ -343,6 +344,13 @@ class LaneState(NamedTuple):
     # gossip lanes' app state (a ``GossipState`` of per-lane arrays; ()
     # — nothing traced — where no lane runs M_GOSSIP)
     gossip: Any = ()
+    # the propagation histogram, int32 ``[len(AGE_EDGES_NS) + 1]``: first
+    # deliveries by age (delivery time less the message's burst instant;
+    # models/gossip.py owns the edges), all lanes' together — one
+    # reduction a slot, nothing lane-sized.  collect() adds the buckets to
+    # the counters, which the oracle's per-host counts equal.  () where no
+    # lane runs M_GOSSIP
+    gossip_age: Any = ()
 
 
 class GossipState(NamedTuple):
@@ -445,6 +453,11 @@ class LaneParams:
     # mesh peers of a gossip lane (the ``[N, D]`` peer table's width; 0
     # where no lane runs M_GOSSIP)
     gossip_degree: int = 0
+    # the gossip lanes' publication instants (ns) and messages a burst:
+    # message ``m`` was published at ``gossip_bursts[m // gossip_messages]``,
+    # the instant a first delivery's age counts from
+    gossip_bursts: tuple = ()
+    gossip_messages: int = 1
 
     @property
     def stream_present(self) -> bool:
@@ -515,8 +528,10 @@ class LaneParams:
                 f"flowtrace requires flow_capacity > 0 (got {self.flow_capacity})"
             )
         if M_GOSSIP in self.models_present:
-            if self.gossip_degree < 1:
-                raise ValueError("gossip lanes need gossip_degree >= 1")
+            if self.gossip_degree < 1 or self.gossip_messages < 1:
+                raise ValueError(
+                    "gossip lanes need gossip_degree and gossip_messages "
+                    ">= 1")
             # the payload words are the stream tier's or the message id's,
             # and the pcap / flowtrace channels carry one send a pop
             if self.stream_present or self.pcap_any or self.flowtrace:
@@ -1268,7 +1283,23 @@ def _process_slot(
         g_first = g_del & ~g_known
         g_push = g_pub | g_first
         gl_hi, gl_lo = pair_max(gs.last_hi, gs.last_lo, thi, tlo)
-        s = s._replace(gossip=gs._replace(
+        with jax.named_scope("gossip_age"):
+            # the message's burst instant and the age's bucket, both
+            # picked by compares against static tables (no gather); the
+            # age is clamped one past the last edge: the overflow bucket
+            b_hi = b_lo = jnp.zeros(n, dtype=i32)
+            for b, t_b in enumerate(p.gossip_bursts):
+                m_b = b * p.gossip_messages
+                of_b = (g_mid >= m_b) & (g_mid < m_b + p.gossip_messages)
+                b_hi = jnp.where(of_b, i32(t_b >> 31), b_hi)
+                b_lo = jnp.where(of_b, i32(t_b & MASK31), b_lo)
+            age = pair_sub_clamp(thi, tlo, b_hi, b_lo, AGE_EDGES_NS[-1] + 1)
+            bucket = sum((age > e).astype(i32) for e in AGE_EDGES_NS)
+            g_age = s.gossip_age + jnp.sum(
+                (jnp.arange(len(AGE_EDGES_NS) + 1, dtype=i32)[:, None]
+                 == bucket[None, :]) & g_first[None, :],
+                axis=1, dtype=i32)
+        s = s._replace(gossip_age=g_age, gossip=gs._replace(
             seen=g_seen,
             first=gs.first + g_first,
             dups=gs.dups + (g_del & g_known),
@@ -4014,7 +4045,7 @@ def pack_state(s: LaneState):
         [jnp.asarray(getattr(s, f), dtype=jnp.int32) for f in sc_fields]
     )
     return (q, c32, sc, s.log, s.stream, s.egress, s.nb_hist, s.fl_buf,
-            s.peaks, s.copop_wide_pops, s.gossip)
+            s.peaks, s.copop_wide_pops, s.gossip, s.gossip_age)
 
 
 def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
@@ -4030,7 +4061,7 @@ def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
 
 def unpack_state(carry) -> LaneState:
     (q, c32, sc, log, stream, egress, nb_hist, fl_buf, peaks,
-     copop_wide_pops, gossip) = carry
+     copop_wide_pops, gossip, gossip_age) = carry
     has_pay = q.shape[0] == 7
     # the optional blocks' own carry leaves say which are live; the append
     # counters have none, so the scalar count left over tells
@@ -4053,7 +4084,8 @@ def unpack_state(carry) -> LaneState:
         stream=stream,
         cd_dropping=c32[len(_I32_N_FIELDS)].astype(bool),
         log=log, egress=egress, nb_hist=nb_hist, fl_buf=fl_buf,
-        peaks=peaks, copop_wide_pops=copop_wide_pops, gossip=gossip, **kw,
+        peaks=peaks, copop_wide_pops=copop_wide_pops, gossip=gossip,
+        gossip_age=gossip_age, **kw,
     )
 
 

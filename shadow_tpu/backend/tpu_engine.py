@@ -21,7 +21,7 @@ from ..config.options import ConfigOptions
 from ..core import rng as _rng
 from ..core import time as stime
 from ..models.base import create_model
-from ..models.gossip import Gossip, gossip_mesh
+from ..models.gossip import AGE_COUNTERS, Gossip, gossip_mesh
 from ..models.phold import Phold
 from ..models.tcpflow import StreamClient, StreamServer
 from ..models.tgen import Ping, TgenClient, TgenMesh, TgenServer
@@ -144,9 +144,11 @@ class TpuEngine:
 
         recv_mult = np.zeros(n, dtype=np.int32)
         # gossip lanes (models/gossip.py): each one's row of its mesh, and
-        # the most message ids any of them can meet (the seen bitmap's bits)
+        # their (publication instants, messages a burst): one static table
+        # of the program — the instants a first delivery's age counts
+        # from, and the message ids a lane can meet (the seen bitmap's bits)
         g_rows: dict[int, np.ndarray] = {}
-        g_messages = 0
+        g_slots: set[tuple] = set()
 
         def assign_tgen(hid: int, a) -> None:
             """One source of truth for tgen model/table assignment —
@@ -246,7 +248,7 @@ class TpuEngine:
                 model[hid] = lanes.M_GOSSIP
                 p_size[hid] = app.size
                 g_rows[hid] = gossip_mesh(n, app.degree, app.mesh_seed)[hid]
-                g_messages = max(g_messages, len(app.bursts) * app.messages)
+                g_slots.add((app.bursts, app.messages))
                 # the start marker, then one publish timer a publication
                 # in message order (the oracle's on_start arms them so);
                 # the message id rides the LOCAL's size word
@@ -430,13 +432,20 @@ class TpuEngine:
                 f"gossip lanes of different degrees {sorted(g_degrees)}: the "
                 "sends of a pop are one static width; use the cpu backend"
             )
+        if len(g_slots) > 1:
+            raise LaneCompatError(
+                "gossip lanes of different bursts or messages a burst "
+                f"{sorted(g_slots)}: a message's burst instant is one static "
+                "table; use the cpu backend"
+            )
+        g_bursts, g_per_burst = g_slots.pop() if g_slots else ((), 1)
         if g_rows and (client_ids.size or server_ids or pcap_any
                        or self._flowtrace_on):
             raise LaneCompatError(
                 "gossip lanes beside stream lanes, pcap capture or flowtrace "
                 "are not lane-compiled yet; use the cpu backend"
             )
-        self._gossip_words = -(-max(g_messages, 1) // 32)
+        self._gossip_words = -(-max(len(g_bursts) * g_per_burst, 1) // 32)
 
         ft_thresh, ft_all = ftr.sample_thresh(cfg.experimental.flowtrace_sample)
         self.params = lanes.LaneParams(
@@ -499,6 +508,8 @@ class TpuEngine:
             inject_batch=inject_batch if ext_mask.any() else 0,
             inject_cross=capacity if ext_mask.any() else 0,
             gossip_degree=g_degrees.pop() if g_degrees else 0,
+            gossip_bursts=g_bursts,
+            gossip_messages=g_per_burst,
         )
 
         up = np.array([bucket_params(int(b)) for b in bw_up], dtype=np.int64)
@@ -1062,6 +1073,9 @@ class TpuEngine:
                 seen=full((n, self._gossip_words)), sends=lane(),
                 first=lane(), dups=lane(), last_hi=lane(), last_lo=lane(),
             ) if p.gossip_degree else (),
+            gossip_age=(
+                full(len(AGE_COUNTERS)) if p.gossip_degree else ()
+            ),
         )
         # ONE transfer of the whole tree, straight onto its placement: no
         # eager device program, no whole copy on one chip before sharding
@@ -1511,7 +1525,7 @@ class TpuEngine:
         if p.copop_inert:
             fields.append("copop_wide_pops")
         if p.gossip_degree:
-            fields.append("gossip")
+            fields += ["gossip", "gossip_age"]
         if p.netobs:
             fields += ["nb_txb", "nb_rxb", "nb_thr", "nb_shed", "nb_hist",
                        "nb_win"]
@@ -1697,6 +1711,9 @@ class TpuEngine:
             add("gossip_sends", int(g.sends.sum()))
             add("gossip_first", int(g.first.sum()))
             add("gossip_duplicates", int(g.dups.sum()))
+            # the propagation histogram: first deliveries by age
+            for key, val in zip(AGE_COUNTERS, s.gossip_age.tolist()):
+                add(key, val)
             # the time the last node first saw a message: how long the
             # run's floods took to cross the mesh (0: nothing delivered)
             last = (g.last_hi.astype(np.int64) << 31) | g.last_lo

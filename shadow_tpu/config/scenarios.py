@@ -474,22 +474,67 @@ def gossip_mesh_config(
     latency: str = "10 ms",
     bandwidth: str = "1 Gbit",
     seed: int = 1,
+    graph_nodes: int | None = None,
+    graph_seed: int = 1,
 ) -> ConfigOptions:
     """Ethereum-style gossip (libp2p gossipsub's eager push over a static
-    mesh, ``models/gossip.py``): ``n_hosts`` nodes on one graph node, each
-    ONE process ``gossip`` with one argument list — every node finds its
-    row of ``gossip_mesh(n_hosts, degree, mesh_seed)`` and its own
-    publications by its host id, so there is no per-host document.  At
-    each instant of ``bursts`` (times after the start at 0 s) ``messages``
-    distinct nodes publish one ``size``-byte message each; self-edge
-    ``latency`` (the lookahead), ``bandwidth`` up and down, zero loss.
+    mesh, ``models/gossip.py``): ``n_hosts`` nodes, each ONE process
+    ``gossip`` with one argument list — every node finds its row of
+    ``gossip_mesh(n_hosts, degree, mesh_seed)`` and its own publications by
+    its host id.  At each instant of ``bursts`` (times after the start at
+    0 s) ``messages`` distinct nodes publish one ``size``-byte message
+    each; ``bandwidth`` up and down.
+
+    Without ``graph_nodes`` the network is one graph node (ONE host group
+    with ``count``, no per-host document): self-edge ``latency`` (the
+    lookahead), zero loss.  With it the network is
+    :func:`routed_graph_gml` ``(graph_nodes, graph_seed, bandwidth)`` — a
+    wide-area latency / loss graph; ``latency`` is not read — and every
+    node is placed on a graph node drawn uniformly from a stream of
+    ``graph_seed`` alone (as :func:`routed_tcp_mesh_config` places its
+    hosts): the deployment is ONE network, ``seed`` drives the loss draws.
+    Host ``i`` has the same id, mesh row and publications either way.
 
     The lane program's shapes are :func:`gossip_shape_law`'s; stop time
     and backend (``tpu``) are the caller's to set on the result."""
     from ..models.gossip import gossip_publishers
 
     times = sorted(units.parse_time(b) for b in bursts)
-    span = gossip_flood_hops(n_hosts, degree) * units.parse_time(latency)
+    process = {
+        "path": "gossip",
+        "args": [
+            "--degree", str(degree), "--mesh-seed", str(mesh_seed),
+            "--bursts", ",".join(f"{t} ns" for t in times),
+            "--messages", str(messages), "--size", str(size),
+        ],
+        "start_time": "0 s",
+    }
+    if graph_nodes is None:
+        gml = (
+            "graph [\n"
+            f'  node [ id 0 host_bandwidth_up "{bandwidth}" '
+            f'host_bandwidth_down "{bandwidth}" ]\n'
+            f'  edge [ source 0 target 0 latency "{latency}" ]\n'
+            "]\n")
+        hop = units.parse_time(latency)
+        hosts = {"node": {"count": n_hosts, "network_node_id": 0,
+                          "processes": [process]}}
+    else:
+        from ..net.graph import NetworkGraph
+
+        gml = routed_graph_gml(graph_nodes, graph_seed, bandwidth)
+        # a flood's hop is budgeted at the longest routed path
+        hop = NetworkGraph.from_gml(gml).max_latency_ns()
+        # a stream of its own, so the graph does not move with the width
+        rnd = random.Random(f"gossip-hosts-{graph_seed}")
+        hosts = {
+            f"node{i:0{len(str(n_hosts))}d}": {
+                "network_node_id": rnd.randrange(graph_nodes),
+                "processes": [process],
+            }
+            for i in range(1, n_hosts + 1)
+        }
+    span = gossip_flood_hops(n_hosts, degree) * hop
     concurrent = messages * max(
         sum(1 for u in times if t <= u < t + span) for t in times
     )
@@ -501,28 +546,12 @@ def gossip_mesh_config(
     return ConfigOptions.from_dict({
         "general": {"stop_time": "12 s", "seed": seed,
                     "heartbeat_interval": None},
-        "network": {"graph": {"type": "gml", "inline": (
-            "graph [\n"
-            f'  node [ id 0 host_bandwidth_up "{bandwidth}" '
-            f'host_bandwidth_down "{bandwidth}" ]\n'
-            f'  edge [ source 0 target 0 latency "{latency}" ]\n'
-            "]\n")}},
+        "network": {"graph": {"type": "gml", "inline": gml}},
         "experimental": {
             "network_backend": "tpu",
             "tpu_lane_queue_capacity": queue,
             "tpu_cross_capacity": cross,
             "tpu_events_per_round": GOSSIP_POPS,
         },
-        "hosts": {"node": {
-            "count": n_hosts, "network_node_id": 0,
-            "processes": [{
-                "path": "gossip",
-                "args": [
-                    "--degree", str(degree), "--mesh-seed", str(mesh_seed),
-                    "--bursts", ",".join(f"{t} ns" for t in times),
-                    "--messages", str(messages), "--size", str(size),
-                ],
-                "start_time": "0 s",
-            }],
-        }},
+        "hosts": hosts,
     })

@@ -10,8 +10,10 @@ a lane model of upstream's test-phold.  Node *i* holds a static peer list
   order ``send(P_i[k], size, payload=m)``;
 - *on_delivery(t, src, m)*: a message already seen counts one
   ``gossip_duplicates`` and does nothing else; a new one joins ``S_i``,
-  counts one ``gossip_first`` and is forwarded, in ``k`` order, to every
-  mesh peer but the one it came from.
+  counts one ``gossip_first`` and one bucket of the propagation
+  histogram (:func:`age_counter` of ``t`` less the message's burst
+  instant), and is forwarded, in ``k`` order, to every mesh peer but the
+  one it came from.
 
 Every send is an ordinary datagram of the engine (``gossip_sends`` counts
 them).  Departures from the protocol: the mesh is static (no GRAFT / PRUNE,
@@ -27,6 +29,7 @@ written twice, here and in ``backend/lanes.py``.
 
 from __future__ import annotations
 
+import bisect
 import functools
 import random
 
@@ -34,6 +37,26 @@ import numpy as np
 
 from ..config import units
 from .base import HostApi, parse_kv_args, register_model
+
+#: upper edges, in ms, of the propagation histogram's buckets: a first
+#: delivery counts into the first bucket whose edge its age (delivery time
+#: less the message's burst instant) does not pass, or into the overflow
+#: bucket.  Counters, NOT cumulative: the buckets sum to ``gossip_first``.
+#: One law for both backends (the lane body compares against these)
+AGE_EDGES_MS = (10, 20, 30, 40, 50, 60, 80, 100, 125, 150, 200, 250, 300,
+                400, 500)
+#: the same edges in the engines' unit
+AGE_EDGES_NS = tuple(e * 1_000_000 for e in AGE_EDGES_MS)
+#: the histogram's counter names, in bucket order
+AGE_COUNTERS = tuple(f"gossip_first_le_{e}ms" for e in AGE_EDGES_MS) + (
+    f"gossip_first_gt_{AGE_EDGES_MS[-1]}ms",)
+
+
+def age_counter(age_ns: int) -> str:
+    """The histogram counter a first delivery ``age_ns`` after its
+    message's burst instant counts into."""
+    return AGE_COUNTERS[bisect.bisect_left(AGE_EDGES_NS, age_ns)]
+
 
 #: redraws of conflicting cycle positions before :func:`gossip_mesh` gives
 #: up (a graph too small for ``degree / 2`` edge-disjoint cycles)
@@ -183,5 +206,6 @@ class Gossip:
             return
         self.seen.add(m)
         api.count("gossip_first")
+        api.count(age_counter(t - self.bursts[m // self.messages]))
         self.last_first_ns = max(self.last_first_ns, t)
         self._push(api, m, but=src)

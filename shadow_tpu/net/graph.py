@@ -339,13 +339,20 @@ class NetworkGraph:
             raise GraphError(f"no path from node {src_node_id} to {dst_node_id}")
         return l, float(self.packet_loss[s, t])
 
-    def min_latency_ns(self) -> int:
-        """Smallest routable latency — the conservative lookahead bound
-        (graph/mod.rs:472-474, runahead.rs:14)."""
+    def _routable_latencies(self) -> np.ndarray:
         mask = self.latency_ns != _UNREACHABLE
         if not mask.any():
             raise GraphError("graph has no routable paths")
-        return int(self.latency_ns[mask].min())
+        return self.latency_ns[mask]
+
+    def min_latency_ns(self) -> int:
+        """Smallest routable latency — the conservative lookahead bound
+        (graph/mod.rs:472-474, runahead.rs:14)."""
+        return int(self._routable_latencies().min())
+
+    def max_latency_ns(self) -> int:
+        """Longest routable latency: what one hop of an overlay can cost."""
+        return int(self._routable_latencies().max())
 
     def node_bandwidth(self, node_id: int) -> tuple[Optional[int], Optional[int]]:
         n = self.nodes[self.id_to_index[node_id]]

@@ -15,7 +15,12 @@ neither engine can fake.
 (d) the shape law: deterministic bounds, the merge's row a power of two, a
     run's ``queue_peak`` / ``cross_peak`` under them;
 (e) ``sends_per_pop`` is a static property of the models present and 1 for
-    every model of today, whose tiny programs lower to the PARENT's text.
+    every model of today, whose tiny programs lower to the PARENT's text;
+(f) the propagation histogram (ISSUE 41): 16 age buckets, one law in
+    ``models/gossip.py`` for both backends, counters the oracle's equal;
+(g) the same factory over a routed, lossy graph (``graph_nodes``): ONE
+    network whatever the run's seed, the lane engine equal to the oracle
+    on it — fused, step and sharded — and one program for every seed.
 """
 
 import functools
@@ -31,8 +36,12 @@ from shadow_tpu.backend.tpu_engine import LaneCompatError, TpuEngine
 from shadow_tpu.config.options import ConfigOptions
 from shadow_tpu.config.scenarios import (
     GOSSIP_POPS, gossip_flood_hops, gossip_mesh_config, gossip_shape_law,
+    routed_graph_gml,
 )
-from shadow_tpu.models.gossip import gossip_mesh, gossip_publishers
+from shadow_tpu.core import rng
+from shadow_tpu.models.gossip import (
+    AGE_COUNTERS, AGE_EDGES_MS, age_counter, gossip_mesh, gossip_publishers,
+)
 
 import test_phold_mesh as phold_tests
 
@@ -104,12 +113,35 @@ def _counts(nodes, degree, messages, bursts=len(BURSTS)):
             "gossip_duplicates": sends - first}
 
 
+def _ages(counters):
+    """The propagation histogram of a result, in bucket order."""
+    return [counters.get(key, 0) for key in AGE_COUNTERS]
+
+
+def _less_ages(counters):
+    """The shared counters without the histogram's buckets, which must
+    sum to ``gossip_first`` (every first delivery counts into one)."""
+    assert sum(_ages(counters)) == counters.get("gossip_first", 0)
+    return {k: v for k, v in _shared(counters).items()
+            if k not in AGE_COUNTERS}
+
+
 def _assert_equals_oracle(eng, res, oracle, last):
     assert res.log_tuples() == oracle.log_tuples()
     assert len(oracle.event_log) > 0
     assert _shared(res.counters) == _shared(oracle.counters)
     assert res.rounds == oracle.rounds
     assert eng.lane_plane["gossip_last_first_ns"] == last > 0
+    # the run's last first delivery belongs to the last burst: its age's
+    # bucket is counted, and no later than the last non-empty one
+    ages = _ages(res.counters)
+    assert sum(ages) == res.counters["gossip_first"]
+    bucket = AGE_COUNTERS.index(
+        age_counter(last - max(eng.params.gossip_bursts)))
+    assert ages[bucket] > 0
+    if len(eng.params.gossip_bursts) == 1:
+        # one burst: it is the last non-empty bucket
+        assert not any(ages[bucket + 1:])
 
 
 # -- (a) against the oracle ---------------------------------------------------
@@ -120,7 +152,7 @@ def test_the_lane_backend_equals_the_oracle(mode):
     eng = TpuEngine(_cfg(64, 4, 3))
     res = eng.run(mode=mode)
     _assert_equals_oracle(eng, res, *_oracle(64, 4, 3))
-    assert _shared(res.counters) == _counts(64, 4, 3)
+    assert _less_ages(res.counters) == _counts(64, 4, 3)
     assert len(res.event_log) == res.counters["gossip_sends"]
 
 
@@ -235,7 +267,10 @@ def _lane_run(nodes, degree, messages):
 def test_every_node_receives_once_and_forwards_to_all_but_one(
         nodes, degree, messages):
     res, plane = _lane_run(nodes, degree, messages)
-    assert _shared(res.counters) == _counts(nodes, degree, messages)
+    assert _less_ages(res.counters) == _counts(nodes, degree, messages)
+    # on one switch hop h lands exactly 10 (h + 1) ms after the burst: the
+    # buckets are the flood's hops, the first the publishers' own peers
+    assert _ages(res.counters)[0] == len(BURSTS) * messages * degree
     # every send is delivered (zero loss, nothing shed)
     assert res.counters["lane_delivered"] == res.counters["gossip_sends"]
     assert (plane["sends_per_pop"], plane["gossip_degree"]) == (
@@ -327,3 +362,230 @@ def test_sends_per_pop_is_a_static_property_of_the_models_present():
     text = lanes.make_run_fn(eng.params, eng.tables).lower(
         eng.initial_state()).as_text(debug_info=True)
     assert "gossip_fanout" in text and "gossip_seen" in text
+
+
+# -- (f) the propagation histogram ---------------------------------------------
+
+
+@pytest.mark.parametrize("age_ms, name", [
+    (0, "gossip_first_le_10ms"), (10, "gossip_first_le_10ms"),
+    (10.000001, "gossip_first_le_20ms"), (60, "gossip_first_le_60ms"),
+    (61, "gossip_first_le_80ms"), (100, "gossip_first_le_100ms"),
+    (124.5, "gossip_first_le_125ms"), (150.5, "gossip_first_le_200ms"),
+    (499, "gossip_first_le_500ms"), (500, "gossip_first_le_500ms"),
+    (500.000001, "gossip_first_gt_500ms"), (11_000, "gossip_first_gt_500ms")])
+def test_an_age_counts_into_the_first_bucket_whose_edge_it_does_not_pass(
+        age_ms, name):
+    assert age_counter(round(age_ms * MS)) == name
+    assert len(AGE_COUNTERS) == len(AGE_EDGES_MS) + 1 == 16
+    assert list(AGE_EDGES_MS) == sorted(set(AGE_EDGES_MS))
+
+
+def test_the_buckets_of_one_switch_are_the_floods_hops():
+    """One switch, 10 ms a hop: a first delivery of hop h is exactly 10 (h
+    + 1) ms old, so the histogram is the flood's breadth by hop, counted
+    here from the mesh alone (a breadth-first walk from each publisher)."""
+    res, _plane = _lane_run(64, 4, 3)
+    peers = gossip_mesh(64, 4, 1)
+    want = [0] * len(AGE_COUNTERS)
+    for pub in gossip_publishers(64, len(BURSTS), 3, 1).reshape(-1):
+        seen, front, hop = {int(pub)}, {int(pub)}, 0
+        while front:
+            front = {int(q) for i in front for q in peers[i]} - seen
+            seen |= front
+            want[AGE_COUNTERS.index(age_counter(10 * (hop + 1) * MS))] += (
+                len(front))
+            hop += 1
+    assert _ages(res.counters) == want and sum(want) == 6 * 63
+
+
+def test_lanes_of_different_bursts_are_refused():
+    cfg = _cfg(64, 4, 3)
+    args = cfg.hosts[5].processes[0].args
+    args[args.index("--bursts") + 1] = "1000000000 ns,2100000000 ns"
+    with pytest.raises(LaneCompatError, match="different bursts"):
+        TpuEngine(cfg)
+    with pytest.raises(ValueError, match="gossip_messages"):
+        lanes.LaneParams(
+            n_lanes=8, capacity=16, pops_per_iter=2, log_capacity=0, seed=1,
+            stop_time=MS, bootstrap_end=0, runahead=MS, gossip_degree=4,
+            gossip_messages=0, models_present=(lanes.M_GOSSIP,))
+
+
+def test_the_histogram_is_a_named_stage_and_nothing_lane_sized():
+    eng = TpuEngine(_cfg(64, 4, 3), log_capacity=0)
+    state = eng.initial_state()
+    assert state.gossip_age.shape == (16,) and state.gossip_age.dtype == (
+        np.int32)
+    assert eng.params.gossip_bursts == (1000 * MS, 2000 * MS)
+    assert eng.params.gossip_messages == 3
+    text = lanes.make_run_fn(eng.params, eng.tables).lower(
+        state).as_text(debug_info=True)
+    assert "gossip_age" in text
+
+
+# -- (g) the same factory over a routed, lossy graph -----------------------------
+
+#: 96 nodes over 12 graph nodes, D 4, two bursts of three
+WAN = dict(nodes=96, degree=4, messages=3, graph_nodes=12)
+
+
+def _wan_cfg(backend="tpu", seed=7, graph_seed=1, nodes=WAN["nodes"],
+             degree=WAN["degree"], messages=WAN["messages"],
+             graph_nodes=WAN["graph_nodes"], bursts=BURSTS, stop_ms=2200):
+    cfg = gossip_mesh_config(nodes, degree, 1, bursts, messages, 512,
+                             bandwidth="1 Gbit", seed=seed,
+                             graph_nodes=graph_nodes, graph_seed=graph_seed)
+    cfg.general.stop_time = stop_ms * MS
+    cfg.experimental.network_backend = backend
+    return cfg
+
+
+@functools.lru_cache(maxsize=None)
+def _wan_oracle(seed=7):
+    return _oracle_run(_wan_cfg("cpu", seed))
+
+
+@pytest.mark.parametrize("mode", ["device", "step"])
+def test_on_a_routed_lossy_graph_the_lane_backend_equals_the_oracle(mode):
+    oracle, last = _wan_oracle()
+    eng = TpuEngine(_wan_cfg())
+    res = eng.run(mode=mode)
+    _assert_equals_oracle(eng, res, oracle, last)
+    plane, c = eng.lane_plane, res.counters
+    assert (plane["graph_nodes"], plane["has_loss"], plane["window_ns"],
+            plane["sends_per_pop"]) == (12, 1, 2 * MS, 4)
+    assert plane["max_path_latency_ns"] > plane["window_ns"]
+    # the deterministic shape bounds hold whatever the latency and loss
+    queue, cross = gossip_shape_law(4, 3)
+    assert plane["queue_peak"] <= queue and plane["cross_peak"] <= cross
+    # some copies were lost, no node missed a message: the two analytic
+    # counts hold under loss, and every send was a first copy, a duplicate
+    # or a lost datagram
+    want = _counts(96, 4, 3)
+    assert c["lane_drop_loss"] > 0
+    assert (c["gossip_sends"], c["gossip_first"]) == (
+        want["gossip_sends"], want["gossip_first"])
+    assert c["gossip_duplicates"] + c["lane_drop_loss"] == (
+        c["gossip_sends"] - c["gossip_first"])
+    # ages are no longer multiples of one link: several buckets fill
+    assert sum(1 for count in _ages(c) if count) >= 4
+
+
+def test_another_run_seed_is_another_run_of_the_same_network():
+    a, b = _wan_oracle(7)[0], _wan_oracle(8)[0]
+    assert a.log_tuples() != b.log_tuples()
+    for key in ("gossip_sends", "gossip_first"):
+        assert a.counters[key] == b.counters[key]
+
+
+def test_without_a_graph_the_factory_returns_todays_configuration():
+    """Field for field: ONE host group with ``count`` on one graph node,
+    the self-edge's latency the lookahead (written out as PR 39 built
+    it)."""
+    got = gossip_mesh_config(64, 4, 1, ("1 s", "2 s"), 3, 512, "10 ms",
+                             "1 Gbit", seed=7)
+    want = ConfigOptions.from_dict({
+        "general": {"stop_time": "12 s", "seed": 7,
+                    "heartbeat_interval": None},
+        "network": {"graph": {"type": "gml", "inline": (
+            "graph [\n"
+            '  node [ id 0 host_bandwidth_up "1 Gbit" '
+            'host_bandwidth_down "1 Gbit" ]\n'
+            '  edge [ source 0 target 0 latency "10 ms" ]\n'
+            "]\n")}},
+        "experimental": {
+            "network_backend": "tpu", "tpu_lane_queue_capacity": 52,
+            "tpu_cross_capacity": 8, "tpu_events_per_round": 2},
+        "hosts": {"node": {
+            "count": 64, "network_node_id": 0,
+            "processes": [{
+                "path": "gossip",
+                "args": ["--degree", "4", "--mesh-seed", "1", "--bursts",
+                         "1000000000 ns,2000000000 ns", "--messages", "3",
+                         "--size", "512"],
+                "start_time": "0 s"}]}},
+    })
+    assert got == want
+    assert got == gossip_mesh_config(64, 4, 1, ("1 s", "2 s"), 3, 512,
+                                     "10 ms", "1 Gbit", seed=7,
+                                     graph_nodes=None, graph_seed=5)
+
+
+def test_the_graph_and_the_placement_depend_on_graph_seed_alone():
+    base = _wan_cfg(seed=7)
+    assert base.network.graph.inline == routed_graph_gml(12, 1, "1 Gbit")
+
+    def placement(cfg):
+        return [h.network_node_id for h in cfg.hosts]
+
+    # the run's seed, the mesh's seed and the traffic move nothing
+    other = gossip_mesh_config(96, 4, 5, ("3 s",), 2, 256,
+                               bandwidth="1 Gbit", seed=99, graph_nodes=12,
+                               graph_seed=1)
+    assert placement(other) == placement(base)
+    assert other.network.graph.inline == base.network.graph.inline
+    assert set(placement(base)) == set(range(12))  # 96 draws over 12 nodes
+    # host i keeps its id (names sort in numeric order), so its mesh row
+    # and publications are the one-switch deployment's
+    assert [h.hostname for h in base.hosts] == [
+        f"node{i:02d}" for i in range(1, 97)]
+    # a wider network extends the same stream; another graph_seed is
+    # another network
+    wide = _wan_cfg(nodes=128, degree=4)
+    assert placement(wide)[:96] == placement(base)
+    moved = _wan_cfg(graph_seed=2)
+    assert placement(moved) != placement(base)
+    assert moved.network.graph.inline == routed_graph_gml(12, 2, "1 Gbit")
+    # the shapes are the deterministic law's: latency moves none of them
+    assert (base.experimental.tpu_lane_queue_capacity,
+            base.experimental.tpu_cross_capacity,
+            base.experimental.tpu_events_per_round) == (52, 8, 2)
+
+
+def test_close_bursts_are_counted_over_the_longest_routed_path():
+    from shadow_tpu.net.graph import NetworkGraph
+
+    longest = NetworkGraph.from_gml(
+        routed_graph_gml(12, 1, "1 Gbit")).max_latency_ns()
+    assert longest > 10 * MS
+    span_ms = gossip_flood_hops(96, 4) * longest // MS
+    # 130 ms apart: two bursts on one 10 ms switch (a flood is budgeted 12
+    # hops x 10 ms), ONE burst on the graph (12 hops x the longest path)
+    assert 10 * gossip_flood_hops(96, 4) < 130 < span_ms
+    switch = gossip_mesh_config(96, 4, 1, ("1 s", "1130 ms"), 6)
+    graph = _wan_cfg(bursts=("1 s", "1130 ms"), messages=6)
+    assert switch.experimental.tpu_lane_queue_capacity == 52
+    assert graph.experimental.tpu_lane_queue_capacity == 116
+
+
+def test_one_routed_gossip_program_serves_every_run_seed():
+    texts = set()
+    for seed in (1, 2**31 - 2):
+        eng = TpuEngine(_wan_cfg(seed=seed), log_capacity=0)
+        assert eng.params.has_loss and eng.params.sends_per_pop == 4
+        fn = lanes.make_run_fn(eng.params, eng.tables)
+        words = tuple(np.uint32(w) for w in rng._split_seed(seed))
+        texts.add(fn.lower(eng.initial_state(), *words).as_text())
+    assert len(texts) == 1
+    # the engine hands the words over on its fused path
+    eng.run(mode="device")
+    assert [int(w) for w in eng._seed_args] == [2**31 - 2, 0]
+
+
+@pytest.mark.parametrize("devices", [2, 4])
+def test_any_mesh_shape_equals_the_oracle_on_the_graph(devices):
+    """The sharded rehearsal at degree 2 (see
+    ``test_any_mesh_shape_equals_the_oracle``): a ring of 16 over four
+    graph nodes, the histogram replicated and reduced across the shards."""
+    def cfg(backend):
+        return _wan_cfg(backend, nodes=16, degree=2, messages=2,
+                        graph_nodes=4, bursts=("1 s",), stop_ms=1400)
+
+    eng = TpuEngine(cfg("tpu"))
+    eng.attach_mesh(parallel.make_mesh(devices))
+    res = eng.run(mode="device")
+    _assert_equals_oracle(eng, res, *_oracle_run(cfg("cpu")))
+    assert eng.lane_plane["mesh_devices"] == devices
+    assert eng.lane_plane["graph_nodes"] == 4
+    assert sum(_ages(res.counters)) == res.counters["gossip_first"] > 0
