@@ -83,6 +83,13 @@ NEVER = stime.NEVER
 # backends elide identically so event logs stay bit-identical
 PASSIVE_MODELS = frozenset({M_NONE, M_TGEN_MESH, M_TGEN_CLIENT, M_TGEN_SERVER})
 STREAM_MODELS = frozenset({M_STREAM_CLIENT, M_STREAM_SERVER})
+# models whose sends look their path up in the [G, G] tables: every model
+# of the [N] send channel whose destination is picked at RUN time (a draw,
+# an echo's source, a round-robin offset; the client models' fixed
+# ``p_peer`` gathers too, PERF.md §7).  A gossip lane's D mesh peers are
+# static, and so is their path: rows of ``LaneTables.g_lat``
+PATH_GATHER_MODELS = frozenset({
+    M_PHOLD, M_TGEN_MESH, M_TGEN_CLIENT, M_PING_CLIENT, M_PING_SERVER})
 # active DATAGRAM models whose DELIVERY handler is WINDOW-INERT, the static
 # property the pop phase (pop_mask) widens an active lane's co-pop on.
 # Three points, each read off _process_slot:
@@ -615,6 +622,30 @@ class LaneTables(NamedTuple):
     # (models/gossip.py gossip_mesh; rows of other lanes are zeros); ()
     # where no lane runs M_GOSSIP
     g_peers: Any = ()
+    # [F, N], lanes minor: the path from a gossip lane to its k-th mesh
+    # peer — ``lat`` / ``thresh_u32`` / ``thresh_all`` at ``[node_of[n],
+    # node_of[g_peers[n, k]]]``, constants of the pair as a flow's
+    # ``flow_lat`` is (TpuEngine._path_tables builds both, per fault epoch
+    # too); () where no lane runs M_GOSSIP and on a graph of one node,
+    # where the [1, 1] lookup already folds to a scalar
+    g_lat: Any = ()
+    g_thresh_u32: Any = ()
+    g_thresh_all: Any = ()
+
+
+def path_sends(p: LaneParams, tb: LaneTables) -> tuple[int, int]:
+    """``(static_path_sends, path_gather_sends)``, two static facts of the
+    program ``p`` and ``tb`` compile to (``lane_plane`` gauges): of a pop's
+    ``sends_per_pop`` sends, how many read their path's latency and loss
+    threshold from per-peer rows (``tb.g_lat``: the gossip lanes' F on a
+    graph of more than one node, else 0), and how many have a destination
+    picked at run time on such a graph, whose path is gathered from the
+    [G, G] tables (send 0 of a ``PATH_GATHER_MODELS`` lane; on one node
+    the lookup folds and nothing is gathered)."""
+    rows = 0 if isinstance(tb.g_lat, tuple) else p.sends_per_pop
+    gathers = tb.lat.shape[-1] > 1 and bool(
+        set(p.models_present) & PATH_GATHER_MODELS)
+    return rows, int(gathers)
 
 
 # --------------------------------------------------------------------------
@@ -1355,10 +1386,14 @@ def _process_slot(
     if M_GOSSIP in mp:
         out_plo = jnp.where(g_push, g_mid, 0)
 
-    def one_send(s, do_send, dst):
+    def one_send(s, do_send, dst, rows=None, gathers=True):
         """One datagram a lane, as the oracle's ``send_packet``: the next
         sequence number, the up bucket's charge, the path's latency and
-        its own loss draw — in the order of the calls."""
+        its own loss draw — in the order of the calls.  The path's three
+        words are a constant of the pair where the destination is static
+        (``rows``: the ``(use, lat, thresh_u32, thresh_all)`` of a gossip
+        lane's k-th peer) and gathered from the [G, G] tables where it is
+        picked at run time (``gathers``, static), as ``dst`` itself is."""
 
         # per-send sequence numbers
         snd_seq = s.send_seq
@@ -1386,9 +1421,18 @@ def _process_slot(
 
         # loss (bootstrap window is loss-free; loss-free graphs skip the draw)
         with jax.named_scope("path_lookup"):
-            my_node = tb.node_of
-            dst_node = tb.node_of[dst]
-            lat = tb.lat[my_node, dst_node]  # int32
+            if gathers:
+                my_node = tb.node_of
+                dst_node = tb.node_of[dst]
+
+            def path_word(table, i):
+                if not gathers:
+                    return rows[i]
+                word = table[my_node, dst_node]
+                return word if rows is None else jnp.where(
+                    rows[0], rows[i], word)
+
+            lat = path_word(tb.lat, 1)  # int32
             if p.has_loss:
                 u = rand_u32_lane(
                     _seed_keys(p, tb),
@@ -1398,8 +1442,8 @@ def _process_slot(
                 bs_hi, bs_lo = p.bootstrap_end >> 31, p.bootstrap_end & MASK31
                 past_bootstrap = pair_ge(thi, tlo, bs_hi, bs_lo)
                 lost = do_send & past_bootstrap & (
-                    tb.thresh_all[my_node, dst_node]
-                    | (u < tb.thresh_u32[my_node, dst_node])
+                    path_word(tb.thresh_all, 3)
+                    | (u < path_word(tb.thresh_u32, 2))
                 )
                 s = s._replace(n_loss=s.n_loss + lost)
             else:
@@ -1445,14 +1489,34 @@ def _process_slot(
                  "up_ld_hi", "up_ld_lo", "n_loss", "min_used_lat", "nb_thr",
                  "nb_txb")
 
+        # peer k's path is a constant of the lane (``tb.g_lat``; () on one
+        # graph node, where the lookup folds and the trace is the
+        # parent's).  Another model's send 0 still gathers, its words
+        # selected by ``g_k`` as its ``dst`` is; sends k >= 1 are gossip's
+        # alone, which the loop form sees (``k`` is a Python integer there,
+        # and traced under the rolled scan, where every k gathers: right,
+        # only not free)
+        with_rows = not isinstance(tb.g_lat, tuple)
+        mixed = bool(set(mp) & PATH_GATHER_MODELS)
+        fan_xs = (jnp.arange(n_f) == 0, tb.g_peers.T)
+        if with_rows:
+            fan_xs += ((tb.g_lat, tb.g_thresh_u32, tb.g_thresh_all),
+                       np.arange(n_f))
+
         def fan_step(carry, x):
             # gossipsub forwards to the mesh less the peer the message
             # came from; a publish goes to all of it
-            first_k, peer_k = x
+            first_k, peer_k, *path = x
             g_k = g_push & (g_pub | (peer_k != src))
+            static = {}
+            if with_rows:
+                path_k, k = path
+                static = dict(rows=(g_k, *path_k), gathers=mixed and (
+                    not jax.core.is_concrete(k) or k == 0))
             st, o = one_send(
                 s._replace(**dict(zip(chain, carry[0]))),
                 (do_send & first_k) | g_k, jnp.where(g_k, peer_k, dst),
+                **static,
             )
             return (tuple(getattr(st, f) for f in chain),
                     carry[1] + g_k), o
@@ -1464,7 +1528,7 @@ def _process_slot(
             (chained, g_sends), sent = scan_or_unroll(
                 fan_step,
                 (tuple(getattr(s, f) for f in chain), s.gossip.sends),
-                (jnp.arange(n_f) == 0, tb.g_peers.T), n_f, spmd_unroll=True,
+                fan_xs, n_f, spmd_unroll=True,
             )
         s = s._replace(**dict(zip(chain, chained)),
                        gossip=s.gossip._replace(sends=g_sends))

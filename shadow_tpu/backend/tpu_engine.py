@@ -588,12 +588,6 @@ class TpuEngine:
             fsv = p_peer[fcl].astype(np.int32)
             el_np = np.concatenate([fcl, fsv])
             peer_np = np.concatenate([fsv, fcl])
-            lat_np = np.asarray(lat)
-            thr_np = np.asarray(thresh)
-            e_nodes = np.asarray(node_idx)[el_np]
-            p_nodes = np.asarray(node_idx)[peer_np]
-            flow_lat = lat_np[e_nodes, p_nodes].astype(np.int32)
-            flow_thr = thr_np[e_nodes, p_nodes]
             flow_segs = np.concatenate(
                 [st_segs[fcl], np.zeros(s_flows, dtype=np.int32)]
             )
@@ -612,19 +606,17 @@ class TpuEngine:
             flow_clid = np.concatenate([fcl, fcl])
         else:
             el_np = peer_np = np.zeros(2, dtype=np.int32)
-            flow_lat = np.zeros(2, dtype=np.int32)
-            flow_thr = np.zeros(2, dtype=np.int64)
             flow_segs = flow_mss = flow_last = np.zeros(2, dtype=np.int32)
             flow_cc = np.zeros(2, dtype=np.int32)
             flow_clid = np.zeros(2, dtype=np.int32)
 
+        self._el_np = el_np  # [2S] endpoint lanes (tiered routing/collect)
+        self._peer_np = peer_np  # [2S] peer lanes (fault-epoch flow tables)
+        self._node_idx = node_idx  # [N] host -> dense node index
+        self._g_peers_np = self._gossip_peers(g_rows, n)
         self.tables = lanes.LaneTables(
             node_of=jnp.asarray(node_idx, dtype=i32),
-            lat=jnp.asarray(lat, dtype=i32),
-            thresh_u32=jnp.asarray(
-                (np.asarray(thresh) & 0xFFFFFFFF).astype(np.uint32)
-            ),
-            thresh_all=jnp.asarray(np.asarray(thresh) >= (1 << 32)),
+            **self._path_tables(lat, thresh),
             up_rate=jnp.asarray(up[:, 0], dtype=i32),
             up_burst=jnp.asarray(up[:, 1], dtype=i32),
             up_kfull=jnp.asarray(up_kfull),
@@ -645,11 +637,6 @@ class TpuEngine:
             flow_lanes=jnp.asarray(el_np),
             flow_peers=jnp.asarray(peer_np),
             flow_clid=jnp.asarray(flow_clid),
-            flow_lat=jnp.asarray(flow_lat, dtype=i32),
-            flow_thresh_u32=jnp.asarray(
-                (flow_thr & 0xFFFFFFFF).astype(np.uint32)
-            ),
-            flow_thresh_all=jnp.asarray(flow_thr >= (1 << 32)),
             flow_segs=jnp.asarray(flow_segs, dtype=i32),
             flow_mss=jnp.asarray(flow_mss, dtype=i32),
             flow_last=jnp.asarray(flow_last, dtype=i32),
@@ -670,13 +657,11 @@ class TpuEngine:
             lane_stream=(
                 jnp.asarray(np.isin(np.arange(n), el_np)) if tiered else ()
             ),
-            g_peers=self._gossip_peers(g_rows, n),
+            g_peers=(() if self._g_peers_np is None
+                     else jnp.asarray(self._g_peers_np)),
         )
         self._local_seq0 = local_seq0
         self._model_np = model  # [N] app model per lane (collect's masks)
-        self._el_np = el_np  # [2S] endpoint lanes (tiered routing/collect)
-        self._peer_np = peer_np  # [2S] peer lanes (fault-epoch flow tables)
-        self._node_idx = node_idx  # [N] host -> dense node index
         self._ep_of_lane = (
             {int(l): r for r, l in enumerate(el_np)} if tiered else {}
         )
@@ -720,14 +705,49 @@ class TpuEngine:
 
     @staticmethod
     def _gossip_peers(g_rows: dict, n: int):
-        """``LaneTables.g_peers``: the ``[N, D]`` peer table (a lane of
-        another model keeps a row of zeros it never reads), or () where no
-        lane runs gossip."""
+        """``LaneTables.g_peers`` on the host: the ``[N, D]`` peer table (a
+        lane of another model keeps a row of zeros it never reads), or
+        None where no lane runs gossip."""
         if not g_rows:
-            return ()
+            return None
         peers = np.zeros((n, len(next(iter(g_rows.values())))), np.int32)
         peers[list(g_rows)] = np.stack(list(g_rows.values()))
-        return jnp.asarray(peers)
+        return peers
+
+    def _path_tables(self, lat, thresh) -> dict:
+        """The ``LaneTables`` fields one epoch's ``[G, G]`` latency and
+        loss-threshold tables decide: the tables themselves (a run-time
+        destination gathers from them) and their compactions for every
+        STATIC destination — a flow's peer (``flow_*``, ``[2S]``) and a
+        gossip lane's D mesh peers (``g_*``, ``[F, N]``, lanes minor) —
+        whose path is a constant of the lane, so a send reads a row and
+        gathers nothing.  ONE law for start-up and each fault epoch."""
+        lat_np, thr_np = np.asarray(lat), np.asarray(thresh)
+        nodes = np.asarray(self._node_idx)
+
+        def words(prefix, lat, thr):
+            return {
+                prefix + "lat": jnp.asarray(lat, dtype=jnp.int32),
+                prefix + "thresh_u32": jnp.asarray(
+                    (thr & 0xFFFFFFFF).astype(np.uint32)),
+                prefix + "thresh_all": jnp.asarray(thr >= (1 << 32)),
+            }
+
+        def paths(prefix, src, dst):
+            a, b = nodes[src], nodes[dst]
+            return words(prefix, lat_np[a, b], thr_np[a, b])
+
+        kw = words("", lat_np, thr_np)
+        if self._s_flows:
+            kw.update(paths("flow_", self._el_np, self._peer_np))
+        else:  # [2]-placeholders
+            kw.update(words("flow_", np.zeros(2, np.int32),
+                            np.zeros(2, np.int64)))
+        # on one graph node the [1, 1] lookup already folds to a scalar
+        if self._g_peers_np is not None and lat_np.shape[0] > 1:
+            peers = self._g_peers_np.T  # [F, N]
+            kw.update(paths("g_", np.arange(peers.shape[1])[None, :], peers))
+        return kw
 
     # -- multi-chip plane (parallel/mesh.py) -------------------------------
 
@@ -1330,33 +1350,11 @@ class TpuEngine:
     # -- fault-epoch segmentation ------------------------------------------
 
     def _segment_tables(self, snap) -> lanes.LaneTables:
-        """Re-upload the versioned gather tables for a fault epoch: the
-        [G, G] latency/threshold tables plus the per-flow compactions the
-        stream tier gathers from them."""
-        import jax.numpy as _jnp
-
-        lat_np = np.asarray(snap.latency_ns)
-        thr_np = np.asarray(snap.loss_threshold)
-        kw = dict(
-            lat=_jnp.asarray(lat_np, dtype=_jnp.int32),
-            thresh_u32=_jnp.asarray(
-                (thr_np & 0xFFFFFFFF).astype(np.uint32)
-            ),
-            thresh_all=_jnp.asarray(thr_np >= (1 << 32)),
-        )
-        if self._s_flows:
-            e_nodes = np.asarray(self._node_idx)[self._el_np]
-            p_nodes = np.asarray(self._node_idx)[self._peer_np]
-            flow_lat = lat_np[e_nodes, p_nodes].astype(np.int32)
-            flow_thr = thr_np[e_nodes, p_nodes]
-            kw.update(
-                flow_lat=_jnp.asarray(flow_lat),
-                flow_thresh_u32=_jnp.asarray(
-                    (flow_thr & 0xFFFFFFFF).astype(np.uint32)
-                ),
-                flow_thresh_all=_jnp.asarray(flow_thr >= (1 << 32)),
-            )
-        return self.tables._replace(**kw)
+        """Re-upload the versioned path tables for a fault epoch: the
+        [G, G] latency/threshold tables plus their per-flow and
+        per-gossip-peer compactions (``_path_tables``)."""
+        return self.tables._replace(
+            **self._path_tables(snap.latency_ns, snap.loss_threshold))
 
     def _run_faulted(
         self, mode: str, on_window=None, resume_state=None,
@@ -1625,6 +1623,7 @@ class TpuEngine:
             )
         log_count = int(s.log_count)
         log_lost = int(s.log_lost)
+        path_rows, path_gathers = lanes.path_sends(p, self.tables)
         self.lane_plane = {
             **shapes,
             "lanes": self.params.n_lanes,
@@ -1656,6 +1655,12 @@ class TpuEngine:
             "max_path_latency_ns": self._max_path_latency_ns,
             "has_loss": int(self.params.has_loss),
             "stream_wide_pop": int(self.params.stream_wide_pop),
+            # of a pop's F sends, how many read their path from per-peer
+            # rows built at start-up and how many may gather it at run
+            # time from a graph of more than one node (static per
+            # compiled program)
+            "static_path_sends": path_rows,
+            "path_gather_sends": path_gathers,
         }
         if p.copop_inert:
             # pop slots (a run offers lane_iters x pops x lanes of them)

@@ -212,6 +212,35 @@ def test_phold_run_fn_full_width(one_chip, as_tpu):
         assert f"/{scope}/" in text, scope
 
 
+def test_gossip_wan_run_fn_reads_rows_and_gathers_no_path(one_chip, as_tpu):
+    """Ethereum-style gossip over a routed, lossy graph as cell
+    ``gossip10k_wan_slot`` times it (D = 8, the seed's words as
+    arguments), kept at 1 000 nodes over 50 graph nodes: the fan-out in
+    the loop form the chip takes, each send's path read from the lane's
+    per-peer rows — no ``gather`` in the compiled ``path_lookup`` scope
+    (PR 42; 64 of them were 79 % of the cell's device time)."""
+    from shadow_tpu.config.scenarios import gossip_mesh_config
+
+    cfg = gossip_mesh_config(1_000, 8, 1, ("1 s",), 8, 512,
+                             bandwidth="1 Gbit", graph_nodes=50,
+                             graph_seed=1)
+    cfg.general.stop_time = 1200 * MS
+    eng = TpuEngine(cfg, log_capacity=0)
+    p, tb = eng.params, eng.tables
+    assert p.has_loss and p.sends_per_pop == 8 and tb.lat.shape == (50, 50)
+    assert tb.g_lat.shape == (8, 1_000)
+    assert lanes.path_sends(p, tb) == (8, 0)
+    word = jax.ShapeDtypeStruct((), np.uint32, sharding=one_chip)
+    compiled = lanes.make_run_fn(p, tb).lower(
+        _shapes(eng.initial_state(), one_chip), word, word).compile()
+    _fits(compiled)
+    lines = compiled.as_text().splitlines()
+    scoped = [line for line in lines if "/path_lookup/" in line]
+    assert scoped and not [line for line in scoped if " gather(" in line]
+    # the parser sees the program's other gathers (the window's)
+    assert any(" gather(" in line for line in lines)
+
+
 def test_udp_round_fn_step_driver(one_chip, as_tpu):
     """The step driver's one-round kernel (run-control / checkpointing),
     kept at 1 000 lanes (same body; the full width is the case above)."""

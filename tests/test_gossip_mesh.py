@@ -20,12 +20,20 @@ neither engine can fake.
     ``models/gossip.py`` for both backends, counters the oracle's equal;
 (g) the same factory over a routed, lossy graph (``graph_nodes``): ONE
     network whatever the run's seed, the lane engine equal to the oracle
-    on it — fused, step and sharded — and one program for every seed.
+    on it — fused, step and sharded — and one program for every seed;
+(h) a static destination's path is a table row (ISSUE 42): on a graph of
+    more than one node a gossip lane's D peers carry ``[F, N]`` latency
+    and loss-threshold rows, equal to the ``[G, G]`` tables element for
+    element and rebuilt at every fault epoch; an all-gossip program
+    traces no ``[G, G]`` gather, a program with a run-time destination
+    beside it gathers for send 0 alone, and both equal the oracle.
 """
 
 import functools
 import hashlib
+import re
 
+import jax
 import numpy as np
 import pytest
 
@@ -96,7 +104,8 @@ def _lossy_cfg(backend="tpu"):
 def _oracle_run(cfg):
     eng = CpuEngine(cfg)
     res = eng.run()
-    last = max(a.last_first_ns for h in eng.hosts for a in h.apps)
+    last = max(getattr(a, "last_first_ns", 0)
+               for h in eng.hosts for a in h.apps)
     return res, last
 
 
@@ -589,3 +598,224 @@ def test_any_mesh_shape_equals_the_oracle_on_the_graph(devices):
     assert eng.lane_plane["mesh_devices"] == devices
     assert eng.lane_plane["graph_nodes"] == 4
     assert sum(_ages(res.counters)) == res.counters["gossip_first"] > 0
+
+
+# -- (h) a static destination's path is a table row ------------------------------
+
+
+def _rows_cfg(backend="tpu", degree=4, faults=()):
+    """Eighteen gossip nodes over THREE graph nodes, beside two tgen
+    clients whose datagrams go to a server each: a client's one send a
+    tick takes its path from the ``[G, G]`` gather (send 0 of a mixed
+    program), a gossip lane's from its rows.  Every path between graph
+    nodes 1 and 2 loses everything (``thresh_all``), the others 0 / 5 /
+    20 %, and a host sends 2 Mbit, so the order of a pop's charges and
+    draws shows in the log.  PHOLD and tgen-mesh cannot stand here: their
+    datagrams reach every host, and the oracle's gossip handler reads a
+    message id off whatever it is handed."""
+    args = ["--degree", str(degree), "--mesh-seed", "3", "--bursts",
+            "1 s,1500 ms", "--messages", "3", "--size", "512"]
+    gossip = {"count": 6, "processes": [
+        {"path": "gossip", "args": args, "start_time": "0 s"}]}
+
+    def client(server):
+        return {"processes": [{
+            "path": "tgen-client", "start_time": "990 ms",
+            "args": ["--server", server, "--interval", "7 ms", "--size",
+                     "900"]}]}
+
+    server = {"processes": [{"path": "tgen-server", "start_time": "0 s"}]}
+    node = ('  node [ id %d host_bandwidth_up "2 Mbit" '
+            'host_bandwidth_down "2 Mbit" ]\n')
+    cfg = {
+        "general": {"stop_time": "2500 ms", "seed": 11,
+                    "heartbeat_interval": None, "bootstrap_end_time": "0 s"},
+        "network": {"graph": {"type": "gml", "inline": (
+            "graph [\n  directed 0\n" + node % 0 + node % 1 + node % 2
+            + '  edge [ source 0 target 0 latency "5 ms" ]\n'
+            '  edge [ source 1 target 1 latency "5 ms" ]\n'
+            '  edge [ source 2 target 2 latency "6 ms" packet_loss 0.05 ]\n'
+            '  edge [ source 0 target 1 latency "8 ms" packet_loss 0.2 ]\n'
+            '  edge [ source 1 target 2 latency "11 ms" packet_loss 1.0 ]\n'
+            '  edge [ source 0 target 2 latency "9 ms" ]\n]\n')}},
+        "experimental": {"network_backend": backend,
+                         "tpu_lane_queue_capacity": 64,
+                         "tpu_events_per_round": 2},
+        "hosts": {"a": {**gossip, "network_node_id": 0},
+                  "b": {**gossip, "network_node_id": 1},
+                  "c": {**gossip, "network_node_id": 2},
+                  "ka": {**client("sb"), "network_node_id": 0},
+                  "kb": {**client("sa"), "network_node_id": 2},
+                  "sa": {**server, "network_node_id": 0},
+                  "sb": {**server, "network_node_id": 1}},
+    }
+    if faults:
+        cfg["faults"] = {"events": list(faults)}
+    return ConfigOptions.from_dict(cfg)
+
+
+#: epochs that change the latency and the loss of links between a gossip
+#: node and its peers, inside both floods: a longer 0-1 link, a 0-2 link
+#: that loses everything, a 1-2 link that no longer does
+FAULTS = (
+    {"at": "1010 ms", "kind": "latency", "source": 0, "target": 1,
+     "latency": "12 ms"},
+    {"at": "1020 ms", "kind": "loss", "source": 0, "target": 2, "loss": 1.0},
+    {"at": "1505 ms", "kind": "loss", "source": 1, "target": 2, "loss": 0.3},
+    {"at": "1515 ms", "kind": "link_down", "source": 0, "target": 2},
+)
+
+
+def _assert_rows_are_the_tables(tb):
+    """``g_*[k, n]`` is the ``[G, G]`` word at ``[node_of[n],
+    node_of[g_peers[n, k]]]``, element for element."""
+    node_of, peers = np.asarray(tb.node_of), np.asarray(tb.g_peers)
+    src, dst = node_of[None, :], node_of[peers.T]
+    for rows, table, dtype in ((tb.g_lat, tb.lat, np.int32),
+                               (tb.g_thresh_u32, tb.thresh_u32, np.uint32),
+                               (tb.g_thresh_all, tb.thresh_all, np.bool_)):
+        rows = np.asarray(rows)
+        assert rows.shape == peers.T.shape and rows.dtype == dtype
+        assert (rows == np.asarray(table)[src, dst]).all()
+
+
+def test_the_per_peer_rows_are_the_tables_element_for_element():
+    eng = TpuEngine(_rows_cfg(), log_capacity=0)
+    tb = eng.tables
+    assert tb.lat.shape == (3, 3) and tb.g_lat.shape == (4, 22)
+    _assert_rows_are_the_tables(tb)
+    # a pair that loses everything is among them, and pairs that differ
+    lost = np.asarray(tb.g_thresh_all)
+    assert 0 < lost.sum() < lost.size
+    assert len(np.unique(np.asarray(tb.g_lat))) > 3
+    # the seeded deployment graph: rows of every width equal their tables
+    wan = TpuEngine(_wan_cfg(), log_capacity=0).tables
+    assert wan.g_lat.shape == (WAN["degree"], WAN["nodes"])
+    _assert_rows_are_the_tables(wan)
+    assert not np.asarray(wan.g_thresh_all).any()
+
+
+def test_on_one_graph_node_there_are_no_rows():
+    """The ``[1, 1]`` lookup already folds to a scalar: no rows, no gauge,
+    and the program is the parent's (``tests/test_turn_block.py`` pins its
+    lowered text)."""
+    eng = TpuEngine(_cfg(64, 4, 3), log_capacity=0)
+    tb = eng.tables
+    assert tb.g_peers.shape == (64, 4) and tb.lat.shape == (1, 1)
+    assert tb.g_lat == () and tb.g_thresh_u32 == () and tb.g_thresh_all == ()
+    assert lanes.path_sends(eng.params, tb) == (0, 0)
+    eng.run(mode="device")
+    assert (eng.lane_plane["static_path_sends"],
+            eng.lane_plane["path_gather_sends"]) == (0, 0)
+
+
+def test_a_fault_epoch_rebuilds_the_rows_from_its_own_tables():
+    eng = TpuEngine(_rows_cfg(faults=FAULTS), log_capacity=0)
+    plan = eng._fault_overlay.segment_plan(eng.params.stop_time)
+    snaps = [snap for _start, _end, snap in plan if snap is not None]
+    assert len(snaps) == len(FAULTS)
+    def rows(tb):
+        return b"".join(np.asarray(a).tobytes() for a in (
+            tb.g_lat, tb.g_thresh_u32, tb.g_thresh_all))
+
+    seen = {rows(eng.tables)}
+    for snap in snaps:
+        tb = eng._segment_tables(snap)
+        assert (np.asarray(tb.lat) == np.asarray(snap.latency_ns)).all()
+        _assert_rows_are_the_tables(tb)
+        assert (np.asarray(tb.g_peers) == np.asarray(eng.tables.g_peers)).all()
+        seen.add(rows(tb))
+    assert len(seen) == len(FAULTS) + 1  # every epoch moved some row
+
+
+def _path_gathers(eng, *args):
+    """The ``gather`` operations the run program traces under the
+    ``path_lookup`` scope (locations of the lowered text)."""
+    text = lanes.make_run_fn(eng.params, eng.tables).lower(
+        eng.initial_state(), *args).as_text(debug_info=True)
+    assert "path_lookup/" in text and "window_gather/" in text
+    scoped = set(re.findall(
+        r'^(#loc\d+) = loc\("[^"]*path_lookup/gather"', text, re.M))
+    ops = re.findall(r'"stablehlo\.gather".* loc\((#loc\d+)\)$', text, re.M)
+    assert len(ops) > len(scoped)  # the window's own gather is seen
+    return sum(loc in scoped for loc in ops)
+
+
+def test_an_all_gossip_program_on_a_graph_traces_no_path_gather(monkeypatch):
+    """Whether the ``[G, G]`` gather is compiled follows from the models
+    present and the graph's size alone: none where every send's
+    destination is a mesh peer; PHOLD's drawn destination on a lossy
+    graph still gathers ``node_of[dst]`` and the three words."""
+    seed = (np.uint32(7), np.uint32(0))
+    gossip = TpuEngine(_lossy_cfg(), log_capacity=0)
+    assert gossip.params.has_loss and gossip.tables.lat.shape == (2, 2)
+    assert lanes.path_sends(gossip.params, gossip.tables) == (4, 0)
+    assert _path_gathers(gossip, *seed) == 0
+    gossip.run(mode="device")
+    assert (gossip.lane_plane["static_path_sends"],
+            gossip.lane_plane["path_gather_sends"]) == (4, 0)
+
+    phold = TpuEngine(phold_tests._lossy_routed_graph("tpu"), log_capacity=0)
+    assert phold.params.has_loss and phold.tables.lat.shape == (3, 3)
+    assert phold.tables.g_lat == ()
+    assert _path_gathers(phold, *seed) == 4  # of ONE slot body (scanned)
+    phold.run(mode="device")
+    assert (phold.lane_plane["static_path_sends"],
+            phold.lane_plane["path_gather_sends"]) == (0, 1)
+
+    # gossip beside a model whose send gathers: in the loop form, which
+    # the chip takes, send 0 alone gathers (k is a Python integer there);
+    # under XLA:CPU's rolled scan the one scanned body does
+    mixed = TpuEngine(_rows_cfg(), log_capacity=0)
+    assert lanes.path_sends(mixed.params, mixed.tables) == (4, 1)
+    assert _path_gathers(mixed, *seed) == 4
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pops = mixed.params.pops_per_iter
+    assert _path_gathers(mixed, *seed) == 4 * pops
+    assert _path_gathers(gossip, *seed) == 0
+    assert _path_gathers(phold, *seed) == 4 * pops
+
+
+def _oracle_less_tgen_sent(cfg):
+    oracle, last = _oracle_run(cfg)
+    # the lane backend has never counted a tgen client's sent bytes
+    oracle.counters.pop("tgen_sent_bytes")
+    return oracle, last
+
+
+@pytest.mark.parametrize("mode", ["device", "step", "loop_form"])
+def test_gossip_beside_a_gathered_destination_equals_the_oracle(mode):
+    """One program, both sources of a path: a tgen client's send 0 gathers
+    its words, a gossip lane's sends read their rows, selected lane by
+    lane as ``dst`` is.  ``loop_form`` is the fan-out as the chip and a
+    sharded build take it (send 0 alone gathers), at degree 2 — XLA:CPU
+    pays 35x more for every unrolled send."""
+    degree = 2 if mode == "loop_form" else 4
+    oracle, last = _oracle_less_tgen_sent(_rows_cfg("cpu", degree))
+    eng = TpuEngine(_rows_cfg(degree=degree))
+    if mode == "loop_form":
+        with lanes._force_unroll():
+            res = eng.run(mode="device")
+    else:
+        res = eng.run(mode=mode)
+    _assert_equals_oracle(eng, res, oracle, last)
+    c = res.counters
+    assert c["lane_drop_loss"] > 10 and c["tgen_recv_bytes"] > 100_000
+    assert (eng.lane_plane["static_path_sends"],
+            eng.lane_plane["path_gather_sends"]) == (degree, 1)
+    assert set(eng.params.models_present) == {
+        lanes.M_TGEN_CLIENT, lanes.M_TGEN_SERVER, lanes.M_GOSSIP}
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize("mode", ["device", "step"])
+def test_a_faulted_gossip_run_equals_the_oracle(mode):
+    """Every epoch's rows are that epoch's tables: a send at or after the
+    epoch takes the new latency and loss, an earlier one never does."""
+    oracle, last = _oracle_less_tgen_sent(_rows_cfg("cpu", faults=FAULTS))
+    calm, _last = _oracle_less_tgen_sent(_rows_cfg("cpu"))
+    assert oracle.log_tuples() != calm.log_tuples()  # the schedule bit
+    eng = TpuEngine(_rows_cfg(faults=FAULTS))
+    res = eng.run(mode=mode)
+    _assert_equals_oracle(eng, res, oracle, last)
+    assert res.counters["lane_drop_loss"] > calm.counters["lane_drop_loss"]

@@ -272,9 +272,15 @@ def test_read_egress_returns_the_first_count_rows(hybrid_engine, which):
 #: the body changes these on purpose: recompute them on its own parent.
 #: PR 41 did, for the gossip body alone: every gossip program gained the
 #: propagation histogram (``gossip_age``; 4c9ad9dc... at PR 39).
+#: ``gossip_wan`` (PR 42) is the gossip body on a routed, lossy graph, whose
+#: sends read their path from per-peer rows: computed on PR 42's OWN tree
+#: (its parent gathered from the [G, G] tables and had no such pin), so
+#: the next PR that touches ``one_send`` sees it move.
 PARENT_TEXT = {
     "gossip":
         "4fa8d8629b29a1a7c0bb695f3db78370c23c73df09816d7b785d8794904d6da1",
+    "gossip_wan":
+        "1b8ad06ae05ac624d98c5ebcca5e99f2aab5e9d15c276574162a9fc11ee526ff",
     "routed_tcp_loss":
         "c44fe83e54f6ad654aa44aaf33217edb896ea3d9e54883fa6cf172f66db1ac34",
     "sharded_passive_mesh":
@@ -287,6 +293,10 @@ def _lowered_text(name):
         import test_gossip_mesh
 
         cfg = test_gossip_mesh._cfg(64, 4, 3)
+    elif name == "gossip_wan":
+        import test_gossip_mesh
+
+        cfg = test_gossip_mesh._wan_cfg()
     elif name == "routed_tcp_loss":
         import test_routed_factory
 
