@@ -336,10 +336,12 @@ class LaneState(NamedTuple):
     # | self | cross]`` held in any merge, overflow tail included (above
     # the capacity: the queue shed); the largest segment the exchange
     # offered any lane in any iteration (above ``cross_cap``: the cross
-    # block shed); and how many events the cross block shed in all (the
-    # part of ``n_queue`` no wider queue would have saved).  Three
-    # reductions an iteration: () — nothing traced, the program unchanged
-    # — where every lane's model is passive (``LaneParams.all_passive``)
+    # block shed, or — a fan-out iteration of several passes, each
+    # holding ``cross_cap`` — may have); and how many events the cross
+    # block shed in all (the part of ``n_queue`` no wider queue would
+    # have saved).  Three reductions an iteration: () — nothing traced,
+    # the program unchanged — where every lane's model is passive
+    # (``LaneParams.all_passive``)
     peaks: Any = ()
     # int32 scalar: pop slots consumed under the window-inert co-pop rule
     # (pop_mask) that the same-instant rule would have refused; its share
@@ -348,6 +350,14 @@ class LaneState(NamedTuple):
     # counters.  () — nothing traced — where no lane's model is in
     # WINDOW_INERT_MODELS (``LaneParams.copop_inert``)
     copop_wide_pops: Any = ()
+    # two int32 scalars of a fan-out program's exchange (``_merge_append``
+    # step 2): the iterations whose sending (pop, lane) slots fitted ONE
+    # pass of the compacted exchange, and the most slots that sent in any
+    # one iteration (against ``LaneParams.exchange_slot_budget``).  Read
+    # into collect()'s ``lane_plane``, never into the counters.  () —
+    # nothing traced — where a pop sends once (``sends_per_pop`` == 1)
+    exchange_compact_iters: Any = ()
+    exchange_slot_peak: Any = ()
     # gossip lanes' app state (a ``GossipState`` of per-lane arrays; ()
     # — nothing traced — where no lane runs M_GOSSIP)
     gossip: Any = ()
@@ -504,17 +514,36 @@ class LaneParams:
 
     @property
     def exchange_entries(self) -> int:
-        """Rows of the [N] exchange's flat sort per iteration: K pops of F
-        sends a lane, plus the compacted stream channels (slot-0 sends,
-        RTO arms, bursts) where they ride it (``_merge_append`` holds it
-        to the traced shape)."""
-        m = self.pops_per_iter * self.sends_per_pop * self.n_lanes
+        """Rows of the [N] exchange's flat sort: K pops of one send a
+        lane, plus the compacted stream channels (slot-0 sends, RTO arms,
+        bursts) where they ride it (``_merge_append`` holds it to the
+        traced shape) — or, where a pop fans out, the F sends of
+        ``exchange_slot_budget`` sending slots a pass."""
+        if self.sends_per_pop > 1:
+            return self.exchange_slot_budget * self.sends_per_pop
+        m = self.pops_per_iter * self.n_lanes
         if (self.stream_present and not self.stream_tiered
                 and not self.stream_one_to_one):
             m += self.pops_per_iter * len(self.stream_clients) * (
                 4 + lstr.ltcp.PUMP_BURST
             )
         return m
+
+    @property
+    def exchange_slot_budget(self) -> int:
+        """S_b: the sending (pop, lane) slots ONE pass of a fan-out
+        program's exchange takes (``_merge_append`` step 2).  A shape
+        law, no option: the compacted exchange, ``S_b x F`` rows, is as
+        wide as a one-send program's ``K x N``, the slots rounded up to
+        ``_SLOT_TILE`` (2 560 slots = 20 480 rows at K = 2, F = 8 and
+        10 000 lanes, where the send channel has 160 000), and never more
+        slots than there are.  0 where a pop sends once: that exchange is
+        ``K x N`` rows already."""
+        if self.sends_per_pop == 1:
+            return 0
+        slots = self.pops_per_iter * self.n_lanes
+        tiles = -(-slots // (self.sends_per_pop * _SLOT_TILE))
+        return min(slots, tiles * _SLOT_TILE)
 
     def __post_init__(self) -> None:
         if self.n_lanes > MAX_LANES:
@@ -2059,6 +2088,112 @@ def _bounds_by_onehot_chunked(dst, n):
     return bounds[:n], bounds[1:] - bounds[:n]
 
 
+#: a fan-out program's slot budget is a whole number of these many (pop,
+#: lane) slots (``LaneParams.exchange_slot_budget``): the compacted
+#: columns' minor dimension fills the chip's 128-wide tiles.  A shape the
+#: code observes, not an option (tests patch the budget itself, as they
+#: patch ``_ONEHOT_BUDGET``)
+_SLOT_TILE = 128
+
+
+def _sorted_exchange(flat_ops, n):
+    """The exchange proper over flat columns of any length ``[m]``: ONE
+    single-key sort by destination (``flat_ops[0]``; an invalid send has
+    ``dst == n``), then each lane's slice bounds of the sorted columns by
+    the law the static shape picks (``exchange_bounds_wide``; both give
+    the same integers).  Returns the sorted columns less ``dst``, and
+    ``start`` / ``cnt`` ``[n]``."""
+    # the sort need not be stable: within a destination's segment the real
+    # entries carry the 4-word event key, a TOTAL order (ties impossible
+    # between distinct events), and the merge sort below re-orders by that
+    # key anyway.  Unstable drops XLA's hidden iota tiebreaker operand
+    # from every compare-exchange stage.  The one observable: when a
+    # segment overflows cross_cap, WHICH entries are shed is no longer
+    # emission order but the sort network's choice — still deterministic
+    # for a given compiled program, and strict mode (the default) raises
+    # on any shed; non-strict overflow was already documented as
+    # non-parity (see tpu_engine.py's strict_capacity note).
+    with jax.named_scope("exchange_sort"):
+        sorted_ops = lax.sort(
+            tuple(flat_ops), dimension=0, num_keys=1, is_stable=False
+        )
+    with jax.named_scope("exchange_bounds"):
+        if exchange_bounds_wide(flat_ops[0].shape[0], n):
+            start, cnt = _bounds_by_onehot_chunked(flat_ops[0], n)
+        else:
+            start, cnt = _bounds_by_onehot(flat_ops[0], n)
+    return list(sorted_ops[1:]), start, cnt
+
+
+def _rank_sending(emits: _SlotEmit, budget, n):
+    """What a fan-out iteration's exchange reads, built once an iteration
+    from the ``[K, F, N]`` send channel: how many (pop, lane) slots put a
+    datagram into the exchange (some ``out_valid[k, :, lane]`` set); their
+    slot ids ranked to the front by ONE single-operand sort of the ``K x
+    N`` slot ids (a slot that did not send is keyed past them all), padded
+    to whole passes of ``budget``; and the slots' words as a ``[K x N, 4 F
+    + 4]`` table of ONE row a slot — its F destinations (``n`` where the
+    send is lost or unused), arrival pairs and sequence numbers, and the
+    four words of its pop (``auxh``, ``size``, ``phi``, ``plo``: one a
+    slot, not F)."""
+    k, f, lanes_n = emits.out_valid.shape
+    slots = k * lanes_n
+    with jax.named_scope("exchange_compact"):
+        sending = jnp.any(emits.out_valid, axis=1).reshape(-1)
+        ranked = lax.sort(
+            jnp.where(sending, jnp.arange(slots, dtype=jnp.int32),
+                      jnp.int32(slots)),
+            dimension=0, is_stable=False,
+        )
+        ranked = jnp.pad(ranked, (0, (-slots) % budget),
+                         constant_values=slots)
+        dst = jnp.where(emits.out_valid, emits.out_dst, jnp.int32(n))
+        table = jnp.concatenate(
+            [dst, emits.out_thi, emits.out_tlo, emits.out_auxl]
+            + [w[:, :1] for w in (emits.out_auxh, emits.out_size,
+                                  emits.out_phi, emits.out_plo)],
+            axis=1,
+        )  # [K, 4F + 4, N]
+        table = table.transpose(0, 2, 1).reshape(slots, 4 * f + 4)
+    return sending.sum(dtype=jnp.int32), ranked, table
+
+
+def _compact_sends(ranked, table, i, budget, n):
+    """Pass ``i`` of a fan-out iteration's exchange: the eight flat
+    columns ``_sorted_exchange`` takes (``dst``, the arrival pair,
+    ``auxh``, ``auxl``, ``size``, the payload pair) over the ``i``-th
+    ``budget`` of the ranked sending slots — ``budget x F`` rows where the
+    send channel has ``K x F x N``.  A slot's words are fetched as ONE row
+    of the table and one 2-D transpose puts the slots minor again, where
+    every word's ``[F, budget]`` block is the flat column as it lies:
+    ``_window_gather``'s layout rule (a gathered row at least a tile wide,
+    the long axis minor once), and never an element gather out of an
+    ``[N]``-minor send word (6.7–11.2 ns an element on a v5e, PERF.md §6
+    PR 41).  Rows past the last sending slot carry ``dst == n`` (the
+    table's own for a lost or unused send)."""
+    slots, width = table.shape
+    f = (width - 4) // 4
+    with jax.named_scope("exchange_compact"):
+        pick = lax.dynamic_slice(ranked, (i * budget,), (budget,))
+        block = table[jnp.minimum(pick, slots - 1)].T  # [4F + 4, budget]
+
+        def per_send(w):
+            return block[w * f:(w + 1) * f]  # [F, budget]
+
+        def per_pop(w):
+            return jnp.broadcast_to(block[4 * f + w][None, :], (f, budget))
+
+        # a slot past the last sending one reads SOME row: only its
+        # destinations have to say so
+        cols = [
+            jnp.where((pick < slots)[None, :], per_send(0), jnp.int32(n)),
+            per_send(1), per_send(2),  # arrival pair
+            per_pop(0), per_send(3),  # auxh, auxl
+            per_pop(1), per_pop(2), per_pop(3),  # size, phi, plo
+        ]
+        return [c.reshape(-1) for c in cols]
+
+
 def _row_fill(mthi):
     """Live events per row of a merged block ``[rows, C + ...]`` (an empty
     slot's time is the NEVER pair): what ``queue_peak`` is the maximum
@@ -2085,7 +2220,23 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
        ``[N, Cx]`` block (``Cx = cross_cap``; ``_window_gather``: tile-wide
        rows, lanes minor once) — the batched equivalent of the reference's
        cross-host queue push (worker.rs:603-615); stream-endpoint lanes'
-       slices reach the tier by the same gather over ``[2S]`` lanes;
+       slices reach the tier by the same gather over ``[2S]`` lanes.
+       **Where a pop fans out** (``p.sends_per_pop`` > 1, static) the
+       exchange runs over the (pop, lane) slots that SENT, not over every
+       slot that could have: the sending slots are ranked to the front
+       (``_rank_sending``: one single-operand sort of the K x N slot ids)
+       and taken ``p.exchange_slot_budget`` a pass (S_b = ceil(K N / F)
+       rounded up to ``_SLOT_TILE``: a static shape law, the compacted
+       exchange as wide as a one-send program's), each slot's F sends
+       fetched as ONE table row (``_compact_sends``), then the same sort,
+       bounds and cross block over ``S_b x F`` rows and step 3.  One
+       ``lax.while_loop`` of ceil(sending / S_b) passes, at least one:
+       the merge is associative, so a flood's front costs a few passes
+       and every other iteration one.  The carry counts the one-pass
+       iterations and keeps the most slots one iteration sent
+       (``lane_plane``: ``exchange_compact_iters``, ``exchange_slot_budget``,
+       ``exchange_slot_peak``; absent where a pop sends once, whose
+       program is untouched);
     3. one row-sort of ``[old C | self | cross Cx]`` by the 4-word key
        keeps the first C per lane — the queue's sorted invariant is
        maintained, so the pop phase needs no sort at all.
@@ -2134,12 +2285,6 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     self_plo = jnp.concatenate(plo_parts, axis=1)
 
     # -- cross-lane block [N, Cx] via sort-by-dst + histogram bounds -------
-    valid = emits.out_valid.reshape(-1)
-    dst = jnp.where(valid, emits.out_dst.reshape(-1), jnp.int32(n))
-    out_thi = emits.out_thi.reshape(-1)
-    out_tlo = emits.out_tlo.reshape(-1)
-    flat_ops = [dst, out_thi, out_tlo, emits.out_auxh.reshape(-1),
-                emits.out_auxl.reshape(-1), emits.out_size.reshape(-1)]
     # one-to-one stream configs take the SPLIT exchange: every stream
     # channel entry's destination is static (each lane has one flow, one
     # role), so stream events skip the flat sort entirely and merge
@@ -2151,6 +2296,54 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     # by the keyed merge either way.
     split_se = sp and p.stream_one_to_one
     has_pay_flat = pay and not split_se
+    cx = p.cross_cap
+    self_words = [self_thi, self_tlo, self_auxh, self_auxl, self_size,
+                  self_phi, self_plo]
+
+    if p.sends_per_pop > 1:
+        # a pop that fans out made the send channel F times wider and left
+        # it as empty as it was (a gossip slot sends only on a publish or a
+        # FIRST delivery: 1–3 % of its rows are real), so the exchange runs
+        # over the slots that SENT, ``exchange_slot_budget`` of them a
+        # pass: as wide as a one-send program's.  Nearly every iteration
+        # is one pass; a denser one (a flood's front) takes ceil(sending /
+        # budget), each through the cross block and the row merge — the
+        # merge is associative, the rows' 4-word key a total order, so
+        # every lane's queue is what one exchange of all K x F x N rows
+        # would leave, and strict capacity raises on any shed.  (Not a
+        # ``lax.cond`` beside the full exchange: a second 8-operand sort
+        # is 4 MB of code, PERF.md §6 PR 43.)
+        assert has_pay_flat and not divert  # gossip rides no stream tier
+        budget = p.exchange_slot_budget
+        n_sending, ranked, table = _rank_sending(emits, budget, n)
+        passes = jnp.maximum((n_sending + (budget - 1)) // budget, 1)
+
+        def one_pass(carry):
+            i, st, cnt_all = carry
+            gather_ops, start, cnt = _sorted_exchange(
+                _compact_sends(ranked, table, i, budget, n), n)
+            words = _cross_block(gather_ops, start, cnt, cx)[1]
+            # the same-lane block rides the first pass
+            own = [jnp.where(i == 0, w, NEVER32) for w in self_words[:2]]
+            cnt_all = cnt_all + cnt
+            st = _merge_rows(p, st, own + self_words[2:], words, cnt_all,
+                             jnp.maximum(cnt - cx, 0))
+            return i + 1, st, cnt_all
+
+        _, s, _ = lax.while_loop(
+            lambda carry: carry[0] < passes, one_pass,
+            (jnp.int32(0), s, jnp.zeros(n, dtype=jnp.int32)),
+        )
+        return s._replace(
+            exchange_compact_iters=s.exchange_compact_iters + (passes == 1),
+            exchange_slot_peak=jnp.maximum(s.exchange_slot_peak, n_sending),
+        )
+    valid = emits.out_valid.reshape(-1)
+    dst = jnp.where(valid, emits.out_dst.reshape(-1), jnp.int32(n))
+    out_thi = emits.out_thi.reshape(-1)
+    out_tlo = emits.out_tlo.reshape(-1)
+    flat_ops = [dst, out_thi, out_tlo, emits.out_auxh.reshape(-1),
+                emits.out_auxl.reshape(-1), emits.out_size.reshape(-1)]
     if has_pay_flat:
         flat_ops.append(emits.out_phi.reshape(-1))
         flat_ops.append(emits.out_plo.reshape(-1))
@@ -2230,35 +2423,8 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
         flat_ops = [
             jnp.concatenate([a, b]) for a, b in zip(flat_ops, extras)
         ]
-    # the sort need not be stable: within a destination's segment the real
-    # entries carry the 4-word event key, a TOTAL order (ties impossible
-    # between distinct events), and the merge sort below re-orders by that
-    # key anyway.  Unstable drops XLA's hidden iota tiebreaker operand
-    # from every compare-exchange stage.  The one observable: when a
-    # segment overflows cross_cap, WHICH entries are shed is no longer
-    # emission order but the sort network's choice — still deterministic
-    # for a given compiled program, and strict mode (the default) raises
-    # on any shed; non-strict overflow was already documented as
-    # non-parity (see tpu_engine.py's strict_capacity note).
-    with jax.named_scope("exchange_sort"):
-        sorted_ops = lax.sort(
-            tuple(flat_ops), dimension=0, num_keys=1, is_stable=False
-        )
-    _dst_s, thi_s, tlo_s, auxh_s, auxl_s, size_s = sorted_ops[:6]
-    pay_s = sorted_ops[6:8] if has_pay_flat else None
-    # segment bounds per destination lane: start[d], cnt[d] of lane d's
-    # slice of the sorted columns.  Two laws give the same integers; the
-    # static shape picks one (exchange_bounds_wide), nothing else differs
     assert flat_ops[0].shape[0] == p.exchange_entries
-    with jax.named_scope("exchange_bounds"):
-        if exchange_bounds_wide(p.exchange_entries, n):
-            start, cnt = _bounds_by_onehot_chunked(flat_ops[0], n)
-        else:
-            start, cnt = _bounds_by_onehot(flat_ops[0], n)
-    cx = p.cross_cap
-    gather_ops = [thi_s, tlo_s, auxh_s, auxl_s, size_s] + (
-        list(pay_s) if has_pay_flat else []
-    )
+    gather_ops, start, cnt = _sorted_exchange(flat_ops, n)
     _in_seg, words = _cross_block(gather_ops, start, cnt, cx)
     cross_thi, cross_tlo, cross_auxh, cross_auxl, cross_size = words[:5]
     if pay:
@@ -2294,6 +2460,30 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
         cross_thi = jnp.where(keep, cross_thi, NEVER32)
         cross_tlo = jnp.where(keep, cross_tlo, NEVER32)
 
+    s = _merge_rows(
+        p, s, self_words,
+        [cross_thi, cross_tlo, cross_auxh, cross_auxl, cross_size]
+        + ([cross_phi, cross_plo] if pay else [None, None]),
+        cnt, lost_pre,
+    )
+    if split_se:
+        s = _merge_stream_rows(p, tb, s, emits)
+    return (s, tier_cross) if divert else s
+
+
+def _merge_rows(p: LaneParams, s: LaneState, self_words, cross_words, cnt,
+                lost_pre):
+    """``_merge_append`` step 3 over one cross block: the row sort of
+    ``[old C | self | cross Cx]`` (seven words each; the payload pair
+    ``None`` where the rows carry none) and everything that reads the
+    merged row.  ``cnt`` is what ``cross_peak`` reads, ``lost_pre`` what
+    the block shed before the merge."""
+    n, c = p.n_lanes, p.capacity
+    pay = p.lanes_have_payload
+    (self_thi, self_tlo, self_auxh, self_auxl, self_size, self_phi,
+     self_plo) = self_words
+    (cross_thi, cross_tlo, cross_auxh, cross_auxl, cross_size, cross_phi,
+     cross_plo) = cross_words
     # -- merge [N, C + self + Cx], keep first C ---------------------------
     # queue state is ALREADY the int32 4-word key: no conversions at all
     with jax.named_scope("row_merge"):
@@ -2375,9 +2565,7 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
             tail_mask, mthi[:, c:], mtlo[:, c:], mh[:, c:], ml[:, c:],
             ms[:, c:], jnp.arange(n, dtype=jnp.int32),
         ), tail=True)
-    if split_se:
-        s = _merge_stream_rows(p, tb, s, emits)
-    return (s, tier_cross) if divert else s
+    return s
 
 
 def _tail_records(tail_mask, thi, tlo, auxh, auxl, size, lane_ids):
@@ -3778,7 +3966,12 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                 return _merge_append(p, tb, st, emits)
 
             def do_sort(st: LaneState) -> LaneState:
-                return _sort_queues(st, with_pay=p_lane.lanes_have_payload)
+                st = _sort_queues(st, with_pay=p_lane.lanes_have_payload)
+                if p_lane.sends_per_pop > 1:
+                    # no slot sent: the iteration fits one pass (of none)
+                    st = st._replace(
+                        exchange_compact_iters=st.exchange_compact_iters + 1)
+                return st
 
             s = lax.cond(any_new, do_merge, do_sort, s)
 
@@ -4109,7 +4302,8 @@ def pack_state(s: LaneState):
         [jnp.asarray(getattr(s, f), dtype=jnp.int32) for f in sc_fields]
     )
     return (q, c32, sc, s.log, s.stream, s.egress, s.nb_hist, s.fl_buf,
-            s.peaks, s.copop_wide_pops, s.gossip, s.gossip_age)
+            s.peaks, s.copop_wide_pops, s.exchange_compact_iters,
+            s.exchange_slot_peak, s.gossip, s.gossip_age)
 
 
 def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
@@ -4125,7 +4319,8 @@ def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
 
 def unpack_state(carry) -> LaneState:
     (q, c32, sc, log, stream, egress, nb_hist, fl_buf, peaks,
-     copop_wide_pops, gossip, gossip_age) = carry
+     copop_wide_pops, exchange_compact_iters, exchange_slot_peak, gossip,
+     gossip_age) = carry
     has_pay = q.shape[0] == 7
     # the optional blocks' own carry leaves say which are live; the append
     # counters have none, so the scalar count left over tells
@@ -4148,7 +4343,9 @@ def unpack_state(carry) -> LaneState:
         stream=stream,
         cd_dropping=c32[len(_I32_N_FIELDS)].astype(bool),
         log=log, egress=egress, nb_hist=nb_hist, fl_buf=fl_buf,
-        peaks=peaks, copop_wide_pops=copop_wide_pops, gossip=gossip,
+        peaks=peaks, copop_wide_pops=copop_wide_pops,
+        exchange_compact_iters=exchange_compact_iters,
+        exchange_slot_peak=exchange_slot_peak, gossip=gossip,
         gossip_age=gossip_age, **kw,
     )
 

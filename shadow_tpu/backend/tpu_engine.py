@@ -1089,6 +1089,8 @@ class TpuEngine:
             },
             peaks=() if p.all_passive else full(3),
             copop_wide_pops=full() if p.copop_inert else (),
+            exchange_compact_iters=full() if p.sends_per_pop > 1 else (),
+            exchange_slot_peak=full() if p.sends_per_pop > 1 else (),
             gossip=lanes.GossipState(
                 seen=full((n, self._gossip_words)), sends=lane(),
                 first=lane(), dups=lane(), last_hi=lane(), last_lo=lane(),
@@ -1522,6 +1524,8 @@ class TpuEngine:
             fields.append("peaks")
         if p.copop_inert:
             fields.append("copop_wide_pops")
+        if p.sends_per_pop > 1:
+            fields += ["exchange_compact_iters", "exchange_slot_peak"]
         if p.gossip_degree:
             fields += ["gossip", "gossip_age"]
         if p.netobs:
@@ -1667,6 +1671,18 @@ class TpuEngine:
             # that only the window-inert co-pop rule consumed
             # (lanes.pop_mask): present where the program compiles it
             self.lane_plane["copop_wide_pops"] = int(s.copop_wide_pops)
+        if p.sends_per_pop > 1:
+            # a fan-out program's exchange (lanes._merge_append step 2):
+            # the iterations (of lane_iters) whose sending slots fitted
+            # ONE pass of the compacted exchange, the static budget of
+            # slots a pass takes, and the most slots that sent in one
+            # iteration (at or under the budget: every iteration was one
+            # pass)
+            self.lane_plane.update(
+                exchange_compact_iters=int(s.exchange_compact_iters),
+                exchange_slot_budget=p.exchange_slot_budget,
+                exchange_slot_peak=int(s.exchange_slot_peak),
+            )
         if self.obs is not None:
             for key, val in self.lane_plane.items():
                 self.obs.metrics.gauge(key, val)

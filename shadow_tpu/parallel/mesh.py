@@ -82,7 +82,8 @@ LANE_FIELDS = frozenset((
 REPLICATED_FIELDS = frozenset((
     "log", "log_count", "log_lost", "rounds", "iters", "codel_lookup_pops",
     "now_we_hi", "now_we_lo", "min_used_lat", "stream",
-    "peaks", "copop_wide_pops", "gossip_age",
+    "peaks", "copop_wide_pops", "exchange_compact_iters",
+    "exchange_slot_peak", "gossip_age",
     "egress", "egress_count", "egress_lost",
     "egress_min_hi", "egress_min_lo",
     "nb_hist", "nb_win",

@@ -212,33 +212,93 @@ def test_phold_run_fn_full_width(one_chip, as_tpu):
         assert f"/{scope}/" in text, scope
 
 
-def test_gossip_wan_run_fn_reads_rows_and_gathers_no_path(one_chip, as_tpu):
+@pytest.fixture(scope="module")
+def gossip_wan_compiled(one_chip):
     """Ethereum-style gossip over a routed, lossy graph as cell
     ``gossip10k_wan_slot`` times it (D = 8, the seed's words as
-    arguments), kept at 1 000 nodes over 50 graph nodes: the fan-out in
-    the loop form the chip takes, each send's path read from the lane's
-    per-peer rows — no ``gather`` in the compiled ``path_lookup`` scope
-    (PR 42; 64 of them were 79 % of the cell's device time)."""
+    arguments), kept at 1 000 nodes over 50 graph nodes, the fan-out in
+    the loop form the chip takes: ONE compile for the cases that read it."""
     from shadow_tpu.config.scenarios import gossip_mesh_config
 
-    cfg = gossip_mesh_config(1_000, 8, 1, ("1 s",), 8, 512,
-                             bandwidth="1 Gbit", graph_nodes=50,
-                             graph_seed=1)
-    cfg.general.stop_time = 1200 * MS
-    eng = TpuEngine(cfg, log_capacity=0)
-    p, tb = eng.params, eng.tables
-    assert p.has_loss and p.sends_per_pop == 8 and tb.lat.shape == (50, 50)
-    assert tb.g_lat.shape == (8, 1_000)
-    assert lanes.path_sends(p, tb) == (8, 0)
-    word = jax.ShapeDtypeStruct((), np.uint32, sharding=one_chip)
-    compiled = lanes.make_run_fn(p, tb).lower(
-        _shapes(eng.initial_state(), one_chip), word, word).compile()
-    _fits(compiled)
-    lines = compiled.as_text().splitlines()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")  # as ``as_tpu``
+        cfg = gossip_mesh_config(1_000, 8, 1, ("1 s",), 8, 512,
+                                 bandwidth="1 Gbit", graph_nodes=50,
+                                 graph_seed=1)
+        cfg.general.stop_time = 1200 * MS
+        eng = TpuEngine(cfg, log_capacity=0)
+        p, tb = eng.params, eng.tables
+        assert p.has_loss and p.sends_per_pop == 8
+        assert tb.lat.shape == (50, 50) and tb.g_lat.shape == (8, 1_000)
+        assert lanes.path_sends(p, tb) == (8, 0)
+        # 2 000 slots, 256 of them (2 048 rows) a pass of the exchange
+        assert (p.exchange_slot_budget, p.exchange_entries) == (256, 2_048)
+        word = jax.ShapeDtypeStruct((), np.uint32, sharding=one_chip)
+        return lanes.make_run_fn(p, tb).lower(
+            _shapes(eng.initial_state(), one_chip), word, word).compile()
+
+
+def test_gossip_wan_run_fn_reads_rows_and_gathers_no_path(
+        gossip_wan_compiled):
+    """Each send's path is read from the lane's per-peer rows — no
+    ``gather`` in the compiled ``path_lookup`` scope (PR 42; 64 of them
+    were 79 % of the cell's device time)."""
+    _fits(gossip_wan_compiled)
+    lines = gossip_wan_compiled.as_text().splitlines()
     scoped = [line for line in lines if "/path_lookup/" in line]
     assert scoped and not [line for line in scoped if " gather(" in line]
     # the parser sees the program's other gathers (the window's)
     assert any(" gather(" in line for line in lines)
+
+
+def _scope_gather_operands(text: str, scope: str) -> list[list[int]]:
+    """The operand shape of every ``gather`` the compiled text holds under
+    ``scope`` (a ``jax.named_scope``), as a list of dimensions."""
+    shapes, found = {}, []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]", line)
+        if m:
+            shapes[m.group(1)] = [int(d) for d in m.group(2).split(",") if d]
+    for line in text.splitlines():
+        m = re.search(r" gather\((%[\w.\-]+),", line)
+        if m and f"/{scope}/" in line:
+            found.append(shapes[m.group(1)])
+    return found
+
+
+def test_the_compacted_exchange_gathers_rows_never_send_words(
+        gossip_wan_compiled):
+    """The sending slots' words are fetched as ONE row a slot out of the
+    ``[K x N, 4 F + 4]`` table: the compiled ``exchange_compact`` scope
+    holds that row gather and no gather whose operand is an ``[N]``-minor
+    send word (PR 41 priced those at 6.7–11.2 ns an element); the exchange
+    under it sorts ``S_b x F`` rows, and nothing sorts the K x F x N of
+    the send channel."""
+    text = gossip_wan_compiled.as_text()
+    operands = _scope_gather_operands(text, "exchange_compact")
+    assert operands == [[2 * 1_000, 4 * 8 + 4]]
+    assert not [dims for dims in operands if dims[-1] == 1_000]
+    sorted_rows = {
+        int(re.search(r"s32\[(\d+)[,\]]", line).group(1))
+        for line in text.splitlines() if " sort(" in line}
+    assert 2_048 in sorted_rows and 16_000 not in sorted_rows
+    for scope in ("exchange_compact", "exchange_sort", "exchange_bounds",
+                  "row_merge"):
+        assert f"/{scope}/" in text, scope
+
+
+def test_the_send_word_parser_sees_an_element_gather(one_chip):
+    """The guard above is held to a program that DOES pick elements out of
+    a lanes-minor ``[F, N]`` word under the scope."""
+    def pick(words, idx):
+        with jax.named_scope("exchange_compact"):
+            return words[:, idx]
+
+    words = jax.ShapeDtypeStruct((8, 1_000), np.int32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((256,), np.int32, sharding=one_chip)
+    text = jax.jit(pick).lower(words, idx).compile().as_text()
+    operands = _scope_gather_operands(text, "exchange_compact")
+    assert operands and all(dims[-1] == 1_000 for dims in operands)
 
 
 def test_udp_round_fn_step_driver(one_chip, as_tpu):

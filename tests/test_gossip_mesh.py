@@ -26,7 +26,15 @@ neither engine can fake.
     and loss-threshold rows, equal to the ``[G, G]`` tables element for
     element and rebuilt at every fault epoch; an all-gossip program
     traces no ``[G, G]`` gather, a program with a run-time destination
-    beside it gathers for send 0 alone, and both equal the oracle.
+    beside it gathers for send 0 alone, and both equal the oracle;
+(i) the exchange sorts the slots that sent (ISSUE 43): a fan-out
+    program's exchange runs over its SENDING (pop, lane) slots,
+    ``LaneParams.exchange_slot_budget`` of them a pass — a law of the
+    shape; with the budget patched under a flood's front one run holds
+    one-pass and many-pass iterations and equals the oracle, on one
+    switch, on the routed lossy graph and under faults; the passes
+    together hand every lane the rows and the count ONE exchange of the
+    whole send channel would; and the three gauges keep their law.
 """
 
 import functools
@@ -151,6 +159,30 @@ def _assert_equals_oracle(eng, res, oracle, last):
     if len(eng.params.gossip_bursts) == 1:
         # one burst: it is the last non-empty bucket
         assert not any(ages[bucket + 1:])
+
+
+@pytest.fixture
+def slot_budget(monkeypatch):
+    """Patch the static slot budget (as tests patch ``_ONEHOT_BUDGET``), so
+    that a tiny flood's front takes several passes of the exchange; None
+    leaves the law's own (a tile of slots or every slot), under which a
+    tiny program's every iteration is one pass.  Returns the check of the
+    gauges' law."""
+    def patch(slots):
+        if slots is not None:
+            monkeypatch.setattr(lanes.LaneParams, "exchange_slot_budget",
+                                property(lambda self: slots))
+
+        def check(eng, res):
+            plane, iters = eng.lane_plane, res.counters["lane_iters"]
+            assert plane["exchange_slot_budget"] == (
+                eng.params.exchange_slot_budget)
+            fits = plane["exchange_slot_peak"] <= plane["exchange_slot_budget"]
+            assert 0 < plane["exchange_compact_iters"] <= iters
+            assert (plane["exchange_compact_iters"] == iters) == fits
+            assert fits == (slots is None)
+        return check
+    return patch
 
 
 # -- (a) against the oracle ---------------------------------------------------
@@ -348,6 +380,10 @@ def test_where_a_pop_sends_once_the_program_is_the_parents(name):
     eng = TpuEngine(TINY[name](), log_capacity=0)
     assert eng.params.sends_per_pop == 1 and eng.params.gossip_degree == 0
     assert eng.tables.g_peers == () and eng.initial_state().gossip == ()
+    # no slot budget, no counter in the carry: the exchange is K x N rows
+    assert eng.params.exchange_slot_budget == 0
+    assert eng.initial_state().exchange_compact_iters == ()
+    assert eng.initial_state().exchange_slot_peak == ()
     text = phold_tests._lowered(TINY[name]())
     assert "gossip" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_TEXT[name]
@@ -455,12 +491,16 @@ def _wan_oracle(seed=7):
     return _oracle_run(_wan_cfg("cpu", seed))
 
 
+@pytest.mark.parametrize("budget", [None, 8], ids=["one_pass", "passes"])
 @pytest.mark.parametrize("mode", ["device", "step"])
-def test_on_a_routed_lossy_graph_the_lane_backend_equals_the_oracle(mode):
+def test_on_a_routed_lossy_graph_the_lane_backend_equals_the_oracle(
+        mode, budget, slot_budget):
     oracle, last = _wan_oracle()
+    check_gauges = slot_budget(budget)
     eng = TpuEngine(_wan_cfg())
     res = eng.run(mode=mode)
     _assert_equals_oracle(eng, res, oracle, last)
+    check_gauges(eng, res)
     plane, c = eng.lane_plane, res.counters
     assert (plane["graph_nodes"], plane["has_loss"], plane["window_ns"],
             plane["sends_per_pop"]) == (12, 1, 2 * MS, 4)
@@ -808,14 +848,146 @@ def test_gossip_beside_a_gathered_destination_equals_the_oracle(mode):
 
 
 @pytest.mark.faults
+@pytest.mark.parametrize("budget", [None, 4], ids=["one_pass", "passes"])
 @pytest.mark.parametrize("mode", ["device", "step"])
-def test_a_faulted_gossip_run_equals_the_oracle(mode):
+def test_a_faulted_gossip_run_equals_the_oracle(mode, budget, slot_budget):
     """Every epoch's rows are that epoch's tables: a send at or after the
     epoch takes the new latency and loss, an earlier one never does."""
     oracle, last = _oracle_less_tgen_sent(_rows_cfg("cpu", faults=FAULTS))
     calm, _last = _oracle_less_tgen_sent(_rows_cfg("cpu"))
     assert oracle.log_tuples() != calm.log_tuples()  # the schedule bit
+    check_gauges = slot_budget(budget)
     eng = TpuEngine(_rows_cfg(faults=FAULTS))
     res = eng.run(mode=mode)
     _assert_equals_oracle(eng, res, oracle, last)
+    check_gauges(eng, res)
     assert res.counters["lane_drop_loss"] > calm.counters["lane_drop_loss"]
+
+
+# -- (i) the exchange sorts the slots that sent ---------------------------------
+
+
+@pytest.mark.parametrize("lanes_n, pops, fan, want", [
+    (10_000, 2, 8, 2_560),  # both gossip cells: 20 480 rows a pass
+    (10_000, 4, 8, 5_120), (100_000, 2, 8, 25_088),
+    (64, 2, 4, 128),  # a tiny program: never more slots than there are
+])
+def test_the_slot_budget_is_a_law_of_the_shape(lanes_n, pops, fan, want):
+    p = lanes.LaneParams(
+        n_lanes=lanes_n, capacity=16, pops_per_iter=pops, log_capacity=0,
+        seed=1, stop_time=MS, bootstrap_end=0, runahead=MS,
+        models_present=(lanes.M_GOSSIP,), gossip_degree=fan)
+    assert p.exchange_slot_budget == want
+    assert want % lanes._SLOT_TILE == 0 and want <= pops * lanes_n
+    # as wide as a one-send program's exchange, to the tile
+    assert p.exchange_entries == want * fan
+    assert want == pops * lanes_n or (
+        0 <= want * fan - pops * lanes_n < fan * lanes._SLOT_TILE)
+
+
+@pytest.mark.parametrize("nodes, degree, messages", SMALL)
+def test_a_flood_of_one_pass_and_many_pass_iterations_equals_the_oracle(
+        nodes, degree, messages, slot_budget):
+    """The budget under the flood's front: the same run holds iterations
+    of one pass and iterations of several, and is the oracle's — log,
+    counters, rounds, the peaks under the law's bounds."""
+    check_gauges = slot_budget(nodes // 8)
+    eng = TpuEngine(_cfg(nodes, degree, messages))
+    res = eng.run(mode="device")
+    _assert_equals_oracle(eng, res, *_oracle(nodes, degree, messages))
+    assert _less_ages(res.counters) == _counts(nodes, degree, messages)
+    check_gauges(eng, res)
+    plane = eng.lane_plane
+    assert plane["exchange_slot_peak"] > 2 * plane["exchange_slot_budget"]
+    queue, cross = gossip_shape_law(degree, messages)
+    assert plane["queue_peak"] <= queue and plane["cross_peak"] <= cross
+
+
+@pytest.mark.parametrize("nodes, degree, messages", SMALL)
+def test_under_the_laws_own_budget_every_tiny_iteration_is_one_pass(
+        nodes, degree, messages):
+    res, plane = _lane_run(nodes, degree, messages)
+    assert plane["exchange_compact_iters"] == res.counters["lane_iters"]
+    assert plane["exchange_slot_budget"] == min(GOSSIP_POPS * nodes, 128)
+    assert 0 < plane["exchange_slot_peak"] <= plane["exchange_slot_budget"]
+    assert not {"exchange_compact_iters", "exchange_slot_peak"} & set(
+        res.counters)
+
+
+_XK, _XF, _XN, _XCX, _XBUDGET = 2, 4, 24, 32, 12
+
+
+def _send_channel(sending_slots, seed=5):
+    """A ``[K, F, N]`` send channel in which exactly the first
+    ``sending_slots`` of a seeded order of the (pop, lane) slots send, one
+    to F datagrams each, to seeded destinations; every word distinct."""
+    rs = np.random.RandomState(seed)
+    shape = (_XK, _XF, _XN)
+    sends = rs.rand(*shape) < 0.6
+    sends[:, 0, :] |= ~sends.any(axis=1)  # a sending slot sends something
+    order = rs.permutation(_XK * _XN)[:sending_slots]
+    slot = np.zeros(_XK * _XN, dtype=bool)
+    slot[order] = True
+    valid = sends & slot.reshape(_XK, 1, _XN)
+
+    def word(shape=shape):
+        return np.broadcast_to(
+            rs.randint(0, 1 << 20, shape), (_XK, _XF, _XN)).astype(np.int32)
+
+    def pop_word():  # one word a slot, not F
+        return word((_XK, 1, _XN))
+
+    empty = lanes._SlotEmit(*[()] * len(lanes._SlotEmit._fields))
+    return empty._replace(
+        out_valid=valid, out_dst=rs.randint(0, _XN, shape).astype(np.int32),
+        out_thi=word(), out_tlo=word(), out_auxh=pop_word(),
+        out_auxl=np.arange(valid.size, dtype=np.int32).reshape(shape),
+        out_size=pop_word(), out_phi=pop_word(), out_plo=pop_word())
+
+
+def _lane_rows(cnt, words):
+    """Per lane, the sorted rows of its cross block's live columns."""
+    cnt = np.asarray(cnt)
+    block = np.stack([np.asarray(w) for w in words], axis=-1)  # [N, Cx, 7]
+    return [sorted(map(tuple, block[d, :cnt[d]])) for d in range(len(cnt))]
+
+
+@pytest.mark.parametrize("sending_slots, passes", [
+    (0, 1), (_XBUDGET, 1), (_XBUDGET + 1, 2), (_XK * _XN, 4)],
+    ids=["none", "the_budget", "one_more", "every_slot"])
+def test_the_passes_hand_every_lane_what_one_full_exchange_would(
+        sending_slots, passes):
+    """The compacted passes against ONE exchange of all K x F x N rows
+    (the one-send law over the flattened channel): every lane's count, and
+    its rows as a multiset (the row merge's key orders them)."""
+    e = _send_channel(sending_slots)
+    flat = [np.where(e.out_valid, e.out_dst, _XN)] + [
+        e.out_thi, e.out_tlo, e.out_auxh, e.out_auxl, e.out_size,
+        e.out_phi, e.out_plo]
+    ops, start, full_cnt = lanes._sorted_exchange(
+        [jax.numpy.asarray(w.reshape(-1)) for w in flat], _XN)
+    full = _lane_rows(
+        full_cnt, lanes._cross_block(ops, start, full_cnt, _XCX)[1])
+    assert int(full_cnt.sum()) == int(e.out_valid.sum())
+    assert int(full_cnt.max()) <= _XCX  # nothing shed: lost_pre is 0 both
+
+    n_sending, ranked, table = lanes._rank_sending(e, _XBUDGET, _XN)
+    assert int(n_sending) == sending_slots
+    assert max(-(-sending_slots // _XBUDGET), 1) == passes
+    assert ranked.shape == (4 * _XBUDGET,) and table.shape == (
+        _XK * _XN, 4 * _XF + 4)
+    cnt_all = np.zeros(_XN, dtype=np.int64)
+    rows = [[] for _ in range(_XN)]
+    for i in range(passes):
+        cols = lanes._compact_sends(ranked, table, i, _XBUDGET, _XN)
+        assert all(c.shape == (_XBUDGET * _XF,) for c in cols)
+        ops, start, cnt = lanes._sorted_exchange(cols, _XN)
+        got = _lane_rows(cnt, lanes._cross_block(ops, start, cnt, _XCX)[1])
+        cnt_all += np.asarray(cnt)
+        rows = [a + b for a, b in zip(rows, got)]
+    assert cnt_all.tolist() == np.asarray(full_cnt).tolist()
+    assert [sorted(r) for r in rows] == full
+    # a pass past the last sending slot holds nothing
+    beyond = lanes._compact_sends(ranked, table, 3, _XBUDGET, _XN)
+    if sending_slots <= 3 * _XBUDGET:
+        assert (np.asarray(beyond[0]) == _XN).all()

@@ -432,6 +432,10 @@ def test_the_co_pop_saves_a_quarter_of_the_iterations(
     assert (sim.obs.finalized["report"]["gauges"]["copop_wide_pops"]
             == plane["copop_wide_pops"])
     assert "copop_wide_pops" not in res.counters
+    # a pop sends once: the exchange is K x N rows and has no slot gauges
+    assert plane["sends_per_pop"] == 1 and not {
+        "exchange_compact_iters", "exchange_slot_budget",
+        "exchange_slot_peak"} & set(plane)
     # the same-instant law: the same run in a third more iterations
     monkeypatch.setattr(lanes, "WINDOW_INERT_MODELS", frozenset())
     eng = TpuEngine(_cfg(hosts, messages, windows), log_capacity=0)

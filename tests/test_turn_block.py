@@ -275,12 +275,16 @@ def test_read_egress_returns_the_first_count_rows(hybrid_engine, which):
 #: ``gossip_wan`` (PR 42) is the gossip body on a routed, lossy graph, whose
 #: sends read their path from per-peer rows: computed on PR 42's OWN tree
 #: (its parent gathered from the [G, G] tables and had no such pin), so
-#: the next PR that touches ``one_send`` sees it move.
+#: the next PR that touches ``one_send`` sees it move.  PR 43 changed the
+#: gossip body on purpose (a fan-out program's exchange runs over its
+#: sending slots, ``lanes._merge_append`` step 2): both gossip pins are of
+#: PR 43's own tree (4fa8d862... and 1b8ad06a... at PR 42); the two
+#: programs in which a pop sends once did not move.
 PARENT_TEXT = {
     "gossip":
-        "4fa8d8629b29a1a7c0bb695f3db78370c23c73df09816d7b785d8794904d6da1",
+        "4e17a3a57a264fc11e48263181039d924887d6039c5bb0dcf01609fd2fadcc10",
     "gossip_wan":
-        "1b8ad06ae05ac624d98c5ebcca5e99f2aab5e9d15c276574162a9fc11ee526ff",
+        "c726742eb0ed5c4de51c9e84eb24f4042ebff3c2160ecf094d002eff3d314086",
     "routed_tcp_loss":
         "c44fe83e54f6ad654aa44aaf33217edb896ea3d9e54883fa6cf172f66db1ac34",
     "sharded_passive_mesh":
