@@ -6,7 +6,7 @@
 # CTest gate (src/test/determinism/CMakeLists.txt).
 
 .PHONY: test gate native smoke-faults smoke-examples lint-determinism \
-	bench-hybrid obs-smoke netobs-smoke flows-smoke turns-smoke \
+	obs-smoke netobs-smoke flows-smoke turns-smoke \
 	fusion-smoke checkpoint-smoke chaos-smoke sweep-smoke \
 	multichip-smoke check-fixtures
 
@@ -46,20 +46,6 @@ check-fixtures:
 	if [ -n "$$bad" ]; then \
 	  echo "committed runtime fixtures detected:"; echo "$$bad"; exit 1; \
 	fi
-
-# The hybrid backend's short deterministic CI smoke (one JSON line): the
-# relay-chain scenario scaled down to CI size, syscall plane on 2 worker
-# processes, packet plane on the CPU-JAX lane kernel.  JAX_PLATFORMS=cpu
-# is this target's own pin, so bench.py accepts the platform and its line
-# says "platform": "cpu" — a code-path check, never a chip number.  The
-# full-scale run is bench.py's hybrid_* keys, on the chip.
-bench-hybrid: native
-	JAX_PLATFORMS=cpu SHADOW_TPU_BENCH_HYBRID_ONLY=1 \
-	  SHADOW_TPU_BENCH_HYBRID_LANES=100 \
-	  SHADOW_TPU_BENCH_HYBRID_CHAINS=4 \
-	  SHADOW_TPU_BENCH_HYBRID_SIM_SECONDS=5 \
-	  SHADOW_TPU_BENCH_HYBRID_WORKERS=2 \
-	  python bench.py
 
 native:
 	$(MAKE) -C native

@@ -265,7 +265,7 @@ def clean_rate(sim_ns: int, wall: float) -> str:
 
 
 def pure_cfg(n_hosts: int, stop_ns: int):
-    """bench.py's pure-mesh shape (``_pure_cfg``)."""
+    """The pure-mesh shape of the benchmark's ``tgen_mesh_10k``."""
     from shadow_tpu.config.presets import flagship_mesh_config
 
     cfg = flagship_mesh_config(n_hosts, queue_capacity=16, pops_per_round=2)

@@ -17,8 +17,12 @@ Package layout:
 - ``engine``    controller/manager round loop, hosts, workers
 - ``backend``   the cpu reference backend and the TPU lane backend
 - ``models``    built-in workloads (phold, tgen-style traffic, ping)
-- ``ops``       pallas kernels for the hot ops
 - ``parallel``  device-mesh sharding of host lanes
+- ``native``    managed OS processes under the preload shim
+- ``faults``    fault schedules, routing overlays, the backend watchdog
+- ``obs``       metrics, span tracing, netobs, flowtrace, the turn ledger
+- ``sweep``     fleet sweeps: S scenarios through one vmapped kernel
+- ``analysis``  shadowlint, the static determinism and lane-parity lint
 - ``utils``     counters, pcap, logging, sim-stats
 
 64-bit JAX mode is required: all simulation time is int64 nanoseconds (see

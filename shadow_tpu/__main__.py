@@ -39,7 +39,6 @@ def build_parser() -> argparse.ArgumentParser:
         "--heartbeat-interval": "general.heartbeat_interval",
         "--network-backend": "experimental.network_backend",
         "--runahead": "experimental.runahead",
-        "--tpu-mesh-shape": "experimental.tpu_mesh_shape",
         "--resume": "experimental.resume_from",
         "--checkpoint-every-windows": "experimental.checkpoint_every_windows",
         "--checkpoint-dir": "experimental.checkpoint_dir",

@@ -429,12 +429,6 @@ class LaneParams:
     # any STREAM endpoint lane captures (static): gates the compacted
     # pcap channels so non-capturing stream sims pay nothing for them
     stream_pcap: bool = False
-    # window-advance+pop steps per fused while-loop trip (amortizes the
-    # per-trip fixed cost of the device while loop; that cost is
-    # unmeasured on the attached chip).
-    # Multiplies XLA compile time with the body size — worth it for small
-    # slot bodies (the passive models), costly for phold/stream
-    unroll: int = 1
     # TIERED stream backend (one-to-one configs): stream endpoints keep a
     # dedicated [2S, C2] queue block + compact network state
     # (lanes_stream.TierState under ``state.stream``), the [N] tier runs
@@ -4366,12 +4360,6 @@ def _build_full_run(p: LaneParams, tb: LaneTables, dynamic_stop=None):
     so one trace serves every segment bound."""
     iter_fn = _build_iter(p, tb, pure_dataflow=True)
 
-    # steps per while-loop trip (p.unroll, experimental.tpu_round_unroll):
-    # several window-advance+pop steps can run per trip to amortize the
-    # per-iteration overhead.  Steps past the end are harmless no-ops (the
-    # saturated window admits no pops), so no per-step guard is needed.
-    unroll = max(int(p.unroll), 1)
-
     if dynamic_stop is None:
         stop_hi, stop_lo = p.stop_time >> 31, p.stop_time & MASK31
     else:
@@ -4408,10 +4396,7 @@ def _build_full_run(p: LaneParams, tb: LaneTables, dynamic_stop=None):
             return iter_fn(st)
 
         def body(carry):
-            st = unpack_state(carry)
-            for _ in range(unroll):
-                st = step(st)
-            return pack_state(st)
+            return pack_state(step(unpack_state(carry)))
 
         return unpack_state(lax.while_loop(cond, body, pack_state(s)))
 

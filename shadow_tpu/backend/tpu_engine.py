@@ -466,7 +466,6 @@ class TpuEngine:
             or (
                 self._fault_overlay is not None and self._fault_overlay.any_loss()
             ),
-            unroll=cfg.experimental.tpu_round_unroll,
             dynamic_runahead=bool(cfg.experimental.use_dynamic_runahead),
             runahead_floor=max(cfg.experimental.runahead or 0, 1),
             stream_one_to_one=one_to_one,

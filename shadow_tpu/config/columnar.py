@@ -158,8 +158,8 @@ def columnar_mesh_config(
     """The flagship tgen all-to-all mesh (presets.flagship_mesh_config's
     pure-UDP shape) built columnar: same hosts, same tables, same events
     — but O(1) Python objects instead of O(n_hosts).  This is the
-    100k-host multi-chip bench scenario (scripts/bench.py ``multichip_*``
-    keys); ``mesh_devices`` presets ``experimental.mesh_devices``."""
+    100k-host multi-chip scenario (the benchmark's ``tgen_mesh_100k``);
+    ``mesh_devices`` presets ``experimental.mesh_devices``."""
     cfg = ConfigOptions.from_yaml(f"""
 general:
   stop_time: {sim_seconds} s

@@ -70,6 +70,13 @@ class NetworkOptions:
     use_shortest_path: bool = True
 
 
+#: fields of ExperimentalOptions that no code reads: parsed so that an
+#: upstream Shadow YAML loads unchanged ("reserved" in
+#: docs/configuration.md).  Every other field has a reader
+#: (tests/test_config.py::test_every_experimental_option_has_a_reader).
+REFERENCE_PARITY_FIELDS = ("use_new_tcp", "use_worker_spinning")
+
+
 @dataclasses.dataclass
 class ExperimentalOptions:
     # PDES window control
@@ -136,20 +143,17 @@ class ExperimentalOptions:
     network_backend: str = "cpu"  # "cpu" | "tpu"
     tpu_lane_queue_capacity: int = 64  # per-host in-flight packet slots
     tpu_events_per_round: int = 8  # max pops per lane per inner step
-    tpu_round_unroll: int = 1  # fused-loop steps per device loop trip
     # cross-lane receive block width per iteration (0 = queue capacity);
     # narrower is faster when per-iteration fan-in is bounded — overflow
     # is counted and strict mode raises, exactly like queue overflow
     tpu_cross_capacity: int = 0
-    tpu_mesh_shape: Optional[tuple[int, ...]] = None  # None = all devices
     # multi-chip sharded lane plane (shadow_tpu/parallel/,
     # docs/multichip.md): shard the per-host lane state over up to this
     # many devices on a 1-D ``Mesh(("hosts",))``.  0 = off
     # (single-device); the actual count is NEGOTIATED down to the largest
     # value that divides the host count and does not exceed the available
     # devices (transparent fallback — never an error).  Results are
-    # bit-identical at any mesh shape.  A 1-D ``tpu_mesh_shape`` tuple is
-    # the older alias for the same request.
+    # bit-identical at any mesh shape.
     mesh_devices: int = 0
     # TIERED stream backend (one-to-one stream configs on a pure-lane,
     # untraced run — TpuEngine decides from the config): stream endpoints
@@ -367,8 +371,6 @@ class ConfigOptions:
                 v = exp_doc.pop(f.name)
                 if f.name == "runahead":
                     v = _opt_time(v)
-                elif f.name == "tpu_mesh_shape" and v is not None:
-                    v = tuple(int(x) for x in v)
                 elif f.name in ("socket_send_buffer", "socket_recv_buffer"):
                     v = units.parse_bytes(v)
                 setattr(experimental, f.name, v)
@@ -446,11 +448,6 @@ class ConfigOptions:
                     value = units.parse_time(value)
                 elif field in self._BYTE_FIELDS:
                     value = units.parse_bytes(value)
-                elif field == "tpu_mesh_shape":
-                    if isinstance(value, str):
-                        value = tuple(int(x) for x in value.split(",") if x)
-                    else:
-                        value = tuple(int(x) for x in value)
                 else:
                     current = getattr(target, field)
                     if isinstance(current, bool):

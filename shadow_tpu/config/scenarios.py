@@ -6,7 +6,7 @@ The BASELINE.md evaluation ladder's config #5 is a Tor-shaped relay
 topology (the reference's 500-relay chutney networks,
 docs/getting_started_tor.md, src/test/tor/minimal/); this module builds
 the self-contained analog from the repo's own native apps — no external
-tools — so the bench and the scale gate measure the MANAGED path (the
+tools — so the benchmark and the scale gate measure the MANAGED path (the
 workload class the reference's 6.38x was measured on,
 /root/reference/MyTest/SUMMARY.md:5-9):
 
@@ -161,12 +161,12 @@ def managed_relay_chains_large(
     hybrid_workers: int = 0,
     seed: int = 42,
 ) -> ConfigOptions:
-    """The HYBRID flagship scenario (bench.py `hybrid_*` keys, ROADMAP
-    A1; chip_smoke.py phase c): 100+ managed OS processes (default 151 = 25 three-relay
-    chains + 75 clients + origin) whose syscall plane runs across
-    ``hybrid_workers`` processes, over 1k+ lane hosts (default 1000 tgen
-    peers) whose data plane — and every managed packet — rides the TPU
-    lanes.  This is the workload class the reference's 6.38x headline was
+    """The HYBRID flagship scenario (the benchmark's `relay_chains_151`,
+    ROADMAP A1; chip_smoke.py phase c): 100+ managed OS processes (default
+    151 = 25 three-relay chains + 75 clients + origin) whose syscall plane
+    runs across ``hybrid_workers`` processes, over 1k+ lane hosts (default
+    1000 tgen peers) whose data plane — and every managed packet — rides the
+    TPU lanes.  This is the workload class the reference's 6.38x headline was
     measured on, at the reference's own scale point."""
     return managed_chain_config(
         data_dir,

@@ -5,13 +5,14 @@ program runs on is JAX's choice (the attached TPU, or XLA:CPU when the
 environment pins ``JAX_PLATFORMS=cpu``).  Every result therefore names the
 device beside the backend: ``describe_devices`` turns the devices an engine
 placed its state on into the ``{platform, kind, count}`` record that
-``sim-stats.json``, ``METRICS_*.json``, the start-up log line, ``bench.py``
-and ``chip_smoke.py`` all carry.
+``sim-stats.json``, ``METRICS_*.json``, the start-up log line,
+``benchmarks/run.py`` and ``chip_smoke.py`` all carry.
 
 ``enable_compile_cache`` is the one place the persistent XLA compile cache
 is switched on.  Process entry points call it (``python -m shadow_tpu``,
-``bench.py``, ``chip_smoke.py``, ``scripts/sweep.py``); ``import shadow_tpu``
-does not, so library users and the test suite decide for themselves.
+``benchmarks/run.py``, ``chip_smoke.py``, ``scripts/sweep.py``); ``import
+shadow_tpu`` does not, so library users and the test suite decide for
+themselves.
 """
 
 from __future__ import annotations

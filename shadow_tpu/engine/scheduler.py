@@ -18,7 +18,7 @@ binaries genuinely run concurrently, which is exactly the workload the
 reference parallelizes.  Pure-model workloads get genuine parallelism
 from the FORK-based backend instead (backend/cpu_mp.MpCpuEngine: worker
 processes own host partitions, cross-partition packets ride pipes at the
-round barrier), which the bench uses for its CPU-side number.  Determinism holds for ANY worker count: within a
+round barrier).  Determinism holds for ANY worker count: within a
 round hosts only touch their own state, cross-host effects are inbox
 appends whose drain order is normalized by the total event order, and
 per-HOST log/min-latency buffers (cpu_engine.Host.log_buf / min_used_lat)

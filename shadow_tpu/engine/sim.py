@@ -854,14 +854,13 @@ class Simulation:
             if self.cfg.faults.events:
                 raise LaneCompatError(
                     "fault schedules are not supported on the sharded-mesh "
-                    "driver; drop experimental.mesh_devices/tpu_mesh_shape "
-                    "or use the cpu backend"
+                    "driver; drop experimental.mesh_devices or use the cpu "
+                    "backend"
                 )
             if self._resume_path is not None:
                 raise CheckpointError(
                     "checkpoint resume is not supported on the sharded-"
-                    "mesh driver; drop experimental.mesh_devices/"
-                    "tpu_mesh_shape to resume"
+                    "mesh driver; drop experimental.mesh_devices to resume"
                 )
             if self.cfg.experimental.flowtrace:
                 log.warning(

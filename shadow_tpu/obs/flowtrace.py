@@ -35,7 +35,7 @@ integer-only, canonically ordered (full-tuple sort), so run-twice
 artifacts diff byte-identical and device↔oracle streams compare with
 ``==``.  The report's **burst attribution** section ranks which flow
 classes (hostname with its trailing digits stripped, e.g. ``client12 ->
-client``) populate which ``mixed_window_hist`` buckets — the instrument
+client``) populate which netobs ``window_hist`` buckets — the instrument
 that sizes ROADMAP item 3's coalescing change.
 """
 

@@ -17,8 +17,7 @@ Surfaces:
   writes) for external consumers that want events, not aggregates.
 
 ``report()`` aggregates everything into the ``METRICS_*.json`` document
-(schema in docs/observability.md) that ``bench.py`` reads its per-phase
-wall-breakdown keys from.
+(schema in docs/observability.md).
 """
 
 from __future__ import annotations
@@ -142,7 +141,7 @@ class MetricsRegistry:
             return dict(self._counters)
 
     def phase_wall_s(self) -> dict[str, float]:
-        """phase -> total wall seconds (the bench breakdown keys)."""
+        """phase -> total wall seconds."""
         with self._lock:
             return {k: p[1] for k, p in self._phases.items()}
 

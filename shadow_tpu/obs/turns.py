@@ -60,7 +60,7 @@ CPU oracle's histogram reads directly as *the legal free-run length
 distribution of this scenario* — the dispatch-collapse item 1 would
 realize.
 
-Two headroom estimates close the loop (``summary()``/bench keys):
+Two headroom estimates close the loop (``summary()`` keys):
 
 - ``kfusion_headroom`` = turns / (turns - fusable turns): the ceiling
   of the fusable-run collapse — every empty-injection dispatch merges
@@ -342,7 +342,7 @@ class TurnLedger:
 
     def summary(self) -> dict:
         """Aggregates only (live-safe: includes the open run without
-        closing it) — what bench.py and the ``turns`` verb read."""
+        closing it) — what the ``turns`` verb reads."""
         pct = self.fusable_percentiles()
         return {
             "turns": self.turns,

@@ -150,16 +150,10 @@ def negotiate_devices(
 
 def negotiate_from_config(cfg, n_lanes: int) -> int:
     """Device count for a config: ``experimental.mesh_devices`` (0 = no
-    mesh, N = shard over up to N devices), with the 1-D
-    ``experimental.tpu_mesh_shape`` tuple as an alias, negotiated against
-    the available device count and the lane count.  Returns 1 when no
+    mesh, N = shard over up to N devices), negotiated against the
+    available device count and the lane count.  Returns 1 when no
     multi-device mesh applies (the callers skip attach entirely)."""
-    exp = cfg.experimental
-    requested = int(getattr(exp, "mesh_devices", 0) or 0)
-    if requested <= 0:
-        shape = getattr(exp, "tpu_mesh_shape", None)
-        if shape is not None and len(shape) == 1:
-            requested = int(shape[0])
+    requested = int(cfg.experimental.mesh_devices or 0)
     if requested <= 1:
         return 1
     return negotiate_devices(requested, n_lanes)

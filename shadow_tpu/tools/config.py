@@ -88,9 +88,7 @@ class ExperimentalDict(TypedDict, total=False):
     network_backend: str  # "cpu" | "tpu"
     tpu_lane_queue_capacity: int
     tpu_events_per_round: int
-    tpu_round_unroll: int
     tpu_cross_capacity: int
-    tpu_mesh_shape: list[int]
 
 
 class ConfigDict(TypedDict, total=False):

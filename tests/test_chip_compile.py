@@ -2,7 +2,7 @@
 
 The TPU compiler is installed here and compiles for a DESCRIBED ``v5e:2x2``
 device without a chip attached.  Each case lowers one program of the main
-path at the shape ``chip_smoke.py`` / ``bench.py`` run it, with the
+path at the shape ``chip_smoke.py`` / ``benchmarks/run.py`` run it, with the
 accelerator branches taken: ``lanes.scan_or_unroll`` and the slot body's
 ``slot_dataflow`` switch on
 ``jax.default_backend() != "cpu"``, which every other test pins to the CPU —
@@ -90,7 +90,7 @@ def _shapes(tree, sharding):
 
 
 def _pure_cfg(n_hosts: int, stop_ns: int):
-    """bench.py ``_pure_cfg`` / chip_smoke.py ``pure_cfg``."""
+    """chip_smoke.py ``pure_cfg``."""
     cfg = flagship_mesh_config(n_hosts, queue_capacity=16, pops_per_round=2)
     cfg.experimental.tpu_cross_capacity = 8
     cfg.general.stop_time = stop_ns
@@ -516,7 +516,7 @@ def test_window_gather_row_fills_its_tile(one_chip, n, k, a, c):
 
 
 def test_sweep_kernel_bench_shape(one_chip, as_tpu):
-    """The fleet-sweep kernel at bench.py's sweep shape: 8 scenarios x
+    """The fleet-sweep kernel at a sweep's shape: 8 scenarios x
     1 000 lanes, tables/stop bounds/states all traced and stacked."""
     from shadow_tpu.sweep import SweepSpec, expand_variants
 

@@ -91,12 +91,12 @@ def test_simulation_facade_backends(tmp_path, backend):
     assert result.rounds > 0
 
 
-def test_simulation_tpu_mesh_shape(tmp_path):
+def test_simulation_tpu_mesh_devices(tmp_path):
     yaml = PING_YAML.replace("DATADIR", str(tmp_path / "mesh"))
     cfg = ConfigOptions.from_yaml(yaml)
     cfg.experimental.network_backend = "tpu"
-    cfg.experimental.tpu_mesh_shape = (2,)
-    # the alias reaching the engine is the point, not the pop count: at
+    cfg.experimental.mesh_devices = 2
+    # the request reaching the engine is the point, not the pop count: at
     # the default 8 pops a sharded run on XLA:CPU takes minutes (see
     # tests/test_multichip.py::_phold_cfg)
     cfg.experimental.tpu_events_per_round = 2

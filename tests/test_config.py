@@ -1,8 +1,13 @@
 """Config parsing: units, YAML document shape, overrides, validation."""
 
+import dataclasses
+import re
+from pathlib import Path
+
 import pytest
 
-from shadow_tpu.config import units
+from shadow_tpu.__main__ import build_parser
+from shadow_tpu.config import options, units
 from shadow_tpu.config.options import ConfigError, ConfigOptions
 from shadow_tpu.core import time as stime
 
@@ -189,7 +194,51 @@ def test_ip_addr_with_count_rejected():
         )
 
 
-def test_mesh_shape_override_coercion():
+def test_mesh_devices_override_coercion():
     cfg = ConfigOptions.from_yaml(BASIC_YAML)
-    cfg.apply_overrides({"experimental.tpu_mesh_shape": "2,4"})
-    assert cfg.experimental.tpu_mesh_shape == (2, 4)
+    cfg.apply_overrides({"experimental.mesh_devices": "4"})
+    assert cfg.experimental.mesh_devices == 4
+    assert type(cfg.experimental.mesh_devices) is int
+
+
+@pytest.mark.parametrize("key", ["tpu_round_unroll", "tpu_mesh_shape"])
+def test_removed_experimental_keys_are_refused(key):
+    """PR 44 removed both: a config that still sets one fails by name, it
+    is not dropped in silence, and the mesh has one spelling on the CLI."""
+    with pytest.raises(ConfigError, match=key):
+        ConfigOptions.from_yaml(
+            BASIC_YAML.replace("general:", f"experimental: {{{key}: 2}}\ngeneral:")
+        )
+    cfg = ConfigOptions.from_yaml(BASIC_YAML)
+    with pytest.raises(ConfigError, match=key):
+        cfg.apply_overrides({f"experimental.{key}": "2"})
+    with pytest.raises(SystemExit):
+        build_parser().parse_args(["cfg.yaml", "--tpu-mesh-shape", "2"])
+
+
+def test_every_experimental_option_has_a_reader():
+    """An option nothing reads is a promise nothing keeps: every field of
+    ExperimentalOptions is read (``.name``) by the package or by the sweep
+    verb, or is declared parsed-for-upstream-YAML-only."""
+    repo = Path(__file__).resolve().parents[1]
+    declared = {"config/options.py", "tools/config.py"}
+    pkg = repo / "shadow_tpu"
+    readers = [repo / "scripts" / "sweep.py"] + [
+        p for p in sorted(pkg.rglob("*.py"))
+        if p.relative_to(pkg).as_posix() not in declared
+    ]
+    text = "\n".join(p.read_text() for p in readers)
+    names = [f.name for f in dataclasses.fields(options.ExperimentalOptions)]
+    assert len(names) == 42  # ROADMAP C4: every PR leaves it no larger
+    assert set(options.REFERENCE_PARITY_FIELDS) <= set(names)
+    unread = [
+        n for n in names
+        if n not in options.REFERENCE_PARITY_FIELDS
+        and not re.search(rf"\.{n}\b", text)
+    ]
+    assert unread == []
+    read_anyway = [
+        n for n in options.REFERENCE_PARITY_FIELDS
+        if re.search(rf"\.{n}\b", text)
+    ]
+    assert read_anyway == []  # a reserved field that gained a reader: unlist it

@@ -140,11 +140,6 @@ hosts:
 """
 
 
-MESH_UNROLLED = MESH.replace(
-    "hosts:", "experimental: {tpu_round_unroll: 2}\nhosts:"
-)
-
-
 MESH_NARROW_CROSS = MESH.replace(
     "hosts:", "experimental: {tpu_cross_capacity: 4}\nhosts:"
 )
@@ -164,14 +159,6 @@ def test_negative_cross_capacity_rejected():
     )
     with pytest.raises(LaneCompatError):
         TpuEngine(cfg)
-
-
-def test_unrolled_device_loop_parity():
-    """tpu_round_unroll > 1 runs several window steps per device-loop trip
-    (trailing no-op steps past the end included) — logs stay identical.
-    (2, not more: XLA CPU compile time grows steeply with body size.)"""
-    cpu, tpu = both_logs(MESH_UNROLLED, mode="device")
-    assert cpu.log_tuples() == tpu.log_tuples()
 
 
 def test_far_future_events_parity():

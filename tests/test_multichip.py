@@ -221,12 +221,15 @@ def test_negotiate_all_available_default():
     assert parallel.negotiate_devices(None, 16, available=8) == 8
 
 
-def test_negotiate_from_config_mesh_shape_alias():
+def test_negotiate_from_config_mesh_devices():
+    """``mesh_devices`` is the one spelling of a mesh request: 0 and 1 ask
+    for none, N for up to N, more than the process has for what it has."""
     cfg = flagship_mesh_config(8, sim_seconds=1, backend="tpu")
-    cfg.experimental.tpu_mesh_shape = (4,)
-    assert parallel.negotiate_from_config(cfg, 8) == 4
-    cfg.experimental.mesh_devices = 2  # explicit knob wins
-    assert parallel.negotiate_from_config(cfg, 8) == 2
+    have = len(jax.devices())
+    assert have == 8
+    for asked, got in ((0, 1), (1, 1), (4, 4), (2 * have, have)):
+        cfg.experimental.mesh_devices = asked
+        assert parallel.negotiate_from_config(cfg, 8) == got
 
 
 def test_engine_rejects_indivisible_mesh():

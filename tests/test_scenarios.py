@@ -1,6 +1,6 @@
-"""The bench evaluation-ladder configs (BASELINE.md configs 1/2/3/5).
+"""The evaluation-ladder configs (BASELINE.md configs 1/2/3/5).
 
-Small-scale gates for the factories bench.py times at full scale: each
+Small-scale gates for the factories the benchmark times at full scale: each
 config must parse, run on both backends where lane-compatible, and the
 managed relay-chain scenario (config #5's self-contained analog) must
 carry real echo traffic through three-relay chains deterministically.

@@ -484,39 +484,8 @@ class TestRunControlVerbs:
 
 
 # ---------------------------------------------------------------------------
-# bench_report sparklines + CLI flag
+# CLI flag
 # ---------------------------------------------------------------------------
-
-
-class TestBenchReportSparklines:
-    def _rounds(self):
-        return {
-            "r01": {"value": 5.0, "mixed_window_hist.b0": 10,
-                    "mixed_window_hist.b3": 2},
-            "r02": {"value": 6.0, "mixed_window_hist.b0": 4,
-                    "fusable_run_hist.b1": 7},
-        }
-
-    def test_markdown_renders_sparkline_rows(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_report", REPO / "scripts" / "bench_report.py"
-        )
-        br = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(br)
-        text = br.render_markdown(self._rounds())
-        # per-bucket rows collapse into one sparkline row per group
-        assert "mixed_window_hist.b0" not in text
-        assert "`mixed_window_hist` (log2 buckets, b0→)" in text
-        assert "`fusable_run_hist` (log2 buckets, b0→)" in text
-        # sparkline law: b0=10 is the max -> full block; b3=2 scaled
-        # to level 1 + (7*2)//10 = 2
-        assert br.sparkline([10, 0, 0, 2]) == "█··▂"
-        assert br.sparkline([]) == "—"
-        doc = json.loads(br.render_json(self._rounds()))
-        assert doc["histograms"]["mixed_window_hist"]["r01"] == [10, 0, 0, 2]
-        assert doc["histograms"]["fusable_run_hist"]["r02"] == [0, 7]
 
 
 class TestCliFlag:
