@@ -73,6 +73,14 @@ def compile_cell(workload: str):
         return run_fn.lower(state).compile()
     dev = SingleDeviceSharding(topo.devices[0])
     args = (shapes(init, dev),)
+    if eng._fault_overlay is not None:
+        # a faulted cell: ONE program for every segment, whose path
+        # leaves, stop pair and seed words are arguments
+        paths = {f: getattr(eng.tables, f) for f in eng._path_fields}
+        i32, u32 = (jax.ShapeDtypeStruct((), t, sharding=dev)
+                    for t in (np.int32, np.uint32))
+        return lanes.make_run_fn(eng.params, eng.tables, epochs=True).lower(
+            *args, shapes(paths, dev), i32, i32, u32, u32).compile()
     if eng.params.has_loss:  # the seed's two words are arguments
         args += (jax.ShapeDtypeStruct((), np.uint32, sharding=dev),) * 2
     return lanes.make_run_fn(eng.params, eng.tables).lower(*args).compile()

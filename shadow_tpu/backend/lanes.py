@@ -46,6 +46,7 @@ the first K columns.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -4194,23 +4195,27 @@ def _effective_runahead(p: LaneParams, s: LaneState):
     )
 
 
-def _build_round(p: LaneParams, tb: LaneTables):
+def _build_round(p: LaneParams, tb: LaneTables, stop=None):
     """Build the raw (un-jitted) one-round advance: state -> (state, done)
     for the STEP driver.  Preserves the pre-round state when the
     simulation already finished (a full-state ``where``); the fused full
-    run uses ``_build_iter`` directly instead."""
+    run uses ``_build_iter`` directly instead.  ``stop`` is an optional
+    traced int64 scalar in place of the static ``p.stop_time`` (a
+    fault-epoch segment's bound: ``make_round_fn(..., epochs=True)``)."""
     iter_body = _build_iter(p, tb)
+    if stop is None:
+        stop = p.stop_time
 
     def round_fn(s: LaneState) -> tuple[LaneState, jnp.ndarray]:
         # rows sorted: col 0 is each queue's min; lexicographic pair min
         start = t_join(*_queue_min(p, s))
-        done = start >= p.stop_time
+        done = start >= stop
         if p.netobs:
             # a live round IS a new window: flush the previous round's
             # occupancy (the trailing window flushes at collect)
             s = _flush_hist(p, s, ~done)
         window_end = jnp.minimum(
-            start + _effective_runahead(p, s), p.stop_time
+            start + _effective_runahead(p, s), stop
         )
         we_hi, we_lo = t_split(window_end)
         s = s._replace(now_we_hi=we_hi, now_we_lo=we_lo)
@@ -4231,10 +4236,15 @@ def _build_round(p: LaneParams, tb: LaneTables):
     return round_fn
 
 
-def make_round_fn(p: LaneParams, tb: LaneTables):
+def make_round_fn(p: LaneParams, tb: LaneTables, epochs: bool = False):
     """Jitted one-round advance: state -> (state, done).  Step-wise driver
-    for debugging, parity tests, and run-control pauses."""
-    return jax.jit(_build_round(p, tb))
+    for debugging, parity tests, and run-control pauses.  ``epochs``: the
+    fault-epoch form, ``round_fn(state, paths, stop_hi, stop_lo, seed_lo,
+    seed_hi)`` (see :func:`make_run_fn`)."""
+    if not epochs:
+        return jax.jit(_build_round(p, tb))
+    return _epoch_fn(
+        tb, lambda t, hi, lo: _build_round(p, t, stop=t_join(hi, lo)))
 
 
 # -- while-carry packing -----------------------------------------------------
@@ -4403,7 +4413,7 @@ def _build_full_run(p: LaneParams, tb: LaneTables, dynamic_stop=None):
     return full_run
 
 
-def make_run_fn(p: LaneParams, tb: LaneTables):
+def make_run_fn(p: LaneParams, tb: LaneTables, epochs: bool = False):
     """Jitted full-simulation run — the bench hot path (one device call per
     simulation): ``run_fn(state)``, or ``run_fn(state, seed_lo, seed_hi)``
     with the master seed's two uint32 words as ARGUMENTS of the program
@@ -4411,13 +4421,57 @@ def make_run_fn(p: LaneParams, tb: LaneTables):
     in it.  The seed words are the only thing a program whose network
     loses packets holds of ``general.seed``, so handed over they leave one
     compiled program — and one entry of the persistent compile cache — for
-    every seed; the draws are bit-identical either way (``_seed_keys``)."""
+    every seed; the draws are bit-identical either way (``_seed_keys``).
+
+    ``epochs``: the serial form of the sweep's law (``make_sweep_fn``
+    without the ``vmap``) for a run segmented at fault epochs:
+    ``run_fn(state, paths, stop_hi, stop_lo, seed_lo, seed_hi)`` runs
+    ``state`` up to the traced bound against ``tb`` with the ``paths``
+    leaves (a dict of the ``LaneTables`` fields one epoch's tables decide:
+    what ``TpuEngine._path_tables`` builds) in place of ``tb``'s — ONE
+    program for every segment, epoch and seed of a schedule.  Its
+    ``.traces`` counts the traces, as ``make_sweep_fn``'s."""
+    if epochs:
+        return _epoch_fn(
+            tb, lambda t, hi, lo: _build_full_run(p, t, dynamic_stop=(hi, lo)))
 
     def full_run(s: LaneState, *seed) -> LaneState:
         t = tb._replace(seed_lo=seed[0], seed_hi=seed[1]) if seed else tb
         return _build_full_run(p, t)(s)
 
     return jax.jit(full_run)
+
+
+def _epoch_fn(tb: LaneTables, build):
+    """The jitted fault-epoch form of a driver: ``build(tables, stop_hi,
+    stop_lo)`` gives the raw function of the state, the tables being
+    ``tb`` with the traced path leaves and seed words laid over it."""
+
+    def epoch_run(s: LaneState, paths, stop_hi, stop_lo, seed_lo, seed_hi):
+        t = tb._replace(**paths, seed_lo=seed_lo, seed_hi=seed_hi)
+        return build(t, stop_hi, stop_lo)(s)
+
+    return _jit_counting_traces(epoch_run)
+
+
+def _jit_counting_traces(fn):
+    """``jax.jit(fn)`` behind a wrapper whose ``.traces`` counts how often
+    ``fn`` was traced — the compile probe a one-compile assertion reads —
+    and whose ``.lower`` is the jitted function's (the AOT path)."""
+
+    @functools.wraps(fn)
+    def counted(*args):
+        wrapper.traces += 1
+        return fn(*args)
+
+    jitted = jax.jit(counted)
+
+    def wrapper(*args):
+        return jitted(*args)
+
+    wrapper.traces = 0
+    wrapper.lower = jitted.lower
+    return wrapper
 
 
 def make_sweep_fn(p: LaneParams):
@@ -4440,17 +4494,10 @@ def make_sweep_fn(p: LaneParams):
     probe the one-compile acceptance assertion reads."""
 
     def run_one(tb: LaneTables, stop_hi, stop_lo, s: LaneState):
-        wrapper.traces += 1
         return _build_full_run(p, tb, dynamic_stop=(stop_hi, stop_lo))(s)
 
-    jitted = jax.jit(jax.vmap(run_one))
-
-    def wrapper(tb, stop_hi, stop_lo, s):
-        return jitted(tb, stop_hi, stop_lo, s)
-
-    wrapper.traces = 0
-    wrapper.lower = jitted.lower  # AOT path (tests/test_chip_compile.py)
-    return wrapper
+    # .lower: the AOT path (tests/test_chip_compile.py)
+    return _jit_counting_traces(jax.vmap(run_one))
 
 
 # --------------------------------------------------------------------------

@@ -10,6 +10,7 @@ the CPU engine produces, so the two backends are drop-in comparable.
 
 from __future__ import annotations
 
+import contextlib
 import time as wall_time
 from typing import Optional
 
@@ -40,6 +41,9 @@ NEVER = stime.NEVER
 # wait for its result, the collect.  The step driver books every round's
 # call and wait to the same two.
 FUSED_PHASES = ("state_build", "dispatch", "device_wait", "collect")
+# An engine with a fault schedule has one more: between a segment's wait
+# and the next segment's call, the host's epoch swap (``_run_faulted``).
+FAULT_PHASE = "fault_swap"
 
 
 class LaneCompatError(ValueError):
@@ -613,9 +617,13 @@ class TpuEngine:
         self._peer_np = peer_np  # [2S] peer lanes (fault-epoch flow tables)
         self._node_idx = node_idx  # [N] host -> dense node index
         self._g_peers_np = self._gossip_peers(g_rows, n)
+        paths = self._path_tables(lat, thresh)
+        # the LaneTables fields an epoch's tables decide (a faulted run's
+        # program takes them as arguments)
+        self._path_fields = tuple(paths)
         self.tables = lanes.LaneTables(
             node_of=jnp.asarray(node_idx, dtype=i32),
-            **self._path_tables(lat, thresh),
+            **paths,
             up_rate=jnp.asarray(up[:, 0], dtype=i32),
             up_burst=jnp.asarray(up[:, 1], dtype=i32),
             up_kfull=jnp.asarray(up_kfull),
@@ -689,15 +697,25 @@ class TpuEngine:
         # the host-phase clock (obs/clock.py, always on): a run's host
         # phases, on the profiler's clock as fused/<phase>.  obs gets
         # them under its own names: device_turn is the blocking wait
+        faulted = self._fault_overlay is not None
         self.clock = TurnClock(
-            self, "fused", FUSED_PHASES,
+            self, "fused", FUSED_PHASES + ((FAULT_PHASE,) if faulted else ()),
             obs_map={
                 "state_build": ("state_build", None, None),
                 "dispatch": ("dispatch", None, None),
                 "device_wait": ("device_turn", None, "active"),
                 "collect": ("collect", None, None),
+                FAULT_PHASE: ("fault_swap", None, None),
             },
         )
+        # the faulted driver (_run_faulted): its ONE program a mode, the
+        # AOT-compiled device one, every epoch's path leaves as placed on
+        # the device (keyed by the overlay's version: a console fault
+        # recompiles the snapshots), and the run's lane_plane gauges
+        self._fault_fns: dict = {}
+        self._fault_compiled = None
+        self._fault_leaves: tuple = (None, {})
+        self._fault_plane: dict = {}
 
     def _resolve(self, hostname: str, n: int) -> int:
         return self.dns.resolve(hostname)
@@ -721,15 +739,20 @@ class TpuEngine:
         gossip lane's D mesh peers (``g_*``, ``[F, N]``, lanes minor) —
         whose path is a constant of the lane, so a send reads a row and
         gathers nothing.  ONE law for start-up and each fault epoch."""
+        return {k: jnp.asarray(v)
+                for k, v in self._path_words(lat, thresh).items()}
+
+    def _path_words(self, lat, thresh) -> dict:
+        """``_path_tables`` on the host (numpy): a faulted run places
+        every epoch's in one transfer (``_epoch_leaves``)."""
         lat_np, thr_np = np.asarray(lat), np.asarray(thresh)
         nodes = np.asarray(self._node_idx)
 
         def words(prefix, lat, thr):
             return {
-                prefix + "lat": jnp.asarray(lat, dtype=jnp.int32),
-                prefix + "thresh_u32": jnp.asarray(
-                    (thr & 0xFFFFFFFF).astype(np.uint32)),
-                prefix + "thresh_all": jnp.asarray(thr >= (1 << 32)),
+                prefix + "lat": np.asarray(lat).astype(np.int32),
+                prefix + "thresh_u32": (thr & 0xFFFFFFFF).astype(np.uint32),
+                prefix + "thresh_all": np.asarray(thr >= (1 << 32)),
             }
 
         def paths(prefix, src, dst):
@@ -1114,7 +1137,8 @@ class TpuEngine:
         ``on_window(window_start, window_end, next_event_time)`` runs after
         every round, the run-control/heartbeat seam).
         ``precompile``: AOT-compile before starting the wall-clock timer so
-        ``wall_seconds`` measures only the steady-state device program.
+        ``wall_seconds`` measures only the steady-state device program
+        (with a fault schedule: the ONE program all its segments run).
         ``resume_state``/``resume_epoch``: continue from a checkpointed
         lane state (engine/checkpoint.py) — the lane pytree carries the
         whole simulation, so running it to stop_time reproduces the
@@ -1130,14 +1154,10 @@ class TpuEngine:
                 )
             self._check_resume_log(resume_state)
         if self._fault_overlay is not None:
-            if precompile:
-                raise LaneCompatError(
-                    "precompile is a bench affordance; it is "
-                    "not supported together with a fault schedule"
-                )
             return self._run_faulted(
                 mode, on_window=on_window, resume_state=resume_state,
                 resume_epoch=resume_epoch, disarm_stalls=disarm_stalls,
+                precompile=precompile,
             )
         # with a mesh attached the state lives on its sharded placement and
         # the driver compiles under the mesh (parallel/mesh.py)
@@ -1191,7 +1211,8 @@ class TpuEngine:
             else:
                 round_fn = lanes.make_round_fn(self.params, self.tables)
             t0 = wall_time.perf_counter()
-            state = self._drive_steps(round_fn, state, on_window, self.params)
+            state = self._drive_steps(
+                round_fn, state, on_window, self.params.stop_time)
             wall = wall_time.perf_counter() - t0
         with self._phase("collect"):
             result = self.collect(state, wall)
@@ -1267,11 +1288,11 @@ class TpuEngine:
         return jax.device_get(state)
 
     def _drive_steps(
-        self, round_fn, state: lanes.LaneState, on_window, p: lanes.LaneParams,
+        self, round_fn, state: lanes.LaneState, on_window, stop: int,
         first_cause: str = "snapshot",
     ) -> lanes.LaneState:
         """The step driver's round loop (one device call per round) up to
-        ``p.stop_time`` — shared by the plain run and every fault-epoch
+        ``stop`` — shared by the plain run and every fault-epoch
         segment.  Each round is timed under the stall watchdog when
         ``faults.watchdog_timeout`` is configured.
 
@@ -1300,9 +1321,9 @@ class TpuEngine:
                     lanes.t_join(state.q_thi[:, 0], state.q_tlo[:, 0])
                 )
                 start = self._next_event_np(state)
-                we_pred = min(start + self.current_runahead(), p.stop_time)
+                we_pred = min(start + self.current_runahead(), stop)
                 active = int((lane_next < we_pred).sum())
-                if p.stream_tiered:
+                if self.params.stream_tiered:
                     tq = state.stream.q
                     tier_next = np.asarray(lanes.t_join(
                         tq[lstr_mod.TQ_THI, :, 0],
@@ -1342,7 +1363,7 @@ class TpuEngine:
                 if self.perf_log is not None:
                     self.perf_log.window_agg(
                         active, start, window_end,
-                        min(next_ev, p.stop_time),
+                        min(next_ev, stop),
                     )
                 if on_window is not None:
                     on_window(start, window_end, next_ev)
@@ -1351,15 +1372,36 @@ class TpuEngine:
     # -- fault-epoch segmentation ------------------------------------------
 
     def _segment_tables(self, snap) -> lanes.LaneTables:
-        """Re-upload the versioned path tables for a fault epoch: the
-        [G, G] latency/threshold tables plus their per-flow and
-        per-gossip-peer compactions (``_path_tables``)."""
+        """The tables of a fault epoch: the [G, G] latency/threshold
+        tables plus their per-flow and per-gossip-peer compactions
+        (``_path_tables``) laid over the engine's."""
         return self.tables._replace(
             **self._path_tables(snap.latency_ns, snap.loss_threshold))
+
+    def _epoch_leaves(self, plan) -> dict:
+        """``{snapshot.at: path leaves}`` for the snapshots of ``plan``,
+        on the device: built once an engine (every epoch's host words,
+        ``_path_words``, placed by ONE ``device_put``) and kept until the
+        overlay recompiles its snapshots (a console fault).  ``None``
+        keys the base tables' own leaves."""
+        version, placed = self._fault_leaves
+        if version != self._fault_overlay.version:
+            placed = {None: {f: getattr(self.tables, f)
+                             for f in self._path_fields}}
+        snaps = {snap.at: snap for _s, _e, snap in plan
+                 if snap is not None and snap.at not in placed}
+        if snaps:
+            placed.update(jax.device_put({
+                at: self._path_words(snap.latency_ns, snap.loss_threshold)
+                for at, snap in snaps.items()
+            }))
+        self._fault_leaves = (self._fault_overlay.version, placed)
+        return placed
 
     def _run_faulted(
         self, mode: str, on_window=None, resume_state=None,
         resume_epoch: int = 0, disarm_stalls: bool = False,
+        precompile: bool = False,
     ) -> SimResult:
         """Run the simulation segmented at fault epochs: each segment is
         an ordinary (fused or step-wise) run whose stop time is the next
@@ -1368,14 +1410,20 @@ class TpuEngine:
         — and the lane state (queues, buckets, RNG counters, flows)
         carries across segments untouched.
 
+        Every segment, of every repeat, runs ONE compiled program
+        (``lanes.make_run_fn(..., epochs=True)``; the step driver's is
+        ``make_round_fn``'s): the epoch's path leaves, the segment's stop
+        bound and the seed's words are its ARGUMENTS, the leaves placed
+        on the device once an engine (``_epoch_leaves``).  The host's
+        work between one segment's wait and the next segment's call is
+        the ``fault_swap`` span.
+
         Resume (engine/checkpoint.py): segments whose end lies at or
         before ``resume_epoch`` already happened inside ``resume_state``
         and are skipped; the first live segment continues from the
         resumed state mid-segment.  Its first ledger row records as
         ``snapshot`` — the segment's ``fault_swap`` row predates the
         checkpoint and lives in the restored ledger."""
-        import dataclasses as _dc
-
         from ..faults.watchdog import BackendStallError
 
         ov = self._fault_overlay
@@ -1385,18 +1433,37 @@ class TpuEngine:
         # test drive them through this serial loop too)
         plan = ov.segment_plan(stop, pad_to=getattr(self, "_fault_pad", 0))
         resumed = resume_state is not None
+        live = [seg for seg in plan
+                if not (resumed and seg[1] <= resume_epoch)]
         # segments run single-device programs, mesh or none
         state = self._start_state(resume_state, None)
-        fns = getattr(self, "_seg_fns", None)
-        if fns is None:
-            fns = self._seg_fns = {}
-        t0 = wall_time.perf_counter()
-        turns = self.obs.turns if self.obs is not None else None
-        seg_rounds = int(np.asarray(state.rounds)) if resumed else 0
-        first_live = True
-        for seg_start, seg_end, snap in plan:
-            if resumed and seg_end <= resume_epoch:
-                continue  # the checkpoint already covers it
+        fn = self._fault_fns.get(mode)
+        if fn is None:
+            make = lanes.make_run_fn if mode == "device" else lanes.make_round_fn
+            fn = self._fault_fns[mode] = make(
+                self.params, self.tables, epochs=True)
+        leaves = self._epoch_leaves(plan)
+        seed = tuple(
+            np.uint32(w) for w in _rng._split_seed(self.params.seed))
+        segments = sum(1 for seg in plan if seg[0] < seg[1])
+        self._fault_plane = {
+            # epochs inside the horizon, the segments they cut it into,
+            # the compiled programs this driver holds for them (one a
+            # mode it has run), and the bytes of path tables they read
+            # (each epoch's set and the base's, as placed on the device)
+            "fault_epochs": segments - 1,
+            "fault_segments": segments,
+            "fault_programs": len(self._fault_fns),
+            "fault_table_bytes": sum(
+                int(a.nbytes)
+                for at in dict.fromkeys(
+                    [None] + [snap.at for *_, snap in plan[1:]])
+                for a in leaves[at].values()),
+        }
+
+        def segment_args(seg_start, seg_end, snap):
+            """What the program takes after the state for one segment (a
+            stall scheduled at its start raises here)."""
             if (
                 0 < seg_start < seg_end
                 and not disarm_stalls
@@ -1406,25 +1473,47 @@ class TpuEngine:
                     f"injected backend stall at {seg_start} ns "
                     "(fault schedule backend_stall event)"
                 )
-            tb = self.tables if snap is None else self._segment_tables(snap)
-            p = _dc.replace(self.params, stop_time=seg_end)
-            key = (seg_start, seg_end, mode)
-            fn = fns.get(key)
+            return (
+                leaves[None if snap is None else snap.at],
+                np.int32(seg_end >> 31), np.int32(seg_end & lanes.MASK31),
+                *seed,
+            )
+
+        # (a resume at the stop time has no segment left to run)
+        args = segment_args(*live[0]) if live else None
+        if mode == "device" and live:
+            if precompile and self._fault_compiled is None:
+                # AOT-compile so the timed run is the steady-state program
+                self._fault_compiled = fn.lower(state, *args).compile()
+            if self._fault_compiled is not None:
+                fn = self._fault_compiled
+        t0 = wall_time.perf_counter()
+        turns = self.obs.turns if self.obs is not None else None
+        seg_rounds = int(np.asarray(state.rounds)) if resumed else 0
+        for i, (seg_start, seg_end, _snap) in enumerate(live):
             swap_cause = (
                 "snapshot"
-                if seg_start == 0 or (resumed and first_live)
+                if seg_start == 0 or (resumed and i == 0)
                 else "fault_swap"
             )
-            first_live = False
             if mode == "device":
-                if fn is None:
-                    fn = fns[key] = lanes.make_run_fn(p, tb)
-                state = jax.block_until_ready(fn(state))
-                if turns is not None:
+                with self.clock.span("dispatch"):
+                    state = fn(state, *args)
+                with self.clock.span("device_wait", name="device_free_run"):
+                    state = jax.block_until_ready(state)
+            else:
+                seg_args = args
+                state = self._drive_steps(
+                    lambda s: fn(s, *seg_args), state, on_window, seg_end,
+                    first_cause=swap_cause,
+                )
+            last = i + 1 == len(live)
+            with (contextlib.nullcontext() if last
+                  else self.clock.span(FAULT_PHASE)):
+                if mode == "device" and turns is not None:
                     # one fused dispatch per epoch segment; the rounds
-                    # delta is its measured free-run length (faulted runs
-                    # are never the timed bench path, so this readback is
-                    # ledger-only)
+                    # delta is its measured free-run length (a readback
+                    # only the ledger asks for)
                     r = int(state.rounds)
                     turns.turn(
                         "free_run" if swap_cause == "snapshot"
@@ -1432,12 +1521,8 @@ class TpuEngine:
                         seg_start, seg_end, windows=r - seg_rounds,
                     )
                     seg_rounds = r
-            else:
-                if fn is None:
-                    fn = fns[key] = lanes.make_round_fn(p, tb)
-                state = self._drive_steps(
-                    fn, state, on_window, p, first_cause=swap_cause,
-                )
+                if not last:
+                    args = segment_args(*live[i + 1])
         wall = wall_time.perf_counter() - t0
         with self._phase("collect"):
             return self.collect(state, wall)
@@ -1682,6 +1767,9 @@ class TpuEngine:
                 exchange_slot_budget=p.exchange_slot_budget,
                 exchange_slot_peak=int(s.exchange_slot_peak),
             )
+        # a faulted run's epochs, segments, programs and table bytes
+        # (_run_faulted; nothing without a schedule)
+        self.lane_plane.update(self._fault_plane)
         if self.obs is not None:
             for key, val in self.lane_plane.items():
                 self.obs.metrics.gauge(key, val)

@@ -464,6 +464,74 @@ def gossip_shape_law(
     return (1 << (row - 1).bit_length()) - 2 * pops - cross, cross
 
 
+def slot_chaos_events(
+    graph,
+    fault_seed: int = 1,
+    loss_edges: int = 15,
+    loss: float = 0.05,
+    latency_edges: int = 15,
+    latency: str = "150 ms",
+    down_edges: int = 10,
+    degrade_at: str = "900 ms",
+    down_at: str = "1060 ms",
+    up_at: str = "2 s",
+    partition_at: str = "4900 ms",
+    heal_at: str = "7 s",
+    region_share: int = 4,
+) -> list[dict]:
+    """The timed chaos schedule ``slot_chaos`` over ``graph`` (a
+    ``NetworkGraph``) as ``docs/faults.md`` event documents — the kinds a
+    chaos test of an Ethereum devnet injects (ethpandaops/attacknet:
+    packet loss, added delay, links down, a partition, each followed by
+    the check that the network recovers), timed against one slot whose
+    bursts stand at 1 / 5 / 9 s:
+
+    - ``degrade_at``: ``loss`` on ``loss_edges`` edges and ``latency`` on
+      ``latency_edges`` others, just before burst 1 floods the graph;
+    - ``down_at``: ``link_down`` on ``down_edges`` further edges, in the
+      middle of that flood;
+    - ``up_at``: ``link_up`` on all of them (clears loss, latency, down);
+    - ``partition_at``: the graph nodes with the lowest ``1 /
+      region_share`` of the ids against the rest, just before burst 2;
+    - ``heal_at``: whole again; burst 3 is the recovery check.
+
+    The edges are drawn WITHOUT replacement from the graph's non-self
+    edges (a self-edge is never touched, so the lookahead window stays)
+    by a numpy stream of ``fault_seed`` alone."""
+    edges = [(e.source, e.target) for e in graph.edges
+             if e.source != e.target]
+    picked = loss_edges + latency_edges + down_edges
+    if picked > len(edges):
+        raise ValueError(
+            f"slot_chaos draws {picked} edges, the graph has {len(edges)} "
+            "that are no self-edge")
+    order = np.random.RandomState(fault_seed).permutation(len(edges))
+    drawn = [edges[i] for i in order[:picked]]
+    lossy = drawn[:loss_edges]
+    slow = drawn[loss_edges:loss_edges + latency_edges]
+    down = drawn[loss_edges + latency_edges:]
+
+    def link(at, kind, edge, **more):
+        return {"at": at, "kind": kind, "source": edge[0], "target": edge[1],
+                **more}
+
+    ids = sorted(graph.node_ids)
+    cut = max(len(ids) // region_share, 1)
+    return (
+        [link(degrade_at, "loss", e, loss=loss) for e in lossy]
+        + [link(degrade_at, "latency", e, latency=latency) for e in slow]
+        + [link(down_at, "link_down", e) for e in down]
+        + [link(up_at, "link_up", e) for e in drawn]
+        + [{"at": partition_at, "kind": "partition",
+            "groups": [ids[:cut], ids[cut:]]},
+           {"at": heal_at, "kind": "heal"}]
+    )
+
+
+#: the fault schedules :func:`gossip_mesh_config` knows by name
+GOSSIP_FAULT_SCHEDULES = {"slot_chaos": slot_chaos_events}
+
+
 def gossip_mesh_config(
     n_hosts: int,
     degree: int = 8,
@@ -476,6 +544,8 @@ def gossip_mesh_config(
     seed: int = 1,
     graph_nodes: int | None = None,
     graph_seed: int = 1,
+    faults=None,
+    fault_seed: int = 1,
 ) -> ConfigOptions:
     """Ethereum-style gossip (libp2p gossipsub's eager push over a static
     mesh, ``models/gossip.py``): ``n_hosts`` nodes, each ONE process
@@ -495,9 +565,19 @@ def gossip_mesh_config(
     hosts): the deployment is ONE network, ``seed`` drives the loss draws.
     Host ``i`` has the same id, mesh row and publications either way.
 
+    ``faults`` puts the network under a fault schedule (``cfg.faults``,
+    ``docs/faults.md``): the NAME of one built over the graph from a
+    stream of ``fault_seed`` alone (``GOSSIP_FAULT_SCHEDULES``:
+    ``"slot_chaos"``, :func:`slot_chaos_events`), or a list of event
+    documents as they are.  A flood's hop is then budgeted at the longest
+    routed path OF ANY EPOCH (a ``latency`` or ``link_down`` epoch
+    lengthens paths).  Every event is kept whatever the stop time (an
+    epoch past it is dropped by ``FaultOverlay.segment_plan``).
+
     The lane program's shapes are :func:`gossip_shape_law`'s; stop time
     and backend (``tpu``) are the caller's to set on the result."""
     from ..models.gossip import gossip_publishers
+    from ..net.graph import NetworkGraph
 
     times = sorted(units.parse_time(b) for b in bursts)
     process = {
@@ -516,15 +596,10 @@ def gossip_mesh_config(
             f'host_bandwidth_down "{bandwidth}" ]\n'
             f'  edge [ source 0 target 0 latency "{latency}" ]\n'
             "]\n")
-        hop = units.parse_time(latency)
         hosts = {"node": {"count": n_hosts, "network_node_id": 0,
                           "processes": [process]}}
     else:
-        from ..net.graph import NetworkGraph
-
         gml = routed_graph_gml(graph_nodes, graph_seed, bandwidth)
-        # a flood's hop is budgeted at the longest routed path
-        hop = NetworkGraph.from_gml(gml).max_latency_ns()
         # a stream of its own, so the graph does not move with the width
         rnd = random.Random(f"gossip-hosts-{graph_seed}")
         hosts = {
@@ -534,6 +609,18 @@ def gossip_mesh_config(
             }
             for i in range(1, n_hosts + 1)
         }
+    # a flood's hop is budgeted at the longest routed path, of any epoch
+    graph = NetworkGraph.from_gml(gml)
+    hop = graph.max_latency_ns()
+    events = []
+    if faults is not None:
+        from ..faults.overlay import FaultOverlay
+        from ..faults.schedule import FaultSchedule
+
+        events = (GOSSIP_FAULT_SCHEDULES[faults](graph, fault_seed)
+                  if isinstance(faults, str) else list(faults))
+        hop = FaultOverlay(
+            FaultSchedule.parse(events), graph, {}, []).max_latency_ns()
     span = gossip_flood_hops(n_hosts, degree) * hop
     concurrent = messages * max(
         sum(1 for u in times if t <= u < t + span) for t in times
@@ -554,4 +641,5 @@ def gossip_mesh_config(
             "tpu_events_per_round": GOSSIP_POPS,
         },
         "hosts": hosts,
+        **({"faults": {"events": events}} if events else {}),
     })

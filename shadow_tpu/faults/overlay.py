@@ -88,6 +88,9 @@ class FaultOverlay:
         for idx in host_node_index.values():
             self._node_host_count[idx] = self._node_host_count.get(idx, 0) + 1
         self._snapshots: list[Snapshot] = []
+        # counts the compiles of the snapshots: whoever keeps a copy of
+        # their tables (TpuEngine's placed path leaves) keys it by this
+        self.version = 0
         self._recompute()
 
     # -- event -> mutable fault state ---------------------------------------
@@ -189,6 +192,7 @@ class FaultOverlay:
             lat, loss, thr = self._compile(over, partition, crashed)
             snapshots.append(Snapshot(t, lat, loss, thr, stall))
         self._snapshots = snapshots
+        self.version += 1
 
     # -- table compilation ---------------------------------------------------
 

@@ -34,7 +34,14 @@ neither engine can fake.
     one-pass and many-pass iterations and equals the oracle, on one
     switch, on the routed lossy graph and under faults; the passes
     together hand every lane the rows and the count ONE exchange of the
-    whole send channel would; and the three gauges keep their law.
+    whole send channel would; and the three gauges keep their law;
+(j) failure as part of the workload (ISSUE 45): a faulted run is ONE
+    compiled program for all its segments and repeats — path leaves, stop
+    bound and seed words its arguments, the leaves placed once an engine —
+    which ``precompile`` compiles ahead; the factory's ``faults`` is a
+    function of ``fault_seed`` alone and touches no self-edge; under
+    partition / heal and link_up schedules on the routed graph both
+    engines agree and keep the two conservation laws of a flood.
 """
 
 import functools
@@ -52,8 +59,10 @@ from shadow_tpu.backend.tpu_engine import LaneCompatError, TpuEngine
 from shadow_tpu.config.options import ConfigOptions
 from shadow_tpu.config.scenarios import (
     GOSSIP_POPS, gossip_flood_hops, gossip_mesh_config, gossip_shape_law,
-    routed_graph_gml,
+    routed_graph_gml, slot_chaos_events,
 )
+from shadow_tpu.faults.schedule import parse_event
+from shadow_tpu.net.graph import NetworkGraph
 from shadow_tpu.core import rng
 from shadow_tpu.models.gossip import (
     AGE_COUNTERS, AGE_EDGES_MS, age_counter, gossip_mesh, gossip_publishers,
@@ -477,10 +486,12 @@ WAN = dict(nodes=96, degree=4, messages=3, graph_nodes=12)
 
 def _wan_cfg(backend="tpu", seed=7, graph_seed=1, nodes=WAN["nodes"],
              degree=WAN["degree"], messages=WAN["messages"],
-             graph_nodes=WAN["graph_nodes"], bursts=BURSTS, stop_ms=2200):
+             graph_nodes=WAN["graph_nodes"], bursts=BURSTS, stop_ms=2200,
+             **faults):
     cfg = gossip_mesh_config(nodes, degree, 1, bursts, messages, 512,
                              bandwidth="1 Gbit", seed=seed,
-                             graph_nodes=graph_nodes, graph_seed=graph_seed)
+                             graph_nodes=graph_nodes, graph_seed=graph_seed,
+                             **faults)
     cfg.general.stop_time = stop_ms * MS
     cfg.experimental.network_backend = backend
     return cfg
@@ -819,7 +830,7 @@ def test_an_all_gossip_program_on_a_graph_traces_no_path_gather(monkeypatch):
 def _oracle_less_tgen_sent(cfg):
     oracle, last = _oracle_run(cfg)
     # the lane backend has never counted a tgen client's sent bytes
-    oracle.counters.pop("tgen_sent_bytes")
+    oracle.counters.pop("tgen_sent_bytes", None)
     return oracle, last
 
 
@@ -847,21 +858,214 @@ def test_gossip_beside_a_gathered_destination_equals_the_oracle(mode):
         lanes.M_TGEN_CLIENT, lanes.M_TGEN_SERVER, lanes.M_GOSSIP}
 
 
+#: a regional split under the first flood, healed before the second: graph
+#: nodes 0-2 of the 12 against the rest
+PARTITION_HEAL = (
+    {"at": "990 ms", "kind": "partition",
+     "groups": [[0, 1, 2], list(range(3, 12))]},
+    {"at": "1500 ms", "kind": "heal"},
+)
+
+
+def _chaos(graph_nodes=WAN["graph_nodes"], fault_seed=1, **more):
+    """``slot_chaos`` cut to the small graph and the two bursts at 1 s and
+    2 s: lossy, slow and down links under the first flood, ``link_up``
+    inside it, a partition across the second flood and its heal."""
+    graph = NetworkGraph.from_gml(routed_graph_gml(graph_nodes, 1, "1 Gbit"))
+    return slot_chaos_events(
+        graph, fault_seed, loss_edges=3, loss=0.3, latency_edges=3,
+        latency="60 ms", down_edges=3, degrade_at="990 ms",
+        down_at="1015 ms", up_at="1040 ms", partition_at="1990 ms",
+        heal_at="2030 ms", **more)
+
+
+def _faulted(schedule, backend):
+    """The faulted configuration of a case, and whether every lane runs
+    gossip (so that the flood's two conservation laws are the run's)."""
+    if schedule == "rows":
+        return _rows_cfg(backend, faults=FAULTS), _rows_cfg(backend), False
+    faults = PARTITION_HEAL if schedule == "partition_heal" else _chaos()
+    return (_wan_cfg(backend, stop_ms=2600, faults=faults),
+            _wan_cfg(backend, stop_ms=2600), True)
+
+
+def _assert_a_flood_is_conserved(counters, degree=WAN["degree"],
+                                 messages=len(BURSTS) * WAN["messages"]):
+    """Whatever the faults did, in a run that ends with nothing in flight:
+    a publisher sends D copies and every first receipt forwards D - 1; and
+    every copy sent was a first copy, a duplicate, or lost on its path."""
+    c = counters
+    assert c.get("lane_drop_queue", 0) == 0
+    assert c["gossip_sends"] == (
+        messages * degree + (degree - 1) * c["gossip_first"])
+    assert c["gossip_sends"] == (
+        c["gossip_first"] + c["gossip_duplicates"] + c["lane_drop_loss"])
+
+
 @pytest.mark.faults
+@pytest.mark.parametrize("schedule", ["rows", "partition_heal", "link_up"])
 @pytest.mark.parametrize("budget", [None, 4], ids=["one_pass", "passes"])
 @pytest.mark.parametrize("mode", ["device", "step"])
-def test_a_faulted_gossip_run_equals_the_oracle(mode, budget, slot_budget):
+def test_a_faulted_gossip_run_equals_the_oracle(mode, budget, schedule,
+                                                slot_budget):
     """Every epoch's rows are that epoch's tables: a send at or after the
-    epoch takes the new latency and loss, an earlier one never does."""
-    oracle, last = _oracle_less_tgen_sent(_rows_cfg("cpu", faults=FAULTS))
-    calm, _last = _oracle_less_tgen_sent(_rows_cfg("cpu"))
+    epoch takes the new latency and loss, an earlier one never does —
+    under latency / loss / link_down epochs beside a gathered destination
+    (``rows``), under a partition and its heal, and under degraded and
+    down links restored by ``link_up`` (the routed graph, all gossip)."""
+    cfg, calm_cfg, all_gossip = _faulted(schedule, "cpu")
+    oracle, last = _oracle_less_tgen_sent(cfg)
+    calm, _last = _oracle_less_tgen_sent(calm_cfg)
     assert oracle.log_tuples() != calm.log_tuples()  # the schedule bit
     check_gauges = slot_budget(budget)
-    eng = TpuEngine(_rows_cfg(faults=FAULTS))
+    cfg.experimental.network_backend = "tpu"
+    eng = TpuEngine(cfg)
     res = eng.run(mode=mode)
     _assert_equals_oracle(eng, res, oracle, last)
     check_gauges(eng, res)
     assert res.counters["lane_drop_loss"] > calm.counters["lane_drop_loss"]
+    if all_gossip:
+        for counters in (oracle.counters, res.counters):
+            _assert_a_flood_is_conserved(counters)
+        # a partition across a whole flood keeps first copies away for
+        # good; a short one the other peers' later copies make up for
+        kept_away = (calm.counters["gossip_first"]
+                     - res.counters["gossip_first"])
+        assert kept_away > 0 if schedule == "partition_heal" else (
+            kept_away >= 0)
+    epochs = len({parse_event(e).at for e in cfg.faults.events})
+    assert (eng.lane_plane["fault_epochs"],
+            eng.lane_plane["fault_segments"],
+            eng.lane_plane["fault_programs"]) == (epochs, epochs + 1, 1)
+
+
+# -- (j) failure as part of the workload ----------------------------------------
+
+FAULT_GAUGES = ("fault_epochs", "fault_segments", "fault_programs",
+                "fault_table_bytes")
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize("mode", ["device", "step"])
+def test_a_faulted_run_is_one_program_for_every_segment_and_repeat(mode):
+    """Five epochs, six segments, three repeats: ONE trace, the leaves
+    placed once, and ``precompile`` compiles that program ahead."""
+    eng = TpuEngine(_wan_cfg(stop_ms=2600, faults=_chaos()), log_capacity=0)
+    first = eng.run(mode=mode, precompile=True)
+    fn, placed = eng._fault_fns[mode], eng._fault_leaves[1]
+    kept = {at: dict(leaves) for at, leaves in placed.items()}
+    assert (eng._fault_compiled is not None) == (mode == "device")
+    for _ in range(2):
+        again = eng.run(mode=mode)
+        assert again.counters == first.counters
+        assert again.rounds == first.rounds
+    assert fn.traces == 1 and eng._fault_fns == {mode: fn}
+    assert eng._fault_leaves[1] is placed and len(placed) == 6
+    for at, leaves in placed.items():  # the very arrays of the first run
+        assert all(leaves[f] is kept[at][f] for f in leaves)
+    plane = eng.lane_plane
+    one_set = sum(int(getattr(eng.tables, f).nbytes)
+                  for f in eng._path_fields)
+    assert [plane[k] for k in FAULT_GAUGES] == [5, 6, 1, 6 * one_set]
+    assert set(eng._path_fields) == {
+        "lat", "thresh_u32", "thresh_all", "flow_lat", "flow_thresh_u32",
+        "flow_thresh_all", "g_lat", "g_thresh_u32", "g_thresh_all"}
+    assert plane["state_reused"] == 1
+    assert eng.clock.phase_s["fault_swap"] > 0
+    oracle, last = _oracle_run(_wan_cfg("cpu", stop_ms=2600,
+                                        faults=_chaos()))
+    assert _shared(again.counters) == _shared(oracle.counters)
+    assert again.rounds == oracle.rounds
+    # a calm engine has no such phase and no such gauge
+    calm = TpuEngine(_wan_cfg(), log_capacity=0)
+    calm.run(mode=mode)
+    assert "fault_swap" not in calm.clock.phase_s
+    assert not set(FAULT_GAUGES) & set(calm.lane_plane)
+
+
+@pytest.mark.faults
+def test_a_fault_added_to_the_overlay_drops_the_placed_leaves():
+    """The console's ``add_event`` recompiles the snapshots: the engine
+    places the new epochs' leaves, keeps its ONE program, and equals the
+    oracle under the longer schedule."""
+    eng = TpuEngine(_wan_cfg(stop_ms=2600, faults=PARTITION_HEAL))
+    before = eng.run(mode="device")
+    placed = eng._fault_leaves[1]
+    late = {"at": "1995 ms", "kind": "partition",
+            "groups": [[0, 1, 2, 3, 4, 5], [6, 7, 8, 9, 10, 11]]}
+    eng._fault_overlay.add_event(parse_event(late))
+    after = eng.run(mode="device")
+    assert eng._fault_leaves[1] is not placed
+    assert set(eng._fault_leaves[1]) == {
+        None, 990 * MS, 1500 * MS, 1995 * MS}
+    assert eng._fault_fns["device"].traces == 1
+    assert eng.lane_plane["fault_epochs"] == 3
+    assert after.counters["gossip_first"] < before.counters["gossip_first"]
+    oracle, last = _oracle_run(_wan_cfg(
+        "cpu", stop_ms=2600, faults=PARTITION_HEAL + (late,)))
+    _assert_equals_oracle(eng, after, oracle, last)
+
+
+def test_precompile_is_still_refused_with_a_resume():
+    eng = TpuEngine(_wan_cfg(stop_ms=1100, faults=PARTITION_HEAL))
+    with pytest.raises(LaneCompatError, match="checkpoint resume"):
+        eng.run(mode="device", precompile=True,
+                resume_state=eng.initial_state())
+
+
+def _edges(events, kinds):
+    return [(e["source"], e["target"]) for e in events if e["kind"] in kinds]
+
+
+def test_the_factorys_schedule_is_a_function_of_the_fault_seed_alone():
+    base = _wan_cfg(graph_nodes=40, faults="slot_chaos")
+    events = base.faults.events
+    assert [e["kind"] for e in events] == (
+        ["loss"] * 15 + ["latency"] * 15 + ["link_down"] * 10
+        + ["link_up"] * 40 + ["partition", "heal"])
+    assert sorted({parse_event(e).at // MS for e in events}) == [
+        900, 1060, 2000, 4900, 7000]
+    # 40 distinct edges of the graph, none a self-edge, all restored
+    graph = NetworkGraph.from_gml(routed_graph_gml(40, 1, "1 Gbit"))
+    real = {(e.source, e.target) for e in graph.edges}
+    hit = _edges(events, ("loss", "latency", "link_down"))
+    assert len(set(hit)) == 40 and set(hit) <= real
+    assert all(a != b for a, b in hit)
+    assert sorted(_edges(events, ("link_up",))) == sorted(hit)
+    groups = events[-2]["groups"]
+    assert groups == [list(range(10)), list(range(10, 40))]
+    # the run's seed, the mesh's seed, the width and the traffic move none
+    other = gossip_mesh_config(128, 8, 5, ("3 s",), 2, 256,
+                               bandwidth="1 Gbit", seed=99, graph_nodes=40,
+                               graph_seed=1, faults="slot_chaos")
+    assert other.faults.events == events
+    assert _wan_cfg(graph_nodes=40, faults="slot_chaos",
+                    fault_seed=2).faults.events != events
+    # a list of event documents is taken as it is
+    given = _wan_cfg(faults=PARTITION_HEAL)
+    assert given.faults.events == list(PARTITION_HEAL)
+    with pytest.raises(ValueError, match="draws 40 edges"):
+        _wan_cfg(faults="slot_chaos")  # 12 graph nodes have fewer
+
+
+def test_without_faults_the_factory_builds_what_it_built():
+    calm, chaos = _wan_cfg(), _wan_cfg(faults=PARTITION_HEAL)
+    assert calm.faults.events == [] and not calm.faults.failover_enabled
+    for cfg in (calm, chaos):
+        assert cfg.hosts == _wan_cfg(faults=None).hosts
+        assert cfg.network.graph.inline == routed_graph_gml(12, 1, "1 Gbit")
+    assert calm.experimental == _wan_cfg(faults=None, fault_seed=9).experimental
+    # the shape law reads the longest routed path of ANY epoch: a slow
+    # link that no route avoids stretches the span two bursts share
+    slow = [{"at": "900 ms", "kind": "latency", "source": a, "target": b,
+             "latency": "400 ms"}
+            for a, b in sorted({(e.source, e.target) for e in
+                                NetworkGraph.from_gml(
+                                    routed_graph_gml(12, 1)).edges})
+            if a != b]
+    wide, calm = _wan_cfg(messages=8, faults=slow), _wan_cfg(messages=8)
+    assert calm.experimental.tpu_lane_queue_capacity == 52
+    assert wide.experimental.tpu_lane_queue_capacity == 116
 
 
 # -- (i) the exchange sorts the slots that sent ---------------------------------
