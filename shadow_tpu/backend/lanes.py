@@ -225,6 +225,20 @@ def split64(v):
     return (v >> 31).astype(jnp.int32), (v & MASK31).astype(jnp.int32)
 
 
+#: a queue row's words, in the order every sort, gather and the packed
+#: carry holds them: the 4-word key, the size, then as many of the payload
+#: words as the models present use (``LaneParams.payload_words``, counted
+#: from the back: one word is ``plo``).  ``LaneState`` holds word ``w`` as
+#: ``q_<w>``
+ROW_WORDS = ("thi", "tlo", "auxh", "auxl", "size")
+PAY_WORDS = ("phi", "plo")
+
+
+def pay_words(count: int) -> tuple:
+    """The names of a row's ``count`` payload words."""
+    return PAY_WORDS[len(PAY_WORDS) - count:]
+
+
 class LaneState(NamedTuple):
     """The full device-resident simulation state (a pytree of arrays)."""
 
@@ -235,8 +249,10 @@ class LaneState(NamedTuple):
     q_auxh: jnp.ndarray  # int32 kind<<29 | src<<12
     q_auxl: jnp.ndarray  # int32 seq
     q_size: jnp.ndarray  # int32
-    # opaque payload words (stream tier: flags<<26|seq, ack — see
-    # lanes_stream.pack_pay); () when no stream models are present
+    # opaque payload words (stream lanes: flags<<26|seq, ack — see
+    # lanes_stream.pack_pay; gossip: the message id, in ``q_plo`` alone).
+    # ``LaneParams.payload_words`` says how many a row carries: both, only
+    # ``q_plo`` (``q_phi`` is ``()``), or neither (both ``()``)
     q_phi: jnp.ndarray  # int32
     q_plo: jnp.ndarray  # int32
     # per-lane counters [N] — int32 throughout (the engine checks for
@@ -485,13 +501,37 @@ class LaneParams:
         return self.gossip_degree if M_GOSSIP in self.models_present else 1
 
     @property
-    def lanes_have_payload(self) -> bool:
-        """The [N] queues carry payload columns when stream events ride
-        them (the tiered backend moves those to the [2S] block) or gossip
-        datagrams do (the message id)."""
-        return (self.stream_present and not self.stream_tiered) or (
-            M_GOSSIP in self.models_present
-        )
+    def payload_words(self) -> int:
+        """How many opaque payload words a row of the [N] queues carries —
+        a static property of the models present, as ``sends_per_pop`` is:
+        2 where stream events ride the [N] queues (a segment's ``phi`` and
+        ``plo``; the tiered backend moves those to the [2S] block, which
+        always keeps its two), else 1 where gossip datagrams do (the
+        message id, ``plo``: ``phi`` then exists nowhere in the program),
+        else 0."""
+        if self.stream_present and not self.stream_tiered:
+            return 2
+        return int(M_GOSSIP in self.models_present)
+
+    @property
+    def pay_words(self) -> tuple:
+        """The payload words a row carries, by name."""
+        return pay_words(self.payload_words)
+
+    @property
+    def row_words(self) -> tuple:
+        """All the words of a row: the operand list of every row sort, in
+        its order."""
+        return ROW_WORDS + self.pay_words
+
+    @property
+    def emit_pay_words(self) -> tuple:
+        """The payload words a slot's insert and send channels carry
+        (``_SlotEmit.ins_*`` / ``out_*``; an absent one is ``()``): the
+        rows' own — or, where the rows carry none, both, as zeros nobody
+        reads and XLA drops: such a program's lowered text is what it has
+        always been, and the tests pin it."""
+        return self.pay_words or PAY_WORDS
 
     @property
     def all_passive(self) -> bool:
@@ -992,29 +1032,30 @@ def scan_or_unroll(step, carry, xs, length: int, spmd_unroll: bool = False):
     return carry, stacked
 
 
-def _sort_queues(s: LaneState, with_pay: bool = False) -> LaneState:
-    """Key-sort every lane's queue by the 4-word key — the split form of
-    the (time, kind, src, seq) total order; empty slots (NEVER pair) end at
-    the back.
+def _q_cols(s: LaneState, words) -> list:
+    """The queue's columns ``[N, C]`` for a list of row words."""
+    return [getattr(s, "q_" + w) for w in words]
 
-    Establishes the sorted-row invariant on entry states
-    (``TpuEngine.initial_state``) and restores it on iterations that pop
-    events but skip the merge (see ``iter_body``).  ``with_pay`` carries the
-    stream payload columns through the permutation (static: stream tier)."""
-    if with_pay:
-        thi, tlo, ah, al, size, phi, plo = lax.sort(
-            (s.q_thi, s.q_tlo, s.q_auxh, s.q_auxl, s.q_size, s.q_phi,
-             s.q_plo),
-            dimension=1, num_keys=4, is_stable=False,
-        )
-        return s._replace(q_thi=thi, q_tlo=tlo, q_auxh=ah, q_auxl=al,
-                          q_size=size, q_phi=phi, q_plo=plo)
-    thi, tlo, ah, al, size = lax.sort(
-        (s.q_thi, s.q_tlo, s.q_auxh, s.q_auxl, s.q_size),
-        dimension=1, num_keys=4, is_stable=False,
-    )
-    return s._replace(q_thi=thi, q_tlo=tlo, q_auxh=ah, q_auxl=al,
-                      q_size=size)
+
+def _q_replace(s: LaneState, words, cols) -> LaneState:
+    """``s`` with those columns as the queue's, word by word."""
+    return s._replace(**{"q_" + w: col for w, col in zip(words, cols)})
+
+
+def _sort_rows(words, cols) -> dict:
+    """ONE row sort by the 4-word key — the split form of the (time, kind,
+    src, seq) total order; empty slots (NEVER pair) end at the back —
+    that carries every word of the row (``LaneParams.row_words``: the
+    operand list) through the permutation.  The sorted columns, by word."""
+    return dict(zip(words, lax.sort(
+        tuple(cols), dimension=1, num_keys=4, is_stable=False)))
+
+
+def _sort_queues(s: LaneState, words) -> LaneState:
+    """Key-sort every lane's queue: restores the sorted-row invariant on
+    iterations that pop events but skip the merge (see ``iter_body``)."""
+    return _q_replace(
+        s, words, _sort_rows(words, _q_cols(s, words)).values())
 
 
 class _SlotEmit(NamedTuple):
@@ -1029,8 +1070,8 @@ class _SlotEmit(NamedTuple):
     ins_auxh: jnp.ndarray  # int32
     ins_auxl: jnp.ndarray  # int32
     ins_size: jnp.ndarray  # int32
-    ins_phi: jnp.ndarray  # int32 payload words
-    ins_plo: jnp.ndarray
+    ins_phi: Any  # int32 payload words (``phi`` () where it is not
+    ins_plo: jnp.ndarray  # one of ``LaneParams.emit_pay_words``)
     # same-lane insert channel 2: timer re-arm / stream pump (LOCAL)
     arm_valid: jnp.ndarray
     arm_thi: jnp.ndarray
@@ -1047,7 +1088,7 @@ class _SlotEmit(NamedTuple):
     out_auxh: jnp.ndarray
     out_auxl: jnp.ndarray
     out_size: jnp.ndarray
-    out_phi: jnp.ndarray  # int32 payload words
+    out_phi: Any  # int32 payload words (``phi`` () as ``ins_phi`` is)
     out_plo: jnp.ndarray
     # COMPACTED stream channels (endpoint rows; () when no stream tier).
     # Destinations/aux words come from the static flow tables, so only
@@ -1409,6 +1450,9 @@ def _process_slot(
     out_phi = out_plo = jnp.zeros(n, dtype=i32)
     if M_GOSSIP in mp:
         out_plo = jnp.where(g_push, g_mid, 0)
+    if "phi" not in p.emit_pay_words:
+        # the rows carry ``plo`` alone: ``phi`` rides no channel
+        ins_phi = out_phi = ()
 
     def one_send(s, do_send, dst, rows=None, gathers=True):
         """One datagram a lane, as the oracle's ``send_packet``: the next
@@ -1560,8 +1604,9 @@ def _process_slot(
      out_valid, out_auxh, out_auxl,
      pc_valid, pc_time, pc_dst, pc_seq, pc_size) = sent
     if n_f > 1:
-        out_size, out_phi, out_plo = (
-            jnp.broadcast_to(a, (n_f, n)) for a in (out_size, out_phi, out_plo)
+        out_size, out_phi, out_plo = jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (n_f, n)),
+            (out_size, out_phi, out_plo),
         )
 
     # ---- compacted stream send/arm channels ([2S] and [B, S]) ------------
@@ -2120,17 +2165,17 @@ def _sorted_exchange(flat_ops, n):
     return list(sorted_ops[1:]), start, cnt
 
 
-def _rank_sending(emits: _SlotEmit, budget, n):
+def _rank_sending(emits: _SlotEmit, budget, n, pay):
     """What a fan-out iteration's exchange reads, built once an iteration
     from the ``[K, F, N]`` send channel: how many (pop, lane) slots put a
     datagram into the exchange (some ``out_valid[k, :, lane]`` set); their
     slot ids ranked to the front by ONE single-operand sort of the ``K x
     N`` slot ids (a slot that did not send is keyed past them all), padded
     to whole passes of ``budget``; and the slots' words as a ``[K x N, 4 F
-    + 4]`` table of ONE row a slot — its F destinations (``n`` where the
-    send is lost or unused), arrival pairs and sequence numbers, and the
-    four words of its pop (``auxh``, ``size``, ``phi``, ``plo``: one a
-    slot, not F)."""
+    + 2 + P]`` table of ONE row a slot — its F destinations (``n`` where
+    the send is lost or unused), arrival pairs and sequence numbers, and
+    the words of its pop (``auxh``, ``size`` and the P payload words
+    ``pay`` the rows carry: one a slot, not F)."""
     k, f, lanes_n = emits.out_valid.shape
     slots = k * lanes_n
     with jax.named_scope("exchange_compact"):
@@ -2145,18 +2190,19 @@ def _rank_sending(emits: _SlotEmit, budget, n):
         dst = jnp.where(emits.out_valid, emits.out_dst, jnp.int32(n))
         table = jnp.concatenate(
             [dst, emits.out_thi, emits.out_tlo, emits.out_auxl]
-            + [w[:, :1] for w in (emits.out_auxh, emits.out_size,
-                                  emits.out_phi, emits.out_plo)],
+            + [getattr(emits, "out_" + w)[:, :1]
+               for w in ("auxh", "size", *pay)],
             axis=1,
-        )  # [K, 4F + 4, N]
-        table = table.transpose(0, 2, 1).reshape(slots, 4 * f + 4)
+        )  # [K, 4F + 2 + P, N]
+        table = table.transpose(0, 2, 1).reshape(slots, -1)
     return sending.sum(dtype=jnp.int32), ranked, table
 
 
-def _compact_sends(ranked, table, i, budget, n):
-    """Pass ``i`` of a fan-out iteration's exchange: the eight flat
-    columns ``_sorted_exchange`` takes (``dst``, the arrival pair,
-    ``auxh``, ``auxl``, ``size``, the payload pair) over the ``i``-th
+def _compact_sends(ranked, table, i, budget, n, f):
+    """Pass ``i`` of a fan-out iteration's exchange: the flat columns
+    ``_sorted_exchange`` takes (``dst``, then a row's words: the arrival
+    pair, ``auxh``, ``auxl``, ``size`` and the payload words the table
+    holds — seven columns at one payload word) over the ``i``-th
     ``budget`` of the ranked sending slots — ``budget x F`` rows where the
     send channel has ``K x F x N``.  A slot's words are fetched as ONE row
     of the table and one 2-D transpose puts the slots minor again, where
@@ -2167,10 +2213,9 @@ def _compact_sends(ranked, table, i, budget, n):
     PR 41).  Rows past the last sending slot carry ``dst == n`` (the
     table's own for a lost or unused send)."""
     slots, width = table.shape
-    f = (width - 4) // 4
     with jax.named_scope("exchange_compact"):
         pick = lax.dynamic_slice(ranked, (i * budget,), (budget,))
-        block = table[jnp.minimum(pick, slots - 1)].T  # [4F + 4, budget]
+        block = table[jnp.minimum(pick, slots - 1)].T  # [4F + 2 + P, budget]
 
         def per_send(w):
             return block[w * f:(w + 1) * f]  # [F, budget]
@@ -2184,7 +2229,8 @@ def _compact_sends(ranked, table, i, budget, n):
             jnp.where((pick < slots)[None, :], per_send(0), jnp.int32(n)),
             per_send(1), per_send(2),  # arrival pair
             per_pop(0), per_send(3),  # auxh, auxl
-            per_pop(1), per_pop(2), per_pop(3),  # size, phi, plo
+            # size, the payload words
+            *(per_pop(w) for w in range(1, width - 4 * f)),
         ]
         return [c.reshape(-1) for c in cols]
 
@@ -2247,37 +2293,26 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     """
     n, c = p.n_lanes, p.capacity
     sp = p.stream_present
-    pay = p.lanes_have_payload  # the rows carry the two payload words
+    pay = p.pay_words  # the payload words the rows carry
 
     # -- same-lane block [N, 2K] (3K with the stream RTO channel; K when
     # every model is passive — the DELIVERY self-insert channel is then
     # statically dead and its always-NEVER columns are dropped) ----------
-    if p.all_passive:
-        self_parts = [emits.arm_valid.T]
-        thi_parts = [emits.arm_thi.T]
-        tlo_parts = [emits.arm_tlo.T]
-        auxh_parts = [emits.arm_auxh.T]
-        auxl_parts = [emits.arm_auxl.T]
-        size_parts = [emits.arm_size.T]
-        phi_parts = [jnp.zeros_like(emits.arm_plo.T)]
-        plo_parts = [emits.arm_plo.T]
-    else:
-        self_parts = [emits.ins_valid.T, emits.arm_valid.T]
-        thi_parts = [emits.ins_thi.T, emits.arm_thi.T]
-        tlo_parts = [emits.ins_tlo.T, emits.arm_tlo.T]
-        auxh_parts = [emits.ins_auxh.T, emits.arm_auxh.T]
-        auxl_parts = [emits.ins_auxl.T, emits.arm_auxl.T]
-        size_parts = [emits.ins_size.T, emits.arm_size.T]
-        phi_parts = [emits.ins_phi.T, jnp.zeros_like(emits.arm_plo.T)]
-        plo_parts = [emits.ins_plo.T, emits.arm_plo.T]
-    self_valid = jnp.concatenate(self_parts, axis=1)
-    self_thi = jnp.where(self_valid, jnp.concatenate(thi_parts, axis=1), NEVER32)
-    self_tlo = jnp.where(self_valid, jnp.concatenate(tlo_parts, axis=1), NEVER32)
-    self_auxh = jnp.concatenate(auxh_parts, axis=1)
-    self_auxl = jnp.concatenate(auxl_parts, axis=1)
-    self_size = jnp.concatenate(size_parts, axis=1)
-    self_phi = jnp.concatenate(phi_parts, axis=1)
-    self_plo = jnp.concatenate(plo_parts, axis=1)
+    chans = ("arm",) if p.all_passive else ("ins", "arm")
+
+    def part(chan, w):
+        if (chan, w) == ("arm", "phi"):  # a timer's phi is always 0
+            return jnp.zeros_like(emits.arm_plo.T)
+        return getattr(emits, f"{chan}_{w}").T
+
+    parts = {w: [part(chan, w) for chan in chans]
+             for w in ("valid", *ROW_WORDS, *p.emit_pay_words)}
+    self_valid = jnp.concatenate(parts.pop("valid"), axis=1)
+    self_cols = {}
+    for w, cols in parts.items():
+        self_cols[w] = jnp.concatenate(cols, axis=1)
+        if w in ("thi", "tlo"):  # an unused entry's time is NEVER
+            self_cols[w] = jnp.where(self_valid, self_cols[w], NEVER32)
 
     # -- cross-lane block [N, Cx] via sort-by-dst + histogram bounds -------
     # one-to-one stream configs take the SPLIT exchange: every stream
@@ -2290,10 +2325,9 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     # static.  Which path an event rides is unobservable — placement is
     # by the keyed merge either way.
     split_se = sp and p.stream_one_to_one
-    has_pay_flat = pay and not split_se
+    flat_pay = () if split_se else pay  # what the flat exchange carries
     cx = p.cross_cap
-    self_words = [self_thi, self_tlo, self_auxh, self_auxl, self_size,
-                  self_phi, self_plo]
+    self_words = [self_cols[w] for w in p.row_words]
 
     if p.sends_per_pop > 1:
         # a pop that fans out made the send channel F times wider and left
@@ -2306,17 +2340,18 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
         # merge is associative, the rows' 4-word key a total order, so
         # every lane's queue is what one exchange of all K x F x N rows
         # would leave, and strict capacity raises on any shed.  (Not a
-        # ``lax.cond`` beside the full exchange: a second 8-operand sort
-        # is 4 MB of code, PERF.md §6 PR 43.)
-        assert has_pay_flat and not divert  # gossip rides no stream tier
+        # ``lax.cond`` beside the full exchange: a second sort of as many
+        # operands is 4 MB of code, PERF.md §6 PR 43.)
+        assert flat_pay and not divert  # gossip rides no stream tier
         budget = p.exchange_slot_budget
-        n_sending, ranked, table = _rank_sending(emits, budget, n)
+        n_sending, ranked, table = _rank_sending(emits, budget, n, pay)
         passes = jnp.maximum((n_sending + (budget - 1)) // budget, 1)
 
         def one_pass(carry):
             i, st, cnt_all = carry
             gather_ops, start, cnt = _sorted_exchange(
-                _compact_sends(ranked, table, i, budget, n), n)
+                _compact_sends(ranked, table, i, budget, n,
+                               p.sends_per_pop), n)
             words = _cross_block(gather_ops, start, cnt, cx)[1]
             # the same-lane block rides the first pass
             own = [jnp.where(i == 0, w, NEVER32) for w in self_words[:2]]
@@ -2339,9 +2374,7 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     out_tlo = emits.out_tlo.reshape(-1)
     flat_ops = [dst, out_thi, out_tlo, emits.out_auxh.reshape(-1),
                 emits.out_auxl.reshape(-1), emits.out_size.reshape(-1)]
-    if has_pay_flat:
-        flat_ops.append(emits.out_phi.reshape(-1))
-        flat_ops.append(emits.out_plo.reshape(-1))
+    flat_ops += [getattr(emits, "out_" + w).reshape(-1) for w in flat_pay]
     if sp and not split_se:
         # the COMPACTED stream channels join the exchange here: slot-0
         # control sends (dst = peer lane), burst data segments (dst =
@@ -2421,14 +2454,10 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     assert flat_ops[0].shape[0] == p.exchange_entries
     gather_ops, start, cnt = _sorted_exchange(flat_ops, n)
     _in_seg, words = _cross_block(gather_ops, start, cnt, cx)
-    cross_thi, cross_tlo, cross_auxh, cross_auxl, cross_size = words[:5]
-    if pay:
-        if has_pay_flat:
-            cross_phi, cross_plo = words[5:]
-        else:
-            # split exchange: the [N] channel never carries payloads
-            cross_phi = jnp.zeros((n, cx), dtype=jnp.int32)
-            cross_plo = jnp.zeros((n, cx), dtype=jnp.int32)
+    cross = dict(zip(ROW_WORDS + flat_pay, words))
+    for w in pay[len(flat_pay):]:
+        # split exchange: the [N] channel never carries payloads
+        cross[w] = jnp.zeros((n, cx), dtype=jnp.int32)
     # receivers of more than Cx events in one iteration lose the tail
     # before the merge even sees it; count those drops too
     lost_pre = jnp.maximum(cnt - cx, 0)
@@ -2445,22 +2474,15 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
     if divert:
         el = tb.flow_lanes
         t_valid, t_words = _cross_block(
-            gather_ops[:5], start[el], cnt[el], cx
+            gather_ops[:len(ROW_WORDS)], start[el], cnt[el], cx
         )
-        tier_cross = dict(
-            zip(("thi", "tlo", "auxh", "auxl", "size"), t_words),
-            valid=t_valid,
-        )
+        tier_cross = dict(zip(ROW_WORDS, t_words), valid=t_valid)
         keep = ~tb.lane_stream[:, None]
-        cross_thi = jnp.where(keep, cross_thi, NEVER32)
-        cross_tlo = jnp.where(keep, cross_tlo, NEVER32)
+        cross["thi"] = jnp.where(keep, cross["thi"], NEVER32)
+        cross["tlo"] = jnp.where(keep, cross["tlo"], NEVER32)
 
-    s = _merge_rows(
-        p, s, self_words,
-        [cross_thi, cross_tlo, cross_auxh, cross_auxl, cross_size]
-        + ([cross_phi, cross_plo] if pay else [None, None]),
-        cnt, lost_pre,
-    )
+    s = _merge_rows(p, s, self_words, [cross[w] for w in p.row_words], cnt,
+                    lost_pre)
     if split_se:
         s = _merge_stream_rows(p, tb, s, emits)
     return (s, tier_cross) if divert else s
@@ -2469,43 +2491,22 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
 def _merge_rows(p: LaneParams, s: LaneState, self_words, cross_words, cnt,
                 lost_pre):
     """``_merge_append`` step 3 over one cross block: the row sort of
-    ``[old C | self | cross Cx]`` (seven words each; the payload pair
-    ``None`` where the rows carry none) and everything that reads the
+    ``[old C | self | cross Cx]`` (the words of ``p.row_words`` each: five
+    and the payload words the rows carry) and everything that reads the
     merged row.  ``cnt`` is what ``cross_peak`` reads, ``lost_pre`` what
     the block shed before the merge."""
     n, c = p.n_lanes, p.capacity
-    pay = p.lanes_have_payload
-    (self_thi, self_tlo, self_auxh, self_auxl, self_size, self_phi,
-     self_plo) = self_words
-    (cross_thi, cross_tlo, cross_auxh, cross_auxl, cross_size, cross_phi,
-     cross_plo) = cross_words
+    words = p.row_words
     # -- merge [N, C + self + Cx], keep first C ---------------------------
     # queue state is ALREADY the int32 4-word key: no conversions at all
     with jax.named_scope("row_merge"):
-        mthi = jnp.concatenate([s.q_thi, self_thi, cross_thi], axis=1)
-        mtlo = jnp.concatenate([s.q_tlo, self_tlo, cross_tlo], axis=1)
-        mh = jnp.concatenate([s.q_auxh, self_auxh, cross_auxh], axis=1)
-        ml = jnp.concatenate([s.q_auxl, self_auxl, cross_auxl], axis=1)
-        ms = jnp.concatenate([s.q_size, self_size, cross_size], axis=1)
-        if pay:
-            mphi = jnp.concatenate([s.q_phi, self_phi, cross_phi], axis=1)
-            mplo = jnp.concatenate([s.q_plo, self_plo, cross_plo], axis=1)
-            mthi, mtlo, mh, ml, ms, mphi, mplo = lax.sort(
-                (mthi, mtlo, mh, ml, ms, mphi, mplo), dimension=1,
-                num_keys=4, is_stable=False,
-            )
-        else:
-            mthi, mtlo, mh, ml, ms = lax.sort(
-                (mthi, mtlo, mh, ml, ms), dimension=1, num_keys=4,
-                is_stable=False,
-            )
+        merged = _sort_rows(words, [
+            jnp.concatenate(cols, axis=1)
+            for cols in zip(_q_cols(s, words), self_words, cross_words)])
+    mthi, mtlo, mh, ml, ms = (merged[w] for w in ROW_WORDS)
     tail_mask = mthi[:, c:] != NEVER32
+    s = _q_replace(s, ROW_WORDS, [merged[w][:, :c] for w in ROW_WORDS])
     s = s._replace(
-        q_thi=mthi[:, :c],
-        q_tlo=mtlo[:, :c],
-        q_auxh=mh[:, :c],
-        q_auxl=ml[:, :c],
-        q_size=ms[:, :c],
         n_queue=s.n_queue + tail_mask.sum(axis=1, dtype=jnp.int32)
         + lost_pre,
     )
@@ -2525,8 +2526,7 @@ def _merge_rows(p: LaneParams, s: LaneState, self_words, cross_words, cnt,
         # but carry their own cause counter so the netobs drop classification
         # can split queue overflow from exchange-width shed
         s = s._replace(nb_shed=s.nb_shed + lost_pre)
-    if pay:
-        s = s._replace(q_phi=mphi[:, :c], q_plo=mplo[:, :c])
+    s = _q_replace(s, p.pay_words, [merged[w][:, :c] for w in p.pay_words])
     if p.flowtrace:
         # queue-overflow drops for sampled flows, from the merge tail's
         # pair times directly (no int64 re-split).  PACKET rows only: the
@@ -3809,13 +3809,12 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
             "src": srccol,
             "seq": s.q_auxl[:, :k],
             "size": s.q_size[:, :k],
-            # without stream or gossip lanes there is no payload column at all
+            # a payload word no model present uses has no column at all
             # (dead carry costs per-iteration wall time); slots still see
-            # zeros operands, which XLA folds
-            "phi": s.q_phi[:, :k] if p_lane.lanes_have_payload
-            else jnp.zeros((p.n_lanes, k), dtype=jnp.int32),
-            "plo": s.q_plo[:, :k] if p_lane.lanes_have_payload
-            else jnp.zeros((p.n_lanes, k), dtype=jnp.int32),
+            # a zeros operand for it, which XLA folds
+            **{w: getattr(s, "q_" + w)[:, :k] if w in p_lane.pay_words
+               else jnp.zeros((p.n_lanes, k), dtype=jnp.int32)
+               for w in PAY_WORDS},
             "act": act,
         }
         consumed = popped["act"]
@@ -3905,7 +3904,7 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                 fb = jnp.zeros(fan, dtype=bool)
                 f32 = jnp.zeros(fan, dtype=jnp.int32)
                 f64 = jnp.zeros(fan, dtype=jnp.int64)
-                return st_, _SlotEmit(
+                emit = _SlotEmit(
                     nb, z32, z32, z32, z32, z32, z32, z32,
                     nb, z32, z32, z32, z32, z32, z32,
                     fb, f32, f32, f32, f32, f32, f32, f32, f32,
@@ -3914,6 +3913,9 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                     fb, f64, f64, f64, f64, f64, f64,
                     _ft_dead(p_lane),
                 )
+                if "phi" not in p_lane.emit_pay_words:
+                    emit = emit._replace(ins_phi=(), out_phi=())
+                return st_, emit
 
             return lax.cond(jnp.any(slot_cols["act"]), live, dead, st)
 
@@ -3961,7 +3963,7 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                 return _merge_append(p, tb, st, emits)
 
             def do_sort(st: LaneState) -> LaneState:
-                st = _sort_queues(st, with_pay=p_lane.lanes_have_payload)
+                st = _sort_queues(st, p_lane.row_words)
                 if p_lane.sends_per_pop > 1:
                     # no slot sent: the iteration fits one pass (of none)
                     st = st._replace(
@@ -4285,11 +4287,9 @@ PK_QUEUE, PK_CROSS, PK_CROSS_SHED = range(3)
 
 
 def pack_state(s: LaneState):
-    q_cols = [s.q_thi, s.q_tlo, s.q_auxh, s.q_auxl, s.q_size]
-    has_pay = not isinstance(s.q_phi, tuple)
-    if has_pay:
-        q_cols += [s.q_phi, s.q_plo]
-    q = jnp.stack(q_cols)
+    # the row's words present: five and the payload columns that exist
+    q = jnp.stack([col for col in _q_cols(s, ROW_WORDS + PAY_WORDS)
+                   if not isinstance(col, tuple)])
     has_nb = not isinstance(s.nb_txb, tuple)
     nb_fields = _NB_N_FIELDS if has_nb else ()
     c32 = jnp.stack(
@@ -4325,7 +4325,7 @@ def unpack_state(carry) -> LaneState:
     (q, c32, sc, log, stream, egress, nb_hist, fl_buf, peaks,
      copop_wide_pops, exchange_compact_iters, exchange_slot_peak, gossip,
      gossip_age) = carry
-    has_pay = q.shape[0] == 7
+    words = ROW_WORDS + pay_words(q.shape[0] - len(ROW_WORDS))
     # the optional blocks' own carry leaves say which are live; the append
     # counters have none, so the scalar count left over tells
     has_eg = not isinstance(egress, tuple)
@@ -4341,9 +4341,9 @@ def unpack_state(carry) -> LaneState:
             f: c32[n_base + i] for i, f in enumerate(_NB_N_FIELDS)
         })
     kw.update({f: sc[i] for i, f in enumerate(sc_fields)})
+    kw.update({"q_" + w: () for w in PAY_WORDS})
+    kw.update({"q_" + w: q[i] for i, w in enumerate(words)})
     return LaneState(
-        q_thi=q[0], q_tlo=q[1], q_auxh=q[2], q_auxl=q[3], q_size=q[4],
-        q_phi=q[5] if has_pay else (), q_plo=q[6] if has_pay else (),
         stream=stream,
         cd_dropping=c32[len(_I32_N_FIELDS)].astype(bool),
         log=log, egress=egress, nb_hist=nb_hist, fl_buf=fl_buf,
@@ -4530,38 +4530,24 @@ def _inject_merge(p: LaneParams, tb: LaneTables, s: LaneState, inj):
     ).astype(jnp.int32)
     start, cnt = bounds[:n], bounds[1:] - bounds[:n]
     cxi = min(p.inject_cross or c, c)
-    _in_seg, (cross_thi, cross_tlo, cross_auxh, cross_auxl, cross_size) = (
-        _cross_block([thi_s, tlo_s, auxh_s, auxl_s, size_s], start, cnt, cxi)
-    )
+    _in_seg, cross_words = _cross_block(
+        [thi_s, tlo_s, auxh_s, auxl_s, size_s], start, cnt, cxi)
     lost_pre = jnp.maximum(cnt - cxi, 0)
 
-    mthi = jnp.concatenate([s.q_thi, cross_thi], axis=1)
-    mtlo = jnp.concatenate([s.q_tlo, cross_tlo], axis=1)
-    mh = jnp.concatenate([s.q_auxh, cross_auxh], axis=1)
-    ml = jnp.concatenate([s.q_auxl, cross_auxl], axis=1)
-    ms = jnp.concatenate([s.q_size, cross_size], axis=1)
-    if p.lanes_have_payload:
-        zpad = jnp.zeros((n, cxi), dtype=jnp.int32)
-        mphi = jnp.concatenate([s.q_phi, zpad], axis=1)
-        mplo = jnp.concatenate([s.q_plo, zpad], axis=1)
-        mthi, mtlo, mh, ml, ms, mphi, mplo = lax.sort(
-            (mthi, mtlo, mh, ml, ms, mphi, mplo), dimension=1, num_keys=4,
-            is_stable=False,
-        )
-        s = s._replace(q_phi=mphi[:, :c], q_plo=mplo[:, :c])
-    else:
-        mthi, mtlo, mh, ml, ms = lax.sort(
-            (mthi, mtlo, mh, ml, ms), dimension=1, num_keys=4,
-            is_stable=False,
-        )
+    cols = [jnp.concatenate([q, new], axis=1)
+            for q, new in zip(_q_cols(s, ROW_WORDS), cross_words)]
+    # a host-staged event carries no payload: zeros beside the rows' own
+    zpad = jnp.zeros((n, cxi), dtype=jnp.int32)
+    cols += [jnp.concatenate([q, zpad], axis=1)
+             for q in _q_cols(s, p.pay_words)]
+    merged = _sort_rows(p.row_words, cols)
+    mthi = merged["thi"]
+    s = _q_replace(s, p.pay_words, [merged[w][:, :c] for w in p.pay_words])
     tail = (mthi[:, c:] != NEVER32).sum(axis=1, dtype=jnp.int32)
     if p.netobs:
         s = s._replace(nb_shed=s.nb_shed + lost_pre)
-    s = s._replace(
-        q_thi=mthi[:, :c], q_tlo=mtlo[:, :c], q_auxh=mh[:, :c],
-        q_auxl=ml[:, :c], q_size=ms[:, :c],
-        n_queue=s.n_queue + tail + lost_pre,
-    )
+    s = _q_replace(s, ROW_WORDS, [merged[w][:, :c] for w in ROW_WORDS])
+    s = s._replace(n_queue=s.n_queue + tail + lost_pre)
     if not p.all_passive:
         # the injection block is ``capacity`` wide (``cxi``), not
         # ``cross_cap``: what it sheds is cured by the QUEUE's option, so

@@ -1047,8 +1047,8 @@ class TpuEngine:
             q_auxh=q_auxh,
             q_auxl=q_auxl,
             q_size=q_size,
-            q_phi=full((n, c)) if p.lanes_have_payload else (),
-            q_plo=full((n, c)) if p.lanes_have_payload else (),
+            **{"q_" + w: full((n, c)) if w in p.pay_words else ()
+               for w in lanes.PAY_WORDS},
             stream=stream0,
             send_seq=lane(),
             local_seq=self._local_seq0.astype(i32),
@@ -1673,6 +1673,9 @@ class TpuEngine:
             "pops_per_iter": p.pops_per_iter,
             # the [N] send channel's width: datagrams one pop may send
             "sends_per_pop": p.sends_per_pop,
+            # opaque payload words a row of the [N] queues carries: 2
+            # where stream events ride them, 1 for gossip's message id, 0
+            "payload_words": p.payload_words,
         }
         queue_peak = cross_peak = n_cross = 0
         if not p.all_passive:

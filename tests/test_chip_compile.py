@@ -269,14 +269,15 @@ def _scope_gather_operands(text: str, scope: str) -> list[list[int]]:
 def test_the_compacted_exchange_gathers_rows_never_send_words(
         gossip_wan_compiled):
     """The sending slots' words are fetched as ONE row a slot out of the
-    ``[K x N, 4 F + 4]`` table: the compiled ``exchange_compact`` scope
+    ``[K x N, 4 F + 3]`` table (gossip's rows carry one payload word,
+    ISSUE 46): the compiled ``exchange_compact`` scope
     holds that row gather and no gather whose operand is an ``[N]``-minor
     send word (PR 41 priced those at 6.7–11.2 ns an element); the exchange
     under it sorts ``S_b x F`` rows, and nothing sorts the K x F x N of
     the send channel."""
     text = gossip_wan_compiled.as_text()
     operands = _scope_gather_operands(text, "exchange_compact")
-    assert operands == [[2 * 1_000, 4 * 8 + 4]]
+    assert operands == [[2 * 1_000, 4 * 8 + 3]]
     assert not [dims for dims in operands if dims[-1] == 1_000]
     sorted_rows = {
         int(re.search(r"s32\[(\d+)[,\]]", line).group(1))
@@ -285,6 +286,31 @@ def test_the_compacted_exchange_gathers_rows_never_send_words(
     for scope in ("exchange_compact", "exchange_sort", "exchange_bounds",
                   "row_merge"):
         assert f"/{scope}/" in text, scope
+
+
+def _sort_operands(text: str) -> dict:
+    """``{named scope: operand count}`` of every ``sort`` a compiled text
+    holds (the scope is the last part of its ``op_name`` before the
+    sort)."""
+    found = {}
+    for line in text.splitlines():
+        m = re.search(r" sort\(([^)]*)\)", line)
+        if m:
+            scope = re.search(r'op_name="[^"]*?(\w+)/sort"', line)
+            found[scope.group(1) if scope else ""] = m.group(1).count("%")
+    return found
+
+
+def test_a_gossip_row_carries_one_payload_word(gossip_wan_compiled):
+    """Gossip's rows carry ``plo`` alone (ISSUE 46, ``LaneParams.
+    payload_words`` == 1): the compiled row sort takes six operands (the
+    4-word key, the size, the message id), the exchange sort those and the
+    destination, and the packed queue state is ``[6, N, C]`` — nowhere a
+    seventh word."""
+    text = gossip_wan_compiled.as_text()
+    assert _sort_operands(text) == {
+        "row_merge": 6, "exchange_sort": 7, "exchange_compact": 1}
+    assert "s32[6,1000," in text and "s32[7,1000," not in text
 
 
 def test_the_send_word_parser_sees_an_element_gather(one_chip):

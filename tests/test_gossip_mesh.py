@@ -41,7 +41,14 @@ neither engine can fake.
     which ``precompile`` compiles ahead; the factory's ``faults`` is a
     function of ``fault_seed`` alone and touches no self-edge; under
     partition / heal and link_up schedules on the routed graph both
-    engines agree and keep the two conservation laws of a flood.
+    engines agree and keep the two conservation laws of a flood;
+(k) a row carries the payload words its models use (ISSUE 46):
+    ``LaneParams.payload_words`` is static — 1 for gossip (the message id,
+    ``plo``; ``q_phi`` is ``()`` and no sort, gather or carry of the
+    program holds a seventh word), 2 where stream events ride the [N]
+    queues, 0 elsewhere, whose programs are the parent's text — and the
+    packed carry, a checkpoint and the sharded placement hold a state of
+    any word count.
 """
 
 import functools
@@ -367,21 +374,40 @@ def test_a_queue_forced_under_its_peak_raises_and_names_the_block():
 
 # -- (e) one law, static --------------------------------------------------------
 
-#: sha256 of the lowered text of three tiny programs AT THE PARENT COMMIT
-#: (PR 38, 16f3482): where a pop sends once, this PR's ``[F, N]`` send
-#: channel is the parent's ``[N]`` one, operation for operation.  A later
-#: PR that changes the body changes these on purpose: recompute them with
-#: ``_lowered`` on its own parent and say so.
+#: sha256 of the lowered text of four tiny programs AT THE PARENT COMMIT:
+#: where a pop sends once, the ``[F, N]`` send channel is the ``[N]`` one,
+#: operation for operation (PR 39).  A later PR that changes the body
+#: changes these on purpose: recompute them with ``_lowered`` on its own
+#: parent and say so.  PR 46 did, on its parent (PR 45, 635dc0e): the
+#: three of PR 38's tree (16f3482) read what they read there — rows that
+#: carry NO payload word (``one_to_one_streams`` is the TIERED stream
+#: program: its two words live in the [2S] block) — and ``star_streams``
+#: (six clients of one server, un-tiered: rows of TWO payload words, the
+#: other fork of every list PR 46 folded) is new and the parent's too.
 PARENT_TEXT = {
     "phold": "c6e0b440614a00160852102498c97e9bce22d1416137ca355cd6cf506eb9fb54",
     "passive_mesh":
         "3433396c1a117ae9005927bebd3a176ccb3d142385a90ebaa6cedbee40f9d43f",
     "one_to_one_streams":
         "91569b63930e08e8d784d64179a05b58b2289c8dccf1eea7621fb458abcc461a",
+    "star_streams":
+        "6eb83eb2facbdcc9fbc89c44761c41f9a2cb25b41f6945c0d8b5a324ec35e3e5",
 }
+
+
+def _star_streams():
+    import test_lane_parity
+
+    return ConfigOptions.from_yaml(test_lane_parity.STREAM_STAR)
+
+
 TINY = {"phold": lambda: phold_tests._cfg(64, 4, 5),
         "passive_mesh": phold_tests._passive_mesh,
-        "one_to_one_streams": phold_tests._one_to_one_streams}
+        "one_to_one_streams": phold_tests._one_to_one_streams,
+        "star_streams": _star_streams}
+#: the payload words a row of each tiny program's [N] queues carries
+TINY_WORDS = {"phold": 0, "passive_mesh": 0, "one_to_one_streams": 0,
+              "star_streams": 2}
 
 
 @pytest.mark.parametrize("name", sorted(TINY))
@@ -407,7 +433,7 @@ def test_sends_per_pop_is_a_static_property_of_the_models_present():
     p = lanes.LaneParams(**base, models_present=(lanes.M_GOSSIP,),
                          gossip_degree=8)
     assert p.sends_per_pop == 8 and p.exchange_entries == 2 * 8 * 8
-    assert p.lanes_have_payload and p.copop_inert and not p.all_passive
+    assert p.payload_words == 1 and p.copop_inert and not p.all_passive
     with pytest.raises(ValueError, match="gossip_degree"):
         lanes.LaneParams(**base, models_present=(lanes.M_GOSSIP,))
     # the fan-out and the bitmap test-and-set are named stages of the
@@ -1121,10 +1147,11 @@ def test_under_the_laws_own_budget_every_tiny_iteration_is_one_pass(
 _XK, _XF, _XN, _XCX, _XBUDGET = 2, 4, 24, 32, 12
 
 
-def _send_channel(sending_slots, seed=5):
+def _send_channel(sending_slots, pay, seed=5):
     """A ``[K, F, N]`` send channel in which exactly the first
     ``sending_slots`` of a seeded order of the (pop, lane) slots send, one
-    to F datagrams each, to seeded destinations; every word distinct."""
+    to F datagrams each, to seeded destinations; every word distinct, and
+    of the payload words only those in ``pay`` emitted."""
     rs = np.random.RandomState(seed)
     shape = (_XK, _XF, _XN)
     sends = rs.rand(*shape) < 0.6
@@ -1146,28 +1173,31 @@ def _send_channel(sending_slots, seed=5):
         out_valid=valid, out_dst=rs.randint(0, _XN, shape).astype(np.int32),
         out_thi=word(), out_tlo=word(), out_auxh=pop_word(),
         out_auxl=np.arange(valid.size, dtype=np.int32).reshape(shape),
-        out_size=pop_word(), out_phi=pop_word(), out_plo=pop_word())
+        out_size=pop_word(), **{"out_" + w: pop_word() for w in pay})
 
 
 def _lane_rows(cnt, words):
     """Per lane, the sorted rows of its cross block's live columns."""
     cnt = np.asarray(cnt)
-    block = np.stack([np.asarray(w) for w in words], axis=-1)  # [N, Cx, 7]
+    block = np.stack([np.asarray(w) for w in words], axis=-1)  # [N, Cx, W]
     return [sorted(map(tuple, block[d, :cnt[d]])) for d in range(len(cnt))]
 
 
+@pytest.mark.parametrize("payload_words", [1, 2])
 @pytest.mark.parametrize("sending_slots, passes", [
     (0, 1), (_XBUDGET, 1), (_XBUDGET + 1, 2), (_XK * _XN, 4)],
     ids=["none", "the_budget", "one_more", "every_slot"])
 def test_the_passes_hand_every_lane_what_one_full_exchange_would(
-        sending_slots, passes):
+        sending_slots, passes, payload_words):
     """The compacted passes against ONE exchange of all K x F x N rows
     (the one-send law over the flattened channel): every lane's count, and
-    its rows as a multiset (the row merge's key orders them)."""
-    e = _send_channel(sending_slots)
+    its rows as a multiset (the row merge's key orders them) — whichever
+    payload words the rows carry (ISSUE 46: gossip's one, ``plo``)."""
+    pay = lanes.pay_words(payload_words)
+    e = _send_channel(sending_slots, pay)
+    assert isinstance(e.out_phi, tuple) == (payload_words == 1)
     flat = [np.where(e.out_valid, e.out_dst, _XN)] + [
-        e.out_thi, e.out_tlo, e.out_auxh, e.out_auxl, e.out_size,
-        e.out_phi, e.out_plo]
+        getattr(e, "out_" + w) for w in lanes.ROW_WORDS + pay]
     ops, start, full_cnt = lanes._sorted_exchange(
         [jax.numpy.asarray(w.reshape(-1)) for w in flat], _XN)
     full = _lane_rows(
@@ -1175,15 +1205,16 @@ def test_the_passes_hand_every_lane_what_one_full_exchange_would(
     assert int(full_cnt.sum()) == int(e.out_valid.sum())
     assert int(full_cnt.max()) <= _XCX  # nothing shed: lost_pre is 0 both
 
-    n_sending, ranked, table = lanes._rank_sending(e, _XBUDGET, _XN)
+    n_sending, ranked, table = lanes._rank_sending(e, _XBUDGET, _XN, pay)
     assert int(n_sending) == sending_slots
     assert max(-(-sending_slots // _XBUDGET), 1) == passes
     assert ranked.shape == (4 * _XBUDGET,) and table.shape == (
-        _XK * _XN, 4 * _XF + 4)
+        _XK * _XN, 4 * _XF + 2 + payload_words)
     cnt_all = np.zeros(_XN, dtype=np.int64)
     rows = [[] for _ in range(_XN)]
     for i in range(passes):
-        cols = lanes._compact_sends(ranked, table, i, _XBUDGET, _XN)
+        cols = lanes._compact_sends(ranked, table, i, _XBUDGET, _XN, _XF)
+        assert len(cols) == 6 + payload_words
         assert all(c.shape == (_XBUDGET * _XF,) for c in cols)
         ops, start, cnt = lanes._sorted_exchange(cols, _XN)
         got = _lane_rows(cnt, lanes._cross_block(ops, start, cnt, _XCX)[1])
@@ -1192,6 +1223,174 @@ def test_the_passes_hand_every_lane_what_one_full_exchange_would(
     assert cnt_all.tolist() == np.asarray(full_cnt).tolist()
     assert [sorted(r) for r in rows] == full
     # a pass past the last sending slot holds nothing
-    beyond = lanes._compact_sends(ranked, table, 3, _XBUDGET, _XN)
+    beyond = lanes._compact_sends(ranked, table, 3, _XBUDGET, _XN, _XF)
     if sending_slots <= 3 * _XBUDGET:
         assert (np.asarray(beyond[0]) == _XN).all()
+
+
+
+# -- (k) a row carries the payload words its models use --------------------------
+
+
+def _gossip_engines():
+    return {"one_switch": lambda **kw: TpuEngine(_cfg(64, 4, 3), **kw),
+            "wide": lambda **kw: TpuEngine(_cfg(128, 8, 4), **kw),
+            "routed_lossy": lambda **kw: TpuEngine(_wan_cfg(), **kw),
+            "beside_tgen": lambda **kw: TpuEngine(_rows_cfg(), **kw)}
+
+
+@pytest.mark.parametrize("name", sorted(_gossip_engines()))
+def test_a_gossip_row_carries_the_message_id_and_no_other_word(name):
+    eng = _gossip_engines()[name](log_capacity=0)
+    p, state = eng.params, eng.initial_state()
+    assert p.payload_words == 1 and p.pay_words == ("plo",)
+    assert p.row_words == ("thi", "tlo", "auxh", "auxl", "size", "plo")
+    assert state.q_phi == ()
+    assert state.q_plo.shape == (p.n_lanes, p.capacity)
+    assert state.q_plo.dtype == np.int32
+    eng.run(mode="device")
+    assert eng.lane_plane["payload_words"] == 1
+
+
+@pytest.mark.parametrize("name", sorted(TINY))
+def test_every_other_program_carries_the_words_it_carried(name):
+    """Stream events on the [N] queues keep both words (an un-tiered star);
+    the tiered stream program's [N] rows, PHOLD's and the passive mesh's
+    carry none."""
+    eng = TpuEngine(TINY[name](), log_capacity=0)
+    p, state = eng.params, eng.initial_state()
+    assert p.payload_words == TINY_WORDS[name]
+    assert p.stream_tiered == (name == "one_to_one_streams")
+    for word in lanes.PAY_WORDS:
+        col = getattr(state, "q_" + word)
+        if p.payload_words:
+            assert col.shape == (p.n_lanes, p.capacity)
+        else:
+            assert col == ()
+
+
+def test_the_word_count_is_a_static_property_of_the_models_present():
+    base = dict(n_lanes=8, capacity=16, pops_per_iter=2, log_capacity=0,
+                seed=1, stop_time=MS, bootstrap_end=0, runahead=MS)
+    for model in set(range(lanes.M_GOSSIP)) - lanes.STREAM_MODELS:
+        assert lanes.LaneParams(
+            **base, models_present=(model,)).payload_words == 0
+    for model in lanes.STREAM_MODELS:
+        p = lanes.LaneParams(**base, models_present=(model,))
+        assert p.payload_words == 2 and p.pay_words == lanes.PAY_WORDS
+        # the tier takes the stream events, and their words, off the [N] rows
+        assert lanes.LaneParams(**base, models_present=(model,),
+                                stream_tiered=True).payload_words == 0
+    assert [lanes.pay_words(k) for k in range(3)] == [
+        (), ("plo",), ("phi", "plo")]
+    assert not hasattr(lanes.LaneParams, "lanes_have_payload")
+
+
+def _stablehlo_sorts(text):
+    """Operand counts of the ``stablehlo.sort`` operations of a lowered
+    text, in order."""
+    return [m.group(1).count("%") for m in re.finditer(
+        r'"stablehlo\.sort"\(([^)]*)\)', text)]
+
+
+@pytest.mark.parametrize("make", [lanes.make_run_fn, lanes.make_round_fn],
+                         ids=["fused", "step"])
+@pytest.mark.parametrize("name", ["one_switch", "routed_lossy"])
+def test_no_sort_gather_or_carry_of_a_gossip_program_holds_a_seventh_word(
+        name, make):
+    """The lowered text of the run function: the row sort takes six
+    operands, the exchange sort seven (the destination and a row's six
+    words), the rank sort one, and the packed carry is ``[6, N, C]``."""
+    eng = _gossip_engines()[name](log_capacity=0)
+    p = eng.params
+    text = make(p, eng.tables).lower(eng.initial_state()).as_text()
+    sorts = _stablehlo_sorts(text)
+    # the rank sort, a pass's exchange and row sorts and, in the step
+    # driver, the row re-sort of an iteration that sent nothing
+    assert sorted(sorts) == sorted(
+        [1, 6, 7] + [6] * (make is lanes.make_round_fn))
+    # (the fused loop's carry is the packed state; the step driver's round
+    # takes the state leaf by leaf)
+    assert (f"tensor<6x{p.n_lanes}x{p.capacity}xi32>" in text) == (
+        make is lanes.make_run_fn)
+    assert f"tensor<7x{p.n_lanes}x" not in text
+
+
+def _distinct_words(eng):
+    """An initial state whose every queue column holds its own numbers."""
+    state = eng.initial_state()
+    n, c = eng.params.n_lanes, eng.params.capacity
+    cols = {"q_" + w: np.arange(n * c, dtype=np.int32).reshape(n, c) + 7 * i
+            for i, w in enumerate(eng.params.row_words)}
+    return state._replace(**cols)
+
+
+def _word_engines():
+    return {"gossip": lambda: _gossip_engines()["one_switch"](),
+            "star_streams": lambda: TpuEngine(_star_streams()),
+            "phold": lambda: TpuEngine(TINY["phold"]())}
+
+
+@pytest.mark.parametrize("name, words", [
+    ("phold", 0), ("gossip", 1), ("star_streams", 2)])
+def test_the_packed_carry_holds_the_words_present_and_unpacks_to_them(
+        name, words):
+    eng = _word_engines()[name]()
+    state = _distinct_words(eng)
+    carry = lanes.pack_state(state)
+    n, c = eng.params.n_lanes, eng.params.capacity
+    assert carry[0].shape == (5 + words, n, c)
+    back = lanes.unpack_state(carry)
+    assert jax.tree.structure(back) == jax.tree.structure(state)
+    assert isinstance(back.q_phi, tuple) == (words < 2)
+    assert isinstance(back.q_plo, tuple) == (words < 1)
+    for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(state)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_checkpoint_of_a_gossip_state_resumes_to_the_same_run(tmp_path):
+    """A lane state of one payload word through the checkpoint container
+    and back into ``run(resume_state=...)``, taken in the middle of the
+    first flood (message ids in flight in ``q_plo``)."""
+    from shadow_tpu.engine.checkpoint import read_checkpoint, write_checkpoint
+
+    whole = TpuEngine(_cfg(64, 4, 3)).run(mode="step")
+    eng, kept = TpuEngine(_cfg(64, 4, 3)), []
+
+    def keep(_start, end, _next):
+        if not kept and end >= 1025 * MS:
+            kept.append(eng.checkpoint_payload())
+
+    eng.run(mode="step", on_window=keep)
+    path = write_checkpoint(tmp_path / "gossip.ckpt", {"kind": "test"},
+                            {"lane_state": kept[0]})
+    state = read_checkpoint(path)[1]["lane_state"]
+    assert state.q_phi == () and state.q_plo.shape == state.q_thi.shape
+    live = state.q_thi != lanes.NEVER32
+    assert live.any() and state.q_plo[live].max() > 0
+    res = TpuEngine(_cfg(64, 4, 3)).run(
+        mode="step", resume_state=state, resume_epoch=0)
+    assert res.log_tuples() == whole.log_tuples()
+    assert (res.counters, res.rounds) == (whole.counters, whole.rounds)
+
+
+@pytest.mark.parametrize("devices", [2, 4])
+def test_the_sharded_placement_holds_a_state_of_one_payload_word(devices):
+    """``parallel/mesh.py`` places the leaves that exist: ``q_plo`` split
+    on the lane axis with the key words, no leaf for ``q_phi``."""
+    eng = _gossip_engines()["one_switch"](log_capacity=0)
+    mesh = parallel.make_mesh(devices)
+    state = _distinct_words(eng)
+    placed = parallel.mesh.shard_state(state, mesh)
+    assert placed.q_phi == ()
+    for word in eng.params.row_words:
+        col = getattr(placed, "q_" + word)
+        assert len(col.devices()) == devices
+        assert col.sharding.spec == jax.sharding.PartitionSpec(
+            parallel.HOST_AXIS)
+        assert np.array_equal(np.asarray(col), getattr(state, "q_" + word))
+    assert jax.tree.structure(placed) == jax.tree.structure(state)
+    # the sharded run function lowers over the same six-word rows
+    text = parallel.make_sharded_run_fn(
+        eng.params, eng.tables, mesh).lower(placed).as_text()
+    assert set(_stablehlo_sorts(text)) == {1, 6, 7}

@@ -279,12 +279,17 @@ def test_read_egress_returns_the_first_count_rows(hybrid_engine, which):
 #: gossip body on purpose (a fan-out program's exchange runs over its
 #: sending slots, ``lanes._merge_append`` step 2): both gossip pins are of
 #: PR 43's own tree (4fa8d862... and 1b8ad06a... at PR 42); the two
-#: programs in which a pop sends once did not move.
+#: programs in which a pop sends once did not move.  PR 46 changed the
+#: gossip body on purpose again (its rows carry ONE payload word, ``plo``:
+#: six operands in the row sort, seven in the exchange sort, a ``[6, N,
+#: C]`` carry): both gossip pins are of PR 46's own tree (4e17a3a5... and
+#: c726742e... at its parent, 635dc0e); the other two, recomputed there,
+#: did not move.
 PARENT_TEXT = {
     "gossip":
-        "4e17a3a57a264fc11e48263181039d924887d6039c5bb0dcf01609fd2fadcc10",
+        "ea1881e1fc06005b63bb17531a64d71f02294f090cad808cb45d0a4f2b0c3f08",
     "gossip_wan":
-        "c726742eb0ed5c4de51c9e84eb24f4042ebff3c2160ecf094d002eff3d314086",
+        "7462edac314f0b056d09c9a9c771112806c3698926c0fe084a83de37b5c381e6",
     "routed_tcp_loss":
         "c44fe83e54f6ad654aa44aaf33217edb896ea3d9e54883fa6cf172f66db1ac34",
     "sharded_passive_mesh":
