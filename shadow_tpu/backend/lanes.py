@@ -120,7 +120,45 @@ PATH_GATHER_MODELS = frozenset({
 # (ii)  every one of the D sends lands at ``pair_max(dep + lat, we)``;
 # (iii) a DELIVERY pop touches the seen bitmap, the gossip counters,
 #       ``send_seq``, ``n_sends``, the UP bucket, ``n_loss`` and
-#       ``min_used_lat``: no word of a PACKET pop.
+#       ``min_used_lat``.  A PACKET pop WRITES none of them but the
+#       duplicate counter (adds commute) and READS one: the seen bitmap,
+#       for the in-window duplicate elision (``gossip_elides``).  That
+#       read is argued apart, below: the one reordering the rule allows
+#       can only make it stale, and a stale read changes no output.
+#
+# In-window duplicate elision (the PACKET branch of _process_slot): where
+# a gossip lane's PACKET pop passes CoDel, its message id's bit is ALREADY
+# set in the lane's seen bitmap, and ``t_deliver`` is before the window's
+# end (``we``: the bound pop_mask cuts every class at, the oracle's
+# ``ev.time < until``), the pop counts the duplicate there and then and
+# self-inserts NO DELIVERY row; everything else of the pop (down bucket,
+# CoDel, ``n_delivered``, the DELIVERED record at ``t_deliver``) is what
+# it was.  The oracle keeps the event; no compared output can tell:
+# (1) the bitmap is monotone: bits are only set, and only by a DELIVERY
+#     or a publish pop;
+# (2) pops take prefixes of a key-sorted row and a cross-lane send lands
+#     at or after the window's end (ii), so a bit a PACKET pop observes
+#     was set by a pop whose key is below the PACKET's, hence below the
+#     key of the PACKET's own DELIVERY (``t_deliver`` >= arrival, and
+#     PACKET < DELIVERY at equal times): the oracle's heap pops that first
+#     copy before this copy's delivery;
+# (3) so the oracle's handler finds this copy a duplicate, and a
+#     duplicate's handler counts ``gossip_duplicates`` and does nothing
+#     else (models/gossip.py ``on_delivery``): no send, no bit, no age;
+# (4) the one reordering the rule allows — [P_a, P_b] co-popped before
+#     P_a's DELIVERY exists — makes P_b's view of the bitmap STALE, never
+#     early: a stale view misses an elision (the copy is queued as
+#     before), it cannot invent one; the ``dups`` adds commute;
+# (5) the DELIVERY not queued would have popped inside this window (the
+#     second gate), so the window's end, the next window's start,
+#     ``rounds`` and the stop bound see nothing — without the gate the
+#     log and the counters would still be the oracle's and the rounds
+#     come out short (tests/test_gossip_mesh.py (l) holds both).
+# Not elided: a copy whose first copy's DELIVERY is still PENDING — ties
+# in ``t_deliver`` broken by (src, seq) decide WHICH copy is first there,
+# and the first copy's source is observable (the peer left out of the
+# forward).  ``LaneState.gossip_elided`` counts the rows not queued.
+#
 # What is NEW with gossip is that the ORDER of two DELIVERY pops of one
 # lane is observable (the first copy of a message is the one forwarded,
 # and its source the one peer left out; PHOLD's handler ignores both).
@@ -385,6 +423,12 @@ class LaneState(NamedTuple):
     # the counters, which the oracle's per-host counts equal.  () where no
     # lane runs M_GOSSIP
     gossip_age: Any = ()
+    # int32 scalar: DELIVERY rows a gossip lane did not queue, its PACKET
+    # pop having counted the duplicate (``gossip_elides``); at most the
+    # run's ``gossip_duplicates``.  Read into collect()'s ``lane_plane``,
+    # never into the counters (the oracle queues every delivery).  () —
+    # nothing traced — where no lane runs M_GOSSIP
+    gossip_elided: Any = ()
 
 
 class GossipState(NamedTuple):
@@ -1154,6 +1198,15 @@ class _SlotEmit(NamedTuple):
     ft: Any = ()
 
 
+def gossip_elides(known, td_hi, td_lo, we_hi, we_lo):
+    """Where a gossip lane's PACKET pop counts its datagram's duplicate
+    itself and queues no DELIVERY row: the message's bit is ALREADY set
+    in the lane's seen bitmap (``known``) and the delivery time falls
+    before the window's end, the bound ``pop_mask`` cuts every class at.
+    Why no compared output can tell is argued at WINDOW_INERT_MODELS."""
+    return known & pair_lt(td_hi, td_lo, we_hi, we_lo)
+
+
 def _process_slot(
     p: LaneParams, tb: LaneTables, s: LaneState, slot, we_hi, we_lo
 ) -> tuple[LaneState, _SlotEmit]:
@@ -1365,6 +1418,10 @@ def _process_slot(
         g_lane = model == M_GOSSIP
         g_pub = is_timer & g_lane
         g_del = is_del & g_lane
+        # a publish and a DELIVERY pop test AND set the message's bit; a
+        # datagram CoDel lets through only tests it, for the elision below
+        g_sets = g_pub | g_del
+        g_pkt = deliver & g_lane
         g_mid = jnp.where(g_pub, size, plo)
         with jax.named_scope("gossip_seen"):
             # one word of the lane's bitmap row, picked by compare (the
@@ -1372,10 +1429,17 @@ def _process_slot(
             g_hit = (
                 jnp.arange(gs.seen.shape[1], dtype=i32)[None, :]
                 == (g_mid >> 5)[:, None]
-            ) & (g_pub | g_del)[:, None]
+            ) & (g_sets | g_pkt)[:, None]
             g_bit = jnp.where(g_hit, (jnp.int32(1) << (g_mid & 31))[:, None], 0)
             g_known = jnp.any((gs.seen & g_bit) != 0, axis=1)
-            g_seen = gs.seen | g_bit
+            g_seen = gs.seen | jnp.where(g_sets[:, None], g_bit, 0)
+        # in-window duplicate elision (argued at WINDOW_INERT_MODELS): a
+        # copy the lane already knows, delivered before the window's end,
+        # is counted here and queues no DELIVERY row
+        g_elide = g_pkt & gossip_elides(g_known, td_hi, td_lo, we_hi, we_lo)
+        ins_valid = ins_valid & ~g_elide
+        s = s._replace(
+            gossip_elided=s.gossip_elided + g_elide.sum(dtype=i32))
         g_first = g_del & ~g_known
         g_push = g_pub | g_first
         gl_hi, gl_lo = pair_max(gs.last_hi, gs.last_lo, thi, tlo)
@@ -1398,7 +1462,7 @@ def _process_slot(
         s = s._replace(gossip_age=g_age, gossip=gs._replace(
             seen=g_seen,
             first=gs.first + g_first,
-            dups=gs.dups + (g_del & g_known),
+            dups=gs.dups + ((g_del & g_known) | g_elide),
             last_hi=jnp.where(g_first, gl_hi, gs.last_hi),
             last_lo=jnp.where(g_first, gl_lo, gs.last_lo),
         ))
@@ -3721,7 +3785,10 @@ def pop_mask(p: LaneParams, model, thi, tlo, kind_cols, we_hi, we_lo):
     # - [D, P]: the same, then the packet;
     # - [P, P'] at distinct instants: the heap may pop P's own DELIVERY
     #   (inserted at its dn-bucket departure) BEFORE P'; the two touch
-    #   disjoint words (iii), so the interleaving is unobservable, and
+    #   disjoint words (iii) — a gossip P' does READ the bitmap P's
+    #   DELIVERY would have set, and a stale read only keeps a row queued
+    #   (WINDOW_INERT_MODELS, the elision's point (4)) — so the
+    #   interleaving is unobservable, and
     #   that DELIVERY is in the sorted queue before any LATER DELIVERY
     #   is popped: dn departures are FIFO, so it sorts at or after every
     #   DELIVERY already queued, and ties fall to the row sort's
@@ -4307,7 +4374,7 @@ def pack_state(s: LaneState):
     )
     return (q, c32, sc, s.log, s.stream, s.egress, s.nb_hist, s.fl_buf,
             s.peaks, s.copop_wide_pops, s.exchange_compact_iters,
-            s.exchange_slot_peak, s.gossip, s.gossip_age)
+            s.exchange_slot_peak, s.gossip, s.gossip_age, s.gossip_elided)
 
 
 def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
@@ -4324,7 +4391,7 @@ def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
 def unpack_state(carry) -> LaneState:
     (q, c32, sc, log, stream, egress, nb_hist, fl_buf, peaks,
      copop_wide_pops, exchange_compact_iters, exchange_slot_peak, gossip,
-     gossip_age) = carry
+     gossip_age, gossip_elided) = carry
     words = ROW_WORDS + pay_words(q.shape[0] - len(ROW_WORDS))
     # the optional blocks' own carry leaves say which are live; the append
     # counters have none, so the scalar count left over tells
@@ -4350,7 +4417,7 @@ def unpack_state(carry) -> LaneState:
         peaks=peaks, copop_wide_pops=copop_wide_pops,
         exchange_compact_iters=exchange_compact_iters,
         exchange_slot_peak=exchange_slot_peak, gossip=gossip,
-        gossip_age=gossip_age, **kw,
+        gossip_age=gossip_age, gossip_elided=gossip_elided, **kw,
     )
 
 

@@ -1120,6 +1120,7 @@ class TpuEngine:
             gossip_age=(
                 full(len(AGE_COUNTERS)) if p.gossip_degree else ()
             ),
+            gossip_elided=full() if p.gossip_degree else (),
         )
         # ONE transfer of the whole tree, straight onto its placement: no
         # eager device program, no whole copy on one chip before sharding
@@ -1611,7 +1612,7 @@ class TpuEngine:
         if p.sends_per_pop > 1:
             fields += ["exchange_compact_iters", "exchange_slot_peak"]
         if p.gossip_degree:
-            fields += ["gossip", "gossip_age"]
+            fields += ["gossip", "gossip_age", "gossip_elided"]
         if p.netobs:
             fields += ["nb_txb", "nb_rxb", "nb_thr", "nb_shed", "nb_hist",
                        "nb_win"]
@@ -1828,13 +1829,18 @@ class TpuEngine:
             # the time the last node first saw a message: how long the
             # run's floods took to cross the mesh (0: nothing delivered)
             last = (g.last_hi.astype(np.int64) << 31) | g.last_lo
-            self.lane_plane.update(
+            # gossip_elided: the duplicates (of gossip_duplicates) counted
+            # at their PACKET pop, whose DELIVERY row was never queued
+            # (lanes.gossip_elides); the oracle has no such number
+            gauges = dict(
                 gossip_degree=p.gossip_degree,
                 gossip_last_first_ns=int(last.max(initial=0)),
+                gossip_elided=int(s.gossip_elided),
             )
+            self.lane_plane.update(gauges)
             if self.obs is not None:
-                for key in ("gossip_degree", "gossip_last_first_ns"):
-                    self.obs.metrics.gauge(key, self.lane_plane[key])
+                for key, val in gauges.items():
+                    self.obs.metrics.gauge(key, val)
         add("lane_iters", int(s.iters))
         add("lane_delivered",
             int(s.n_delivered.sum()) + tier_sum(lstr_mod.TV_N_DEL))

@@ -83,7 +83,7 @@ REPLICATED_FIELDS = frozenset((
     "log", "log_count", "log_lost", "rounds", "iters", "codel_lookup_pops",
     "now_we_hi", "now_we_lo", "min_used_lat", "stream",
     "peaks", "copop_wide_pops", "exchange_compact_iters",
-    "exchange_slot_peak", "gossip_age",
+    "exchange_slot_peak", "gossip_age", "gossip_elided",
     "egress", "egress_count", "egress_lost",
     "egress_min_hi", "egress_min_lo",
     "nb_hist", "nb_win",

@@ -313,6 +313,24 @@ def test_a_gossip_row_carries_one_payload_word(gossip_wan_compiled):
     assert "s32[6,1000," in text and "s32[7,1000," not in text
 
 
+def test_a_packet_pops_bitmap_lookup_adds_no_sort_and_no_gather(
+        gossip_wan_compiled):
+    """A gossip lane's PACKET pop looks its message up in the seen bitmap
+    by the compare the publish and DELIVERY pops already made (ISSUE 47,
+    ``lanes.gossip_elides``): the compiled program holds the three sorts
+    and four gathers it held at the parent (64be6b1), none of either under
+    ``gossip_seen``, and the run's count of elided rows is a word of its
+    carry."""
+    text = gossip_wan_compiled.as_text()
+    lines = text.splitlines()
+    assert sum(" sort(" in line for line in lines) == 3
+    assert sum(" gather(" in line for line in lines) == 4
+    seen = [line for line in lines if "/gossip_seen/" in line]
+    assert seen and not [
+        line for line in seen if " gather(" in line or " sort(" in line]
+    assert "gossip_elided" in text
+
+
 def test_the_send_word_parser_sees_an_element_gather(one_chip):
     """The guard above is held to a program that DOES pick elements out of
     a lanes-minor ``[F, N]`` word under the scope."""

@@ -48,7 +48,18 @@ neither engine can fake.
     program holds a seventh word), 2 where stream events ride the [N]
     queues, 0 elsewhere, whose programs are the parent's text — and the
     packed carry, a checkpoint and the sharded placement hold a state of
-    any word count.
+    any word count;
+(l) a duplicate the lane already knows is counted at its PACKET pop
+    (ISSUE 47, ``lanes.gossip_elides``): a copy whose message's bit is
+    set when its PACKET pops, delivered before the window's end, queues
+    no DELIVERY row — the UNEDITED oracle keeps the event and every
+    parity test above stays as it was; ``lane_plane["gossip_elided"]`` is
+    over 0, at most what a counted run of the oracle says heap order
+    would elide, and in no ``SimResult.counters``; each flood takes fewer
+    iterations than with the predicate patched off; the window gate (slow
+    hosts: without it the rounds come out short); the stale view (one
+    pop an iteration elides exactly the oracle's count, two fewer); and a
+    program without gossip lanes carries no such word.
 """
 
 import functools
@@ -1394,3 +1405,146 @@ def test_the_sharded_placement_holds_a_state_of_one_payload_word(devices):
     text = parallel.make_sharded_run_fn(
         eng.params, eng.tables, mesh).lower(placed).as_text()
     assert set(_stablehlo_sorts(text)) == {1, 6, 7}
+
+
+# -- (l) a known copy is counted at its PACKET pop (ISSUE 47) -------------------
+
+
+def _slow_hosts_cfg(backend="tpu"):
+    """64 x 4 on one switch with 500 Kbit hosts: a 512-byte datagram holds
+    the down bucket for 8.8 of the window's 10 ms, so the copies behind it
+    are delivered in LATER windows than their PACKET pops."""
+    cfg = gossip_mesh_config(64, 4, 1, BURSTS, 3, 512, "10 ms", "500 Kbit",
+                             seed=7)
+    cfg.general.stop_time = 2900 * MS
+    cfg.experimental.network_backend = backend
+    return cfg
+
+
+#: every kind of flood the parity tests above run, by name
+FLOODS = {
+    **{f"one_switch_{n}x{d}": functools.partial(_cfg, n, d, m)
+       for n, d, m in SMALL},
+    "lossy_two_nodes": lambda backend: _lossy_cfg(backend),
+    "routed_lossy": lambda backend: _wan_cfg(backend),
+    "partition_heal": lambda backend: _faulted("partition_heal", backend)[0],
+    "slow_hosts": _slow_hosts_cfg,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _oracle_knowing(flood):
+    """The UNEDITED oracle's run of a flood with a count taken around its
+    ``inbound`` (ISSUE 47's sizing): of the delivered datagrams, those
+    whose message the destination had ALREADY seen when the PACKET popped
+    (``known``), and of them those delivered before the window's end
+    (``in_window``): what a lane that pops in heap order would elide."""
+    count = {"known": 0, "in_window": 0}
+    inbound = CpuEngine.inbound
+
+    def counting(self, dst_host, ev):
+        known = ev.data[1] in dst_host.apps[0].seen
+        inbound(self, dst_host, ev)
+        record = dst_host.log_buf[-1]
+        if known and record.outcome == lanes.DELIVERED:
+            count["known"] += 1
+            count["in_window"] += record.time < self.window_end
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(CpuEngine, "inbound", counting)
+        oracle, last = _oracle_run(FLOODS[flood]("cpu"))
+    return oracle, last, count["known"], count["in_window"]
+
+
+def _flood_run(flood, mode="device", pops=None):
+    cfg = FLOODS[flood]("tpu")
+    if pops is not None:
+        cfg.experimental.tpu_events_per_round = pops
+    eng = TpuEngine(cfg)
+    return eng, eng.run(mode=mode)
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize("mode", ["device", "step"])
+@pytest.mark.parametrize("flood", sorted(FLOODS))
+def test_a_known_copy_queues_no_delivery_and_the_flood_takes_fewer_iterations(
+        flood, mode, monkeypatch):
+    """The oracle keeps the event; the lane counts the duplicate at the
+    PACKET pop and the run is the oracle's all the same — log, counters,
+    rounds — in fewer iterations than with the elision patched off."""
+    oracle, last, _known, in_window = _oracle_knowing(flood)
+    eng, res = _flood_run(flood, mode)
+    _assert_equals_oracle(eng, res, oracle, last)
+    elided = eng.lane_plane["gossip_elided"]
+    # a stale view (a co-popped [P, P'] of one message) misses an elision,
+    # nothing invents one: at most what heap order would have elided
+    assert 0 < elided <= in_window <= res.counters["gossip_duplicates"]
+    # a gauge of the lane plane, which the comparison does not hold the
+    # oracle to
+    assert "gossip_elided" not in res.counters
+    assert "gossip_elided" not in oracle.counters
+    # today's path for every copy: the same run, more iterations
+    monkeypatch.setattr(lanes, "gossip_elides",
+                        lambda known, *window: known & False)
+    eng_off, res_off = _flood_run(flood, mode)
+    _assert_equals_oracle(eng_off, res_off, oracle, last)
+    assert eng_off.lane_plane["gossip_elided"] == 0
+    assert res.counters["lane_iters"] < res_off.counters["lane_iters"]
+    assert eng.lane_plane["queue_peak"] <= eng_off.lane_plane["queue_peak"]
+
+
+@pytest.mark.parametrize("mode", ["device", "step"])
+def test_a_copy_delivered_past_the_windows_end_takes_todays_path(
+        mode, monkeypatch):
+    """The window gate: on slow hosts many known copies leave the down
+    bucket after the window's end; they are queued as before (fewer rows
+    elided than duplicates, and than known copies), and counters, log AND
+    rounds are the oracle's.  Without the gate the log and the counters
+    still are — the record is written at the PACKET pop either way — but a
+    window that held nothing but such a delivery is never opened: the
+    rounds come out short."""
+    oracle, last, known, in_window = _oracle_knowing("slow_hosts")
+    assert in_window < known <= oracle.counters["gossip_duplicates"]
+    eng, res = _flood_run("slow_hosts", mode)
+    _assert_equals_oracle(eng, res, oracle, last)
+    assert 0 < eng.lane_plane["gossip_elided"] <= in_window
+    monkeypatch.setattr(lanes, "gossip_elides", lambda known, *window: known)
+    eng, res = _flood_run("slow_hosts", mode)
+    assert eng.lane_plane["gossip_elided"] > in_window
+    assert res.log_tuples() == oracle.log_tuples()
+    assert _shared(res.counters) == _shared(oracle.counters)
+    assert res.rounds < oracle.rounds
+
+
+@pytest.mark.parametrize("flood", ["routed_lossy", "lossy_two_nodes"])
+def test_a_stale_view_of_the_bitmap_misses_an_elision_and_invents_none(flood):
+    """At one pop an iteration a lane pops in the oracle's heap order and
+    elides exactly what the counted oracle says.  At two, a lane fed
+    ``[P_a, P_b]`` — two copies of one message at two instants of one
+    window — co-pops them before ``P_a``'s DELIVERY exists (the reordering
+    the window-inert rule allows), so ``P_b`` finds the bit unset: the
+    second copy takes today's path, fewer rows are elided, and the run is
+    the oracle's both times."""
+    oracle, last, _known, in_window = _oracle_knowing(flood)
+    elided = {}
+    for pops in (1, 2):
+        eng, res = _flood_run(flood, pops=pops)
+        assert eng.params.pops_per_iter == pops
+        _assert_equals_oracle(eng, res, oracle, last)
+        elided[pops] = eng.lane_plane["gossip_elided"]
+    assert 0 < elided[2] < elided[1] == in_window
+
+
+@pytest.mark.parametrize("name", sorted(TINY))
+def test_a_program_without_gossip_lanes_carries_no_elision_word(name):
+    eng = TpuEngine(TINY[name](), log_capacity=0)
+    state = eng.initial_state()
+    assert state.gossip_elided == ()
+    assert lanes.pack_state(state)[-1] == ()
+    assert lanes.unpack_state(lanes.pack_state(state)).gossip_elided == ()
+    if name == "phold":
+        eng.run(mode="device")
+        assert "gossip_elided" not in eng.lane_plane
+    gossip = TpuEngine(_cfg(64, 4, 3), log_capacity=0).initial_state()
+    assert gossip.gossip_elided.shape == () and (
+        gossip.gossip_elided.dtype == np.int32)
