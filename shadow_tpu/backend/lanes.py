@@ -756,6 +756,19 @@ def path_sends(p: LaneParams, tb: LaneTables) -> tuple[int, int]:
     return rows, int(gathers)
 
 
+def path_gather_load(p: LaneParams, tb: LaneTables) -> tuple[int, int]:
+    """``(path_gather_tables, path_gather_elems_per_iter)``, static too:
+    the [G, G] tables a gathered send reads (the latency; with the loss
+    draw compiled in its two thresholds as well) and the elements one
+    iteration gathers in the ``path_gather`` scope — ``node_of[dst]`` and
+    a word of each table for every lane at every pop, whether the slot
+    holds an event or not.  ``(0, 0)`` where no send gathers."""
+    if not path_sends(p, tb)[1]:
+        return 0, 0
+    tables = 3 if p.has_loss else 1
+    return tables, p.pops_per_iter * p.n_lanes * (1 + tables)
+
+
 # --------------------------------------------------------------------------
 # vectorized component laws (identical arithmetic to net/token_bucket.py and
 # net/codel.py — see docs/SEMANTICS.md), on int32 pairs
@@ -1553,14 +1566,18 @@ def _process_slot(
 
         # loss (bootstrap window is loss-free; loss-free graphs skip the draw)
         with jax.named_scope("path_lookup"):
+            # the run-time gathers apart from the draw and the compares
+            # (``path_gather_load``): node_of[dst], then a [G, G] word each
             if gathers:
                 my_node = tb.node_of
-                dst_node = tb.node_of[dst]
+                with jax.named_scope("path_gather"):
+                    dst_node = tb.node_of[dst]
 
             def path_word(table, i):
                 if not gathers:
                     return rows[i]
-                word = table[my_node, dst_node]
+                with jax.named_scope("path_gather"):
+                    word = table[my_node, dst_node]
                 return word if rows is None else jnp.where(
                     rows[0], rows[i], word)
 

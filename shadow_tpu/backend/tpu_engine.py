@@ -1716,6 +1716,7 @@ class TpuEngine:
         log_count = int(s.log_count)
         log_lost = int(s.log_lost)
         path_rows, path_gathers = lanes.path_sends(p, self.tables)
+        gather_tables, gather_elems = lanes.path_gather_load(p, self.tables)
         self.lane_plane = {
             **shapes,
             "lanes": self.params.n_lanes,
@@ -1753,6 +1754,11 @@ class TpuEngine:
             # compiled program)
             "static_path_sends": path_rows,
             "path_gather_sends": path_gathers,
+            # what a gathered send reads: the [G, G] tables (1, or 3 with
+            # the loss draw) and the elements an iteration gathers (pops x
+            # lanes x (node_of[dst] + a word a table); 0 with no gather)
+            "path_gather_tables": gather_tables,
+            "path_gather_elems_per_iter": gather_elems,
         }
         if p.copop_inert:
             # pop slots (a run offers lane_iters x pops x lanes of them)

@@ -246,6 +246,32 @@ def routed_graph_gml(
     return "\n".join(out + ["]", ""])
 
 
+def _one_switch_gml(latency: str, bandwidth: str) -> str:
+    """One graph node: self-edge ``latency`` (the lookahead), zero loss."""
+    return (
+        "graph [\n"
+        f'  node [ id 0 host_bandwidth_up "{bandwidth}" '
+        f'host_bandwidth_down "{bandwidth}" ]\n'
+        f'  edge [ source 0 target 0 latency "{latency}" ]\n'
+        "]\n")
+
+
+def _placed_hosts(name: str, stream: str, n_hosts: int, graph_nodes: int,
+                  graph_seed: int, process: dict) -> dict:
+    """``n_hosts`` host documents ``<name>00001`` ... (sorted by id), each
+    running ``process`` on a graph node drawn uniformly from the stream
+    ``random.Random(f"{stream}-hosts-{graph_seed}")`` — one of its own, so
+    the graph does not move with the width."""
+    rnd = random.Random(f"{stream}-hosts-{graph_seed}")
+    return {
+        f"{name}{i:0{len(str(n_hosts))}d}": {
+            "network_node_id": rnd.randrange(graph_nodes),
+            "processes": [process],
+        }
+        for i in range(1, n_hosts + 1)
+    }
+
+
 def routed_tcp_mesh_config(
     n_hosts: int,
     graph_nodes: int,
@@ -297,10 +323,14 @@ def routed_tcp_mesh_config(
 
 # -- PHOLD: random destinations, an active lane model ------------------------
 
-#: windows the PHOLD shape law budgets for: 10 sim-s at a 10 ms lookahead,
-#: the horizon of this repo's presets (the stop time is the caller's, set
-#: after the factory returns, and the tail moves with the LOG of this number)
+#: windows the PHOLD shape law budgets for on one switch: 10 sim-s at a 10 ms
+#: lookahead, the horizon of this repo's presets (the stop time is the
+#: caller's, set after the factory returns, and the tail moves with the LOG of
+#: this number)
 PHOLD_LAW_WINDOWS = 1000
+#: the same horizon as simulated time: on a graph the factory budgets for
+#: this many ns of windows of the graph's smallest latency
+PHOLD_LAW_HORIZON_NS = PHOLD_LAW_WINDOWS * 10_000_000
 #: pops an iteration of a PHOLD program: every other deployment's.  4 pops
 #: take 15 % fewer iterations at 2.3x the iteration (PERF.md 6, PR 35)
 PHOLD_POPS = 2
@@ -328,42 +358,72 @@ def poisson_tail_quantile(mean: float, p: float) -> int:
 
 def phold_shape_law(
     n_hosts: int, messages: int, windows: int = PHOLD_LAW_WINDOWS,
-    pops: int = PHOLD_POPS,
+    pops: int = PHOLD_POPS, window_ns: float = 1, mean_hop_ns: float = 1,
+    far_hop_ns: float = 1,
 ) -> tuple[int, int]:
     """``(tpu_lane_queue_capacity, tpu_cross_capacity)`` for a PHOLD mesh
     of ``n_hosts`` lanes and ``messages`` messages a lane, run for
-    ``windows`` lookahead windows at ``pops`` pops an iteration.
+    ``windows`` lookahead windows at ``pops`` pops an iteration.  The three
+    path facts enter as RATIOS and default to one switch, where a hop is
+    one window: ``window_ns`` the lookahead (``NetworkGraph.min_latency_ns``),
+    ``mean_hop_ns`` the mean path latency between two lanes, ``far_hop_ns``
+    the mean path latency INTO the lane farthest from the rest (at most
+    the longest routed path, ``max_latency_ns``).
 
     Destinations are uniform draws, so what a lane is handed is a Poisson
     count, and a width is a TAIL QUANTILE of it, taken so that one run
     overflows anywhere with probability about 1 / 1 000:
 
-    - a window's arrivals at one lane are ~Poisson(messages); each costs
-      two dependent pops (the PACKET, then the DELIVERY it inserts, whose
-      pop is the send), so the fullest lane of a window sets the
-      iterations: ``iters = ceil(2 q / pops)`` with ``q`` the quantile at
-      1 / (1 000 x lanes x windows).  That IS what a run takes since the
-      lanes co-pop any DELIVERY* PACKET* prefix (``lanes.pop_mask``, the
-      window-inert class): ``q`` arrivals are ``q / 2`` packet pairs and
-      ``q / 2`` delivery pairs at 2 pops; under the same-instant rule
-      before it a DELIVERY popped alone and a window took ``1.5 q``;
-    - a QUEUE holds what is left of this window's arrivals plus what the
-      merge has already filed for the next: ~Poisson(2 x messages), at
-      1 / (1 000 x lanes x windows x iters), plus the engine's headroom;
+    - a message spends a hop to lane B in flight for the path latency into
+      B and is then sent on at once, so of its time the share ``(mean
+      latency into B) / (lanes x mean_hop_ns)`` is spent on the way to B:
+      over the ``lanes x messages`` messages, what is IN FLIGHT to B is
+      ~Poisson(messages x (mean latency into B) / mean_hop_ns) — messages
+      to an average lane whatever the latencies, more to a far one — and
+      all of it sits in B's queue from the iteration of its send, each a
+      PACKET, then the DELIVERY it becomes;
+    - a window's arrivals at one lane are ~Poisson(messages x window_ns /
+      mean_hop_ns) (every lane is sent the same share); each costs two
+      dependent pops (the PACKET, then the DELIVERY it inserts, whose pop
+      is the send), so the fullest lane of a window sets the iterations:
+      ``iters = ceil(2 q / pops)`` with ``q`` the quantile at 1 / (1 000 x
+      lanes x windows).  That IS what a run takes since the lanes co-pop
+      any DELIVERY* PACKET* prefix (``lanes.pop_mask``, the window-inert
+      class): ``q`` arrivals are ``q / 2`` packet pairs and ``q / 2``
+      delivery pairs at 2 pops; under the same-instant rule before it a
+      DELIVERY popped alone and a window took ``1.5 q``;
+    - a QUEUE holds what was in flight to it when the window opened plus
+      what the window's sends file while it pops: ~Poisson(messages x
+      (far_hop_ns + window_ns) / mean_hop_ns) at the farthest lane, at
+      1 / (1 000 x lanes x windows x iters), plus the engine's headroom.
+      On one switch that is ~Poisson(2 x messages): what is left of this
+      window's arrivals and what the merge has already filed for the next.
+      On a graph the window is a small part of a hop (2 of 18.6 ms on
+      ``routed_graph_gml(200, 1)``) and the far lane's share leads (28.2
+      of 18.6 ms): the longest routed path (42 ms) in its place would be
+      a bound no lane's MEAN reaches, and would double the merge's row;
     - a CROSS segment holds one iteration's fan-in: every lane sends at
       most ``pops``, so ~Poisson(<= pops) (reached at start-up, when every
-      lane pops ``pops`` initial messages), at the same tail.
+      lane pops ``pops`` initial messages, whatever the graph), at the
+      same tail.
 
-    The merge's row is ``capacity + 2 pops + cross`` columns and its sort
-    pads to a power of two (PERF.md 4), so the columns left under that
-    power go to the queue: they cost nothing.  Strict capacity is the
-    backstop: a run past the tail raises and names the block."""
+    Loss only thins the population, so the law ignores it.  The merge's
+    row is ``capacity + 2 pops + cross`` columns and its sort pads to a
+    power of two (PERF.md 4), so the columns left under that power go to
+    the queue: they cost nothing.  Strict capacity is the backstop: a run
+    past the tail raises and names the block."""
     if min(n_hosts, messages, windows, pops) < 1:
         raise ValueError("n_hosts, messages, windows and pops must be >= 1")
+    if not 0 < window_ns <= mean_hop_ns <= far_hop_ns:
+        raise ValueError(
+            "need 0 < window_ns <= mean_hop_ns <= far_hop_ns: the window is "
+            "the smallest path, the far lane's mean no smaller than the mean")
     draws = 1000 * n_hosts * windows
-    iters = -(-2 * poisson_tail_quantile(messages, 1 / draws) // pops)
+    handed = messages * window_ns / mean_hop_ns
+    iters = -(-2 * poisson_tail_quantile(handed, 1 / draws) // pops)
     p = 1 / (draws * iters)
-    queue = poisson_tail_quantile(2 * messages, p) + QUEUE_HEADROOM
+    held = messages * (far_hop_ns + window_ns) / mean_hop_ns
+    queue = poisson_tail_quantile(held, p) + QUEUE_HEADROOM
     cross = poisson_tail_quantile(pops, p)
     row = queue + 2 * pops + cross
     return (1 << (row - 1).bit_length()) - 2 * pops - cross, cross
@@ -376,42 +436,70 @@ def phold_mesh_config(
     latency: str = "10 ms",
     bandwidth: str = "1 Gbit",
     seed: int = 1,
+    graph_nodes: int | None = None,
+    graph_seed: int = 1,
 ) -> ConfigOptions:
     """PHOLD (Fujimoto 1990) as upstream Shadow ships it (the reference's
-    ``src/test/phold/test_phold.c``): ``n_hosts`` hosts on one graph
-    node, each one process ``phold --messages <messages> --size <size>``
-    (``models/phold.py``: every received datagram answered by one to a
-    peer drawn uniformly from the others), self-edge ``latency`` (the
-    lookahead), ``bandwidth`` up and down, zero loss — so the population
-    of ``n_hosts x messages`` datagrams is conserved.
+    ``src/test/phold/test_phold.c``): ``n_hosts`` hosts, each one process
+    ``phold --messages <messages> --size <size>`` (``models/phold.py``:
+    every received datagram answered by one to a peer drawn uniformly from
+    the others), ``bandwidth`` up and down.
+
+    Without ``graph_nodes`` the network is one graph node (ONE host group
+    with ``count``): self-edge ``latency`` (the lookahead), zero loss — so
+    the population of ``n_hosts x messages`` datagrams is conserved.  With
+    it the network is :func:`routed_graph_gml` ``(graph_nodes, graph_seed,
+    bandwidth)`` — a wide-area latency / loss graph; ``latency`` is not
+    read — and host ``i`` (the same id either way, a document of its own)
+    is placed on a graph node drawn uniformly from the stream
+    ``random.Random(f"phold-hosts-{graph_seed}")``, of ``graph_seed`` alone
+    (as :func:`gossip_mesh_config` places its nodes): the deployment is
+    ONE network, ``seed`` drives the peer and loss draws.  A lost datagram
+    is final there, so the population decays.
 
     The lane program's shapes are set here by :func:`phold_shape_law`
-    (random fan-in has no hand-set safe width); stop time and backend
-    (``tpu``) are the caller's to set on the result."""
-    queue, cross = phold_shape_law(n_hosts, messages)
+    (random fan-in has no hand-set safe width) — on a graph from its
+    window, the placed lanes' mean path and the mean path into the
+    farthest lane, over ``PHOLD_LAW_HORIZON_NS`` of windows; stop time and
+    backend (``tpu``) are the caller's to set on the result."""
+    process = {
+        "path": "phold",
+        "args": ["--messages", str(messages), "--size", str(size)],
+        "start_time": "0 s",
+    }
+    if graph_nodes is None:
+        queue, cross = phold_shape_law(n_hosts, messages)
+        gml = _one_switch_gml(latency, bandwidth)
+        hosts = {"lp": {"count": n_hosts, "network_node_id": 0,
+                        "processes": [process]}}
+    else:
+        from ..net.graph import NetworkGraph
+
+        gml = routed_graph_gml(graph_nodes, graph_seed, bandwidth)
+        hosts = _placed_hosts("lp", "phold", n_hosts, graph_nodes, graph_seed,
+                              process)
+        # the law's path facts, over the lanes as placed
+        graph = NetworkGraph.from_gml(gml)
+        share = np.bincount(
+            [graph.id_to_index[h["network_node_id"]] for h in hosts.values()],
+            minlength=graph_nodes) / n_hosts
+        into = share @ graph.latency_ns  # mean path into a lane of each node
+        window = graph.min_latency_ns()
+        queue, cross = phold_shape_law(
+            n_hosts, messages, windows=-(-PHOLD_LAW_HORIZON_NS // window),
+            window_ns=window, mean_hop_ns=float(into @ share),
+            far_hop_ns=float(into[share > 0].max()))
     return ConfigOptions.from_dict({
         "general": {"stop_time": "10 s", "seed": seed,
                     "heartbeat_interval": None},
-        "network": {"graph": {"type": "gml", "inline": (
-            "graph [\n"
-            f'  node [ id 0 host_bandwidth_up "{bandwidth}" '
-            f'host_bandwidth_down "{bandwidth}" ]\n'
-            f'  edge [ source 0 target 0 latency "{latency}" ]\n'
-            "]\n")}},
+        "network": {"graph": {"type": "gml", "inline": gml}},
         "experimental": {
             "network_backend": "tpu",
             "tpu_lane_queue_capacity": queue,
             "tpu_cross_capacity": cross,
             "tpu_events_per_round": PHOLD_POPS,
         },
-        "hosts": {"lp": {
-            "count": n_hosts, "network_node_id": 0,
-            "processes": [{
-                "path": "phold",
-                "args": ["--messages", str(messages), "--size", str(size)],
-                "start_time": "0 s",
-            }],
-        }},
+        "hosts": hosts,
     })
 
 
@@ -590,25 +678,13 @@ def gossip_mesh_config(
         "start_time": "0 s",
     }
     if graph_nodes is None:
-        gml = (
-            "graph [\n"
-            f'  node [ id 0 host_bandwidth_up "{bandwidth}" '
-            f'host_bandwidth_down "{bandwidth}" ]\n'
-            f'  edge [ source 0 target 0 latency "{latency}" ]\n'
-            "]\n")
+        gml = _one_switch_gml(latency, bandwidth)
         hosts = {"node": {"count": n_hosts, "network_node_id": 0,
                           "processes": [process]}}
     else:
         gml = routed_graph_gml(graph_nodes, graph_seed, bandwidth)
-        # a stream of its own, so the graph does not move with the width
-        rnd = random.Random(f"gossip-hosts-{graph_seed}")
-        hosts = {
-            f"node{i:0{len(str(n_hosts))}d}": {
-                "network_node_id": rnd.randrange(graph_nodes),
-                "processes": [process],
-            }
-            for i in range(1, n_hosts + 1)
-        }
+        hosts = _placed_hosts("node", "gossip", n_hosts, graph_nodes,
+                              graph_seed, process)
     # a flood's hop is budgeted at the longest routed path, of any epoch
     graph = NetworkGraph.from_gml(gml)
     hop = graph.max_latency_ns()

@@ -818,12 +818,13 @@ def test_a_fault_epoch_rebuilds_the_rows_from_its_own_tables():
 
 def _path_gathers(eng, *args):
     """The ``gather`` operations the run program traces under the
-    ``path_lookup`` scope (locations of the lowered text)."""
+    ``path_lookup/path_gather`` scope (locations of the lowered text)."""
     text = lanes.make_run_fn(eng.params, eng.tables).lower(
         eng.initial_state(), *args).as_text(debug_info=True)
     assert "path_lookup/" in text and "window_gather/" in text
     scoped = set(re.findall(
-        r'^(#loc\d+) = loc\("[^"]*path_lookup/gather"', text, re.M))
+        r'^(#loc\d+) = loc\("[^"]*path_lookup/path_gather/gather"', text,
+        re.M))
     ops = re.findall(r'"stablehlo\.gather".* loc\((#loc\d+)\)$', text, re.M)
     assert len(ops) > len(scoped)  # the window's own gather is seen
     return sum(loc in scoped for loc in ops)

@@ -43,13 +43,15 @@ OWN = {"lane_iters", "lane_delivered", "lane_sends", "lane_drop_queue",
 #: ... and (ISSUE 42) no send reads per-peer path rows, and on one graph
 #: node none gathers its path either (the [1, 1] lookup folds)
 #: ... and (ISSUE 46) a row of its queues carries no payload word
+#: ... and (ISSUE 48) so it reads no [G, G] table and gathers no element
 ONE_SWITCH = {"graph_nodes": 1, "window_ns": 10 * MS,
               "max_path_latency_ns": 10 * MS, "has_loss": 0,
               "stream_wide_pop": 1, "lane_drop_loss": 0,
               "stream_retransmits": 0, "codel_lookup_pops": 0,
               "queue_capacity": 16, "cross_capacity": 8, "pops_per_iter": 2,
               "sends_per_pop": 1, "payload_words": 0,
-              "static_path_sends": 0, "path_gather_sends": 0}
+              "static_path_sends": 0, "path_gather_sends": 0,
+              "path_gather_tables": 0, "path_gather_elems_per_iter": 0}
 
 
 def _mesh_cfg(tmp_path, hosts=2_000, stop_ms=1_100, mesh_devices=0):

@@ -17,7 +17,13 @@ engine can fake.
     the obs gauges;
 (e) the window-inert co-pop (ISSUE 38): the predicate alone on hand-written
     rows, the iterations it saves and its engage counter, the delivery
-    ties it exists for against the oracle, and that the law is static.
+    ties it exists for against the oracle, and that the law is static;
+(f) the same model on a routed, lossy graph (ISSUE 48:
+    ``phold_mesh_config(..., graph_nodes=G)``): (a) again with the run-time
+    ``[G, G]`` gathers and the loss draw compiled in, the placement's and
+    the default's pins, the shape law where a window is shorter than a
+    hop, the laws a decaying population keeps in both engines, and the
+    ``path_gather_*`` gauges.
 """
 
 import dataclasses
@@ -564,3 +570,338 @@ def test_the_co_pop_law_is_static(name, differs, monkeypatch):
     with_rule = _lowered(make())
     monkeypatch.setattr(lanes, "WINDOW_INERT_MODELS", frozenset())
     assert (_lowered(make()) != with_rule) == differs
+
+
+# -- (f) a destination picked at run time on a graph (ISSUE 48) --------------
+
+#: the rehearsal width of ``phold10k_wan_m4``: 64 hosts over
+#: ``routed_graph_gml(8, 1)``, 100 windows of 2 ms (4 of ~3 760 hops lost)
+WAN = dict(hosts=64, messages=4, graph_nodes=8, stop_ms=200)
+
+
+def _wan_cfg(backend="tpu", seed=7, hosts=WAN["hosts"], stop_ms=WAN["stop_ms"],
+             graph_nodes=WAN["graph_nodes"], graph_seed=1, **shapes):
+    cfg = phold_mesh_config(hosts, WAN["messages"], 256, bandwidth="1 Gbit",
+                            seed=seed, graph_nodes=graph_nodes,
+                            graph_seed=graph_seed)
+    cfg.general.stop_time = stop_ms * MS
+    cfg.experimental.network_backend = backend
+    for key, val in shapes.items():
+        setattr(cfg.experimental, key, val)
+    return cfg
+
+
+@functools.lru_cache(maxsize=None)
+def _wan_oracle(stop_ms=WAN["stop_ms"]):
+    """(result, datagrams sent, events left in the queues at the stop)."""
+    eng = CpuEngine(_wan_cfg("cpu", stop_ms=stop_ms))
+    res = eng.run()
+    return (res, sum(h.send_seq for h in eng.hosts),
+            sum(len(h.queue) for h in eng.hosts))
+
+
+@functools.lru_cache(maxsize=None)
+def _wan_lane_run():
+    eng = TpuEngine(_wan_cfg(), log_capacity=0)
+    return eng.run(mode="device"), dict(eng.lane_plane)
+
+
+def _assert_equals_the_wan_oracle(res, stop_ms=WAN["stop_ms"]):
+    oracle = _wan_oracle(stop_ms)[0]
+    assert res.log_tuples() == oracle.log_tuples()
+    assert _shared(res.counters) == _shared(oracle.counters)
+    assert res.rounds == oracle.rounds == stop_ms // 2
+    lost = oracle.counters["lane_drop_loss"]
+    assert lost > 0
+    assert len(oracle.event_log) == oracle.counters["phold_hops"] + lost
+
+
+@pytest.mark.parametrize("mode", ["device", "step"])
+def test_the_routed_lossy_factory_equals_the_oracle(mode):
+    eng = TpuEngine(_wan_cfg())
+    _assert_equals_the_wan_oracle(eng.run(mode=mode))
+    assert eng.tables.lat.shape == (8, 8) and eng.params.has_loss
+
+
+def test_the_routed_facade_with_the_log_off_equals_the_oracles_counters(
+        tmp_path):
+    cfg = _wan_cfg()
+    cfg.general.data_directory = str(tmp_path / "data")
+    sim = Simulation(cfg, event_log=False)
+    res = sim.run()
+    oracle = _wan_oracle()[0]
+    assert res.event_log == []
+    assert _shared(res.counters) == _shared(oracle.counters)
+    assert res.rounds == oracle.rounds
+    plane = json.loads(
+        (sim.data_dir / "sim-stats.json").read_text())["lane_plane"]
+    want = _wan_lane_run()[1]
+    keys = SHAPES + ("graph_nodes", "has_loss", "path_gather_sends",
+                     "path_gather_tables", "path_gather_elems_per_iter")
+    assert {k: plane[k] for k in keys} == {k: want[k] for k in keys}
+
+
+def test_the_command_line_runs_the_routed_network_from_a_file(tmp_path):
+    """The factory's network as a user would write it: the graph inline,
+    one document a host with its graph node, the law's shapes."""
+    import subprocess
+    import sys
+
+    cfg = _wan_cfg()
+    exp = cfg.experimental
+    hosts = "\n".join(
+        f"  {h.hostname}: {{network_node_id: {h.network_node_id}, processes: "
+        "[{path: phold, args: --messages 4 --size 256}]}" for h in cfg.hosts)
+    gml = scenarios.routed_graph_gml(WAN["graph_nodes"], 1, "1 Gbit")
+    path = tmp_path / "phold_wan.yaml"
+    path.write_text(f"""
+general: {{stop_time: {WAN["stop_ms"]} ms, seed: 7, heartbeat_interval: null,
+          data_directory: {tmp_path / "data"}}}
+network:
+  graph:
+    type: gml
+    inline: |
+{chr(10).join("      " + line for line in gml.splitlines())}
+experimental: {{network_backend: tpu,
+               tpu_lane_queue_capacity: {exp.tpu_lane_queue_capacity},
+               tpu_cross_capacity: {exp.tpu_cross_capacity},
+               tpu_events_per_round: 2}}
+hosts:
+{hosts}
+""")
+    done = subprocess.run(
+        [sys.executable, "-m", "shadow_tpu", str(path)],
+        capture_output=True, text=True, timeout=240)
+    assert done.returncode == 0, done.stderr[-2000:]
+    stats = json.loads((tmp_path / "data" / "sim-stats.json").read_text())
+    oracle = _wan_oracle()[0]
+    assert stats["counters"]["phold_hops"] == oracle.counters["phold_hops"]
+    assert stats["counters"]["lane_drop_loss"] == oracle.counters[
+        "lane_drop_loss"]
+    want = _wan_lane_run()[1]
+    assert {k: stats["lane_plane"][k] for k in SHAPES} == {
+        k: want[k] for k in SHAPES}
+    assert stats["lane_plane"]["path_gather_sends"] == 1
+
+
+@pytest.mark.multichip
+@pytest.mark.parametrize("devices", [1, 2, 4])
+def test_the_routed_factory_equals_the_oracle_at_any_mesh_shape(devices):
+    eng = TpuEngine(_wan_cfg(stop_ms=100))
+    eng.attach_mesh(parallel.make_mesh(devices))
+    _assert_equals_the_wan_oracle(eng.run(mode="device"), stop_ms=100)
+    assert eng.lane_plane["mesh_devices"] == devices
+
+
+def test_a_decaying_population_keeps_its_laws_in_both_engines():
+    """Under path loss no count is analytic, but the population's laws
+    hold: a datagram is sent at the start or by a hop, every delivery is a
+    hop, a lost message is never replaced, and what is alive at the stop
+    is what started less what was lost."""
+    start = WAN["hosts"] * WAN["messages"]
+    res, _plane = _wan_lane_run()
+    c = res.counters
+    assert c["lane_sends"] == start + c["phold_hops"]
+    assert c["phold_hops"] == c["lane_delivered"]
+    assert 0 < c["lane_drop_loss"] <= start
+    alive = c["lane_sends"] - c["lane_delivered"] - c["lane_drop_loss"]
+    assert alive == start - c["lane_drop_loss"]
+    assert "lane_drop_queue" not in c
+    # the oracle's own books: per-host send counters and the event queues
+    oracle, sent, queued = _wan_oracle()
+    assert sent == start + oracle.counters["phold_hops"] == c["lane_sends"]
+    assert oracle.counters["lane_drop_loss"] == c["lane_drop_loss"]
+    assert queued == start - oracle.counters["lane_drop_loss"] == alive
+
+
+def _placement(cfg):
+    return [h.network_node_id for h in cfg.hosts]
+
+
+def test_the_placement_follows_the_graph_seed_alone():
+    a, b = _wan_cfg(seed=7), _wan_cfg(seed=8)
+    assert _placement(a) == _placement(b) and a.network == b.network
+    assert (a.general.seed, b.general.seed) == (7, 8)
+    # two widths, one graph; the narrower placement is the wider's head
+    wide = _wan_cfg(hosts=128)
+    assert wide.network == a.network
+    assert _placement(wide)[:64] == _placement(a)
+    assert wide.network.graph.inline == scenarios.routed_graph_gml(
+        8, 1, "1 Gbit")
+    other = _wan_cfg(graph_seed=2)
+    assert other.network != a.network and _placement(other) != _placement(a)
+    # host i keeps its id: documents sort in i order
+    assert [h.hostname for h in a.hosts] == [
+        f"lp{i:02d}" for i in range(1, 65)]
+    assert set(_placement(a)) <= set(range(8))
+
+
+def test_without_a_graph_the_configuration_is_the_parents():
+    """``graph_nodes=None``: the document PR 35 wrote, key for key."""
+    cfg = phold_mesh_config(64, 4, 256, "10 ms", "1 Gbit", seed=3)
+    queue, cross = phold_shape_law(64, 4)
+    assert cfg == ConfigOptions.from_dict({
+        "general": {"stop_time": "10 s", "seed": 3,
+                    "heartbeat_interval": None},
+        "network": {"graph": {"type": "gml", "inline": (
+            "graph [\n"
+            '  node [ id 0 host_bandwidth_up "1 Gbit" '
+            'host_bandwidth_down "1 Gbit" ]\n'
+            '  edge [ source 0 target 0 latency "10 ms" ]\n'
+            "]\n")}},
+        "experimental": {
+            "network_backend": "tpu",
+            "tpu_lane_queue_capacity": queue,
+            "tpu_cross_capacity": cross,
+            "tpu_events_per_round": 2,
+        },
+        "hosts": {"lp": {
+            "count": 64, "network_node_id": 0,
+            "processes": [{
+                "path": "phold",
+                "args": ["--messages", "4", "--size", "256"],
+                "start_time": "0 s",
+            }],
+        }},
+    })
+    assert cfg == phold_mesh_config(64, 4, 256, "10 ms", "1 Gbit", seed=3,
+                                    graph_nodes=None, graph_seed=5)
+    assert phold_shape_law(10_000, 4) == (42, 18)
+
+
+def _path_facts(cfg):
+    """(window, mean hop, far hop) of the lanes as placed, in ns."""
+    import numpy as np
+
+    from shadow_tpu.net.graph import NetworkGraph
+
+    graph = NetworkGraph.from_gml(cfg.network.graph.inline)
+    lat = graph.latency_ns
+    nodes = [graph.id_to_index[n] for n in _placement(cfg)]
+    into = lat[nodes].mean(axis=0)  # mean path into a lane of each node
+    return (graph.min_latency_ns(), float(into[nodes].mean()),
+            float(into[nodes].max()))
+
+
+@pytest.mark.parametrize("hosts, graph_nodes",
+                         [(64, 8), (512, 8), (10_000, 200)])
+def test_the_factorys_graph_shapes_are_the_written_law(hosts, graph_nodes):
+    cfg = _wan_cfg(hosts=hosts, graph_nodes=graph_nodes)
+    exp = cfg.experimental
+    queue, cross, pops = (exp.tpu_lane_queue_capacity, exp.tpu_cross_capacity,
+                          exp.tpu_events_per_round)
+    window, mean_hop, far_hop = _path_facts(cfg)
+    assert window == 2 * MS < mean_hop < far_hop
+    windows = scenarios.PHOLD_LAW_HORIZON_NS // window
+    assert windows == 5 * scenarios.PHOLD_LAW_WINDOWS
+    assert (queue, cross) == phold_shape_law(
+        hosts, 4, windows=windows, window_ns=window, mean_hop_ns=mean_hop,
+        far_hop_ns=far_hop) and pops == 2
+    # the law, restated: the fullest lane of a window is handed the tail of
+    # Poisson(messages x window / mean hop); the farthest lane's queue holds
+    # Poisson(messages x (far hop + window) / mean hop)
+    draws = 1000 * hosts * windows
+    iters = -(-2 * poisson_tail_quantile(4 * window / mean_hop, 1 / draws)
+              // pops)
+    p = 1 / (draws * iters)
+    assert _poisson_tail(pops, cross) < p <= _poisson_tail(pops, cross - 1)
+    need = poisson_tail_quantile(
+        4 * (far_hop + window) / mean_hop, p) + scenarios.QUEUE_HEADROOM
+    row = queue + 2 * pops + cross
+    assert row & (row - 1) == 0 and queue >= need
+    assert row // 2 < need + 2 * pops + cross
+    if hosts == 10_000:
+        # 39 + 4 + 18 = 61 columns: the one-switch cell's row of 64
+        assert (need, queue, cross, iters) == (39, 42, 18, 10)
+        assert mean_hop == pytest.approx(18.64e6, rel=1e-3)
+        assert far_hop == pytest.approx(28.16e6, rel=1e-3)
+    # a longer hop into the far lane never narrows the queue's need
+    assert phold_shape_law(hosts, 4, windows=windows, window_ns=window,
+                           mean_hop_ns=mean_hop,
+                           far_hop_ns=2 * far_hop)[0] >= queue
+
+
+def test_the_law_refuses_path_facts_out_of_order():
+    for facts in (dict(window_ns=3, mean_hop_ns=2, far_hop_ns=4),
+                  dict(window_ns=1, mean_hop_ns=5, far_hop_ns=4),
+                  dict(window_ns=0, mean_hop_ns=1, far_hop_ns=1)):
+        with pytest.raises(ValueError):
+            phold_shape_law(64, 4, **facts)
+
+
+def test_the_graph_laws_widths_sit_above_a_runs_peaks():
+    _res, plane = _wan_lane_run()
+    exp = _wan_cfg().experimental
+    queue, cross = exp.tpu_lane_queue_capacity, exp.tpu_cross_capacity
+    assert (plane["queue_capacity"], plane["cross_capacity"],
+            plane["pops_per_iter"]) == (queue, cross, 2)
+    assert 4 <= plane["queue_peak"] <= queue - scenarios.QUEUE_HEADROOM
+    assert 2 <= plane["cross_peak"] <= cross
+
+
+def test_a_graph_shape_forced_under_its_peak_raises_and_names_the_block():
+    plane = _wan_lane_run()[1]
+    narrow = TpuEngine(_wan_cfg(
+        tpu_lane_queue_capacity=plane["queue_peak"] - 2), log_capacity=0)
+    with pytest.raises(RuntimeError) as e:
+        narrow.run(mode="device")
+    msg = str(e.value)
+    assert "off the tail of a lane QUEUE" in msg
+    assert "raise experimental.tpu_lane_queue_capacity" in msg
+    assert "CROSS" not in msg and "tpu_cross_capacity" not in msg
+    narrow = TpuEngine(_wan_cfg(
+        tpu_cross_capacity=plane["cross_peak"] - 1), log_capacity=0)
+    with pytest.raises(RuntimeError) as e:
+        narrow.run(mode="device")
+    msg = str(e.value)
+    assert "by the CROSS block" in msg
+    assert f"the block holds {plane['cross_peak'] - 1})" in msg
+    assert "raise experimental.tpu_cross_capacity" in msg
+    assert "tpu_lane_queue_capacity" not in msg
+
+
+def _gossip_on_the_graph():
+    from shadow_tpu.config.scenarios import gossip_mesh_config
+
+    cfg = gossip_mesh_config(64, 4, 1, ("10 ms",), 2, 512, bandwidth="1 Gbit",
+                             graph_nodes=8, graph_seed=1)
+    cfg.general.stop_time = 100 * MS
+    return cfg
+
+
+@pytest.mark.parametrize("name, want", [
+    ("phold_on_the_graph", (1, 3, 2 * 64 * 4)),
+    ("phold_on_one_switch", (0, 0, 0)),
+    ("gossip_on_the_graph", (0, 0, 0)),
+])
+def test_lane_plane_states_what_a_send_gathers(name, want):
+    """``path_gather_tables`` / ``path_gather_elems_per_iter``: static
+    facts beside ``path_gather_sends`` in EVERY program's ``lane_plane`` —
+    3 tables and pops x lanes x 4 elements where PHOLD's drawn destination
+    meets a lossy graph, nothing where the lookup folds (one node) or the
+    peers' paths are rows (gossip)."""
+    if name == "phold_on_the_graph":
+        plane = _wan_lane_run()[1]
+        assert plane["graph_nodes"] == 8 and plane["has_loss"] == 1
+    else:
+        cfg = (_cfg(64, 4, 5) if name == "phold_on_one_switch"
+               else _gossip_on_the_graph())
+        eng = TpuEngine(cfg, log_capacity=0)
+        eng.run(mode="device")
+        plane = eng.lane_plane
+        assert lanes.path_gather_load(eng.params, eng.tables) == (0, 0)
+        assert (plane["graph_nodes"] > 1) == (name == "gossip_on_the_graph")
+    assert (plane["path_gather_sends"], plane["path_gather_tables"],
+            plane["path_gather_elems_per_iter"]) == want
+
+
+def test_a_loss_free_graph_gathers_one_table():
+    """Without the loss draw a gathered send reads the latency alone."""
+    cfg = _lossy_routed_graph("tpu")
+    cfg.network.graph.inline = cfg.network.graph.inline.replace(
+        " packet_loss 0.02", "").replace(" packet_loss 0.01", "")
+    eng = TpuEngine(cfg, log_capacity=0)
+    assert not eng.params.has_loss and eng.tables.lat.shape == (3, 3)
+    pops, n = eng.params.pops_per_iter, eng.params.n_lanes
+    assert lanes.path_gather_load(eng.params, eng.tables) == (
+        1, pops * n * 2)
