@@ -84,11 +84,13 @@ NEVER = stime.NEVER
 # backends elide identically so event logs stay bit-identical
 PASSIVE_MODELS = frozenset({M_NONE, M_TGEN_MESH, M_TGEN_CLIENT, M_TGEN_SERVER})
 STREAM_MODELS = frozenset({M_STREAM_CLIENT, M_STREAM_SERVER})
-# models whose sends look their path up in the [G, G] tables: every model
-# of the [N] send channel whose destination is picked at RUN time (a draw,
-# an echo's source, a round-robin offset; the client models' fixed
-# ``p_peer`` gathers too, PERF.md §7).  A gossip lane's D mesh peers are
-# static, and so is their path: rows of ``LaneTables.g_lat``
+# models whose sends GATHER their path: every model of the [N] send channel
+# whose destination is picked at RUN time (a draw, an echo's source, a
+# round-robin offset; the client models' fixed ``p_peer`` gathers too,
+# PERF.md §7).  On a graph of G > 1 nodes such a send reads the pair's two
+# packed words by ONE flat index, ``node_of[lane] * G + node_of[dst]``
+# (``LaneTables.flat_lat`` / ``flat_thresh``).  A gossip lane's D mesh
+# peers are static, and so is their path: rows of ``LaneTables.g_lat``
 PATH_GATHER_MODELS = frozenset({
     M_PHOLD, M_TGEN_MESH, M_TGEN_CLIENT, M_PING_CLIENT, M_PING_SERVER})
 # active DATAGRAM models whose DELIVERY handler is WINDOW-INERT, the static
@@ -659,7 +661,16 @@ class LaneParams:
 class LaneTables(NamedTuple):
     """Device-resident per-lane constants (not mutated by the sim).
     Everything on the hot path is int32 (the engine validates magnitudes
-    and raises LaneCompatError out of range — see TpuEngine)."""
+    and raises LaneCompatError out of range — see TpuEngine).
+
+    A path's three words — latency, loss threshold, lose-everything — are
+    held in the layout each kind of send reads: the ``[G, G]`` tables
+    (every program; on one graph node the lookup folds), rows per static
+    destination (``flow_*``, ``g_*``: nothing gathered), and for a
+    destination picked at run time two packed ``[G * G]`` words gathered
+    by one flat index (``flat_lat``, ``flat_thresh``; ``path_gather_tables``
+    counts them).  ``TpuEngine._path_words`` builds all of them from one
+    epoch's tables."""
 
     node_of: jnp.ndarray  # [N] int32: lane -> graph node index
     lat: jnp.ndarray  # [G, G] int32 latency ns (< 2**31 enforced)
@@ -739,6 +750,30 @@ class LaneTables(NamedTuple):
     g_lat: Any = ()
     g_thresh_u32: Any = ()
     g_thresh_all: Any = ()
+    # [G * G], row-major over (source node, destination node): the words a
+    # send whose destination is picked at RUN time gathers, by the ONE
+    # index ``node_of[lane] * G + node_of[dst]`` (a one-word index into a
+    # 1-D table; the [G, G] tables take a two-word one).  ``flat_lat`` is
+    # ``lat | (thresh_all << 31)``: bit 31 is free, a routed pair's
+    # latency is positive and the engine rejects any at or above NEVER32 =
+    # 2**31 - 1, so ``word & MASK31`` is the latency and ``word < 0`` the
+    # pair that loses everything (a pair without a route, -1 in ``lat``,
+    # is one no two hosts form: its word is never used); ``flat_thresh``
+    # is ``thresh_u32``.  Built by
+    # ``TpuEngine._path_words`` with the tables above, per fault epoch too;
+    # () where no send gathers (``gathers_path``), and there the program
+    # takes no such argument
+    flat_lat: Any = ()  # int32
+    flat_thresh: Any = ()  # uint32
+
+
+def gathers_path(p: LaneParams, graph_nodes: int) -> bool:
+    """Whether the program ``p`` compiles to on a graph of ``graph_nodes``
+    nodes has a send that gathers its path: a destination picked at run
+    time (a ``PATH_GATHER_MODELS`` lane) on more than one node.  On one
+    node the [1, 1] lookup folds and nothing is gathered."""
+    return graph_nodes > 1 and bool(
+        set(p.models_present) & PATH_GATHER_MODELS)
 
 
 def path_sends(p: LaneParams, tb: LaneTables) -> tuple[int, int]:
@@ -748,24 +783,23 @@ def path_sends(p: LaneParams, tb: LaneTables) -> tuple[int, int]:
     threshold from per-peer rows (``tb.g_lat``: the gossip lanes' F on a
     graph of more than one node, else 0), and how many have a destination
     picked at run time on such a graph, whose path is gathered from the
-    [G, G] tables (send 0 of a ``PATH_GATHER_MODELS`` lane; on one node
-    the lookup folds and nothing is gathered)."""
+    packed ``flat_*`` words (send 0 of a ``PATH_GATHER_MODELS`` lane; on
+    one node the lookup folds and nothing is gathered)."""
     rows = 0 if isinstance(tb.g_lat, tuple) else p.sends_per_pop
-    gathers = tb.lat.shape[-1] > 1 and bool(
-        set(p.models_present) & PATH_GATHER_MODELS)
-    return rows, int(gathers)
+    return rows, int(gathers_path(p, tb.lat.shape[-1]))
 
 
 def path_gather_load(p: LaneParams, tb: LaneTables) -> tuple[int, int]:
     """``(path_gather_tables, path_gather_elems_per_iter)``, static too:
-    the [G, G] tables a gathered send reads (the latency; with the loss
-    draw compiled in its two thresholds as well) and the elements one
+    the packed words a gathered send reads beside ``node_of[dst]``
+    (``flat_lat``: the latency and the lose-everything bit; with the loss
+    draw compiled in ``flat_thresh`` as well) and the elements one
     iteration gathers in the ``path_gather`` scope — ``node_of[dst]`` and
-    a word of each table for every lane at every pop, whether the slot
-    holds an event or not.  ``(0, 0)`` where no send gathers."""
+    each word for every lane at every pop, whether the slot holds an event
+    or not.  ``(0, 0)`` where no send gathers."""
     if not path_sends(p, tb)[1]:
         return 0, 0
-    tables = 3 if p.has_loss else 1
+    tables = 2 if p.has_loss else 1
     return tables, p.pops_per_iter * p.n_lanes * (1 + tables)
 
 
@@ -1537,8 +1571,10 @@ def _process_slot(
         its own loss draw — in the order of the calls.  The path's three
         words are a constant of the pair where the destination is static
         (``rows``: the ``(use, lat, thresh_u32, thresh_all)`` of a gossip
-        lane's k-th peer) and gathered from the [G, G] tables where it is
-        picked at run time (``gathers``, static), as ``dst`` itself is."""
+        lane's k-th peer) and gathered where it is picked at run time
+        (``gathers``, static), as ``dst`` itself is: by one flat index
+        from the packed ``tb.flat_*`` words, unpacked here to the same
+        three."""
 
         # per-send sequence numbers
         snd_seq = s.send_seq
@@ -1567,17 +1603,30 @@ def _process_slot(
         # loss (bootstrap window is loss-free; loss-free graphs skip the draw)
         with jax.named_scope("path_lookup"):
             # the run-time gathers apart from the draw and the compares
-            # (``path_gather_load``): node_of[dst], then a [G, G] word each
+            # (``path_gather_load``): node_of[dst], then each packed word
+            # at the pair's flat index.  On one graph node there are no
+            # packed words: the [1, 1] lookup folds
+            def gather(table, at):
+                with jax.named_scope("path_gather"):
+                    return table[at]
+
+            words = None  # indexed as ``rows``: (-, lat, thresh_u32, all)
             if gathers:
                 my_node = tb.node_of
-                with jax.named_scope("path_gather"):
-                    dst_node = tb.node_of[dst]
+                dst_node = gather(tb.node_of, dst)
+                if not isinstance(tb.flat_lat, tuple):
+                    flat = my_node * tb.lat.shape[-1] + dst_node
+                    w_lat = gather(tb.flat_lat, flat)
+                    words = (None, w_lat)  # loss-free: bit 31 is clear
+                    if p.has_loss:
+                        words = (None, w_lat & MASK31,
+                                 gather(tb.flat_thresh, flat), w_lat < 0)
 
             def path_word(table, i):
                 if not gathers:
                     return rows[i]
-                with jax.named_scope("path_gather"):
-                    word = table[my_node, dst_node]
+                word = (gather(table, (my_node, dst_node)) if words is None
+                        else words[i])
                 return word if rows is None else jnp.where(
                     rows[0], rows[i], word)
 

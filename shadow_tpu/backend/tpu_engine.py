@@ -733,12 +733,14 @@ class TpuEngine:
 
     def _path_tables(self, lat, thresh) -> dict:
         """The ``LaneTables`` fields one epoch's ``[G, G]`` latency and
-        loss-threshold tables decide: the tables themselves (a run-time
-        destination gathers from them) and their compactions for every
-        STATIC destination — a flow's peer (``flow_*``, ``[2S]``) and a
-        gossip lane's D mesh peers (``g_*``, ``[F, N]``, lanes minor) —
-        whose path is a constant of the lane, so a send reads a row and
-        gathers nothing.  ONE law for start-up and each fault epoch."""
+        loss-threshold tables decide: the tables themselves, their two
+        packed words by flat index where a run-time destination gathers
+        its path (``flat_*``, ``[G * G]``) and their compactions for
+        every STATIC destination — a flow's peer (``flow_*``, ``[2S]``)
+        and a gossip lane's D mesh peers (``g_*``, ``[F, N]``, lanes
+        minor) — whose path is a constant of the lane, so a send reads a
+        row and gathers nothing.  ONE law for start-up and each fault
+        epoch."""
         return {k: jnp.asarray(v)
                 for k, v in self._path_words(lat, thresh).items()}
 
@@ -769,6 +771,19 @@ class TpuEngine:
         if self._g_peers_np is not None and lat_np.shape[0] > 1:
             peers = self._g_peers_np.T  # [F, N]
             kw.update(paths("g_", np.arange(peers.shape[1])[None, :], peers))
+        if lanes.gathers_path(self.params, lat_np.shape[-1]):
+            # bit 31 of a latency is free for the lose-everything bit:
+            # __init__ rejects any latency, of any epoch, at or above
+            # NEVER32, and a routed pair's latency is positive (a pair
+            # without a route, -1, is one no two hosts form: RoutingInfo
+            # refuses the others, and its word is never used).  A
+            # loss-free program reads the word as the latency, unmasked
+            assert lat_np.min() >= -1 and lat_np.max() < lanes.NEVER32
+            assert self.params.has_loss or not kw["thresh_all"].any()
+            kw["flat_lat"] = (
+                kw["lat"] | (kw["thresh_all"].astype(np.int32) << 31)
+            ).reshape(-1)
+            kw["flat_thresh"] = kw["thresh_u32"].reshape(-1)
         return kw
 
     # -- multi-chip plane (parallel/mesh.py) -------------------------------

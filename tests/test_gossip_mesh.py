@@ -834,7 +834,7 @@ def test_an_all_gossip_program_on_a_graph_traces_no_path_gather(monkeypatch):
     """Whether the ``[G, G]`` gather is compiled follows from the models
     present and the graph's size alone: none where every send's
     destination is a mesh peer; PHOLD's drawn destination on a lossy
-    graph still gathers ``node_of[dst]`` and the three words."""
+    graph still gathers ``node_of[dst]`` and the two packed words."""
     seed = (np.uint32(7), np.uint32(0))
     gossip = TpuEngine(_lossy_cfg(), log_capacity=0)
     assert gossip.params.has_loss and gossip.tables.lat.shape == (2, 2)
@@ -847,7 +847,7 @@ def test_an_all_gossip_program_on_a_graph_traces_no_path_gather(monkeypatch):
     phold = TpuEngine(phold_tests._lossy_routed_graph("tpu"), log_capacity=0)
     assert phold.params.has_loss and phold.tables.lat.shape == (3, 3)
     assert phold.tables.g_lat == ()
-    assert _path_gathers(phold, *seed) == 4  # of ONE slot body (scanned)
+    assert _path_gathers(phold, *seed) == 3  # of ONE slot body (scanned)
     phold.run(mode="device")
     assert (phold.lane_plane["static_path_sends"],
             phold.lane_plane["path_gather_sends"]) == (0, 1)
@@ -857,12 +857,12 @@ def test_an_all_gossip_program_on_a_graph_traces_no_path_gather(monkeypatch):
     # under XLA:CPU's rolled scan the one scanned body does
     mixed = TpuEngine(_rows_cfg(), log_capacity=0)
     assert lanes.path_sends(mixed.params, mixed.tables) == (4, 1)
-    assert _path_gathers(mixed, *seed) == 4
+    assert _path_gathers(mixed, *seed) == 3
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     pops = mixed.params.pops_per_iter
-    assert _path_gathers(mixed, *seed) == 4 * pops
+    assert _path_gathers(mixed, *seed) == 3 * pops
     assert _path_gathers(gossip, *seed) == 0
-    assert _path_gathers(phold, *seed) == 4 * pops
+    assert _path_gathers(phold, *seed) == 3 * pops
 
 
 def _oracle_less_tgen_sent(cfg):

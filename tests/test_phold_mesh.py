@@ -23,7 +23,11 @@ engine can fake.
     ``[G, G]`` gathers and the loss draw compiled in, the placement's and
     the default's pins, the shape law where a window is shorter than a
     hop, the laws a decaying population keeps in both engines, and the
-    ``path_gather_*`` gauges.
+    ``path_gather_*`` gauges;
+(g) the packed words a gathered send reads by one flat index (ISSUE 49:
+    ``LaneTables.flat_lat`` / ``flat_thresh``): what they unpack to,
+    where they are absent, the latency guard that frees bit 31, and a
+    fault schedule's epoch swap against the oracle.
 """
 
 import dataclasses
@@ -31,12 +35,14 @@ import functools
 import json
 import math
 
+import jax
+import numpy as np
 import pytest
 
 from shadow_tpu import parallel
 from shadow_tpu.backend import lanes
 from shadow_tpu.backend.cpu_engine import CpuEngine
-from shadow_tpu.backend.tpu_engine import TpuEngine
+from shadow_tpu.backend.tpu_engine import LaneCompatError, TpuEngine
 from shadow_tpu.config import scenarios
 from shadow_tpu.config.options import ConfigOptions
 from shadow_tpu.config.scenarios import (
@@ -465,9 +471,15 @@ def _slow_downlink_mesh(backend):
     return cfg
 
 
-def _lossy_routed_graph(backend):
+def _phold_on_a_graph(backend, down, edges):
+    """Twelve PHOLD hosts dealt round the graph nodes (one a ``down``
+    bandwidth), over the GML ``edges``."""
+    nodes = "\n".join(
+        f'        node [ id {i} host_bandwidth_up "10 Mbit" '
+        f'host_bandwidth_down "{bw}" ]' for i, bw in enumerate(down))
+    edges = "\n".join(f"        edge [ {e} ]" for e in edges)
     hosts = "\n".join(
-        f"  h{i}: {{network_node_id: {i % 3}, processes: "
+        f"  h{i}: {{network_node_id: {i % len(down)}, processes: "
         f"[{{path: phold, args: [--messages, '6']}}]}}" for i in range(12))
     return ConfigOptions.from_yaml(f"""
 general: {{stop_time: 150 ms, seed: 11}}
@@ -477,19 +489,23 @@ network:
     inline: |
       graph [
         directed 0
-        node [ id 0 host_bandwidth_up "10 Mbit" host_bandwidth_down "10 Mbit" ]
-        node [ id 1 host_bandwidth_up "10 Mbit" host_bandwidth_down "5 Mbit" ]
-        node [ id 2 host_bandwidth_up "10 Mbit" host_bandwidth_down "10 Mbit" ]
-        edge [ source 0 target 0 latency "2 ms" ]
-        edge [ source 0 target 1 latency "5 ms" packet_loss 0.02 ]
-        edge [ source 1 target 1 latency "2 ms" ]
-        edge [ source 1 target 2 latency "3 ms" packet_loss 0.01 ]
-        edge [ source 2 target 2 latency "2 ms" ]
+{nodes}
+{edges}
       ]
 experimental: {{network_backend: {backend}, tpu_events_per_round: 2}}
 hosts:
 {hosts}
 """)
+
+
+def _lossy_routed_graph(backend):
+    return _phold_on_a_graph(backend, ["10 Mbit", "5 Mbit", "10 Mbit"], [
+        'source 0 target 0 latency "2 ms"',
+        'source 0 target 1 latency "5 ms" packet_loss 0.02',
+        'source 1 target 1 latency "2 ms"',
+        'source 1 target 2 latency "3 ms" packet_loss 0.01',
+        'source 2 target 2 latency "2 ms"',
+    ])
 
 
 TIES = {"slow_downlink": _slow_downlink_mesh, "lossy_routed": _lossy_routed_graph}
@@ -870,16 +886,17 @@ def _gossip_on_the_graph():
 
 
 @pytest.mark.parametrize("name, want", [
-    ("phold_on_the_graph", (1, 3, 2 * 64 * 4)),
+    ("phold_on_the_graph", (1, 2, 2 * 64 * 3)),
     ("phold_on_one_switch", (0, 0, 0)),
     ("gossip_on_the_graph", (0, 0, 0)),
 ])
 def test_lane_plane_states_what_a_send_gathers(name, want):
     """``path_gather_tables`` / ``path_gather_elems_per_iter``: static
     facts beside ``path_gather_sends`` in EVERY program's ``lane_plane`` —
-    3 tables and pops x lanes x 4 elements where PHOLD's drawn destination
-    meets a lossy graph, nothing where the lookup folds (one node) or the
-    peers' paths are rows (gossip)."""
+    2 packed words and pops x lanes x 3 elements (``node_of[dst]`` and a
+    word each) where PHOLD's drawn destination meets a lossy graph, nothing
+    where the lookup folds (one node) or the peers' paths are rows
+    (gossip)."""
     if name == "phold_on_the_graph":
         plane = _wan_lane_run()[1]
         assert plane["graph_nodes"] == 8 and plane["has_loss"] == 1
@@ -905,3 +922,151 @@ def test_a_loss_free_graph_gathers_one_table():
     pops, n = eng.params.pops_per_iter, eng.params.n_lanes
     assert lanes.path_gather_load(eng.params, eng.tables) == (
         1, pops * n * 2)
+
+
+# -- (g) the packed path words (ISSUE 49) -------------------------------------
+
+#: the largest latency the lane backend admits: NEVER32 itself is refused
+MAX_LAT = lanes.NEVER32 - 1
+
+
+def _edge_cases_graph(far_ns=MAX_LAT):
+    """PHOLD over two graph nodes: the hop between them takes ``far_ns``
+    and loses 30 %, and node 1's own switch loses everything."""
+    return _phold_on_a_graph("tpu", ["10 Mbit", "10 Mbit"], [
+        'source 0 target 0 latency "2 ms"',
+        f'source 0 target 1 latency "{far_ns} ns" packet_loss 0.3',
+        'source 1 target 1 latency "2 ms" packet_loss 1.0',
+    ])
+
+
+#: two epochs inside the run: a longer 0-1 hop, then a 1-2 hop that loses
+#: everything (the lose-everything bit arrives with an epoch's words)
+PHOLD_FAULTS = (
+    {"at": "40 ms", "kind": "latency", "source": 0, "target": 1,
+     "latency": "7 ms"},
+    {"at": "80 ms", "kind": "loss", "source": 1, "target": 2, "loss": 1.0},
+)
+
+
+def _faulted_cfg(backend):
+    cfg = _lossy_routed_graph(backend)
+    cfg.faults.events = list(PHOLD_FAULTS)
+    return cfg
+
+
+@functools.lru_cache(maxsize=None)
+def _faulted_oracle():
+    return CpuEngine(_faulted_cfg("cpu")).run()
+
+
+def _assert_unpacks_to_the_tables(tb):
+    """Every pair's two words, at the flat index a send computes, are the
+    ``[G, G]`` tables' three."""
+    g = tb.lat.shape[-1]
+    a, b = np.asarray(tb.flat_lat), np.asarray(tb.flat_thresh)
+    assert (a.shape, a.dtype, b.shape, b.dtype) == (
+        (g * g,), np.int32, (g * g,), np.uint32)
+    src, dst = np.divmod(np.arange(g * g), g)
+    assert ((a & lanes.MASK31) == np.asarray(tb.lat)[src, dst]).all()
+    assert ((a < 0) == np.asarray(tb.thresh_all)[src, dst]).all()
+    assert (b == np.asarray(tb.thresh_u32)[src, dst]).all()
+
+
+def _epoch_arguments(eng):
+    """The arguments of the program a fault schedule would run: the state,
+    the epoch's path leaves, the stop pair and the seed's two words."""
+    paths = {f: getattr(eng.tables, f) for f in eng._path_fields}
+    text = lanes.make_run_fn(eng.params, eng.tables, epochs=True).lower(
+        eng.initial_state(), paths, np.int32(0), np.int32(1), np.uint32(7),
+        np.uint32(0)).as_text()
+    main = text[text.index("func.func public @main("):]
+    return paths, main[:main.index("->")].count("%arg")
+
+
+def _case_unpack(monkeypatch):
+    eng = TpuEngine(_edge_cases_graph(), log_capacity=0)
+    tb = eng.tables
+    lat, lost = np.asarray(tb.lat), np.asarray(tb.thresh_all)
+    assert lat.max() == MAX_LAT == 2**31 - 2 and lat.min() > 0
+    assert lost.any() and not lost.all() and eng.params.has_loss
+    _assert_unpacks_to_the_tables(tb)
+    # the largest latency keeps bit 31 clear; the pair that loses all sets it
+    assert np.asarray(tb.flat_lat).max() == MAX_LAT
+    assert (np.asarray(tb.flat_lat) < 0).sum() == lost.sum() == 1
+    # every fault epoch's words are packed by the same law
+    faulted = TpuEngine(_faulted_cfg("tpu"), log_capacity=0)
+    plan = faulted._fault_overlay.segment_plan(faulted.params.stop_time)
+    seen = set()
+    for _start, _end, snap in plan:
+        tb = faulted.tables if snap is None else faulted._segment_tables(snap)
+        _assert_unpacks_to_the_tables(tb)
+        seen.add(np.asarray(tb.flat_lat).tobytes())
+    assert len(seen) == len(PHOLD_FAULTS) + 1
+
+
+def _case_absent(make):
+    def case(monkeypatch):
+        eng = TpuEngine(make(), log_capacity=0)
+        assert eng.tables.flat_lat == () and eng.tables.flat_thresh == ()
+        assert not lanes.gathers_path(eng.params, eng.tables.lat.shape[-1])
+        assert lanes.path_gather_load(eng.params, eng.tables) == (0, 0)
+        # as an epoch's leaves the program is handed the [G, G] tables,
+        # the flows' rows and the gossip peers' rows, and no packed word:
+        # its arguments are among them (what it does not read is pruned)
+        paths, n_args = _epoch_arguments(eng)
+        assert not any(f.startswith("flat_") for f in paths)
+        assert len(paths) == (6 if isinstance(eng.tables.g_lat, tuple) else 9)
+        state = len(jax.tree.leaves(eng.initial_state()))
+        assert state < n_args <= state + len(paths) + 4
+    return case
+
+
+def _case_never32(monkeypatch):
+    def packed(*_args):
+        raise AssertionError("a word was packed before the latency guard")
+
+    monkeypatch.setattr(TpuEngine, "_path_words", packed)
+    with pytest.raises(LaneCompatError, match="link latency"):
+        TpuEngine(_edge_cases_graph(far_ns=lanes.NEVER32), log_capacity=0)
+
+
+def _case_faulted(mode):
+    def case(monkeypatch):
+        oracle = _faulted_oracle()
+        eng = TpuEngine(_faulted_cfg("tpu"))
+        res = eng.run(mode=mode)
+        assert len(oracle.event_log) > 1000
+        assert res.log_tuples() == oracle.log_tuples()
+        assert _shared(res.counters) == _shared(oracle.counters)
+        assert res.rounds == oracle.rounds
+        # both epochs were swapped in, and the last one's pair lost it all
+        assert eng.lane_plane["fault_epochs"] == len(PHOLD_FAULTS)
+        calm = _ties_oracle("lossy_routed").counters["lane_drop_loss"]
+        assert res.counters["lane_drop_loss"] > 2 * calm > 0
+        assert lanes.path_gather_load(eng.params, eng.tables) == (
+            2, eng.params.pops_per_iter * eng.params.n_lanes * 3)
+    return case
+
+
+PACKED_WORDS = {
+    "unpack": _case_unpack,
+    "absent_on_one_switch": _case_absent(lambda: _cfg(64, 4, 5)),
+    "absent_in_an_all_gossip_program": _case_absent(_gossip_on_the_graph),
+    "absent_in_a_stream_only_program": _case_absent(_one_to_one_streams),
+    "a_latency_at_never32_is_refused_first": _case_never32,
+    "faulted_device": _case_faulted("device"),
+    "faulted_step": _case_faulted("step"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACKED_WORDS))
+def test_the_packed_path_words(case, monkeypatch):
+    """A gathered send's two words (``flat_lat``: the latency with the
+    lose-everything bit at 31; ``flat_thresh``): equal to the ``[G, G]``
+    tables pair for pair, at the largest admitted latency and on a pair
+    that loses everything, in every fault epoch; ``()`` — and no argument
+    of the program — where no send gathers; never packed from a latency
+    the guard refuses; and carried by the epoch swap, PHOLD on the lossy
+    graph under a schedule against the oracle (log, counters, rounds)."""
+    PACKED_WORDS[case](monkeypatch)
