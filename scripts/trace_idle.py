@@ -13,7 +13,12 @@ first host phase to the first operation, and from the last operation to the
 last phase's end).  Each gap is cut by the host plane's ``hybrid/*`` and
 ``fused/*`` TraceMe intervals (the drivers' host-phase clock,
 ``shadow_tpu/obs/clock.py``; the INNERMOST phase takes a nested stretch)
-and what no phase covers is ``(no phase)``.
+and what no phase covers is ``(no phase)``.  A fused run is one turn of
+its clock, a ``fused/run`` interval around its phases, so what a run
+spends outside every ``fused/<phase>`` is ``fused/run``'s and only the
+time outside every run stays ``(no phase)``.  Each run's closed row (its
+phases' seconds and its notes: the stats of the empty ``fused/row`` span
+the clock leaves at the turn's end) is printed beside the gaps.
 
 The two planes' clocks differ by a constant of the order of a millisecond
 (the device's events are stamped on its own clock).  It is read off the
@@ -32,6 +37,7 @@ from collections import defaultdict
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 PHASE = re.compile(r"^((?:hybrid|fused)/\w+)")
+ROW = re.compile(r"^(?:hybrid|fused)/row$")  # a closed turn's row, no phase
 NO_PHASE = "(no phase)"
 SETTLE_NS = 500_000  # a wait ends this soon after the program it waited for
 
@@ -141,7 +147,7 @@ def idle_gaps(leaves, programs, lo, hi):
 def reduce(path, offset_ns=None):
     from jax.profiler import ProfileData
 
-    phases, devices = [], []
+    phases, devices, rows = [], [], []
     for plane in ProfileData.from_file(path).planes:
         if DEVICE_PLANE.match(plane.name):
             ops = programs = ()
@@ -156,6 +162,9 @@ def reduce(path, offset_ns=None):
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
+                    if ROW.match(e.name):
+                        rows.append((e.start_ns, dict(e.stats)))
+                        continue
                     m = PHASE.match(e.name)
                     if m:
                         phases.append((e.start_ns, e.start_ns + e.duration_ns,
@@ -164,7 +173,9 @@ def reduce(path, offset_ns=None):
         raise SystemExit(f"{path}: no operation ran on a TPU in this trace")
     stretches = innermost(phases)
     waits = [e for _s, e, n in phases if n.endswith("/device_wait")]
-    report = {"phases_seen": len(phases), "devices": {}}
+    report = {"phases_seen": len(phases), "devices": {},
+              "rows": [stats for _t, stats in sorted(
+                  rows, key=lambda r: r[0])]}
     for name, leaves, programs in devices:
         off = clock_offset(waits, programs) if offset_ns is None else offset_ns
         leaves = [(s + off, e + off) for s, e in leaves]
@@ -208,6 +219,10 @@ def main(argv=None) -> int:
                            if k == kind), reverse=True)
             for v, p in rows:
                 print(f"    {p:<24} {v:.6f}s  {100 * v / total:6.2f} %")
+    for row in rep["rows"]:
+        print("run row: " + ", ".join(
+            f"{k} {v:.6f}" if isinstance(v, float) else f"{k} {v}"
+            for k, v in row.items() if k not in ("t_start", "t_end")))
     return 0
 
 

@@ -209,6 +209,10 @@ MOD_SMALL_LIMIT = _pairs.MOD_SMALL_LIMIT
 # Must match obs.netobs.HIST_BUCKETS (import would cycle).
 NB_HIST_BUCKETS = 24
 
+# the loop ledger (``LaneState.loop_hist`` / ``loop_acc``): what the
+# loop's iterations and windows held — see ``LoopAcc``
+_I32_MAX = (1 << 31) - 1
+
 # pair arithmetic helpers (shared with the stream tier — lanes_pairs.py)
 pair_lt = _pairs.pair_lt
 pair_ge = _pairs.pair_ge
@@ -431,6 +435,43 @@ class LaneState(NamedTuple):
     # never into the counters (the oracle queues every delivery).  () —
     # nothing traced — where no lane runs M_GOSSIP
     gossip_elided: Any = ()
+    # the loop ledger: what the loop's iterations and windows held —
+    # ``loop_hist`` int32 ``[NB_HIST_BUCKETS]``, WINDOWS by the number of
+    # iterations they took (the bucket law above, ``hist_fold_index``),
+    # and ``loop_acc`` int32 ``[7]``, the words of ``LoopAcc`` in its
+    # order; both replicated under a mesh.  Read in collect()'s one transfer into
+    # ``lane_plane`` (``loop_*``), never into the counters: the oracle has
+    # no iterations.  By ``peaks``' rule: () — nothing traced, the program
+    # unchanged — where every lane's model is passive
+    # (``LaneParams.all_passive``: such a mesh takes one iteration a
+    # window)
+    loop_hist: Any = ()
+    loop_acc: Any = ()
+
+
+class LoopAcc(NamedTuple):
+    """The words of the loop ledger's running counts, ``LaneState.
+    loop_acc`` (on the device ONE int32 vector in this order — as scalars
+    of the carry's scalar vector every reduction's result had to cross to
+    the body's scalar unit, which cost more than the vector's own kernel,
+    PERF.md §6 PR 50; the host decodes it into this tuple).  Every word
+    but the first only grows, by non-negative steps, and SATURATES at
+    2**31 - 1 (``_ledger_add``): a run of more than 2**31 pop slots
+    (100 000 lanes x 2 pops x 10 738 iterations) reads the ceiling, never
+    a wrapped count."""
+
+    round_iters: Any  # iterations of the OPEN window so far (folded into
+                      # ``loop_hist`` when the next opens; the trailing
+                      # window's by ``TpuEngine.collect``)
+    round_max: Any  # the most iterations a CLOSED window took
+    pop_slots: Any  # live [N] pop slots, of ``iters x pops x n_lanes``
+    active_lanes: Any  # [N] lanes that popped at all, summed over iters
+    no_send: Any  # iterations whose exchange handed no lane a row
+    exch_passes: Any  # passes of a FAN-OUT program's exchange
+                      # (``_merge_append`` step 2); stays 0 where a pop
+                      # sends once: a pass an iteration
+    tier_pop_slots: Any  # live stream-TIER pop slots, of ``iters x
+                         # stream_pops x 2 S``; stays 0 without a tier
 
 
 class GossipState(NamedTuple):
@@ -2494,6 +2535,7 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
             lambda carry: carry[0] < passes, one_pass,
             (jnp.int32(0), s, jnp.zeros(n, dtype=jnp.int32)),
         )
+        s = _ledger_add(s, no_send=n_sending == 0, exch_passes=passes)
         return s._replace(
             exchange_compact_iters=s.exchange_compact_iters + (passes == 1),
             exchange_slot_peak=jnp.maximum(s.exchange_slot_peak, n_sending),
@@ -2583,6 +2625,11 @@ def _merge_append(p: LaneParams, tb: LaneTables, s: LaneState,
         ]
     assert flat_ops[0].shape[0] == p.exchange_entries
     gather_ops, start, cnt = _sorted_exchange(flat_ops, n)
+    # the loop ledger: an iteration whose exchange handed no lane a row
+    # (``cross_peak``'s reduction, read once more) still pays its sort and
+    # its row merge
+    if _has_ledger(s):
+        s = _ledger_add(s, no_send=cnt.max() == 0)
     _in_seg, words = _cross_block(gather_ops, start, cnt, cx)
     cross = dict(zip(ROW_WORDS + flat_pay, words))
     for w in pay[len(flat_pay):]:
@@ -3113,19 +3160,78 @@ def ilog2_i32(x):
     return r
 
 
+def hist_fold_index(count, enable):
+    """THE bucket law of the repo's log2 histograms (``obs.netobs.
+    hist_bucket`` is its host form): ``(do, idx)`` for folding one
+    ``count`` — bucket ``floor(log2(count))``, the last of the
+    ``NB_HIST_BUCKETS`` taking the tail; a zero count, or ``enable``
+    false, folds nowhere: ``idx`` is then one past the row, which an
+    ``.at[idx].add(1, mode="drop")`` drops."""
+    do = enable & (count > 0)
+    bucket = jnp.minimum(ilog2_i32(count), NB_HIST_BUCKETS - 1)
+    return do, jnp.where(do, bucket, NB_HIST_BUCKETS)
+
+
 def _flush_hist(p: LaneParams, s: LaneState, enable) -> LaneState:
     """Fold the running window occupancy (packet arrivals) into the [B]
     histogram and reset it — called exactly when a NEW window begins
     (and once more at collect, host-side, for the trailing window).
     Packet-free windows leave ``nb_win == 0`` and are skipped — on both
     backends identically, so the histogram stays bit-comparable."""
-    do = enable & (s.nb_win > 0)
-    bucket = jnp.minimum(ilog2_i32(s.nb_win), NB_HIST_BUCKETS - 1)
-    idx = jnp.where(do, bucket, NB_HIST_BUCKETS)
+    do, idx = hist_fold_index(s.nb_win, enable)
     return s._replace(
         nb_hist=s.nb_hist.at[idx].add(1, mode="drop"),
         nb_win=jnp.where(do, 0, s.nb_win),
     )
+
+
+def _has_ledger(s: LaneState) -> bool:
+    """Whether this program carries the loop ledger: the state's own leaf
+    says (``TpuEngine.initial_state`` decides, by ``LaneParams.
+    all_passive`` of the WHOLE program — a tiered program's [N] pass runs
+    under a params view without the stream models)."""
+    return not isinstance(s.loop_acc, tuple)
+
+
+def _ledger_add(s: LaneState, **steps) -> LaneState:
+    """``loop_acc[word] += step`` for non-negative int32 scalar steps by
+    ``LoopAcc`` word, saturating at 2**31 - 1.  Where the program carries
+    no ledger it adds nothing, but a step computed for it is traced all
+    the same (and a loop body's text keeps dead operations): a site whose
+    step is a reduction asks ``_has_ledger`` first."""
+    if not _has_ledger(s):
+        return s
+    with jax.named_scope("loop_ledger"):
+        step = jnp.stack([jnp.asarray(steps.get(word, 0), dtype=jnp.int32)
+                          for word in LoopAcc._fields])
+        return s._replace(
+            loop_acc=jnp.minimum(s.loop_acc, _I32_MAX - step) + step)
+
+
+def _ledger_open_window(s: LaneState, fresh) -> LaneState:
+    """Where ``fresh`` (a new window opens): fold the iterations the
+    finished window took into ``loop_hist``, keep the most any took, and
+    start the count again — at the instant ``_flush_hist`` folds netobs's
+    window, in every loop form.  The trailing window's count stays in the
+    ``round_iters`` word for ``TpuEngine.collect``.  The fold is one
+    elementwise add of a one-hot row and the two words' update a select
+    on the word's index (scatters into a few words are kernels of their
+    own on the chip; ``idx`` past the row, ``hist_fold_index``'s
+    "nowhere", matches no bucket)."""
+    if not _has_ledger(s):
+        return s
+    with jax.named_scope("loop_ledger"):
+        acc = s.loop_acc
+        word = np.arange(len(LoopAcc._fields))
+        at_iters, at_max = (word == LoopAcc._fields.index(w)
+                            for w in ("round_iters", "round_max"))
+        took = acc[LoopAcc._fields.index("round_iters")]
+        do, idx = hist_fold_index(took, fresh)
+        acc = jnp.where(do & at_iters, 0, acc)
+        acc = jnp.where(do & at_max, jnp.maximum(acc, took), acc)
+        hit = np.arange(NB_HIST_BUCKETS) == idx
+        return s._replace(
+            loop_hist=s.loop_hist + hit.astype(jnp.int32), loop_acc=acc)
 
 
 def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
@@ -3222,6 +3328,10 @@ def _stream_tier_iter(p: LaneParams, tb: LaneTables, s: LaneState,
         prefix = same_t & pkt_prefix
     allowed = prefix | first_col
     act_b = allowed & pair_lt(thi_b, tlo_b, we_hi, we_lo)
+    # the loop ledger: the tier's live pop slots (its work is here, not
+    # in the [N] lanes)
+    if _has_ledger(s):
+        s = _ledger_add(s, tier_pop_slots=act_b.sum(dtype=i32))
     if p.netobs:
         # tier PACKET pops join the window occupancy count ([N] pops are
         # added by iter_body; wire arrivals are the one event class whose
@@ -3934,6 +4044,14 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                              we_hi, we_lo)
         if p_lane.copop_inert:
             s = s._replace(copop_wide_pops=s.copop_wide_pops + wide)
+        if _has_ledger(s):
+            # the loop ledger: one more iteration of the open window, its
+            # live pop slots, and the lanes that popped at all
+            with jax.named_scope("loop_ledger"):
+                slots = act.sum(dtype=jnp.int32)
+                popping = act.any(axis=1).sum(dtype=jnp.int32)
+            s = _ledger_add(s, round_iters=1, pop_slots=slots,
+                            active_lanes=popping)
         kcol, srccol = unpack_aux_hi(s.q_auxh[:, :k])
         popped = {
             "thi": thi,
@@ -4101,7 +4219,11 @@ def _build_iter(p: LaneParams, tb: LaneTables, pure_dataflow: bool = False):
                     # no slot sent: the iteration fits one pass (of none)
                     st = st._replace(
                         exchange_compact_iters=st.exchange_compact_iters + 1)
-                return st
+                # the ledger's books of a merge not run, as the
+                # unconditional one keeps them
+                return _ledger_add(
+                    st, no_send=1,
+                    exch_passes=int(p_lane.sends_per_pop > 1))
 
             s = lax.cond(any_new, do_merge, do_sort, s)
 
@@ -4349,6 +4471,7 @@ def _build_round(p: LaneParams, tb: LaneTables, stop=None):
             # a live round IS a new window: flush the previous round's
             # occupancy (the trailing window flushes at collect)
             s = _flush_hist(p, s, ~done)
+        s = _ledger_open_window(s, ~done)
         window_end = jnp.minimum(
             start + _effective_runahead(p, s), stop
         )
@@ -4440,7 +4563,8 @@ def pack_state(s: LaneState):
     )
     return (q, c32, sc, s.log, s.stream, s.egress, s.nb_hist, s.fl_buf,
             s.peaks, s.copop_wide_pops, s.exchange_compact_iters,
-            s.exchange_slot_peak, s.gossip, s.gossip_age, s.gossip_elided)
+            s.exchange_slot_peak, s.gossip, s.gossip_age, s.gossip_elided,
+            s.loop_hist, s.loop_acc)
 
 
 def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
@@ -4457,7 +4581,7 @@ def _scalar_fields(has_eg: bool, has_nb: bool, has_fl: bool, has_ap: bool):
 def unpack_state(carry) -> LaneState:
     (q, c32, sc, log, stream, egress, nb_hist, fl_buf, peaks,
      copop_wide_pops, exchange_compact_iters, exchange_slot_peak, gossip,
-     gossip_age, gossip_elided) = carry
+     gossip_age, gossip_elided, loop_hist, loop_acc) = carry
     words = ROW_WORDS + pay_words(q.shape[0] - len(ROW_WORDS))
     # the optional blocks' own carry leaves say which are live; the append
     # counters have none, so the scalar count left over tells
@@ -4483,7 +4607,8 @@ def unpack_state(carry) -> LaneState:
         peaks=peaks, copop_wide_pops=copop_wide_pops,
         exchange_compact_iters=exchange_compact_iters,
         exchange_slot_peak=exchange_slot_peak, gossip=gossip,
-        gossip_age=gossip_age, gossip_elided=gossip_elided, **kw,
+        gossip_age=gossip_age, gossip_elided=gossip_elided,
+        loop_hist=loop_hist, loop_acc=loop_acc, **kw,
     )
 
 
@@ -4520,6 +4645,7 @@ def _build_full_run(p: LaneParams, tb: LaneTables, dynamic_stop=None):
             if p.netobs:
                 # window advance: flush the finished window's occupancy
                 st = _flush_hist(p, st, fresh)
+            st = _ledger_open_window(st, fresh)
             # clamp before adding runahead: min_next may be the NEVER pair
             # on a no-op trailing step
             c_hi, c_lo = pair_sel(
@@ -4931,6 +5057,7 @@ def _build_hybrid_fused_run(p: LaneParams, tb: LaneTables, k_cap: int,
                 fresh = pair_ge(mn_hi, mn_lo, st.now_we_hi, st.now_we_lo) & live
                 if p.netobs:
                     st = _flush_hist(p, st, fresh)
+                st = _ledger_open_window(st, fresh)
                 c_hi, c_lo = pair_sel(live, mn_hi, mn_lo, stop_hi, stop_lo)
                 c_hi, c_lo = pair_add32(c_hi, c_lo, _effective_runahead(p, st))
                 c_hi, c_lo = pair_sel(

@@ -29,6 +29,7 @@ from ..models.tgen import Ping, TgenClient, TgenMesh, TgenServer
 from ..net import codel as codel_mod
 from ..net.token_bucket import bucket_params
 from ..obs import flowtrace as ftr
+from ..obs import netobs as netobs_mod
 from ..obs.clock import TurnClock
 from . import lanes
 from . import lanes_stream as lstr_mod
@@ -44,6 +45,26 @@ FUSED_PHASES = ("state_build", "dispatch", "device_wait", "collect")
 # An engine with a fault schedule has one more: between a segment's wait
 # and the next segment's call, the host's epoch swap (``_run_faulted``).
 FAULT_PHASE = "fault_swap"
+# A run is one TURN of that clock: ``run`` is the turn's residual (what a
+# run spends outside the phases above), and the closed turn leaves one row
+# — in the clock's ring and in ``obs.clock.journal["fused"]`` — with these
+# notes, ints all (docs/observability.md "The fused run's row"): how the
+# run was driven, what it covered, the static shapes a ledger count is
+# divided by, and the loop ledger's gauges (0 where the program carries
+# none).
+LOOP_GAUGES = (
+    "loop_pop_slots", "loop_tier_pop_slots", "loop_active_lanes",
+    "loop_iters_no_send", "loop_exchange_passes", "loop_round_iters_max",
+    "loop_round_iters_p50", "loop_round_iters_p95",
+)
+FUSED_NOTES = (
+    "mode",  # 0 device, 1 step
+    "rounds", "lane_iters",
+    "segments",  # 1, or a faulted run's segments
+    "state_reused", "log_capacity",
+    "lanes", "pops_per_iter", "stream_pops", "flows",
+) + LOOP_GAUGES
+RUN_PHASE = "run"
 
 
 class LaneCompatError(ValueError):
@@ -699,7 +720,9 @@ class TpuEngine:
         # them under its own names: device_turn is the blocking wait
         faulted = self._fault_overlay is not None
         self.clock = TurnClock(
-            self, "fused", FUSED_PHASES + ((FAULT_PHASE,) if faulted else ()),
+            self, "fused",
+            FUSED_PHASES + ((FAULT_PHASE,) if faulted else ()) + (RUN_PHASE,),
+            notes=FUSED_NOTES, turn_phase=RUN_PHASE, journal=True,
             obs_map={
                 "state_build": ("state_build", None, None),
                 "dispatch": ("dispatch", None, None),
@@ -1136,6 +1159,10 @@ class TpuEngine:
                 full(len(AGE_COUNTERS)) if p.gossip_degree else ()
             ),
             gossip_elided=full() if p.gossip_degree else (),
+            loop_hist=() if p.all_passive else full(lanes.NB_HIST_BUCKETS),
+            loop_acc=(
+                () if p.all_passive else full(len(lanes.LoopAcc._fields))
+            ),
         )
         # ONE transfer of the whole tree, straight onto its placement: no
         # eager device program, no whole copy on one chip before sharding
@@ -1161,7 +1188,23 @@ class TpuEngine:
         uninterrupted run's suffix exactly.  ``disarm_stalls`` skips
         injected ``backend_stall`` raises on the faulted path: the
         checkpoint-anchored failover resume must replay *through* the
-        epoch that killed the first attempt."""
+        epoch that killed the first attempt.
+
+        A run is one TURN of the engine's clock: it leaves one row (the
+        phases' seconds, ``run`` their residual, ``FUSED_NOTES``) in
+        ``self.clock.ring`` and in ``obs.clock.journal["fused"]``."""
+        with self.clock.turn():
+            self.clock.note("mode", int(mode != "device"))
+            self.clock.note("segments", 1)
+            return self._run(
+                mode, precompile, on_window, resume_state, resume_epoch,
+                disarm_stalls)
+
+    def _run(
+        self, mode: str, precompile: bool, on_window, resume_state,
+        resume_epoch: int, disarm_stalls: bool,
+    ) -> SimResult:
+        """``run``'s body, inside the run's turn."""
         if resume_state is not None:
             if precompile:
                 raise LaneCompatError(
@@ -1241,6 +1284,13 @@ class TpuEngine:
                 "free_run", 0, self.params.stop_time, windows=result.rounds
             )
         return result
+
+    def run_row(self) -> Optional[dict]:
+        """The last run's row of the clock, as a dict (``sim-stats.json``
+        ``fused_run``: where that run's wall went — the phases' seconds,
+        ``run`` their residual — and its notes); None before any run."""
+        ring = self.clock.ring
+        return ring[-1]._asdict() if ring else None
 
     def _start_state(self, resume_state, mesh):
         """The ``state_build`` phase: the state a run starts from, on its
@@ -1462,6 +1512,7 @@ class TpuEngine:
         seed = tuple(
             np.uint32(w) for w in _rng._split_seed(self.params.seed))
         segments = sum(1 for seg in plan if seg[0] < seg[1])
+        self.clock.note("segments", segments)
         self._fault_plane = {
             # epochs inside the horizon, the segments they cut it into,
             # the compiled programs this driver holds for them (one a
@@ -1622,6 +1673,8 @@ class TpuEngine:
         ]
         if not p.all_passive:
             fields.append("peaks")
+        if not isinstance(s.loop_acc, tuple):
+            fields += ["loop_hist", "loop_acc"]
         if p.copop_inert:
             fields.append("copop_wide_pops")
         if p.sends_per_pop > 1:
@@ -1795,9 +1848,27 @@ class TpuEngine:
         # a faulted run's epochs, segments, programs and table bytes
         # (_run_faulted; nothing without a schedule)
         self.lane_plane.update(self._fault_plane)
+        loop_gauges, loop_hist = self._loop_ledger(s)
+        self.lane_plane.update(loop_gauges)
         if self.obs is not None:
             for key, val in self.lane_plane.items():
                 self.obs.metrics.gauge(key, val)
+        if loop_hist:
+            # the histogram whole: sim-stats.json's, no obs gauge
+            self.lane_plane["loop_hist"] = loop_hist
+        # the run's row (run(): one turn of the clock a run)
+        note = self.clock.note
+        note("rounds", int(s.rounds))
+        note("lane_iters", int(s.iters))
+        note("state_reused", self._state_reused)
+        note("log_capacity", p.log_capacity)
+        note("lanes", p.n_lanes)
+        note("pops_per_iter", p.pops_per_iter)
+        if p.stream_tiered:
+            note("stream_pops", p.stream_pops)
+            note("flows", self._s_flows)
+        for key, val in loop_gauges.items():
+            note(key, val)
         if log_lost:
             # surface the overflow as a metrics-registry counter BEFORE
             # raising: failed runs still flush partial obs artifacts
@@ -1926,6 +1997,46 @@ class TpuEngine:
             counters=counters,
             per_host_counters=[],
         )
+
+    def _loop_ledger(self, s: lanes.LaneState) -> tuple[dict, dict]:
+        """The loop ledger as ``collect`` reports it, from the host copy
+        of ``s``: the ``loop_*`` gauges (``LOOP_GAUGES``; ``lane_plane``,
+        the obs gauges, the run's row) and the histogram in
+        ``NETOBS_*.json``'s ``window_hist`` form (``lane_plane
+        ["loop_hist"]``: windows by the iterations they took).  Both
+        empty where the program carries no ledger
+        (``lanes.LaneState.loop_hist``).  The trailing window, which no
+        later one folded, is folded here — as netobs folds its own."""
+        if isinstance(s.loop_acc, tuple):
+            return {}, {}
+        p = self.params
+        acc = lanes.LoopAcc(*(int(v) for v in s.loop_acc))
+        by_round = np.asarray(s.loop_hist, dtype=np.int64).tolist()
+        if acc.round_iters:
+            by_round[netobs_mod.hist_bucket(acc.round_iters)] += 1
+        round_max = max(acc.round_max, acc.round_iters)
+        pct = netobs_mod.hist_percentile
+        gauges = {
+            # live [N] pop slots, of lane_iters x pops_per_iter x lanes
+            "loop_pop_slots": acc.pop_slots,
+            # [N] lanes that popped at all, summed over the iterations
+            "loop_active_lanes": acc.active_lanes,
+            # iterations whose exchange handed no lane a row
+            "loop_iters_no_send": acc.no_send,
+            # passes of the exchange: one an iteration, but for a
+            # fan-out program's denser iterations
+            "loop_exchange_passes": (
+                acc.exch_passes if p.sends_per_pop > 1 else int(s.iters)),
+            # iterations a window took: the most, and two percentiles
+            # (a bucket's upper edge, at most the exact maximum)
+            "loop_round_iters_max": round_max,
+            "loop_round_iters_p50": pct(by_round, 0.50, round_max),
+            "loop_round_iters_p95": pct(by_round, 0.95, round_max),
+        }
+        if p.stream_tiered:
+            # live TIER pop slots, of lane_iters x stream_pops x 2 flows
+            gauges["loop_tier_pop_slots"] = acc.tier_pop_slots
+        return gauges, {"scheme": "log2", "round_iters": by_round}
 
     # -- netobs telemetry plane (obs/netobs.py) ----------------------------
 

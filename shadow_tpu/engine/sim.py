@@ -941,6 +941,7 @@ class Simulation:
             "backend": self.cfg.experimental.network_backend,
             "device": self._device_info(),
             "lane_plane": self._lane_plane(),
+            "fused_run": self._fused_run(),
             "num_hosts": len(self.cfg.hosts),
             "seed": self.cfg.general.seed,
             "counters": dict(sorted(result.counters.items())),
@@ -965,6 +966,13 @@ class Simulation:
         run; None for the engines that hold no lane plane of their own."""
         info = getattr(self.engine, "lane_plane", None)
         return dict(info) if info else None
+
+    def _fused_run(self) -> Optional[dict]:
+        """The pure-lane engine's row of its last run (``TpuEngine.
+        run_row``: the run's host phases in seconds and its notes); None
+        for the engines whose driver is not the fused one."""
+        row = getattr(self.engine, "run_row", None)
+        return row() if row is not None else None
 
     def _outcome_counts(self, result: SimResult) -> dict[str, int]:
         out: dict[str, int] = {}

@@ -25,6 +25,15 @@ is the turn's residual.  A closed turn leaves one row in ``ring`` (the
 last ``RING_TURNS`` turns): its index, its ``perf_counter`` bounds, the
 seconds of every phase and the notes the driver set (``note`` / ``add``).
 
+A clock built with ``journal=True`` also leaves every closed row in the
+module's ``journal`` — one ``deque(maxlen=RING_TURNS)`` a clock prefix —
+where a reader finds it after the clock's owner is gone (the fused
+driver's: one turn a run, and a benchmark reader sees no engine).  Such
+a row ends with ``owner``, a process-wide serial the clock took at
+construction, which tells one engine's runs from another's.  Under a
+profiler session such a clock also hands the closed row to the trace, as
+the stats of an empty ``<prefix>/row`` span.
+
 No lock (a driver's loop is one thread), no option, no environment
 variable: the clock is on in every run.  It runs cold, once every few
 milliseconds, between a driver's device calls, so a span is ONE object
@@ -36,13 +45,19 @@ span costs in a loop and in a run).  This is the one module that imports
 
 from __future__ import annotations
 
+import itertools
 import time as wall_time
-from collections import deque, namedtuple
+from collections import defaultdict, deque, namedtuple
 from typing import Optional
 
 from jax.profiler import TraceAnnotation
 
 RING_TURNS = 8192  # rows kept: the last turns of a run
+
+#: ``journal[prefix]``: the last ``RING_TURNS`` rows closed by the clocks
+#: of that prefix built with ``journal=True``, in closing order
+journal: dict = defaultdict(lambda: deque(maxlen=RING_TURNS))
+_owners = itertools.count()
 
 _perf_counter = wall_time.perf_counter
 
@@ -131,7 +146,16 @@ class _Turn(_Span):
         self._close(t1)  # books the turn's own residual into its row
         c = self._clock
         c._turn = None
-        c.ring.append(c.Row(c.turns, self.t0, t1, *self.secs, *self.notes))
+        row = c.Row(c.turns, self.t0, t1, *self.secs, *self.notes, *c._tail)
+        c.ring.append(row)
+        if c._tail:
+            journal[c.prefix].append(row)
+            if c.annotating():
+                # the row itself on the profiler's clock, as the stats of
+                # an empty ``<prefix>/row`` span: what a trace's reader
+                # prints beside the gaps (scripts/trace_idle.py)
+                with c.annotate(f"{c.prefix}/row", **row._asdict()):
+                    pass
         c.turns += 1
 
 
@@ -149,7 +173,9 @@ class TurnClock:
     "rows"``, detail 0) is not either (no tracer-capacity burn).
     ``totals``: ``(stats, {key: (phase, ...)})`` keeps ``stats[key]`` the
     sum of those phases' cumulative seconds — the names accepted readers
-    divide by (``device_sync_s``, ``syscall_service_s``)."""
+    divide by (``device_sync_s``, ``syscall_service_s``).  ``journal``:
+    every closed row goes to the module's ``journal[prefix]`` too, with
+    the clock's ``owner`` serial as its last field."""
 
     def __init__(
         self,
@@ -160,6 +186,7 @@ class TurnClock:
         turn_phase: Optional[str] = None,
         obs_map: Optional[dict] = None,
         totals: Optional[tuple] = None,
+        journal: bool = False,
     ) -> None:
         self._owner = owner
         self.prefix = prefix
@@ -178,8 +205,11 @@ class TurnClock:
         # puts a stub here with ``use_annotator``
         self.annotate = TraceAnnotation
         self.annotating = TraceAnnotation.is_enabled
+        # a journal row's last field: which clock (so which engine) left it
+        self._tail = (next(_owners),) if journal else ()
         self.Row = namedtuple(
             "TurnRow", ("turn", "t_start", "t_end") + self.phases + self.notes
+            + (("owner",) if journal else ())
         )
         self.ring: deque = deque(maxlen=RING_TURNS)
         self.turns = 0  # turns closed so far: the next turn's index

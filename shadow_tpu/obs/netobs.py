@@ -41,6 +41,7 @@ determinism contract of docs/determinism.md).
 from __future__ import annotations
 
 import json
+import math
 from pathlib import Path
 from typing import Optional
 
@@ -79,6 +80,25 @@ def hist_bucket(count: int) -> int:
     """floor(log2(count)) clamped to the fixed bucket range (count >= 1).
     The identical law to the device's ``ilog2_i32`` path."""
     return min(max(int(count), 1).bit_length() - 1, HIST_BUCKETS - 1)
+
+
+def hist_percentile(buckets, q: float, ceiling: Optional[int] = None) -> int:
+    """The ``q``-quantile (nearest rank) of the counts a log2 histogram
+    holds, read as its bucket's UPPER edge — bucket ``b`` holds counts in
+    ``[2**b, 2**(b + 1))``, so ``2**(b + 1) - 1`` — clipped to ``ceiling``
+    where the largest count is known (the last bucket, which takes the
+    tail, has no edge of its own).  0 for an empty histogram."""
+    total = sum(int(v) for v in buckets)
+    if not total:
+        return 0
+    rank = max(1, math.ceil(q * total - 1e-9))
+    seen = 0
+    for b, v in enumerate(buckets):
+        seen += int(v)
+        if seen >= rank:
+            break
+    edge = (1 << (b + 1)) - 1
+    return edge if ceiling is None else min(edge, int(ceiling))
 
 
 def empty_arrays(n_hosts: int) -> dict[str, np.ndarray]:

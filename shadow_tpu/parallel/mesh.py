@@ -84,6 +84,7 @@ REPLICATED_FIELDS = frozenset((
     "now_we_hi", "now_we_lo", "min_used_lat", "stream",
     "peaks", "copop_wide_pops", "exchange_compact_iters",
     "exchange_slot_peak", "gossip_age", "gossip_elided",
+    "loop_hist", "loop_acc",
     "egress", "egress_count", "egress_lost",
     "egress_min_hi", "egress_min_lo",
     "nb_hist", "nb_win",

@@ -58,6 +58,72 @@ def test_gaps_by_kind_and_their_phases():
         sum(g1 - g0 for g0, g1, _k in gaps) / 1e9)
 
 
+def test_a_runs_remainder_is_the_runs_own_phase():
+    """A fused run is one ``fused/run`` interval around its phases: the
+    stretch between two phases is its, only what lies outside every run
+    stays unattributed."""
+    stretches = ti.innermost([(10, 190, "fused/run"),
+                              (20, 40, "fused/dispatch"),
+                              (40, 110, "fused/device_wait"),
+                              (150, 180, "fused/collect")])
+    assert stretches == [
+        (10, 20, "fused/run"), (20, 40, "fused/dispatch"),
+        (40, 110, "fused/device_wait"), (110, 150, "fused/run"),
+        (150, 180, "fused/collect"), (180, 190, "fused/run")]
+    by = ti.attribute([(0, 15, "outside_first_to_last_op"),
+                       (100, 160, "outside_first_to_last_op")], stretches)
+    assert dict(by) == {
+        ("outside_first_to_last_op", ti.NO_PHASE): 10e-9,
+        ("outside_first_to_last_op", "fused/run"): 45e-9,
+        ("outside_first_to_last_op", "fused/device_wait"): 10e-9,
+        ("outside_first_to_last_op", "fused/collect"): 10e-9}
+    # a closed turn's row is no phase
+    assert ti.ROW.match("fused/row") and not ti.ROW.match("fused/run")
+
+
+def test_a_traced_runs_row_rides_the_trace(tmp_path):
+    """A trace of one fused run (XLA:CPU: no device plane, so the
+    reduction itself refuses) holds the run's interval, its phases and
+    its row's stats."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from shadow_tpu.backend.tpu_engine import TpuEngine
+    from shadow_tpu.config.columnar import columnar_mesh_config
+
+    cfg = columnar_mesh_config(64, queue_capacity=16, pops_per_round=2)
+    cfg.general.stop_time = 50 * MS
+    eng = TpuEngine(cfg, log_capacity=0)
+    eng.run(mode="device")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = eng.run(mode="device")
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names, rows = [], []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if ti.ROW.match(e.name):
+                    rows.append(dict(e.stats))
+                elif ti.PHASE.match(e.name):
+                    names.append(e.name)
+    assert sorted(names) == sorted(
+        f"fused/{p}" for p in ("run", "state_build", "dispatch",
+                               "device_wait", "collect"))
+    (row,) = rows
+    want = eng.run_row()
+    assert row["rounds"] == res.rounds == want["rounds"]
+    assert row["lane_iters"] == want["lane_iters"]
+    assert row["state_reused"] == 1 and row["owner"] == want["owner"]
+    assert row["collect"] == pytest.approx(want["collect"])
+    with pytest.raises(SystemExit, match="no operation ran on a TPU"):
+        ti.reduce(path)
+
+
 @pytest.mark.parametrize("skew_us", [-1500, 0, 1700])
 def test_the_device_clocks_offset_is_read_off_the_waits(skew_us):
     """Ten 3 ms programs 33 ms apart on a device clock that is ``skew``

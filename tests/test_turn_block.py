@@ -290,13 +290,18 @@ def test_read_egress_returns_the_first_count_rows(hybrid_engine, which):
 #: ``lanes.gossip_elides``; one more scalar, ``gossip_elided``, in the
 #: carry): both gossip pins are of PR 47's own tree (ea1881e1... and
 #: 7462edac... at its parent, 64be6b1); the other two did not move.
+#: PR 50 changed every program SOME lane of which runs an active model on
+#: purpose (the loop ledger: two more leaves of the carry, ``lanes.LaneState.
+#: loop_hist`` and ``loop_acc``): the three pins below are of PR 50's own
+#: tree (f2ce5c73..., 6b3203eb... and c44fe83e... at its parent, aca1e6f);
+#: the passive mesh, which carries no ledger, did not move.
 PARENT_TEXT = {
     "gossip":
-        "f2ce5c73086202e82baf042acc584e866dc4b5b07b723ac2bd0115710526d094",
+        "0d0cf9b939eaea1b84bd260cb9c91f94a7eefab12c07f2b8c23911b1a8548a5d",
     "gossip_wan":
-        "6b3203eba2662edf65d51d42a898745aa4dfb0b29e397cf39e1bb2c1304bdcf4",
+        "b0be5fdf7347c2bfaa9d63e8f71d54ed8a8d80301eebf05a87d445cf6269e5b0",
     "routed_tcp_loss":
-        "c44fe83e54f6ad654aa44aaf33217edb896ea3d9e54883fa6cf172f66db1ac34",
+        "b11063dffcb96fc16a7c2cb5649168a445b5cf3125a3fe4b3dd6ea43e502a808",
     "sharded_passive_mesh":
         "ab2e7fc35e0fc7691f3c802bbc8dcd72fc1a0abfcb88407f476a165333343648",
 }

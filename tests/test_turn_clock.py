@@ -45,9 +45,13 @@ class Names:
 
     def __init__(self) -> None:
         self.seen: list[str] = []
+        self.rows: list[tuple] = []
 
-    def __call__(self, name: str):
-        self.seen.append(name)
+    def __call__(self, name: str, **stats):
+        if stats:  # a journal clock's closed row, handed to the trace
+            self.rows.append((name, stats))
+        else:
+            self.seen.append(name)
         return self
 
     def __enter__(self):
@@ -490,12 +494,13 @@ def test_the_fused_drivers_four_phases(tmp_path, obs):
     if obs:
         eng.obs = Recorder()
     eng.run(mode="device")
-    assert names.seen == ["fused/state_build", "fused/dispatch",
+    # a run is one turn of the clock: ``run`` is its residual
+    assert names.seen == ["fused/run", "fused/state_build", "fused/dispatch",
                           "fused/device_wait", "fused/collect"]
     ph = eng.clock.phase_s
-    assert tuple(ph) == tpu_engine.FUSED_PHASES
+    assert tuple(ph) == tpu_engine.FUSED_PHASES + (tpu_engine.RUN_PHASE,)
     assert all(v > 0 for v in ph.values())
-    assert not eng.clock.ring  # this driver opens no turn
+    assert len(eng.clock.ring) == 1
     if obs:
         walls = eng.obs.metrics.phase_wall_s()
         assert walls == {"state_build": ph["state_build"],
@@ -513,7 +518,9 @@ def test_the_step_driver_books_every_round_to_the_same_two_phases(tmp_path):
     rounds = res.rounds + 1  # the last call finds the run done
     assert names.seen.count("fused/dispatch") == rounds
     assert names.seen.count("fused/device_wait") == rounds
-    assert set(names.seen) == {f"fused/{p}" for p in tpu_engine.FUSED_PHASES}
+    assert set(names.seen) == {
+        f"fused/{p}"
+        for p in tpu_engine.FUSED_PHASES + (tpu_engine.RUN_PHASE,)}
     spans = eng.obs.metrics.report()["phases"]
     assert spans["device_turn"]["spans"] == spans["dispatch"]["spans"] == rounds
     waits = [e for e in eng.obs.tracer.events if e["cat"] == "device_turn"]
@@ -521,6 +528,118 @@ def test_the_step_driver_books_every_round_to_the_same_two_phases(tmp_path):
     assert all("active" in e["args"] for e in waits)
     assert eng.obs.metrics.phase_wall_s()["device_turn"] == pytest.approx(
         eng.clock.phase_s["device_wait"], rel=1e-9)
+
+
+# -- 5. one journal row a run ------------------------------------------------------
+
+
+def _tiles(row, phases) -> None:
+    """The phases, ``run`` among them, sum to the row's wall."""
+    assert sum(getattr(row, p) for p in phases) == pytest.approx(
+        row.t_end - row.t_start, rel=1e-9)
+    assert all(getattr(row, p) >= 0 for p in phases)
+
+
+@pytest.mark.parametrize("mode", ["device", "step"])
+def test_a_run_leaves_one_row_in_the_ring_and_in_the_journal(tmp_path, mode):
+    journal = clock_mod.journal["fused"]
+    eng = tpu_engine.TpuEngine(_mesh_cfg(tmp_path), log_capacity=0)
+    before = len(journal)
+    first = eng.run(mode=mode)
+    again = eng.run(mode=mode)
+    assert len(eng.clock.ring) == 2 == len(journal) - before
+    rows = list(journal)[-2:]
+    assert rows == list(eng.clock.ring)
+    assert [r.turn for r in rows] == [0, 1]
+    assert rows[0].owner == rows[1].owner
+    for row, res in zip(rows, (first, again)):
+        _tiles(row, eng.clock.phases)
+        assert row.run > 0
+        assert row.mode == int(mode == "step") and row.segments == 1
+        assert row.rounds == res.rounds
+        assert row.lane_iters == res.counters["lane_iters"]
+        assert row.lanes == 64 and row.pops_per_iter == 2
+        assert row.log_capacity == 0
+    assert [r.state_reused for r in rows] == [0, 1]
+    # ints all, under half a kilobyte
+    assert all(isinstance(getattr(rows[0], n), int)
+               for n in tpu_engine.FUSED_NOTES)
+    assert len(rows[0]) * 8 < 512
+    assert eng.run_row() == rows[1]._asdict()
+
+
+def test_two_engines_rows_are_apart_by_owner(tmp_path):
+    journal = clock_mod.journal["fused"]
+    a = tpu_engine.TpuEngine(_mesh_cfg(tmp_path), log_capacity=0)
+    b = tpu_engine.TpuEngine(_mesh_cfg(tmp_path), log_capacity=0)
+    a.run(mode="device")
+    b.run(mode="device")
+    a.run(mode="device")
+    last = list(journal)[-3:]
+    assert last[0].owner == last[2].owner != last[1].owner
+    assert [r.turn for r in last] == [0, 0, 1]
+
+
+def test_a_faulted_run_is_one_row_of_as_many_dispatches_as_segments():
+    import test_phold_mesh
+
+    eng = tpu_engine.TpuEngine(test_phold_mesh._faulted_cfg("tpu"),
+                               log_capacity=0)
+    names = Names()
+    eng.clock.use_annotator(names)
+    eng.run(mode="device")
+    segments = eng.lane_plane["fault_segments"]
+    assert segments > 1
+    assert names.seen[0] == "fused/run" and names.seen.count("fused/run") == 1
+    assert names.seen.count("fused/dispatch") == segments
+    assert names.seen.count("fused/fault_swap") == segments - 1
+    assert len(eng.clock.ring) == 1
+    row = eng.clock.ring[-1]
+    assert row is clock_mod.journal["fused"][-1]
+    assert row.segments == segments and row.fault_swap > 0
+    # under a profiler session the row rides the trace too
+    assert names.rows == [("fused/row", row._asdict())]
+    assert eng.clock.phases == (
+        tpu_engine.FUSED_PHASES
+        + (tpu_engine.FAULT_PHASE, tpu_engine.RUN_PHASE))
+    _tiles(row, eng.clock.phases)
+
+
+def test_a_run_that_raises_still_closes_its_row():
+    import test_phold_mesh
+
+    eng = tpu_engine.TpuEngine(
+        test_phold_mesh._cfg(64, 4, 5, tpu_cross_capacity=1),
+        log_capacity=0)
+    with pytest.raises(RuntimeError, match="capacity overflow"):
+        eng.run(mode="device")
+    assert len(eng.clock.ring) == 1
+    _tiles(eng.clock.ring[-1], eng.clock.phases)
+    # and the next run opens its own turn
+    with pytest.raises(RuntimeError, match="capacity overflow"):
+        eng.run(mode="device")
+    assert len(eng.clock.ring) == 2
+
+
+def test_the_journal_is_bounded_and_only_a_journal_clock_writes_it():
+    plain = _clock()
+    with plain.turn():
+        pass
+    assert "t" not in clock_mod.journal
+    assert "owner" not in plain.ring[-1]._fields
+    c = _clock(journal=True)
+    other = _clock(journal=True)
+    for _ in range(RING_TURNS + 5):
+        with c.turn():
+            pass
+    with other.turn():
+        pass
+    rows = clock_mod.journal["t"]
+    assert len(rows) == RING_TURNS == rows.maxlen
+    assert rows[-1] is other.ring[-1] and rows[-2] is c.ring[-1]
+    assert rows[-1].owner != rows[-2].owner
+    assert rows[0].turn == 6  # the oldest rows went
+    del clock_mod.journal["t"]
 
 
 def test_trace_annotation_is_imported_in_one_module():
